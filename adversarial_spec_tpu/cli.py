@@ -347,8 +347,7 @@ def create_parser() -> argparse.ArgumentParser:
         type=int,
         default=None,  # None = inherit ADVSPEC_GAMMA (default 8)
         help="Draft length per speculative step (>= 1; default 8, "
-        "ADVSPEC_GAMMA sets the process default; the tpu_ladder gamma "
-        "sweep measures the on-chip crossover)",
+        "ADVSPEC_GAMMA sets the process default)",
     )
 
     d.add_argument(
@@ -1130,6 +1129,11 @@ def run_critique(args: argparse.Namespace) -> int:
     # Observability report: flight-recorder occupancy, event mix, host
     # syncs by reason, retrace watch (unexpected recompiles flagged).
     perf["obs"] = obs.snapshot()
+    # What jax ran on in this process and what its compiler did
+    # (persistent-cache hits vs compiles); None on a mock-only round.
+    from adversarial_spec_tpu.utils import jaxenv
+
+    perf["device"] = jaxenv.device_report()
     if args.metrics_out:
         obs.write_metrics(args.metrics_out)
         _err(f"metrics written to {args.metrics_out}")

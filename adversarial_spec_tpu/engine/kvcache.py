@@ -27,6 +27,7 @@ engine routes its prefix-cache accounting through ``PageAllocator``).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import numpy as np
@@ -335,6 +336,30 @@ def init_page_pool(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _row_index(layers, pool_array, page_ids, offsets):
+    """Index tuple addressing pool[l, page_ids[b, s], h, offsets[b, s]]
+    for every (l, b, h, s) — the dense cache's [L, B, Hkv, S] axes.
+    ``layers`` is a scalar layer (the L axis drops) or an [L, 1, 1, 1]
+    column of layers.
+
+    Every leading pool axis is indexed, so a scatter or gather through
+    it moves whole D-rows, contiguous in the pool's heads-major layout.
+    Leaving the layer or head axis as a slice widens the window per
+    token, and XLA:TPU then re-lays the WHOLE pool out around the
+    operation: a pool-sized temporary a pool sized to the chip cannot
+    pay (models/transformer.py:forward_paged_decode scatters the same
+    way for the same reason).
+    """
+    import jax.numpy as jnp
+
+    return (
+        layers,
+        jnp.asarray(page_ids)[:, None, :],
+        jnp.arange(pool_array.shape[2])[None, :, None],
+        jnp.asarray(offsets)[:, None, :],
+    )
+
+
 def write_tokens(
     pool: dict[str, jnp.ndarray],
     k_new: jnp.ndarray,  # [L, B, Hkv, S, D] — heads-major cache layout
@@ -346,36 +371,22 @@ def write_tokens(
 ) -> dict[str, jnp.ndarray]:
     """Scatter freshly computed K/V into their pages (vectorized).
 
+    The pool is DONATED and updated in place — callers rebind it
+    (``pool = write_tokens(pool, ...)``). Dispatched eagerly, every
+    ``.at[].set`` would instead allocate a second pool beside the first.
+
     Quantized pools take the matching scale slices (both or neither) —
     the same [L, B, Hkv, S, 1] layout the dense int8 cache stores.
     """
-    import jax.numpy as jnp
-
-    L, B, H, S, D = k_new.shape
-    pid = jnp.asarray(page_ids).reshape(-1)  # [B*S]
-    off = jnp.asarray(offsets).reshape(-1)
-
-    def flat(x):  # [L, B, H, S, *] → [B*S, L, H, *] (token-major updates)
-        return jnp.transpose(x, (1, 3, 0, 2, 4)).reshape(
-            B * S, L, H, x.shape[-1]
-        )
-
-    # pool[l, pid[n], :, off[n]] = new[n, l] for every layer l, token n.
-    # Advanced indices (pid at dim 1, off at dim 3) are separated by the
-    # head slice, so the token axis lands in front of the result — the
-    # updates are built token-major to match.
-    out = {
-        "k": pool["k"].at[:, pid, :, off].set(flat(k_new)),
-        "v": pool["v"].at[:, pid, :, off].set(flat(v_new)),
-    }
+    new = {"k": k_new, "v": v_new}
     if "ks" in pool:
         if ks_new is None or vs_new is None:
             raise ValueError(
                 "quantized pool requires ks_new/vs_new scale slices"
             )
-        out["ks"] = pool["ks"].at[:, pid, :, off].set(flat(ks_new))
-        out["vs"] = pool["vs"].at[:, pid, :, off].set(flat(vs_new))
-    return out
+        new.update(ks=ks_new, vs=vs_new)
+    write, _ = _pool_jits()
+    return write(pool, new, page_ids, offsets)
 
 
 def read_tokens(
@@ -390,23 +401,46 @@ def read_tokens(
     a cached prefix's KV into a fresh admission's dense prefill cache
     (engine/scheduler.py) so only the suffix runs through the model.
     """
+    _, read = _pool_jits()
+    return read(pool, page_ids, offsets)
+
+
+@functools.cache
+def _pool_jits():
+    """(write, read) as jitted programs, built on first use: jax stays
+    a lazy import here (see the module docstring)."""
+    import jax
     import jax.numpy as jnp
 
-    B, S = np.asarray(page_ids).shape
-    pid = jnp.asarray(page_ids).reshape(-1)  # [B*S]
-    off = jnp.asarray(offsets).reshape(-1)
+    def write(pool, new, page_ids, offsets):
+        # One scatter per layer: a single scatter over every (layer,
+        # token, head) row compiles in time proportional to the token
+        # count on XLA:TPU (25 s at 5k tokens against 0.2 s this way).
+        def one_layer(layer, pool):
+            return {
+                name: pool[name]
+                .at[_row_index(layer, pool[name], page_ids, offsets)]
+                .set(new[name][layer])
+                for name in pool
+            }
 
-    def gather(x):
-        # x[l, pid[n], :, off[n]] → [B*S, L, H, *] (token axis in front,
-        # same advanced-indexing rule write_tokens relies on), then back
-        # to the cache layout [L, B, H, S, *].
-        g = x[:, pid, :, off]
-        L, H = x.shape[0], x.shape[2]
-        return jnp.transpose(
-            g.reshape(B, S, L, H, x.shape[-1]), (2, 0, 3, 1, 4)
-        )
+        n_layers = pool["k"].shape[0]
+        return jax.lax.fori_loop(0, n_layers, one_layer, pool)
 
-    return {k: gather(v) for k, v in pool.items()}
+    def read(pool, page_ids, offsets):
+        return {
+            name: x[
+                _row_index(
+                    jnp.arange(x.shape[0])[:, None, None, None],
+                    x,
+                    page_ids,
+                    offsets,
+                )
+            ]
+            for name, x in pool.items()
+        }
+
+    return jax.jit(write, donate_argnames=("pool",)), jax.jit(read)
 
 
 def token_positions_to_pages(

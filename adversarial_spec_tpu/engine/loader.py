@@ -323,6 +323,53 @@ def load_hf_checkpoint(
     return params
 
 
+def _random_params(
+    cfg: ModelConfig, dtype, seed: int, quant: str, mesh
+) -> Params:
+    """Synthetic weights, built ONE WEIGHT AT A TIME on the device.
+
+    Each weight is its own jitted program: it traces the whole
+    ``init_params`` (+ quantization), returns one weight, and XLA drops
+    the rest as dead code — so the values are ``init_params``' own for
+    the same seed, the weight is born in its target sharding, and
+    neither the full-precision tree nor a host copy ever exists. At 7B
+    widths the bf16 tree alone is 14.5 GB; a 16 GB chip holds the int8
+    model only if it never sees that tree.
+    """
+    from adversarial_spec_tpu.ops import quant as quant_mod
+    from adversarial_spec_tpu.parallel.sharding import param_shardings
+
+    def build(key):
+        p = init_params(key, cfg, dtype=dtype)
+        return quant_mod.quantize_params(p, fmt=quant) if quant else p
+
+    def is_weight(node) -> bool:  # a quantized {q|q4, scale} pair is ONE weight
+        return quant_mod.is_quantized(node) or quant_mod.is_quantized_int4(
+            node
+        )
+
+    key = jax.random.key(seed)
+    shapes = jax.eval_shape(build, key)
+    shardings = (
+        param_shardings(mesh, shapes)
+        if mesh is not None
+        else jax.tree.map(lambda _: None, shapes)
+    )
+
+    def one(path, _shape, sharding):
+        def pick(k):
+            node = build(k)
+            for entry in path:
+                node = node[entry.key]
+            return node
+
+        return jax.jit(pick, out_shardings=sharding)(key)
+
+    return jax.tree_util.tree_map_with_path(
+        one, shapes, shardings, is_leaf=is_weight
+    )
+
+
 def materialize_params(
     checkpoint: str,
     family: str,
@@ -330,10 +377,15 @@ def materialize_params(
     dtype: jnp.dtype = jnp.bfloat16,
     seed: int = 0,
     max_seq_len: int = 0,
-    device_put=None,
+    mesh=None,
     quant: str = "",
+    n_layers: int = 0,
 ) -> tuple[Params, ModelConfig]:
     """checkpoint == "random" → synthetic init; else HF safetensors dir.
+
+    ``mesh``: place every tensor straight into its sharding on this
+    mesh (parallel/sharding.py rules) as it is built or read; None =
+    the default device.
 
     ``quant`` ("int8" / "int4", ops/quant.py) quantizes the matmul
     weights AT materialization, so every consumer (native-cache writer,
@@ -342,17 +394,17 @@ def materialize_params(
     RAM (engine/weightres.py), at a half/quarter of the bf16 bytes.
     """
     from adversarial_spec_tpu.ops.quant import quantize_params
+    from adversarial_spec_tpu.parallel.sharding import make_device_put
 
-    cfg = get_config(family, size, max_seq_len=max_seq_len)
+    cfg = get_config(family, size, max_seq_len, n_layers)
     if checkpoint == "random":
-        params = init_params(jax.random.key(seed), cfg, dtype=dtype)
-        if device_put is not None:
-            params = jax.tree_util.tree_map_with_path(
-                lambda path, x: device_put(path, np.asarray(x)), params
-            )
-        return (quantize_params(params, fmt=quant) if quant else params), cfg
+        return _random_params(cfg, dtype, seed, quant, mesh), cfg
     params = load_hf_checkpoint(
-        checkpoint, cfg, family, dtype=dtype, device_put=device_put
+        checkpoint,
+        cfg,
+        family,
+        dtype=dtype,
+        device_put=make_device_put(mesh, dtype) if mesh is not None else None,
     )
     if quant:
         params = quantize_params(params, fmt=quant)

@@ -45,6 +45,10 @@ class ModelSpec:
     # 0 = keep the model config's native context length (e.g. 131072 for
     # llama-3.2 1b/3b); nonzero overrides it.
     max_seq_len: int = 0
+    # 0 = the config's published depth; nonzero cuts the layer stack to
+    # fit a chip (widths are never cut — a depth-cut model keeps every
+    # matmul and cache shape of the published one).
+    n_layers: int = 0
     # "" = full precision; "int8" / "int4" = weight-only quantization
     # (ops/quant.py QUANT_FORMATS) — int4 packs two weights per byte,
     # the format that fits a multi-model opponent pool resident.

@@ -838,7 +838,6 @@ def sharded_scheduler_decode_chunk(
     Sampling keys are folded with the device index so rows on different
     devices draw independent randomness.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import DP
@@ -882,7 +881,7 @@ def sharded_scheduler_decode_chunk(
             **static_kw,
         )
 
-    return shard_map(
+    return jax.shard_map(
         local_chunk,
         mesh=mesh,
         in_specs=(
@@ -902,7 +901,7 @@ def sharded_scheduler_decode_chunk(
             P(),
         ),
         out_specs=(pool_spec, rows, rows, rows, rows, rows),
-        check_rep=False,
+        check_vma=False,
     )(
         params,
         pool,
@@ -1296,6 +1295,10 @@ class ContinuousBatcher:
                 f"prompt (bucketed) + budget = {total} tokens exceeds the "
                 f"model context {self.cfg.max_seq_len}"
             )
+        # Pages back the REAL length under the prefix cache's canonical
+        # layout, the left-padded bucket otherwise (_start_admission*).
+        if self.prefix_cache is not None:
+            total = len(req.prompt_ids) + req.max_new_tokens
         if total > self.capacity_tokens:
             raise ValueError(
                 f"request needs {total} tokens but the pool holds only "

@@ -15,7 +15,7 @@ switchboard both consult, following the established
   actionable message the old import-time check in speculative.py gave,
   instead of failing deep inside a traced accept loop. Unlike the old
   import-time constant, ``configure(gamma=...)`` retunes a live process
-  (tests, the tpu_ladder γ sweep) without a reimport.
+  (tests, a γ sweep) without a reimport.
 - **stats**: per-round speculation counters both real engines and the
   mock's deterministic CPU accounting record into. ``reset`` zeroes in
   place so engines holding a reference keep counting into the same

@@ -70,8 +70,8 @@ from adversarial_spec_tpu.models.transformer import Cache, Params, forward
 
 # Draft length per speculative step. Larger γ emits more tokens per
 # verification forward when drafts match (revision-heavy [SPEC] output)
-# but wastes a γ+1-wide forward when they miss; 8 is the prior, the
-# ladder's gamma sweep (tpu_ladder.py) measures the crossover on chip.
+# but wastes a γ+1-wide forward when they miss; 8 is the prior (the
+# on-chip crossover: not measured).
 # The knob LIVES in engine/spec.py now (``ADVSPEC_GAMMA`` / ``--gamma``,
 # reconfigurable per round without a reimport); this module-level value
 # is the import-time snapshot kept for callers that treat γ as a
@@ -532,7 +532,6 @@ def speculative_decode_steps_dp(
     weights (replicated), so no manual tp collectives are needed. The
     engine gates on ``mesh.size == mesh.shape[DP]``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import DP
@@ -570,14 +569,14 @@ def speculative_decode_steps_dp(
             jax.lax.psum(n_row_iters, DP),
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_spec, cache_spec, *rowspec,
                   P(), P(), P(), P(), P()),
         out_specs=(cache_spec, rowspec[1], rowspec[2], rowspec[4],
                    rowspec[5], rowspec[6], P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(params, cache, *row_arrays, stop_at, eos_ids, key, temperature,
       top_p)
 
@@ -600,7 +599,6 @@ def rowwise_decode_steps_dp(
     **static_kw,
 ):
     """``rowwise_decode_steps`` with rows sharded over a dp-only mesh."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import DP
@@ -620,13 +618,13 @@ def rowwise_decode_steps_dp(
             stop_at_l, eos_l, key_l, temp_l, tp_l, **static_kw,
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_spec, cache_spec, *rowspec,
                   P(), P(), P(), P(), P()),
         out_specs=(cache_spec, rowspec[0], rowspec[2], rowspec[3],
                    rowspec[4]),
-        check_rep=False,
+        check_vma=False,
     )(params, cache, *row_arrays, stop_at, eos_ids, key, temperature,
       top_p)

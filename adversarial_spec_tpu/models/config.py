@@ -127,6 +127,19 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
         rope_theta=10000.0,
         sliding_window=128,
     ),
+    # Toy widths with 7b's head layout in miniature (GQA 2:1, no window)
+    # and 4 KV heads, so a tp=4 mesh has a head per device to shard —
+    # what chip_smoke.py --chips 4 rehearses on four virtual CPU devices.
+    ("mistral", "tiny-tp4"): ModelConfig(
+        vocab_size=512,
+        dim=256,
+        n_layers=2,
+        n_heads=8,
+        n_kv_heads=4,
+        head_dim=32,
+        ffn_dim=512,
+        rope_theta=1000000.0,
+    ),
     ("mistral", "7b"): ModelConfig(
         vocab_size=32768,  # v0.3 extended vocabulary (v0.2 was 32000)
         dim=4096,
@@ -235,7 +248,11 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
 }
 
 
-def get_config(family: str, size: str, max_seq_len: int = 0) -> ModelConfig:
+def get_config(
+    family: str, size: str, max_seq_len: int = 0, n_layers: int = 0
+) -> ModelConfig:
+    """The named config; nonzero ``max_seq_len`` / ``n_layers`` override
+    its context length / depth (registry ModelSpec fields)."""
     key = (family, size)
     if key not in CONFIGS:
         known = ", ".join(f"{f}/{s}" for f, s in sorted(CONFIGS))
@@ -243,4 +260,6 @@ def get_config(family: str, size: str, max_seq_len: int = 0) -> ModelConfig:
     cfg = CONFIGS[key]
     if max_seq_len:
         cfg = replace(cfg, max_seq_len=max_seq_len)
+    if n_layers:
+        cfg = replace(cfg, n_layers=n_layers)
     return cfg

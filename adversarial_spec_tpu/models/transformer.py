@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from adversarial_spec_tpu.models.config import ModelConfig
-from adversarial_spec_tpu.ops.quant import matmul
+from adversarial_spec_tpu.ops.quant import div_const, matmul
 from adversarial_spec_tpu.ops.rope import apply_rope, rope_angles
 
 Params = dict[str, Any]
@@ -83,7 +83,7 @@ def init_params(
 
     def dense(key, shape, fan_in):
         w = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
-        return (w / math.sqrt(fan_in)).astype(dtype)
+        return div_const(w, math.sqrt(fan_in)).astype(dtype)
 
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
     QD = cfg.n_heads * cfg.head_dim
@@ -682,36 +682,48 @@ def forward_paged_decode(
 
     flat_page = write_page.reshape(-1)
     flat_off = write_off.reshape(-1)
+    heads = jnp.arange(cfg.n_kv_heads)
 
-    def layer_body(x, scanned):
-        lp, layer_id, pool_l = scanned
-        k_pages, v_pages = pool_l["k"], pool_l["v"]
-        ks_pages = pool_l.get("ks")
-        vs_pages = pool_l.get("vs")
+    def layer_body(carry, scanned):
+        # The WHOLE pool rides the scan carry and every layer updates and
+        # reads it in place, addressed by layer index. Scanning it as
+        # per-layer xs/ys instead makes XLA slice one layer's pages out
+        # (a copy), restack them into a second pool-sized buffer, and
+        # copy that back over the carried pool every step: temporaries
+        # of twice the pool, which a pool sized to the chip cannot pay.
+        x, pool = carry
+        lp, layer_id = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
         q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
 
-        # Pages are heads-major [n_pages, Hkv, page_size, D]; advanced
-        # indices (write_page at dim 0, write_off at dim 2) separated by
-        # the head slice put the flattened (row, span) axis first →
-        # update [B·S, Hkv, D]. One scatter per layer regardless of span
-        # width (rejected-draft targets are the trash page, never read).
+        # Pages are heads-major [L, n_pages, Hkv, page_size, D]. The
+        # scatter addresses (layer, page, head, offset) and moves whole
+        # D-rows — contiguous in that layout, so XLA keeps the pool in
+        # the layout the attention kernels require. (A [Hkv, D] update
+        # window per token makes it re-lay the WHOLE pool out
+        # token-major and convert it back for every kernel call.) One
+        # scatter per pool array per layer regardless of span width
+        # (rejected-draft targets are the trash page, never read).
         kf = k.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
         vf = v.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
         if quant_kv:
             kq, ks = _quantize_kv(kf)  # [B·S, Hkv, D], [B·S, Hkv, 1]
             vq, vs = _quantize_kv(vf)
-            k_pages = k_pages.at[flat_page, :, flat_off].set(kq)
-            v_pages = v_pages.at[flat_page, :, flat_off].set(vq)
-            ks_pages = ks_pages.at[flat_page, :, flat_off].set(ks)
-            vs_pages = vs_pages.at[flat_page, :, flat_off].set(vs)
+            new_kv = {"k": kq, "v": vq, "ks": ks, "vs": vs}
         else:
-            k_pages = k_pages.at[flat_page, :, flat_off].set(
-                kf.astype(k_pages.dtype)
-            )
-            v_pages = v_pages.at[flat_page, :, flat_off].set(
-                vf.astype(v_pages.dtype)
-            )
+            new_kv = {
+                "k": kf.astype(pool["k"].dtype),
+                "v": vf.astype(pool["v"].dtype),
+            }
+        pool = {
+            name: pool[name]
+            .at[layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]]
+            .set(val)
+            for name, val in new_kv.items()
+        }
+        qkw = (
+            dict(k_scale=pool["ks"], v_scale=pool["vs"]) if quant_kv else {}
+        )
 
         start = _layer_window_start(
             cfg, layer_id, bounds[..., 0], q_pos
@@ -726,9 +738,6 @@ def forward_paged_decode(
             )
 
             layer_bounds = jnp.stack([start[:, 0], end[:, 0]], axis=1)
-            qkw = (
-                dict(k_scale=ks_pages, v_scale=vs_pages) if quant_kv else {}
-            )
             if not single_device:
                 from adversarial_spec_tpu.parallel.mesh import DP as _DPAX
 
@@ -743,11 +752,12 @@ def forward_paged_decode(
                 )
                 out = wrapper(
                     q[:, 0],
-                    k_pages,
-                    v_pages,
+                    pool["k"],
+                    pool["v"],
                     page_table,
                     layer_bounds,
                     mesh,
+                    layer_id,
                     attn_softcap=cfg.attn_softcap,
                     scale=cfg.attn_scale,
                     interpret=pallas_interpret,
@@ -756,13 +766,14 @@ def forward_paged_decode(
             else:
                 out = paged_decode_attention(
                     q[:, 0],
-                    k_pages,
-                    v_pages,
+                    pool["k"],
+                    pool["v"],
                     page_table,
                     layer_bounds,
                     attn_softcap=cfg.attn_softcap,
                     scale=cfg.attn_scale,
                     interpret=pallas_interpret,
+                    layer=layer_id,
                     **qkw,
                 )[:, None]
         elif use_pallas and single_device:
@@ -775,43 +786,40 @@ def forward_paged_decode(
             # [start, end) window (in-span causality).
             out = paged_decode_attention_mq(
                 q,
-                k_pages,
-                v_pages,
+                pool["k"],
+                pool["v"],
                 page_table,
                 start,
                 end,
                 attn_softcap=cfg.attn_softcap,
                 scale=cfg.attn_scale,
                 interpret=pallas_interpret,
-                **(
-                    dict(k_scale=ks_pages, v_scale=vs_pages)
-                    if quant_kv
-                    else {}
-                ),
+                layer=layer_id,
+                **qkw,
             )
         else:
             # Gather reference path: page table → dense [B, Hkv, T, D]
             # (densified ONCE per row — the whole span reads it).
             safe_table = jnp.maximum(page_table, 0)
 
-            def to_dense(pages):  # [B, P, Hkv, page, *] → [B, Hkv, T, *]
-                g = pages[safe_table]
+            def to_dense(pages):  # [L, n_pages, Hkv, page, *] → [B, Hkv, T, *]
+                g = pages[layer_id, safe_table]  # [B, P, Hkv, page, *]
                 return jnp.swapaxes(g, 1, 2).reshape(
                     B, cfg.n_kv_heads, -1, pages.shape[-1]
                 )
 
             if quant_kv:
                 k_dense = (
-                    to_dense(k_pages).astype(jnp.float32)
-                    * to_dense(ks_pages)
+                    to_dense(pool["k"]).astype(jnp.float32)
+                    * to_dense(pool["ks"])
                 ).astype(x.dtype)
                 v_dense = (
-                    to_dense(v_pages).astype(jnp.float32)
-                    * to_dense(vs_pages)
+                    to_dense(pool["v"]).astype(jnp.float32)
+                    * to_dense(pool["vs"])
                 ).astype(x.dtype)
             else:
-                k_dense = to_dense(k_pages)
-                v_dense = to_dense(v_pages)
+                k_dense = to_dense(pool["k"])
+                v_dense = to_dense(pool["v"])
             T = k_dense.shape[2]
             slot = jnp.arange(T)[None, None, :]
             # <= 0 is unmapped: page 0 is the reserved trash page (callers
@@ -834,18 +842,14 @@ def forward_paged_decode(
                 scale=cfg.attn_scale,
             )
         x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm)
-        new_l = {"k": k_pages, "v": v_pages}
-        if quant_kv:
-            new_l.update(ks=ks_pages, vs=vs_pages)
-        return x, new_l
+        return (x, pool), None
 
-    # The pool dict scans as a pytree (same pattern as forward()'s
-    # cache): one scan serves both the raw and int8 layouts. Always a
-    # decode step here (S=1) → always unrolled for weight-DMA pipelining.
-    x, new_pool = jax.lax.scan(
+    # Always a decode step here (short S) → always unrolled for
+    # weight-DMA pipelining.
+    (x, new_pool), _ = jax.lax.scan(
         layer_body,
-        x,
-        (params["layers"], layer_ids, pool),
+        (x, pool),
+        (params["layers"], layer_ids),
         unroll=_DECODE_UNROLL,
     )
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)

@@ -54,10 +54,10 @@ _SUBLANE = 8
 _TILE_VMEM_BUDGET = 1 << 20
 
 
-# Operator/harvest override for the KV tile length: the VMEM-budget
-# heuristic below picks the largest fitting block, but the DMA-size vs
-# grid-parallelism balance is an empirical question the ladder's blockt
-# sweep (tpu_ladder.py) answers on chip. 0 = auto.
+# Operator override for the KV tile length: the VMEM-budget heuristic
+# below picks the largest fitting block, but the DMA-size vs
+# grid-parallelism balance is an empirical question only a chip run
+# answers (not measured). 0 = auto.
 _BLOCK_T_OVERRIDE = int(os.environ.get("ADVSPEC_BLOCK_T", "0"))
 _warned_block_t: set[int] = set()
 
@@ -374,7 +374,6 @@ def decode_attention_tp(
     dp/tp (sp during decode) see replicated operands and compute
     identical local results.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import DP, TP
@@ -400,12 +399,12 @@ def decode_attention_tp(
         operands += [k_scale, v_scale]
     else:
         fn = kernel
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(DP, TP, None),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 

@@ -49,9 +49,26 @@ from adversarial_spec_tpu.ops.flash_common import flash_update_heads
 _SUBLANE = 8
 
 
+def _layered(layer, *pages):
+    """Normalize the pool operands to the layer-stacked form
+    [L, n_pages, Hkv, page_size, *] plus an int32[1] layer index.
+
+    ``layer`` given: the operands ARE the whole pool and the kernel DMAs
+    pages of that layer straight out of it — the decode step hands the
+    pool over untouched instead of slicing (= copying) one layer's pages
+    per call. ``layer`` None: the operands are one layer's pages, viewed
+    as a one-layer pool.
+    """
+    if layer is None:
+        layer = 0
+        pages = tuple(x if x is None else x[None] for x in pages)
+    return jnp.asarray(layer, jnp.int32).reshape(1), pages
+
+
 def _paged_attn_kernel(
     bounds_ref,  # SMEM [B, 2]: (start, end) token window per row
     table_ref,  # SMEM [B, P]: physical page id per (row, logical page)
+    layer_ref,  # SMEM [1]: pool layer (consumed by the index_maps only)
     q_ref,  # VMEM [1, Hkv, G8, D]
     k_ref,  # VMEM [1, Hkv, page, D] — page slab selected by index_map
     v_ref,  # VMEM [1, Hkv, page, D]
@@ -128,8 +145,13 @@ def paged_decode_attention(
     interpret: bool = False,
     k_scale: jnp.ndarray | None = None,  # [n_pages, Hkv, page, 1] (int8)
     v_scale: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,  # int32 scalar: see _layered
 ) -> jnp.ndarray:
     """Fused paged decode attention. Returns [B, Hq, D].
+
+    With ``layer``, ``k_pages``/``v_pages`` (and the scales) are the
+    whole layer-stacked pool [L, n_pages, Hkv, page_size, *] and the
+    kernel reads that layer's pages in place.
 
     Page-table sentinel convention (shared with the jnp gather path in
     models/transformer.py:forward_paged_decode): physical page 0 is the
@@ -141,8 +163,11 @@ def paged_decode_attention(
     per-(token, head) symmetric scale pages; dequant happens inside the
     kernel on the VMEM-resident page.
     """
+    layer, (k_pages, v_pages, k_scale, v_scale) = _layered(
+        layer, k_pages, v_pages, k_scale, v_scale
+    )
     B, Hq, D = q.shape
-    Hkv, page_size = k_pages.shape[1], k_pages.shape[2]
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     P = page_table.shape[1]
     g = Hq // Hkv
     G8 = max(_SUBLANE, g)
@@ -153,10 +178,11 @@ def paged_decode_attention(
     if G8 != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - g), (0, 0)))
 
-    def page_map(b, p, bounds_ref, table_ref):
-        return (jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
+    def page_map(b, p, bounds_ref, table_ref, layer_ref):
+        return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
 
-    page_spec = pl.BlockSpec((1, Hkv, page_size, D), page_map)
+    # The layer dim is squeezed: the kernel sees [1, Hkv, page_size, *].
+    page_spec = pl.BlockSpec((None, 1, Hkv, page_size, D), page_map)
     in_specs = [
         pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
         page_spec,
@@ -164,7 +190,7 @@ def paged_decode_attention(
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, Hkv, page_size, 1), page_map)
+        scale_spec = pl.BlockSpec((None, 1, Hkv, page_size, 1), page_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
@@ -177,7 +203,7 @@ def paged_decode_attention(
             quantized=quantized,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, P),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
@@ -191,13 +217,14 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
-    )(bounds, page_table, *operands)
+    )(bounds, page_table, layer, *operands)
 
     return out[:, :, :g, :].reshape(B, Hq, D)
 
 
 def _paged_mq_attn_kernel(
     table_ref,  # SMEM [B, P]: physical page id per (row, logical page)
+    layer_ref,  # SMEM [1]: pool layer (consumed by the index_maps only)
     bounds_ref,  # VMEM [1, G8, 2]: per query-row [start, end). VMEM, not
     # SMEM scalar-prefetch: Mosaic only loads SCALARS from SMEM and this
     # kernel needs the whole per-query bounds vector (the _mq_attn_kernel
@@ -280,6 +307,7 @@ def paged_decode_attention_mq(
     interpret: bool = False,
     k_scale: jnp.ndarray | None = None,  # [n_pages, Hkv, page, 1] (int8)
     v_scale: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,  # int32 scalar: see _layered
 ) -> jnp.ndarray:
     """Multi-position fused paged attention. Returns [B, S, Hq, D].
 
@@ -293,8 +321,11 @@ def paged_decode_attention_mq(
     flatten paying the gather γ+1 times. Page-table sentinel convention
     unchanged: entries <= 0 are unmapped and masked.
     """
+    layer, (k_pages, v_pages, k_scale, v_scale) = _layered(
+        layer, k_pages, v_pages, k_scale, v_scale
+    )
     B, S, Hq, D = q.shape
-    Hkv, page_size = k_pages.shape[1], k_pages.shape[2]
+    Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     P = page_table.shape[1]
     g = Hq // Hkv
     rows = S * g
@@ -324,10 +355,10 @@ def paged_decode_attention_mq(
         bnd = jnp.pad(bnd, ((0, 0), (0, G8 - rows), (0, 0)))
         bnd = bnd.at[:, rows:, 0].set(T)
 
-    def page_map(b, p, table_ref):
-        return (jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
+    def page_map(b, p, table_ref, layer_ref):
+        return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
 
-    page_spec = pl.BlockSpec((1, Hkv, page_size, D), page_map)
+    page_spec = pl.BlockSpec((None, 1, Hkv, page_size, D), page_map)
     in_specs = [
         pl.BlockSpec((1, G8, 2), lambda b, p, *_: (b, 0, 0)),
         pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
@@ -336,7 +367,7 @@ def paged_decode_attention_mq(
     ]
     operands = [bnd, qg, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, Hkv, page_size, 1), page_map)
+        scale_spec = pl.BlockSpec((None, 1, Hkv, page_size, 1), page_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
@@ -349,7 +380,7 @@ def paged_decode_attention_mq(
             quantized=quantized,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, P),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
@@ -363,7 +394,7 @@ def paged_decode_attention_mq(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
-    )(page_table, *operands)
+    )(page_table, layer, *operands)
 
     out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
@@ -371,11 +402,12 @@ def paged_decode_attention_mq(
 
 def paged_decode_attention_dp_tp(
     q: jnp.ndarray,  # [B, Hq, D]
-    k_pages: jnp.ndarray,  # [n_pages, Hkv, page_size, D]
-    v_pages: jnp.ndarray,  # [n_pages, Hkv, page_size, D]
+    k_pages: jnp.ndarray,  # [L, n_pages, Hkv, page_size, D] — the pool
+    v_pages: jnp.ndarray,  # [L, n_pages, Hkv, page_size, D]
     page_table: jnp.ndarray,  # [B, P] GLOBAL physical ids (see contract)
     bounds: jnp.ndarray,  # [B, 2]
     mesh,
+    layer: jnp.ndarray,  # int32 scalar: which layer's pages to read
     attn_softcap: float = 0.0,
     scale: float | None = None,
     interpret: bool = False,
@@ -399,12 +431,11 @@ def paged_decode_attention_dp_tp(
     after the shift and stay masked; out-of-slice ids cannot occur by
     construction.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import DP, TP
 
-    n_pages = k_pages.shape[0]
+    n_pages = k_pages.shape[1]
     dp = mesh.shape[DP]
     local_pages = n_pages // dp
 
@@ -415,38 +446,36 @@ def paged_decode_attention_dp_tp(
         interpret=interpret,
     )
 
-    def fn(q_, k_, v_, t_, b_, *scales):
+    def fn(q_, k_, v_, t_, b_, layer_, *scales):
         base = jax.lax.axis_index(DP) * local_pages
-        t_local = t_ - base
-        if scales:
-            return kernel(
-                q_, k_, v_, t_local, b_,
-                k_scale=scales[0], v_scale=scales[1],
-            )
-        return kernel(q_, k_, v_, t_local, b_)
+        qkw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return kernel(q_, k_, v_, t_ - base, b_, layer=layer_, **qkw)
 
-    page_spec = P(DP, TP, None, None)
-    in_specs = [P(DP, TP, None), page_spec, page_spec, P(DP, None), P(DP, None)]
-    operands = [q, k_pages, v_pages, page_table, bounds]
+    page_spec = P(None, DP, TP, None, None)
+    in_specs = [
+        P(DP, TP, None), page_spec, page_spec, P(DP, None), P(DP, None), P(),
+    ]
+    operands = [q, k_pages, v_pages, page_table, bounds, layer]
     if k_scale is not None:
         in_specs += [page_spec, page_spec]
         operands += [k_scale, v_scale]
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(DP, TP, None),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 
 def paged_decode_attention_tp(
     q: jnp.ndarray,  # [B, Hq, D]
-    k_pages: jnp.ndarray,  # [n_pages, Hkv, page_size, D]
-    v_pages: jnp.ndarray,  # [n_pages, Hkv, page_size, D]
+    k_pages: jnp.ndarray,  # [L, n_pages, Hkv, page_size, D] — the pool
+    v_pages: jnp.ndarray,  # [L, n_pages, Hkv, page_size, D]
     page_table: jnp.ndarray,  # [B, P] GLOBAL physical ids
     bounds: jnp.ndarray,  # [B, 2]
     mesh,
+    layer: jnp.ndarray,  # int32 scalar: which layer's pages to read
     attn_softcap: float = 0.0,
     scale: float | None = None,
     interpret: bool = False,
@@ -466,7 +495,6 @@ def paged_decode_attention_tp(
     global-page-table layout has no per-device page locality (dp-local
     pools are the scheduler's sharded path, engine/scheduler.py).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from adversarial_spec_tpu.parallel.mesh import TP
@@ -477,26 +505,28 @@ def paged_decode_attention_tp(
         scale=scale,
         interpret=interpret,
     )
+    page_spec = P(None, None, TP, None, None)  # pool: Hkv over tp
     in_specs = [
         P(None, TP, None),  # q: heads over tp
-        P(None, TP, None, None),  # pages: Hkv over tp
-        P(None, TP, None, None),
+        page_spec,
+        page_spec,
         P(None, None),  # table: replicated
         P(None, None),  # bounds: replicated
+        P(),  # layer: replicated
     ]
-    operands = [q, k_pages, v_pages, page_table, bounds]
+    operands = [q, k_pages, v_pages, page_table, bounds, layer]
     if k_scale is not None:
-        fn = lambda q_, k_, v_, t_, b_, ks_, vs_: kernel(  # noqa: E731
-            q_, k_, v_, t_, b_, k_scale=ks_, v_scale=vs_
-        )
-        in_specs += [P(None, TP, None, None), P(None, TP, None, None)]
+        in_specs += [page_spec, page_spec]
         operands += [k_scale, v_scale]
-    else:
-        fn = kernel
-    return shard_map(
+
+    def fn(q_, k_, v_, t_, b_, layer_, *scales):
+        qkw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return kernel(q_, k_, v_, t_, b_, layer=layer_, **qkw)
+
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(None, TP, None),
-        check_rep=False,
+        check_vma=False,
     )(*operands)

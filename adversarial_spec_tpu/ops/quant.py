@@ -42,10 +42,19 @@ QUANTIZABLE = frozenset(
 # non-empty formats.
 
 
+def div_const(x: jnp.ndarray, c: float) -> jnp.ndarray:
+    """``x / c`` that stays a division when traced into a jitted
+    program. XLA rewrites divide-by-constant into a multiply by the
+    reciprocal, one ulp off the eager result; the barrier hides the
+    constant, so weights built one per program (engine/loader.py) are
+    bit-identical to the eagerly built tree the tests pin tokens on."""
+    return x / jax.lax.optimization_barrier(jnp.float32(c))
+
+
 def quantize_int8(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
     """Symmetric per-output-channel int8 over the contraction (-2) axis."""
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
+    scale = div_const(jnp.maximum(amax, 1e-8), 127.0)
     q = jnp.clip(
         jnp.round(w.astype(jnp.float32) / scale), -127, 127
     ).astype(jnp.int8)
@@ -87,7 +96,7 @@ def quantize_int4(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
     """Symmetric per-output-channel packed int4 over the contraction
     (-2) axis (range [-7, 7]: symmetric, so dequant is one multiply)."""
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 7.0
+    scale = div_const(jnp.maximum(amax, 1e-8), 7.0)
     q = jnp.clip(
         jnp.round(w.astype(jnp.float32) / scale), -7, 7
     ).astype(jnp.int8)

@@ -28,27 +28,6 @@ DP, TP, SP = "dp", "tp", "sp"
 MeshAxes = (DP, TP, SP)
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check=False):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (replication check spelled
-    ``check_vma``); older ones only have the experimental module
-    (``check_rep``). Callers that can't assume a pinned jax go through
-    this shim instead of picking one spelling.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
-    )
-
-
 def maybe_initialize_distributed() -> None:
     """Bring up the multi-host runtime when launched as one process per
     host. Safe no-op otherwise.
@@ -83,14 +62,7 @@ def maybe_initialize_distributed() -> None:
                 "set but JAX_COORDINATOR_ADDRESS is not; set all three"
             )
         return
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        already = is_init()
-    else:  # older jax: peek at the global client
-        from jax._src import distributed as _dist
-
-        already = _dist.global_state.client is not None
-    if already:
+    if jax.distributed.is_initialized():
         return
     num = os.environ.get("JAX_NUM_PROCESSES")
     pid = os.environ.get("JAX_PROCESS_ID")

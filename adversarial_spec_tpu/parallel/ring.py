@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from adversarial_spec_tpu.parallel.mesh import SP, compat_shard_map
+from adversarial_spec_tpu.parallel.mesh import SP
 
 
 def _block_attend(
@@ -221,9 +221,10 @@ def ring_attention(
         in_specs = (spec, spec, spec, P(None))
         args = (q, k, v, kv_start)
 
-    return compat_shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=spec,
+        check_vma=False,
     )(*args)

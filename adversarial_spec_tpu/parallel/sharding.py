@@ -110,12 +110,7 @@ def make_device_put(mesh: Mesh, dtype):
     )
 
     def put(path_names: tuple, arr):
-        # Callers pass either plain-string tuples (load_hf_checkpoint) or
-        # jax tree paths of DictKey entries (materialize_params' random
-        # branch) — normalize both, else every rule lookup misses and all
-        # params land replicated (OOM at 70B/tp=8).
-        name = getattr(path_names[-1], "key", path_names[-1])
-        spec = _PARAM_RULES.get(name, P())
+        spec = _PARAM_RULES.get(path_names[-1], P())
         if isinstance(arr, np.ndarray) and arr.dtype != np_dtype:
             arr = arr.astype(np_dtype)
         return jax.device_put(arr, NamedSharding(mesh, spec))

@@ -46,7 +46,7 @@ from adversarial_spec_tpu.models.transformer import (
     rms_norm,
 )
 from adversarial_spec_tpu.ops.rope import rope_angles
-from adversarial_spec_tpu.parallel.mesh import SP, TP, compat_shard_map
+from adversarial_spec_tpu.parallel.mesh import SP, TP
 from adversarial_spec_tpu.parallel.ring import ring_attention_local
 from adversarial_spec_tpu.parallel.sharding import param_sharding_rules
 
@@ -186,11 +186,12 @@ def sp_prefill(
 
     seq_spec = P(None, SP)
     cache_spec = P(None, None, TP, SP, None)  # [L, B, Hkv(tp), S(sp), D]
-    logits, k_all, v_all = compat_shard_map(
+    logits, k_all, v_all = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(seq_spec, P(None), _param_in_specs(params)),
         out_specs=(P(None, None), cache_spec, cache_spec),
+        check_vma=False,
     )(tokens, pad_lens, params)
     return logits, {"k": k_all, "v": v_all}
 

@@ -46,6 +46,7 @@ from adversarial_spec_tpu import serve as serve_mod
 from adversarial_spec_tpu.serve import driver, gate, protocol
 from adversarial_spec_tpu.serve.gate import EnginePump
 from adversarial_spec_tpu.serve.sched import ServeScheduler
+from adversarial_spec_tpu.utils import jaxenv
 
 
 # asyncio's default StreamReader limit is 64 KiB; a debate request
@@ -333,6 +334,10 @@ class ServeDaemon:
                     # scrapers and tools/load_replay.py see the same
                     # pressure the scheduler sheds on.
                     "pressure": self.sched.pressure_snapshot(),
+                    # What jax runs on in THIS process and what its
+                    # compiler did (None while only mock engines have
+                    # served: jax is never imported for them).
+                    "device": jaxenv.device_report(),
                     "uptime_s": round(time.monotonic() - self._t_start, 3),
                 },
             )

@@ -285,6 +285,45 @@ class TestPreflightConfig:
         assert not np.array_equal(np.asarray(a["embed"]), np.asarray(c["embed"]))
 
 
+class TestRandomCheckpointPerWeight:
+    """Random checkpoints are built one weight per jitted program, on
+    the device, already quantized and sharded (the full-precision tree
+    never exists — at 7B it would not fit the chip). Same seed, same
+    values as the eager whole-tree init the parity tests build."""
+
+    @pytest.mark.parametrize("family", ["llama", "mistral", "gemma2", "qwen2"])
+    @pytest.mark.parametrize("quant", ["", "int8", "int4"])
+    def test_bit_identical_to_the_whole_tree_init(self, family, quant):
+        import jax
+        import jax.numpy as jnp
+
+        from adversarial_spec_tpu.models.transformer import init_params
+        from adversarial_spec_tpu.ops.quant import quantize_params
+
+        got, cfg = materialize_params(
+            "random", family, "tiny", dtype=jnp.bfloat16, seed=5, quant=quant
+        )
+        want = init_params(jax.random.key(5), cfg, dtype=jnp.bfloat16)
+        if quant:
+            want = quantize_params(want, fmt=quant)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(
+                np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8)
+            )
+
+    def test_depth_cut_keeps_every_width(self):
+        """ModelSpec.n_layers cuts depth and nothing else."""
+        full, cfg = materialize_params("random", "mistral", "tiny")
+        cut, cut_cfg = materialize_params(
+            "random", "mistral", "tiny", n_layers=1
+        )
+        assert (cfg.n_layers, cut_cfg.n_layers) == (2, 1)
+        assert cut["layers"]["wq"].shape == (1,) + full["layers"]["wq"].shape[1:]
+        assert cut["embed"].shape == full["embed"].shape
+
+
 class TestHostRamBound:
     def test_peak_staging_is_one_stacked_param(self, tmp_path):
         """Pins the loader docstring's claim (engine/loader.py module

@@ -172,8 +172,8 @@ class TestContinuousBatcher:
 
 class TestPagedUnderDp:
     """Paged decode over a dp-sharded mesh: per-device page pools,
-    device-local tables, zero cross-device page traffic (VERDICT r1
-    item 4 — paged no longer excludes multi-device)."""
+    device-local tables, zero cross-device page traffic (paged no
+    longer excludes multi-device)."""
 
     @pytest.fixture(autouse=True)
     def _needs_8_devices(self):
@@ -554,7 +554,7 @@ class TestPagedUnderTp:
         dense decode path after reshard_cache_for_decode) — so paged
         tokens must reproduce single-device paged tokens. Exercises the
         sp_prefill → reshard → page-migration handoff (the 16k-context
-        config's paged decode, VERDICT r4 item 9)."""
+        config's paged decode)."""
         if len(jax.devices()) < 2:
             pytest.skip("requires 2 virtual devices")
         from adversarial_spec_tpu.parallel.mesh import make_mesh

@@ -154,8 +154,8 @@ class TestGoldenChatTemplates:
     """Golden parity: the engine's ``.format``-string CHAT_TEMPLATES vs
     the families' PUBLIC jinja chat templates rendered by transformers'
     OWN machinery (``render_jinja_template`` — the exact code
-    ``PreTrainedTokenizer.apply_chat_template`` calls). VERDICT r4
-    item 6: a silent template mismatch on real instruct checkpoints
+    ``PreTrainedTokenizer.apply_chat_template`` calls).
+    A silent template mismatch on real instruct checkpoints
     would degrade critique quality with no failing test — this pins it.
 
     The vendored .jinja fixtures (tests/fixtures/chat_templates/) are

@@ -1,6 +1,6 @@
-"""Repo tooling: harvest analysis (MIN_T recommendation, tuned-env
-extraction, bench.py's application of both), the graftlint static-
-analysis framework, and the mutation runner's generation invariants."""
+"""Repo tooling: bench.py's in-process device contract, the graftlint
+static-analysis framework, and the mutation runner's generation
+invariants."""
 
 import json
 import sys
@@ -11,154 +11,56 @@ import pytest
 REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
 
-from tools.crossover_report import (  # noqa: E402
-    load,
-    recommended_env,
-    recommended_min_t,
-)
 
+class TestBench:
+    """bench.py runs every mode in-process on the platform jax gives it:
+    no device probe, no measuring child, no CPU fallback."""
 
-def _steps(rows):
-    return {r["step"]: r for r in rows}
-
-
-class TestRecommendedMinT:
-    def test_kernel_wins_everywhere(self):
-        steps = _steps(
-            [
-                {"step": f"crossover_T{t}_kernel", "decode_tok_s": 500},
-                {"step": f"crossover_T{t}_xla", "decode_tok_s": 400},
-            ][i]
-            for t in (1280, 4096)
-            for i in (0, 1)
-        )
-        assert recommended_min_t(steps) == 0
-
-    def test_clean_crossover(self):
-        steps = _steps(
-            [
-                {"step": "crossover_T1280_kernel", "decode_tok_s": 380},
-                {"step": "crossover_T1280_xla", "decode_tok_s": 490},
-                {"step": "crossover_T4096_kernel", "decode_tok_s": 400},
-                {"step": "crossover_T4096_xla", "decode_tok_s": 300},
-                {"step": "crossover_T8192_kernel", "decode_tok_s": 280},
-                {"step": "crossover_T8192_xla", "decode_tok_s": 150},
-            ]
-        )
-        assert recommended_min_t(steps) == 4096
-
-    def test_kernel_never_wins(self):
-        steps = _steps(
-            [
-                {"step": "crossover_T1280_kernel", "decode_tok_s": 300},
-                {"step": "crossover_T1280_xla", "decode_tok_s": 490},
-                {"step": "crossover_T4096_kernel", "decode_tok_s": 200},
-                {"step": "crossover_T4096_xla", "decode_tok_s": 300},
-            ]
-        )
-        assert recommended_min_t(steps) == 1 << 31  # kernel off
-
-    def test_mid_loss_resets_suffix(self):
-        """kernel wins at 1280, loses at 4096, wins at 8192 → floor is
-        8192 (the clean winning suffix), never 1280."""
-        steps = _steps(
-            [
-                {"step": "crossover_T1280_kernel", "decode_tok_s": 500},
-                {"step": "crossover_T1280_xla", "decode_tok_s": 400},
-                {"step": "crossover_T4096_kernel", "decode_tok_s": 200},
-                {"step": "crossover_T4096_xla", "decode_tok_s": 300},
-                {"step": "crossover_T8192_kernel", "decode_tok_s": 400},
-                {"step": "crossover_T8192_xla", "decode_tok_s": 300},
-            ]
-        )
-        assert recommended_min_t(steps) == 8192
-
-    def test_no_data(self):
-        assert recommended_min_t({}) is None
-
-
-class TestRecommendedEnv:
-    def test_sweep_beats_default(self):
-        steps = _steps(
-            [
-                {"step": "north_star", "decode_tok_s": 500},
-                {"step": "chunk64", "decode_tok_s": 450},
-                {"step": "chunk256", "decode_tok_s": 560},
-                {"step": "unroll1", "decode_tok_s": 480},
-                {"step": "unroll2", "decode_tok_s": 490},
-            ]
-        )
-        env = recommended_env(steps)
-        assert env["ADVSPEC_DECODE_CHUNK"] == "256"
-        assert "ADVSPEC_DECODE_UNROLL" not in env  # default 4 won
-
-    def test_defaults_win_yields_no_overrides(self):
-        steps = _steps(
-            [
-                {"step": "north_star", "decode_tok_s": 500},
-                {"step": "chunk64", "decode_tok_s": 450},
-                {"step": "unroll1", "decode_tok_s": 400},
-            ]
-        )
-        assert recommended_env(steps) == {}
-
-    def test_spec_off_beating_spec_on_sets_kill_switch(self):
-        """The comparison uses the PINNED spec_on/spec_off pair, not
-        north_star (whose speculation default is governed by the very
-        env var being recommended — a north_star baseline would flap)."""
-        steps = _steps(
-            [
-                {"step": "north_star", "decode_tok_s": 560},
-                {"step": "spec_on", "decode_tok_s": 500},
-                {"step": "spec_off", "decode_tok_s": 550},
-            ]
-        )
-        assert recommended_env(steps)["ADVSPEC_SPECULATIVE"] == "0"
-
-    def test_spec_off_losing_keeps_speculation(self):
-        steps = _steps(
-            [
-                {"step": "north_star", "decode_tok_s": 500},
-                {"step": "spec_on", "decode_tok_s": 500},
-                {"step": "spec_off", "decode_tok_s": 400},
-            ]
-        )
-        assert "ADVSPEC_SPECULATIVE" not in recommended_env(steps)
-
-
-class TestBenchAppliesHarvest:
-    def test_harvested_tuning_reads_latest_jsonl(self, tmp_path,
-                                                 monkeypatch):
+    def test_no_probe_child_or_fallback(self):
         import bench
 
-        rows = [
-            {"step": "north_star", "decode_tok_s": 500},
-            {"step": "chunk256", "decode_tok_s": 600},
-            {"step": "crossover_T1280_kernel", "decode_tok_s": 380},
-            {"step": "crossover_T1280_xla", "decode_tok_s": 490},
-            {"step": "crossover_T4096_kernel", "decode_tok_s": 400},
-            {"step": "crossover_T4096_xla", "decode_tok_s": 300},
-        ]
-        results = tmp_path / "tpu_results"
-        results.mkdir()
-        (results / "r04.jsonl").write_text(
-            "\n".join(json.dumps(r) for r in rows)
-        )
-        # Point bench at the temp repo layout.
-        monkeypatch.setattr(
-            bench.os.path, "abspath", lambda p: str(tmp_path / "bench.py")
-        )
-        env = bench._harvested_tuning()
-        assert env["ADVSPEC_DECODE_CHUNK"] == "256"
-        assert env["ADVSPEC_PALLAS_MIN_T"] == "4096"
+        for name in (
+            "_probe_tpu",
+            "_run_tpu_in_child",
+            "_run_cpu_fallback",
+            "_harvested_tuning",
+        ):
+            assert not hasattr(bench, name), name
+        src = (REPO_ROOT / "bench.py").read_text()
+        for gone in ("--_tpu-child", "BENCH_FORCE_CPU", "BENCH_TPU_TIMEOUT_S"):
+            assert gone not in src, gone
 
-    def test_no_harvest_is_empty(self, tmp_path, monkeypatch):
+    def test_failing_runner_is_a_nonzero_exit(self, monkeypatch, capsys):
+        """A runner that raises must fail the command — never a payload
+        from some other device."""
+        import bench
+
+        def boom(platform):
+            raise RuntimeError("device fell over")
+
+        monkeypatch.setattr(bench, "_run_bench", boom)
+        monkeypatch.setattr(sys, "argv", ["bench.py"])
+        with pytest.raises(RuntimeError, match="device fell over"):
+            bench.main()
+        assert capsys.readouterr().out == ""
+
+    def test_payload_names_the_device(self, monkeypatch, capsys):
+        import jax
+
         import bench
 
         monkeypatch.setattr(
-            bench.os.path, "abspath", lambda p: str(tmp_path / "bench.py")
+            bench,
+            "_run_bench",
+            lambda platform: {"metric": "m", "value": 1, "platform": "?"},
         )
-        assert bench._harvested_tuning() == {}
+        monkeypatch.setattr(sys, "argv", ["bench.py"])
+        assert bench.main() == 0
+        out = json.loads(capsys.readouterr().out)
+        dev = jax.devices()[0]
+        assert out["platform"] == dev.platform == "cpu"
+        assert out["device_kind"] == dev.device_kind
+        assert out["device_count"] == len(jax.devices())
 
     @pytest.mark.slow
     def test_round_loop_mode_runs(self):
@@ -171,12 +73,6 @@ class TestBenchAppliesHarvest:
         assert out["decode_tokens_total"] == 5 * 4 * 256
         assert out["value"] > 0
         assert out["vs_baseline"] is None  # cpu: no north-star ratio
-
-    def test_load_tolerates_junk_lines(self, tmp_path):
-        p = tmp_path / "r.jsonl"
-        p.write_text('not json\n{"step": "north_star", '
-                     '"decode_tok_s": 1}\n')
-        assert load(str(p))["north_star"]["decode_tok_s"] == 1
 
 
 class TestGraftlint:
@@ -205,8 +101,8 @@ class TestGraftlint:
 
     def test_repo_is_clean(self):
         """The package + tools + tests + entry scripts lint clean under
-        EVERY registered rule (the executed typecheck gate, VERDICT r4
-        item 5, now with the serving-discipline rules on top) — and no
+        EVERY registered rule (the executed typecheck gate, now with
+        the serving-discipline rules on top) — and no
         grandfathered debt: the committed baseline must be empty."""
         import subprocess
 
@@ -1621,7 +1517,7 @@ class TestBenchTrend:
 class TestMutationRun:
     """tools/mutation_run.py — mutant generation invariants (the full
     subprocess sweep runs via `python tools/mutation_run.py`; its score
-    is recorded in NOTES.md)."""
+    is recorded in PARITY.md, row 8)."""
 
     def test_every_site_yields_a_distinct_compiling_mutant(self):
         from tools.mutation_run import enumerate_mutants, make_mutant

@@ -1,0 +1,189 @@
+"""Described-topology compiles: the serving path's device kernels, built by
+the real TPU compiler for a v5e that is described, not attached.
+
+Interpret-mode tests (tests/test_pallas.py) prove the kernels' math; they
+cannot see what Mosaic refuses — a slice off the tiling, too much VMEM, a
+block no layout admits. These compile each kernel at the shapes the batcher
+gives it for Mistral-7B-v0.3 and Qwen2-7B and assert the compiled text holds
+a `tpu_custom_call`: a kernel that silently fell back to the XLA path fails
+here, at no chip time. Compiles, not runs — nothing here is a speed.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from adversarial_spec_tpu.models.config import get_config
+from adversarial_spec_tpu.ops import pallas_paged, quant
+
+PAGE = 64  # ContinuousBatcher's page size
+B, GAMMA = 4, 8  # four opponents; default draft length
+N_PAGES = 257  # a 16k-token pool + the trash page
+# decode rows, verify rows (B·(γ+1)), one admission prefill chunk
+ROW_COUNTS = {"decode": B, "verify": B * (GAMMA + 1), "prefill": 512}
+MODELS = {
+    "mistral-7b": get_config("mistral", "7b"),
+    "qwen2-7b": get_config("qwen2", "7b"),
+}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip to place shapes on. The persistent compile
+    cache is off around these: such a compile is written to it but cannot
+    be read back without a chip, and the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or one that cannot describe v5e
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _shape(chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _pool(chip, cfg, dtype):
+    """One pool array as the batcher holds it: every layer's pages."""
+    return _shape(
+        chip, (cfg.n_layers, N_PAGES, cfg.n_kv_heads, PAGE, cfg.head_dim), dtype
+    )
+
+
+@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("entry", ["decode", "verify_span", "decode_int8_kv"])
+def test_paged_attention_compiles(chip, model, entry):
+    """The three paged-attention entry points, reading one layer's pages
+    out of the whole pool by index — as forward_paged_decode calls them."""
+    cfg = MODELS[model]
+    span = GAMMA + 1 if entry == "verify_span" else 0
+    int8_kv = entry == "decode_int8_kv"
+    q_shape = (B, span) if span else (B,)
+    q = _shape(chip, q_shape + (cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+    pool = _pool(chip, cfg, jnp.int8 if int8_kv else jnp.bfloat16)
+    table = _shape(chip, (B, cfg.max_seq_len // PAGE), jnp.int32)
+    # verify: per-position (starts, ends); decode: one (start, end) a row
+    windows = (
+        [_shape(chip, (B, span), jnp.int32)] * 2
+        if span
+        else [_shape(chip, (B, 2), jnp.int32)]
+    )
+    scales = (
+        [_shape(chip, pool.shape[:-1] + (1,), jnp.float32)] * 2
+        if int8_kv
+        else []
+    )
+    kernel = (
+        pallas_paged.paged_decode_attention_mq
+        if span
+        else pallas_paged.paged_decode_attention
+    )
+
+    def fn(q, k, v, table, layer, *rest):
+        window, sc = rest[: len(windows)], rest[len(windows) :]
+        qkw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return kernel(
+            q, k, v, table, *window, layer=layer,
+            attn_softcap=cfg.attn_softcap, scale=cfg.attn_scale, **qkw,
+        )
+
+    text = _compiled_text(
+        fn, q, pool, pool, table, _shape(chip, (), jnp.int32), *windows, *scales
+    )
+    assert "tpu_custom_call" in text
+
+
+def _weight_shapes(cfg):
+    """(in, out) of the FFN up-projection and the output head — the
+    widest per-layer matmul and the one odd, vocabulary-sized one."""
+    return {"ffn_up": (cfg.dim, cfg.ffn_dim), "head": (cfg.dim, cfg.vocab_size)}
+
+
+@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("fmt", ["int8", "int4"])
+@pytest.mark.parametrize("rows", ROW_COUNTS)
+@pytest.mark.parametrize("weight", ["ffn_up", "head"])
+def test_dequant_matmul_compiles(chip, model, fmt, rows, weight):
+    """ops.quant.matmul(use_pallas=True) — the dispatcher the forwards
+    call, so a shape `fused_supported` turns away (and hands to XLA
+    without a word) fails here."""
+    k, n = _weight_shapes(MODELS[model])[weight]
+    x = _shape(chip, (ROW_COUNTS[rows], k), jnp.bfloat16)
+    if fmt == "int8":
+        w = {"q": _shape(chip, (k, n), jnp.int8)}
+    else:
+        w = {"q4": _shape(chip, (k // 2, n), jnp.int8)}
+    w["scale"] = _shape(chip, (1, n), jnp.float32)
+    text = _compiled_text(
+        lambda x, w: quant.matmul(x, w, use_pallas=True), x, w
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.slow
+def test_whole_decode_chunk_compiles_in_place(chip):
+    """The batcher's whole decode program at Mistral-7B int8, full depth:
+    every layer's kernels are in it, and the donated pool is updated in
+    place — temporaries stay far below the pool (they were twice the
+    pool while the layer scan restacked it and the scatter re-laid it
+    out; a pool sized to the chip cannot pay that)."""
+    from adversarial_spec_tpu.engine import scheduler
+    from adversarial_spec_tpu.engine.kvcache import (
+        PagedCacheLayout,
+        init_page_pool,
+    )
+    from adversarial_spec_tpu.models.transformer import init_params
+
+    cfg = MODELS["mistral-7b"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _shape(chip, s.shape, s.dtype), tree)
+
+    params = on_chip(
+        jax.eval_shape(
+            lambda: quant.quantize_params(
+                init_params(jax.random.key(0), cfg, jnp.bfloat16), fmt="int8"
+            )
+        )
+    )
+    layout = PagedCacheLayout(
+        n_pages=N_PAGES, page_size=PAGE, n_layers=cfg.n_layers,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+    )
+    pool = on_chip(jax.eval_shape(lambda: init_page_pool(layout, jnp.bfloat16)))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    row = _shape(chip, (B,), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = scheduler.scheduler_decode_chunk.lower(
+        params, cfg, pool,
+        _shape(chip, (B, cfg.max_seq_len // PAGE), jnp.int32),
+        row, row, row, row, row,
+        _shape(chip, (B,), jnp.bool_),
+        _shape(chip, (B, 128), jnp.int32),
+        _shape(chip, (1,), jnp.int32),
+        _shape(chip, key.shape, key.dtype),
+        _shape(chip, (), jnp.float32),
+        _shape(chip, (), jnp.float32),
+        chunk=32, greedy=False, top_k=0, use_top_p=False,
+        use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2

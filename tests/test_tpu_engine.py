@@ -199,7 +199,7 @@ class TestCliTpuPath:
 
 class TestPerRowUsageAttribution:
     def test_early_eos_row_billed_less(self, engine, monkeypatch):
-        """VERDICT r1 item 8: device time attributes proportionally to
+        """Device time attributes proportionally to
         per-row decode counts — an early-EOS row must report less device
         and decode time than a full-budget row, and the row sums must
         reproduce the call totals."""

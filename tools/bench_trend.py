@@ -2,7 +2,7 @@
 table.
 
 Eight PRs in, the bench record is scattered across per-mode files
-(``BENCH_prefix.json``, ``BENCH_obs.json``, …) and per-run ladder
+(``BENCH_prefix.json``, ``BENCH_obs.json``, …) and per-run driver
 wrappers (``BENCH_r01.json``'s ``{n, cmd, rc, tail, parsed}``) that
 nobody joins — this tool is the join: one row per file with the mode,
 headline metric, value/unit, platform, and budget verdict, so a
@@ -10,7 +10,7 @@ reviewer reads the whole perf trajectory at a glance and a regression
 (or a silently invalid bench file) can't hide in a file nobody opens.
 
 Every file is SCHEMA-VALIDATED first: metric-style payloads must carry
-``metric``/``value``/``unit``/``platform`` with the right types; ladder
+``metric``/``value``/``unit``/``platform`` with the right types; run
 wrappers must carry ``n``/``cmd``/``rc`` and, when the wrapped run
 succeeded, a ``parsed`` metric payload. A violation is a nonzero exit —
 ``tools/lint_all.py --full`` runs this, so a malformed bench file fails
@@ -42,8 +42,8 @@ _METRIC_REQUIRED: dict[str, tuple[type, ...]] = {
     "unit": (str,),
     "platform": (str,),
 }
-# Ladder wrapper contract (tpu_session.sh round files).
-_LADDER_REQUIRED: dict[str, tuple[type, ...]] = {
+# Run-wrapper contract (the driver's BENCH_r*/MULTICHIP_r* round files).
+_WRAPPER_REQUIRED: dict[str, tuple[type, ...]] = {
     "n": (int,),
     "cmd": (str,),
     "rc": (int,),
@@ -269,11 +269,11 @@ def validate_bench_file(path: Path) -> tuple[dict | None, list[str]]:
             row["brownout_transitions"] = payload["brownout_transitions"]
         return row, []
 
-    # Ladder wrapper: the headline lives in ``parsed``. Any parsed
+    # Run wrapper: the headline lives in ``parsed``. Any parsed
     # payload PRESENT must schema-validate (a failed run may still
     # carry one, and its fields flow into the table); rc 0 with no
     # parsed payload is a wrapper bug.
-    problems = _check_fields(payload, _LADDER_REQUIRED, path.name)
+    problems = _check_fields(payload, _WRAPPER_REQUIRED, path.name)
     parsed = payload.get("parsed")
     if payload.get("rc") == 0 and not isinstance(parsed, dict):
         problems.append(f"{path.name}: rc 0 but no parsed metric payload")

@@ -34,7 +34,7 @@ DEFAULT_ROOTS = (
     "tests",
     "bench.py",
     "__graft_entry__.py",
-    "tpu_ladder.py",
+    "chip_smoke.py",
 )
 
 

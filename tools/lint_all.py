@@ -1,5 +1,5 @@
-"""Unified lint/QA runner with ONE exit code — the preflight gate
-tpu_session.sh runs before burning a TPU window on the ladder.
+"""Unified lint/QA runner with ONE exit code — the preflight gate to
+run before spending chip time.
 
 Stages (each prints its own verdict; the runner exits nonzero if ANY
 stage failed):
@@ -330,8 +330,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument(
         "--changed",
         action="store_true",
-        help="lint only files changed vs --base (the tpu_session.sh "
-        "fast preflight); falls back to a full lint when git cannot "
+        help="lint only files changed vs --base (the fast "
+        "preflight); falls back to a full lint when git cannot "
         "answer. Absence-proving checks (GL-CONFIG) skip on a subset.",
     )
     ap.add_argument(
