@@ -236,7 +236,7 @@ class MockEngine:
         ``stalled + overlapped == prefill`` invariant pins with ==.
 
         Emits the SAME observability schema the real scheduler does
-        (StepEvent + step/prefill/TTFT metrics), with the synthetic
+        (StepEvent + step/prefill-wall metrics), with the synthetic
         seconds as the observed values — so the whole obs pipeline
         (events JSONL, Prometheus text) pins byte-deterministically on
         CPU without a TPU in the loop."""
@@ -252,7 +252,7 @@ class MockEngine:
         )
         if obs_mod.config().enabled:
             obs_mod.hot.prefill_chunk.observe(synth_s)
-            obs_mod.hot.ttft.observe(synth_s)
+            obs_mod.hot.prefill_wall.observe(synth_s)
             obs_mod.emit(
                 obs_mod.StepEvent(
                     kind="fused" if overlapped else "prefill",
@@ -294,7 +294,6 @@ class MockEngine:
         if not spec_mod.config().enabled:
             return
         gamma = spec_mod.config().gamma
-        span = gamma + 1
         ctx = (req.system + "\n" + req.user).split()
         out = text.split()
         # Most-recent-bigram index over the growing context, the host
@@ -332,12 +331,6 @@ class MockEngine:
             drafted += n_allowed
             accepted += k
             spec_mod.stats.record_step(n_allowed, k, n_emit)
-            # Synthetic step wall: ONE batched forward per verify step,
-            # 1/1024 s (the same tokens/1024 second-scale the interleave
-            # accounting uses), split by the position-share convention.
-            spec_mod.stats.record_wall(
-                (1 / 1024) / (span + 1), (1 / 1024) * span / (span + 1)
-            )
             if obs_on:
                 obs_mod.hot.spec_tokens_per_step.observe(float(n_emit))
                 obs_mod.emit(

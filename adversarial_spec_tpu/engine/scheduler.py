@@ -207,6 +207,9 @@ class SchedResult:
     # prefill_time_s it IS the request's service wall — the end wall of
     # its ``request`` trace span (tools/trace_view.py checks the sum).
     decode_time_s: float = 0.0
+    # Submit -> admission start: the wait for a free slot (and for the
+    # one admission in flight), before any of this request's prefill.
+    queue_wait_s: float = 0.0
     # Streaming early-convergence cancellation (engine/streaming.py):
     # ``cancelled`` marks a CLEAN mid-decode stop requested by the
     # consumer (``tokens`` holds the partial transcript, no error);
@@ -304,16 +307,17 @@ def _decode_chunk_impl(
             pallas_interpret=pallas_interpret,
             mesh=mesh,
         )
-        key, sub = jax.random.split(key)
-        nxt = sample_tokens(
-            logits[:, 0],
-            sub,
-            greedy=greedy,
-            top_k=top_k,
-            temperature=temperature,
-            top_p=top_p,
-            use_top_p=use_top_p,
-        )
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            nxt = sample_tokens(
+                logits[:, 0],
+                sub,
+                greedy=greedy,
+                top_k=top_k,
+                temperature=temperature,
+                top_p=top_p,
+                use_top_p=use_top_p,
+            )
         is_eos = (nxt[:, None] == eos_ids[None, :]).any(axis=-1)
         nxt = jnp.where(active, nxt, 0)
         write_pos = jnp.minimum(n_emitted, cap - 1)
@@ -589,19 +593,20 @@ def _spec_chunk_impl(
     )
 
     # --- Accept by rejection sampling against the true distribution. ---
-    filt = filtered_logits(
-        logits,
-        greedy=greedy,
-        top_k=top_k,
-        temperature=temperature,
-        top_p=top_p,
-        use_top_p=use_top_p,
-    )  # [B, span, V]
-    probs = jax.nn.softmax(filt, axis=-1)
-    key, u_key, res_key = jax.random.split(key, 3)
-    n_acc, bonus = accept_spans(
-        probs, draft, n_allowed, u_key, res_key, greedy=greedy
-    )
+    with jax.named_scope("sample"):
+        filt = filtered_logits(
+            logits,
+            greedy=greedy,
+            top_k=top_k,
+            temperature=temperature,
+            top_p=top_p,
+            use_top_p=use_top_p,
+        )  # [B, span, V]
+        probs = jax.nn.softmax(filt, axis=-1)
+        key, u_key, res_key = jax.random.split(key, 3)
+        n_acc, bonus = accept_spans(
+            probs, draft, n_allowed, u_key, res_key, greedy=greedy
+        )
     emitted = jnp.concatenate(
         [draft, jnp.zeros((B, 1), draft.dtype)], axis=1
     )
@@ -1151,6 +1156,7 @@ class ContinuousBatcher:
         # Per-slot request telemetry, stamped at admission handoff.
         self._slot_cached: list[int] = [0] * B
         self._slot_prefill_s: list[float] = [0.0] * B
+        self._slot_queue_s: list[float] = [0.0] * B
         # Per-slot causal-trace state: the owner's trace/span ids and
         # its accumulated decode wall (each step's decode share splits
         # evenly over the rows live at dispatch; the slot sums
@@ -1304,15 +1310,13 @@ class ContinuousBatcher:
                 f"request needs {total} tokens but the pool holds only "
                 f"{self.capacity_tokens}; raise capacity_tokens"
             )
-        self.queue.append(req)
-        if req.deadline_s > 0:
-            import time
+        import time
 
+        self.queue.append(req)
+        self._queued_t[req.req_id] = time.monotonic()
+        if req.deadline_s > 0:
             self._deadline_t[req.req_id] = time.monotonic() + req.deadline_s
         if obs_mod.config().enabled:
-            import time
-
-            self._queued_t[req.req_id] = time.monotonic()
             obs_mod.emit(
                 obs_mod.RequestEvent(
                     req_id=req.req_id,
@@ -1635,12 +1639,14 @@ class ContinuousBatcher:
         ends (wall = the measured queue wait) and the 'prefill' span
         opens. Called by both admission variants under the request's
         ambient scope (``_admit``)."""
-        if not obs_mod.config().enabled:
-            return
         import time
 
         t0 = self._queued_t.pop(req.req_id, None)
         wait = (time.monotonic() - t0) if t0 is not None else 0.0
+        self._slot_queue_s[slot] = wait
+        if not obs_mod.config().enabled:
+            return
+        obs_mod.hot.batcher_queue_wait.observe(wait)
         obs_mod.emit(
             obs_mod.SpanEvent(
                 name="queued",
@@ -1868,10 +1874,10 @@ class ContinuousBatcher:
         self._record_prefill_time(elapsed, overlapped=False)
         self._slot_prefill_s[slot] = adm.prefill_s + elapsed
         if obs_mod.config().enabled:
-            # TTFT as the batcher sees it: this request's own prefill
-            # wall (stalled + overlapped chunks) through the handoff
-            # that produced its first sampled token.
-            obs_mod.hot.ttft.observe(self._slot_prefill_s[slot])
+            # This request's own prefill wall (stalled + overlapped
+            # chunks) through the handoff that produced its first
+            # sampled token. No queueing in it: not a TTFT.
+            obs_mod.hot.prefill_wall.observe(self._slot_prefill_s[slot])
             obs_mod.hot.pool_util.set(
                 round(
                     1.0
@@ -1954,7 +1960,9 @@ class ContinuousBatcher:
                 # causes stamp with ITS trace/span (obs/trace.py).
                 req0 = self.queue[0]
                 try:
-                    with obs_mod.trace_scope(req0.trace_id, req0.span_id):
+                    with obs_mod.trace_scope(
+                        req0.trace_id, req0.span_id
+                    ), obs_mod.phase("drive.admit"):
                         started = self._start_admission(slot, req0)
                 except Exception as e:
                     # Fault isolation: only this request is affected —
@@ -1978,7 +1986,9 @@ class ContinuousBatcher:
                     # Short prefills (≤ one ADMISSION_CHUNK of work left —
                     # possibly several sub-chunk pieces on the canonical
                     # path) admit to completion immediately.
-                    with obs_mod.trace_scope(req0.trace_id, req0.span_id):
+                    with obs_mod.trace_scope(
+                        req0.trace_id, req0.span_id
+                    ), obs_mod.phase("drive.prefill"):
                         while (
                             self._admission is not None
                             and self._admission.slot == slot
@@ -2031,10 +2041,9 @@ class ContinuousBatcher:
         if requeued:
             self._retried.add(req.req_id)
             self.queue.append(req)
-            if obs_mod.config().enabled:
-                import time
+            import time
 
-                self._queued_t[req.req_id] = time.monotonic()
+            self._queued_t[req.req_id] = time.monotonic()
             obs_mod.emit(
                 obs_mod.RequestEvent(
                     req_id=req.req_id,
@@ -2368,6 +2377,7 @@ class ContinuousBatcher:
         cached = self._slot_cached[slot]
         prefill_s = self._slot_prefill_s[slot]
         decode_s = self._slot_decode_s[slot]
+        queue_s = self._slot_queue_s[slot]
         self._release_slot(slot)
         self._deadline_t.pop(req.req_id, None)
         stream_mod.stats.record_cancel(n, saved)
@@ -2384,6 +2394,7 @@ class ContinuousBatcher:
                 spec_drafted=st[1],
                 spec_accepted=st[2],
                 decode_time_s=decode_s,
+                queue_wait_s=queue_s,
                 trace_id=req.trace_id,
                 span_id=req.span_id,
             )
@@ -2486,6 +2497,7 @@ class ContinuousBatcher:
                 spec_drafted=st[1],
                 spec_accepted=st[2],
                 decode_time_s=self._slot_decode_s[slot],
+                queue_wait_s=self._slot_queue_s[slot],
                 trace_id=req.trace_id,
                 span_id=req.span_id,
             )
@@ -2583,6 +2595,12 @@ class ContinuousBatcher:
         (partial tokens + ``fault_kind`` on its result, one requeue first
         when transient) while co-resident rows keep decoding.
         """
+        if obs_mod.config().enabled:
+            obs_mod.hot.batcher_runs.inc()
+            obs_mod.hot.batcher_rows.inc(len(self.queue))
+            obs_mod.hot.batcher_distinct_prompts.inc(
+                len({tuple(r.prompt_ids) for r in self.queue})
+            )
         if self.interleave:
             self._drive_pipelined(timeout_s)
         else:
@@ -3133,18 +3151,20 @@ class ContinuousBatcher:
         decoded tokens already land on host every step, so the stream
         consumer adds no new sanctioned sync."""
         active_ref, emitted_ref, out_ref, live_slots = entry
-        # graftlint: disable=GL-SYNC -- pipelined fetch: called only when the entry resolved (is_ready) or at the depth bound, the double buffer's one sanctioned blocking point
-        act = np.asarray(active_ref)
-        for s, gen in live_slots:
-            if gen == self._slot_gen[s] and not act[s]:
-                self._active_np[s] = False
+        with obs_mod.phase("drive.fetch"):
+            # graftlint: disable=GL-SYNC -- pipelined fetch: called only when the entry resolved (is_ready) or at the depth bound, the double buffer's one sanctioned blocking point
+            act = np.asarray(active_ref)
+            for s, gen in live_slots:
+                if gen == self._slot_gen[s] and not act[s]:
+                    self._active_np[s] = False
         if emitted_ref is None:
             return
-        # graftlint: disable=GL-SYNC -- stream token fetch riding the same resolved/depth-bound entry fetch as the flags above (no new sync point; the async copy started at dispatch)
-        emitted_np = np.asarray(emitted_ref)
-        # graftlint: disable=GL-SYNC -- stream token fetch (the out_buf snapshot in the same entry; see above)
-        out_np = np.asarray(out_ref)
-        self._stream_entry(emitted_np, out_np, live_slots)
+        with obs_mod.phase("drive.stream"):
+            # graftlint: disable=GL-SYNC -- stream token fetch riding the same resolved/depth-bound entry fetch as the flags above (no new sync point; the async copy started at dispatch)
+            emitted_np = np.asarray(emitted_ref)
+            # graftlint: disable=GL-SYNC -- stream token fetch (the out_buf snapshot in the same entry; see above)
+            out_np = np.asarray(out_ref)
+            self._stream_entry(emitted_np, out_np, live_slots)
 
     def _drive_pipelined(self, timeout_s: float) -> None:
         """Admit → dispatch (fused when an admission and live rows
@@ -3159,366 +3179,356 @@ class ContinuousBatcher:
         deadline = time.monotonic() + timeout_s if timeout_s > 0 else None
         inflight: deque[tuple] = deque()  # (active_ref, live_slots)
         while self._has_work():
-            if deadline is not None and time.monotonic() > deadline:
-                # Entries in flight resolve through the same lazy arrays
-                # _collect reads; their per-step flags are moot now.
-                inflight.clear()
-                self._expire_timeout()
-                break
-            # Per-request watchdog: evict over-deadline work before
-            # admitting/dispatching more (host clock math; evictions
-            # ride the fault surgery's existing sanctioned fetches).
-            self._expire_request_deadlines()
-            self._admit()
-            adm = self._admission
-            live = [s for s in range(self.B) if self._active_np[s]]
-            t0 = time.monotonic()
-            fused_share = 0.0
-            dispatched = False
-            # Speculation: each iteration's "decode work" becomes one
-            # γ-draft + verify program per live row, and the host MUST
-            # learn each row's accepted length before it can dispatch
-            # the next step (draft pages roll back, coverage re-sizes,
-            # flags advance per-row) — so the spec path runs one step
-            # deep with a sanctioned counts fetch per iteration instead
-            # of the double buffer; the γ+1 tokens a step can emit are
-            # what buy that sync back.
-            spec = self.speculative
-            width = (self.gamma + 1) if spec else self.chunk
-            spec_counts = None
-            spec_slots: tuple = ()
-            # Fuse only the LEADING prefill chunks (strictly more work
-            # left after this chunk): the FINAL chunk runs standalone so
-            # the handoff happens before this iteration's decode chunk
-            # and the newcomer joins it immediately — fusing the last
-            # chunk would push the join one chunk later, fragmenting
-            # decode into extra programs for every admission (measured
-            # net-negative: the join lag costs more than the one
-            # remaining stall saves). Corollary: a fused step never
-            # finishes a prefill; every handoff happens inside
-            # _advance_admission.
-            chunk_len = (
-                self._fused_chunk_len(adm.remaining, len(live), width)
-                if adm is not None and live
-                else 0
-            )
-            ride = (
-                adm is not None
-                and live
-                and not adm.fuse_deferred
-                and chunk_len < adm.remaining
-            )
-            if spec and live and (ride or adm is None):
-                # Coverage sizing for the step dispatched below. The
-                # standalone-admission branch prepares AFTER its
-                # handoff instead (the handoff may activate a new row,
-                # and preparing here too would repeat the per-row
-                # extend walk and a second full page-table push).
-                alloc_len = self._prepare_spec_step(live)
-            if ride:
-                try:
-                    # Fused dispatches run under the riding admission's
-                    # trace scope so its retrace/compile observations
-                    # attribute to the request that shaped the program.
-                    with obs_mod.trace_scope(
-                        adm.req.trace_id, adm.req.span_id
-                    ):
-                        if spec:
-                            spec_slots = tuple(
-                                (s, self._slot_gen[s]) for s in live
-                            )
-                            spec_counts = self._dispatch_spec(
-                                alloc_len, adm, chunk_len
-                            )
-                        else:
-                            self._dispatch_fused(adm, chunk_len)
-                    # Telemetry attribution for the fused program: the
-                    # halves aren't separately measurable without a
-                    # profiler, so split this iteration's wall clock by
-                    # token share (prompt tokens vs the decode/verify
-                    # half's upper bound) — deterministic given host
-                    # state.
-                    fused_share = chunk_len / (
-                        chunk_len + len(live) * width
-                    )
-                    dispatched = True
-                except Exception as e:
-                    # A dispatch-time fault (chaos seam, trace error) is
-                    # treated as decode-side surgery: the admission's
-                    # state refs still point at the step before and it
-                    # stays in flight; older in-flight entries stay
-                    # valid (they can only deactivate). Defer the NEXT
-                    # chunk to the standalone path so a fault that
-                    # actually originates in the prefill half aborts the
-                    # admission there instead of evicting another
-                    # innocent resident every iteration.
-                    adm.fuse_deferred = True
-                    spec_counts = None
-                    self._handle_decode_fault(e)
-            else:
-                if adm is not None:
-                    # Final chunk, nothing live to ride, or the last
-                    # fused dispatch carrying this admission faulted: a
-                    # standalone (stalled) chunk, timed + recorded
-                    # inside _advance_admission — which also performs
-                    # the handoff when the prefill completes, so the
-                    # new row is live for the decode dispatch below.
+            # The whole body is ONE phase; every call it makes lies in
+            # exactly one drive.* phase below, and what is left (the
+            # ``live`` lists, the telemetry itself) is the remainder.
+            with obs_mod.phase("drive.iteration"):
+                if deadline is not None and time.monotonic() > deadline:
+                    # Entries in flight resolve through the same lazy
+                    # arrays _collect reads; their per-step flags are
+                    # moot now.
+                    inflight.clear()
+                    self._expire_timeout()
+                    break
+                # Per-request watchdog: evict over-deadline work before
+                # admitting/dispatching more (host clock math; evictions
+                # ride the fault surgery's existing sanctioned fetches).
+                with obs_mod.phase("drive.admit"):
+                    self._expire_request_deadlines()
+                self._admit()
+                adm = self._admission
+                live = [s for s in range(self.B) if self._active_np[s]]
+                t0 = time.monotonic()
+                fused_share = 0.0
+                dispatched = False
+                # Speculation: each iteration's "decode work" becomes one
+                # γ-draft + verify program per live row, and the host MUST
+                # learn each row's accepted length before it can dispatch
+                # the next step (draft pages roll back, coverage re-sizes,
+                # flags advance per-row) — so the spec path runs one step
+                # deep with a sanctioned counts fetch per iteration instead
+                # of the double buffer; the γ+1 tokens a step can emit are
+                # what buy that sync back.
+                spec = self.speculative
+                width = (self.gamma + 1) if spec else self.chunk
+                spec_counts = None
+                spec_slots: tuple = ()
+                # Fuse only the LEADING prefill chunks (strictly more work
+                # left after this chunk): the FINAL chunk runs standalone so
+                # the handoff happens before this iteration's decode chunk
+                # and the newcomer joins it immediately — fusing the last
+                # chunk would push the join one chunk later, fragmenting
+                # decode into extra programs for every admission (measured
+                # net-negative: the join lag costs more than the one
+                # remaining stall saves). Corollary: a fused step never
+                # finishes a prefill; every handoff happens inside
+                # _advance_admission.
+                chunk_len = (
+                    self._fused_chunk_len(adm.remaining, len(live), width)
+                    if adm is not None and live
+                    else 0
+                )
+                ride = (
+                    adm is not None
+                    and live
+                    and not adm.fuse_deferred
+                    and chunk_len < adm.remaining
+                )
+                if spec and live and (ride or adm is None):
+                    # Coverage sizing for the step dispatched below. The
+                    # standalone-admission branch prepares AFTER its
+                    # handoff instead (the handoff may activate a new row,
+                    # and preparing here too would repeat the per-row
+                    # extend walk and a second full page-table push).
+                    with obs_mod.phase("drive.prepare"):
+                        alloc_len = self._prepare_spec_step(live)
+                if ride:
                     try:
+                        # Fused dispatches run under the riding admission's
+                        # trace scope so its retrace/compile observations
+                        # attribute to the request that shaped the program.
                         with obs_mod.trace_scope(
                             adm.req.trace_id, adm.req.span_id
-                        ):
-                            self._advance_admission()
-                        adm.fuse_deferred = False
-                    except Exception as e:
-                        self._abort_admission(e)
-                    live = [
-                        s for s in range(self.B) if self._active_np[s]
-                    ]
-                    if spec and live:
-                        # The handoff may have activated a new row;
-                        # its coverage must be sized before it joins
-                        # the verify step.
-                        alloc_len = self._prepare_spec_step(live)
-                    # Restart the clock: the standalone chunk's seconds
-                    # are already in the stalled-prefill bucket — the
-                    # decode dt below must not re-count them (their sum
-                    # is what the engine subtracts from total wall).
-                    t0 = time.monotonic()
-                if live:
-                    try:
-                        if spec:
-                            spec_slots = tuple(
-                                (s, self._slot_gen[s]) for s in live
-                            )
-                            spec_counts = self._dispatch_spec(
-                                alloc_len, None, 0
-                            )
-                        else:
-                            self._dispatch_decode()
+                        ), obs_mod.phase("drive.dispatch"):
+                            if spec:
+                                spec_slots = tuple(
+                                    (s, self._slot_gen[s]) for s in live
+                                )
+                                spec_counts = self._dispatch_spec(
+                                    alloc_len, adm, chunk_len
+                                )
+                            else:
+                                self._dispatch_fused(adm, chunk_len)
+                        # Telemetry attribution for the fused program: the
+                        # halves aren't separately measurable without a
+                        # profiler, so split this iteration's wall clock by
+                        # token share (prompt tokens vs the decode/verify
+                        # half's upper bound) — deterministic given host
+                        # state.
+                        fused_share = chunk_len / (
+                            chunk_len + len(live) * width
+                        )
                         dispatched = True
                     except Exception as e:
+                        # A dispatch-time fault (chaos seam, trace error) is
+                        # treated as decode-side surgery: the admission's
+                        # state refs still point at the step before and it
+                        # stays in flight; older in-flight entries stay
+                        # valid (they can only deactivate). Defer the NEXT
+                        # chunk to the standalone path so a fault that
+                        # actually originates in the prefill half aborts the
+                        # admission there instead of evicting another
+                        # innocent resident every iteration.
+                        adm.fuse_deferred = True
                         spec_counts = None
                         self._handle_decode_fault(e)
-            if dispatched and spec:
-                depth = 1
-                step_sync = "spec_counts"
-                counts_np = None
-                if spec_counts is not None:
+                else:
+                    if adm is not None:
+                        # Final chunk, nothing live to ride, or the last
+                        # fused dispatch carrying this admission faulted: a
+                        # standalone (stalled) chunk, timed + recorded
+                        # inside _advance_admission — which also performs
+                        # the handoff when the prefill completes, so the
+                        # new row is live for the decode dispatch below.
+                        try:
+                            with obs_mod.trace_scope(
+                                adm.req.trace_id, adm.req.span_id
+                            ), obs_mod.phase("drive.prefill"):
+                                self._advance_admission()
+                            adm.fuse_deferred = False
+                        except Exception as e:
+                            self._abort_admission(e)
+                        live = [
+                            s for s in range(self.B) if self._active_np[s]
+                        ]
+                        if spec and live:
+                            # The handoff may have activated a new row;
+                            # its coverage must be sized before it joins
+                            # the verify step.
+                            with obs_mod.phase("drive.prepare"):
+                                alloc_len = self._prepare_spec_step(live)
+                        # Restart the clock: the standalone chunk's seconds
+                        # are already in the stalled-prefill bucket — the
+                        # decode dt below must not re-count them (their sum
+                        # is what the engine subtracts from total wall).
+                        t0 = time.monotonic()
+                    if live:
+                        try:
+                            with obs_mod.phase("drive.dispatch"):
+                                if spec:
+                                    spec_slots = tuple(
+                                        (s, self._slot_gen[s]) for s in live
+                                    )
+                                    spec_counts = self._dispatch_spec(
+                                        alloc_len, None, 0
+                                    )
+                                else:
+                                    self._dispatch_decode()
+                            dispatched = True
+                        except Exception as e:
+                            spec_counts = None
+                            self._handle_decode_fault(e)
+                if dispatched and spec:
+                    depth = 1
+                    step_sync = "spec_counts"
+                    counts_np = None
+                    if spec_counts is not None:
+                        with obs_mod.phase("drive.fetch"):
+                            try:
+                                # Start the copy before the blocking fetch —
+                                # marginal, but free.
+                                spec_counts.copy_to_host_async()
+                            except Exception:
+                                pass  # optional fast path only
+                            try:
+                                # The spec path's ONE sanctioned per-step sync:
+                                # the host cannot size the next step's page
+                                # coverage, roll rejected drafts back, or
+                                # advance per-row flags without the accepted
+                                # counts. A [5, B] int fetch — the γ+1 tokens
+                                # the step can emit amortize it.
+                                # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and size the next step's coverage (the one sanctioned speculative sync)
+                                counts_np = np.asarray(spec_counts)
+                            except Exception as e:
+                                # An async device fault surfaces at the fetch:
+                                # same eviction surgery as dispatch-time.
+                                self._handle_decode_fault(e)
+                        interleave_mod.stats.record_sync()
+                        obs_mod.record_sync("spec_counts")
+                        if counts_np is not None:
+                            with obs_mod.phase("drive.apply"):
+                                self._apply_spec_counts(counts_np, spec_slots)
+                            if self._stream_armed(
+                                s for s, _ in spec_slots
+                            ):
+                                # Stream delivery at the spec path's ONE
+                                # sanctioned per-step sync: the counts
+                                # fetch above already blocked on this
+                                # step, so the token fetch adds no new
+                                # sync point (out_buf is the step's live
+                                # output here — its donation happens at
+                                # the NEXT dispatch). Emitted counts come
+                                # from the host views _apply_spec_counts
+                                # just advanced.
+                                with obs_mod.phase("drive.stream"):
+                                    # graftlint: disable=GL-SYNC -- stream token fetch at the sanctioned spec_counts sync (the counts fetch above already blocked on this step)
+                                    out_np = np.asarray(self.out_buf)
+                                    self._stream_entry(
+                                        self._cur_len_np - self._row_len_np,
+                                        out_np,
+                                        spec_slots,
+                                    )
+                    dt = time.monotonic() - t0
+                    if fused_share > 0.0:
+                        p = dt * fused_share
+                        self._record_prefill_time(p, overlapped=True)
+                        adm.prefill_s += p
+                        self.decode_time_s += dt - p
+                        spec_dt = dt - p
+                    else:
+                        self.decode_time_s += dt
+                        spec_dt = dt
+                    if live:
+                        # Per-request decode attribution: this step's decode
+                        # wall splits evenly over the rows live at dispatch
+                        # (slot sums reproduce decode_time_s — the 'decode'
+                        # trace span's wall).
+                        dec_share = spec_dt / len(live)
+                        for s in live:
+                            self._slot_decode_s[s] += dec_share
+                    if obs_mod.config().enabled:
+                        obs_mod.hot.step_wall.observe(dt)
+                        obs_mod.emit(
+                            obs_mod.StepEvent(
+                                kind=(
+                                    "fused_spec"
+                                    if fused_share > 0.0
+                                    else "spec"
+                                ),
+                                n_live=len(live),
+                                admission_slot=(
+                                    adm.slot if fused_share > 0.0 else -1
+                                ),
+                                prefill_tokens=(
+                                    chunk_len if fused_share > 0.0 else 0
+                                ),
+                                decode_chunk=width,
+                                pipeline_depth=depth,
+                                sync_reason=step_sync,
+                                # The riding admission's span; batch-level
+                                # otherwise (trace stamps from ambient).
+                                span_id=(
+                                    adm.req.span_id
+                                    if fused_share > 0.0
+                                    else ""
+                                ),
+                                trace_id=(
+                                    adm.req.trace_id
+                                    if fused_share > 0.0
+                                    else ""
+                                ),
+                            )
+                        )
+                elif dispatched:
+                    # Streaming consumers ride the double buffer: the entry
+                    # carries the step's emitted counts plus an out_buf
+                    # SNAPSHOT (jnp.copy — out_buf itself is donated to the
+                    # next dispatch, so a raw ref would be deleted before
+                    # the depth-bound fetch; the copy is a device-side op
+                    # that overlaps compute and only exists while a
+                    # consumer is attached).
+                    streaming = self._stream_armed(live)
+                    with obs_mod.phase("drive.dispatch"):
+                        entry = (
+                            self.active,
+                            self.n_emitted if streaming else None,
+                            jnp.copy(self.out_buf) if streaming else None,
+                            tuple((s, self._slot_gen[s]) for s in live),
+                        )
+                        for ref in entry[:3]:
+                            if ref is None:
+                                continue
+                            try:
+                                # Start the device→host copy now; the fetch
+                                # one iteration later should find it resolved.
+                                ref.copy_to_host_async()
+                            except Exception:
+                                pass  # optional fast path only
+                    inflight.append(entry)
+                    depth = len(inflight)
+                    step_sync = ""
                     try:
-                        # Start the copy before the blocking fetch —
-                        # marginal, but free.
-                        spec_counts.copy_to_host_async()
-                    except Exception:
-                        pass  # optional fast path only
-                    try:
-                        # The spec path's ONE sanctioned per-step sync:
-                        # the host cannot size the next step's page
-                        # coverage, roll rejected drafts back, or
-                        # advance per-row flags without the accepted
-                        # counts. A [5, B] int fetch — the γ+1 tokens
-                        # the step can emit amortize it.
-                        # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and size the next step's coverage (the one sanctioned speculative sync)
-                        counts_np = np.asarray(spec_counts)
-                    except Exception as e:
-                        # An async device fault surfaces at the fetch:
-                        # same eviction surgery as dispatch-time.
-                        self._handle_decode_fault(e)
-                    interleave_mod.stats.record_sync()
-                    obs_mod.record_sync("spec_counts")
-                    if counts_np is not None:
-                        self._apply_spec_counts(counts_np, spec_slots)
-                        if self._stream_armed(
-                            s for s, _ in spec_slots
+                        # Retire completed steps ADAPTIVELY: any entry whose
+                        # flags already resolved (is_ready — free to fetch)
+                        # applies now, so completions/slot-frees are seen
+                        # with zero lag whenever the device keeps up (CPU:
+                        # effectively every iteration). Only force a
+                        # blocking fetch at the depth bound — that is the
+                        # double buffer proper, and it only engages when the
+                        # device is genuinely still executing step N-1.
+                        while inflight and (
+                            len(inflight) >= self.pipeline_depth
+                            or self._entry_ready(inflight[0])
                         ):
-                            # Stream delivery at the spec path's ONE
-                            # sanctioned per-step sync: the counts
-                            # fetch above already blocked on this
-                            # step, so the token fetch adds no new
-                            # sync point (out_buf is the step's live
-                            # output here — its donation happens at
-                            # the NEXT dispatch). Emitted counts come
-                            # from the host views _apply_spec_counts
-                            # just advanced.
-                            # graftlint: disable=GL-SYNC -- stream token fetch at the sanctioned spec_counts sync (the counts fetch above already blocked on this step)
-                            out_np = np.asarray(self.out_buf)
-                            self._stream_entry(
-                                self._cur_len_np - self._row_len_np,
-                                out_np,
-                                spec_slots,
-                            )
-                dt = time.monotonic() - t0
-                span = self.gamma + 1
-                if fused_share > 0.0:
-                    p = dt * fused_share
-                    self._record_prefill_time(p, overlapped=True)
-                    adm.prefill_s += p
-                    self.decode_time_s += dt - p
-                    spec_dt = dt - p
-                else:
-                    self.decode_time_s += dt
-                    spec_dt = dt
-                if live:
-                    # Per-request decode attribution: this step's decode
-                    # wall splits evenly over the rows live at dispatch
-                    # (slot sums reproduce decode_time_s — the 'decode'
-                    # trace span's wall).
-                    dec_share = spec_dt / len(live)
-                    for s in live:
-                        self._slot_decode_s[s] += dec_share
-                # Draft/verify wall split by position share: the bigram
-                # scan costs about one forward position against the
-                # span's γ+1 (SpecStats' deterministic convention).
-                spec_mod.stats.record_wall(
-                    spec_dt / (span + 1), spec_dt * span / (span + 1)
-                )
-                if obs_mod.config().enabled:
-                    obs_mod.hot.step_wall.observe(dt)
+                            if not self._entry_ready(inflight[0]):
+                                # Depth bound forced a genuinely blocking
+                                # fetch — the double buffer's one sanctioned
+                                # blocking point, made runtime-visible.
+                                obs_mod.record_sync("depth_fetch")
+                                step_sync = "depth_fetch"
+                            self._fetch_entry(inflight.popleft())
+                    except Exception as e:
+                        # An async device fault surfaces at the fetch, one
+                        # step late: same eviction surgery as dispatch-time.
+                        inflight.clear()
+                        self._handle_decode_fault(e)
+                    dt = time.monotonic() - t0
+                    if fused_share > 0.0:
+                        p = dt * fused_share
+                        self._record_prefill_time(p, overlapped=True)
+                        adm.prefill_s += p
+                        self.decode_time_s += dt - p
+                        dec_dt = dt - p
+                    else:
+                        self.decode_time_s += dt
+                        dec_dt = dt
                     if live:
-                        # Per-row inter-token latency from the tokens
-                        # the step ACTUALLY emitted (the fetched
-                        # counts), not the optimistic γ+1 program
-                        # width — near-zero acceptance must not report
-                        # a γ+1-fold rosier latency than delivered.
-                        emitted = (
-                            sum(
-                                int(counts_np[2, s])
-                                for s, _ in spec_slots
+                        # Per-request decode attribution (see the spec
+                        # branch): even split over rows live at dispatch.
+                        dec_share = dec_dt / len(live)
+                        for s in live:
+                            self._slot_decode_s[s] += dec_share
+                    if obs_mod.config().enabled:
+                        obs_mod.hot.step_wall.observe(dt)
+                        obs_mod.emit(
+                            obs_mod.StepEvent(
+                                kind=(
+                                    "fused" if fused_share > 0.0 else "decode"
+                                ),
+                                n_live=len(live),
+                                admission_slot=(
+                                    adm.slot if fused_share > 0.0 else -1
+                                ),
+                                prefill_tokens=(
+                                    chunk_len if fused_share > 0.0 else 0
+                                ),
+                                decode_chunk=self.chunk,
+                                pipeline_depth=depth,
+                                sync_reason=step_sync,
+                                span_id=(
+                                    adm.req.span_id
+                                    if fused_share > 0.0
+                                    else ""
+                                ),
+                                trace_id=(
+                                    adm.req.trace_id
+                                    if fused_share > 0.0
+                                    else ""
+                                ),
                             )
-                            if counts_np is not None
-                            else 0
                         )
-                        obs_mod.hot.inter_token.observe(
-                            dt * len(live) / max(emitted, 1)
-                        )
-                    obs_mod.emit(
-                        obs_mod.StepEvent(
-                            kind=(
-                                "fused_spec"
-                                if fused_share > 0.0
-                                else "spec"
-                            ),
-                            n_live=len(live),
-                            admission_slot=(
-                                adm.slot if fused_share > 0.0 else -1
-                            ),
-                            prefill_tokens=(
-                                chunk_len if fused_share > 0.0 else 0
-                            ),
-                            decode_chunk=width,
-                            pipeline_depth=depth,
-                            sync_reason=step_sync,
-                            # The riding admission's span; batch-level
-                            # otherwise (trace stamps from ambient).
-                            span_id=(
-                                adm.req.span_id
-                                if fused_share > 0.0
-                                else ""
-                            ),
-                            trace_id=(
-                                adm.req.trace_id
-                                if fused_share > 0.0
-                                else ""
-                            ),
-                        )
-                    )
-            elif dispatched:
-                # Streaming consumers ride the double buffer: the entry
-                # carries the step's emitted counts plus an out_buf
-                # SNAPSHOT (jnp.copy — out_buf itself is donated to the
-                # next dispatch, so a raw ref would be deleted before
-                # the depth-bound fetch; the copy is a device-side op
-                # that overlaps compute and only exists while a
-                # consumer is attached).
-                streaming = self._stream_armed(live)
-                entry = (
-                    self.active,
-                    self.n_emitted if streaming else None,
-                    jnp.copy(self.out_buf) if streaming else None,
-                    tuple((s, self._slot_gen[s]) for s in live),
-                )
-                for ref in entry[:3]:
-                    if ref is None:
-                        continue
-                    try:
-                        # Start the device→host copy now; the fetch one
-                        # iteration later should find it resolved.
-                        ref.copy_to_host_async()
-                    except Exception:
-                        pass  # optional fast path only
-                inflight.append(entry)
-                depth = len(inflight)
-                step_sync = ""
-                try:
-                    # Retire completed steps ADAPTIVELY: any entry whose
-                    # flags already resolved (is_ready — free to fetch)
-                    # applies now, so completions/slot-frees are seen
-                    # with zero lag whenever the device keeps up (CPU:
-                    # effectively every iteration). Only force a
-                    # blocking fetch at the depth bound — that is the
-                    # double buffer proper, and it only engages when the
-                    # device is genuinely still executing step N-1.
-                    while inflight and (
-                        len(inflight) >= self.pipeline_depth
-                        or self._entry_ready(inflight[0])
-                    ):
-                        if not self._entry_ready(inflight[0]):
-                            # Depth bound forced a genuinely blocking
-                            # fetch — the double buffer's one sanctioned
-                            # blocking point, made runtime-visible.
-                            obs_mod.record_sync("depth_fetch")
-                            step_sync = "depth_fetch"
-                        self._fetch_entry(inflight.popleft())
-                except Exception as e:
-                    # An async device fault surfaces at the fetch, one
-                    # step late: same eviction surgery as dispatch-time.
-                    inflight.clear()
-                    self._handle_decode_fault(e)
-                dt = time.monotonic() - t0
-                if fused_share > 0.0:
-                    p = dt * fused_share
-                    self._record_prefill_time(p, overlapped=True)
-                    adm.prefill_s += p
-                    self.decode_time_s += dt - p
-                    dec_dt = dt - p
-                else:
-                    self.decode_time_s += dt
-                    dec_dt = dt
-                if live:
-                    # Per-request decode attribution (see the spec
-                    # branch): even split over rows live at dispatch.
-                    dec_share = dec_dt / len(live)
-                    for s in live:
-                        self._slot_decode_s[s] += dec_share
-                if obs_mod.config().enabled:
-                    obs_mod.hot.step_wall.observe(dt)
-                    if live:
-                        obs_mod.hot.inter_token.observe(dt / self.chunk)
-                    obs_mod.emit(
-                        obs_mod.StepEvent(
-                            kind="fused" if fused_share > 0.0 else "decode",
-                            n_live=len(live),
-                            admission_slot=(
-                                adm.slot if fused_share > 0.0 else -1
-                            ),
-                            prefill_tokens=(
-                                chunk_len if fused_share > 0.0 else 0
-                            ),
-                            decode_chunk=self.chunk,
-                            pipeline_depth=depth,
-                            sync_reason=step_sync,
-                            span_id=(
-                                adm.req.span_id
-                                if fused_share > 0.0
-                                else ""
-                            ),
-                            trace_id=(
-                                adm.req.trace_id
-                                if fused_share > 0.0
-                                else ""
-                            ),
-                        )
-                    )
-            self._collect(self._active_np)
+                with obs_mod.phase("drive.collect"):
+                    self._collect(self._active_np)
 
     # -- legacy serialized loop -------------------------------------------
 
@@ -3566,7 +3576,6 @@ class ContinuousBatcher:
                         live_slots = tuple(
                             (s, self._slot_gen[s]) for s in live
                         )
-                        counts_np = None
                         try:
                             counts = self._dispatch_spec(
                                 alloc_len, None, 0
@@ -3594,27 +3603,9 @@ class ContinuousBatcher:
                                 dec_share = dt / len(live)
                                 for s in live:
                                     self._slot_decode_s[s] += dec_share
-                            spec_mod.stats.record_wall(
-                                dt / (width + 1),
-                                dt * width / (width + 1),
-                            )
                             if obs_mod.config().enabled:
                                 obs_mod.record_sync("legacy_step")
                                 obs_mod.hot.step_wall.observe(dt)
-                                # Actual per-row emission, as in the
-                                # pipelined loop — γ+1 is the program
-                                # width, not the delivered tokens.
-                                emitted = (
-                                    sum(
-                                        int(counts_np[2, s])
-                                        for s, _ in live_slots
-                                    )
-                                    if counts_np is not None
-                                    else 0
-                                )
-                                obs_mod.hot.inter_token.observe(
-                                    dt * len(live) / max(emitted, 1)
-                                )
                                 obs_mod.emit(
                                     obs_mod.StepEvent(
                                         kind="spec",
@@ -3644,7 +3635,6 @@ class ContinuousBatcher:
                         if obs_mod.config().enabled:
                             obs_mod.record_sync("legacy_step")
                             obs_mod.hot.step_wall.observe(dt)
-                            obs_mod.hot.inter_token.observe(dt / self.chunk)
                             obs_mod.emit(
                                 obs_mod.StepEvent(
                                     kind="decode",

@@ -80,13 +80,10 @@ class SpecStats(procconfig.StatsBase):
     committed (per-row ``n_allowed`` — the budget/page-clamped draft
     width), so ``accepted / drafted`` is a true acceptance rate, not
     diluted by positions that were never eligible. ``emitted_tokens``
-    additionally counts each step's bonus/rejection token.
-
-    The draft/verify wall split is attributed by position share of the
-    fused draft+verify program (the draft's bigram scan costs about one
-    forward position against the span's γ+1): measuring the halves
-    separately would need a profiler — the same deterministic-share
-    convention the fused prefill+decode step uses.
+    additionally counts each step's bonus/rejection token. No wall
+    lives here: a verify step's time is ``advspec_step_wall_seconds``
+    and the drive loop's phases (``obs.phase``); its draft half is not
+    separately measurable outside a profile.
     """
 
     # PER-ROW verify steps: +1 per LIVE row per dispatched program (B
@@ -98,18 +95,12 @@ class SpecStats(procconfig.StatsBase):
     accepted_tokens: int = 0  # draft positions accepted
     emitted_tokens: int = 0  # tokens emitted by spec steps (incl. bonus)
     rolled_back_pages: int = 0  # draft pages released by rollback
-    draft_time_s: float = 0.0
-    verify_time_s: float = 0.0
 
     def record_step(self, drafted: int, accepted: int, emitted: int) -> None:
         self.spec_steps += 1
         self.drafted_tokens += drafted
         self.accepted_tokens += accepted
         self.emitted_tokens += emitted
-
-    def record_wall(self, draft_s: float, verify_s: float) -> None:
-        self.draft_time_s += draft_s
-        self.verify_time_s += verify_s
 
     def record_rollback(self, pages: int) -> None:
         self.rolled_back_pages += pages

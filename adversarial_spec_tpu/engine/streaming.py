@@ -158,3 +158,12 @@ def consumer_supported(engine) -> bool:
         return "consumer" in inspect.signature(engine.chat).parameters
     except (TypeError, ValueError):
         return False
+
+
+def wants_n_tokens(consumer) -> bool:
+    """True when a stream consumer asked to be called as
+    ``consumer(row, text, n_tokens)``: the count of token ids behind
+    ``text``, which only the engine knows (a ``wants_n_tokens = True``
+    attribute on the callable; the serve gate sets it). Every other
+    consumer keeps the two-argument call."""
+    return bool(getattr(consumer, "wants_n_tokens", False))

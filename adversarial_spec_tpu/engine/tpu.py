@@ -59,7 +59,12 @@ from adversarial_spec_tpu.engine.tokenizer import (
     apply_chat_template,
     load_tokenizer,
 )
-from adversarial_spec_tpu.engine.types import ChatRequest, Completion, SamplingParams
+from adversarial_spec_tpu.engine.types import (
+    ChatRequest,
+    Completion,
+    SamplingParams,
+    Served,
+)
 from adversarial_spec_tpu.models.config import ModelConfig
 from adversarial_spec_tpu.parallel.mesh import (
     make_mesh,
@@ -789,8 +794,14 @@ class TpuEngine:
             # re-map each group's row back through the group indices.
             group_consumer = None
             if consumer is not None:
-                def group_consumer(row, text, _c=consumer, _ix=tuple(indices)):
-                    return _c(_ix[row], text)
+                def group_consumer(
+                    row, text, *n_tokens, _c=consumer, _ix=tuple(indices)
+                ):
+                    return _c(_ix[row], text, *n_tokens)
+
+                group_consumer.wants_n_tokens = stream_mod.wants_n_tokens(
+                    consumer
+                )
             try:
                 completions = self._chat_one_model(
                     alias,
@@ -889,15 +900,18 @@ class TpuEngine:
         instruct = lm.spec.checkpoint != "random"
 
         prompts = []
-        for req in batch:
-            text = apply_chat_template(
-                lm.spec.family, req.system, req.user, instruct
-            )
-            ids = tok.encode(text)
-            # Reserve room for generation within the model's context.
-            prompts.append(
-                _trim_prompt(ids, lm.cfg.max_seq_len - params.max_new_tokens)
-            )
+        with obs_mod.phase("engine.tokenize"):
+            for req in batch:
+                text = apply_chat_template(
+                    lm.spec.family, req.system, req.user, instruct
+                )
+                ids = tok.encode(text)
+                # Reserve room for generation within the model's context.
+                prompts.append(
+                    _trim_prompt(
+                        ids, lm.cfg.max_seq_len - params.max_new_tokens
+                    )
+                )
 
         # Paged single-device specs serve through the continuous batcher:
         # opponents occupy decode slots, early-EOS rows free their pages
@@ -1077,34 +1091,49 @@ class TpuEngine:
         tok_total = float(sum(r.n_generated for r in results)) or 1.0
         overhead = total_time - decode_time
         completions = []
-        for r in results:  # sorted by req_id == prompt order
-            frac = r.n_generated / tok_total
-            decode_share = decode_time * frac
-            completions.append(
-                Completion(
-                    # Fault-evicted rows keep their partial decode in
-                    # ``text`` (diagnostic value) but carry the error so
-                    # the debate core's retry/degrade policy applies.
-                    # Cancelled rows are CLEAN partials: the consumer
-                    # read everything it needed before stopping them.
-                    text=tok.decode(r.tokens[: r.n_generated]),
-                    error=r.error,
-                    cancelled=r.cancelled,
-                    transient=(
-                        r.fault_kind is not None
-                        and faults.FaultKind(r.fault_kind).transient
-                    ),
-                    usage=Usage(
-                        input_tokens=len(prompts[r.req_id]),
-                        output_tokens=r.n_generated,
-                        device_time_s=overhead / len(results) + decode_share,
-                        decode_tokens=r.n_generated,
-                        decode_time_s=decode_share,
-                        cached_tokens=r.cached_tokens,
-                        prefill_time_s=r.prefill_time_s,
-                    ),
+        with obs_mod.phase("engine.finish"):
+            for r in results:  # sorted by req_id == prompt order
+                frac = r.n_generated / tok_total
+                decode_share = decode_time * frac
+                completions.append(
+                    Completion(
+                        # Fault-evicted rows keep their partial decode
+                        # in ``text`` (diagnostic value) but carry the
+                        # error so the debate core's retry/degrade
+                        # policy applies. Cancelled rows are CLEAN
+                        # partials: the consumer read everything it
+                        # needed before stopping them.
+                        text=tok.decode(r.tokens[: r.n_generated]),
+                        error=r.error,
+                        cancelled=r.cancelled,
+                        transient=(
+                            r.fault_kind is not None
+                            and faults.FaultKind(r.fault_kind).transient
+                        ),
+                        usage=Usage(
+                            input_tokens=len(prompts[r.req_id]),
+                            output_tokens=r.n_generated,
+                            device_time_s=(
+                                overhead / len(results) + decode_share
+                            ),
+                            decode_tokens=r.n_generated,
+                            decode_time_s=decode_share,
+                            cached_tokens=r.cached_tokens,
+                            prefill_time_s=r.prefill_time_s,
+                        ),
+                        # What the batcher itself measured for this
+                        # request, and the ids it was given and served
+                        # (the daemon's ``timing`` /
+                        # ``return_token_ids``).
+                        served=Served(
+                            prompt_token_ids=prompts[r.req_id],
+                            token_ids=r.tokens[: r.n_generated],
+                            batcher_queue_s=r.queue_wait_s,
+                            prefill_s=r.prefill_time_s,
+                            decode_s=r.decode_time_s,
+                        ),
+                    )
                 )
-            )
         return completions
 
     @staticmethod
@@ -1123,8 +1152,13 @@ class TpuEngine:
         represents. Returning False asks the batcher to cancel the
         request mid-decode."""
 
+        # A consumer may ask for the count of ids behind each text (the
+        # daemon's ``n_tokens`` on stream events).
+        counted = stream_mod.wants_n_tokens(consumer)
+
         def on_tokens(token_ids) -> bool:
-            return bool(consumer(row, tok.decode(token_ids)))
+            n_tokens = (len(token_ids),) if counted else ()
+            return bool(consumer(row, tok.decode(token_ids), *n_tokens))
 
         return on_tokens
 
@@ -1142,7 +1176,7 @@ class TpuEngine:
         whenever two drains interleave on one engine."""
         tok = lm.tokenizer
         n_slots, capacity = batcher_key[0], batcher_key[1]
-        with lm.mesh:
+        with lm.mesh, obs_mod.phase("engine.acquire_batcher"):
             if lm.batcher is not None and lm.batcher_key == batcher_key:
                 # Round R+1 reuses round R's batcher: same compiled chunk
                 # programs AND a warm prefix cache (the shared
@@ -1186,6 +1220,9 @@ class TpuEngine:
                 )
                 lm.batcher = batcher
                 lm.batcher_key = batcher_key
+                if obs_mod.config().enabled:
+                    obs_mod.hot.batcher_builds.inc()
+        with lm.mesh:
             # Per-round telemetry delta: the persistent batcher's
             # counters accumulate across rounds.
             decode_t0 = batcher.decode_time_s
@@ -1214,5 +1251,6 @@ class TpuEngine:
                         ),
                     )
                 )
-            results = batcher.run_all(timeout_s=params.timeout_s)
+            with obs_mod.phase("engine.run_all"):
+                results = batcher.run_all(timeout_s=params.timeout_s)
             return results, batcher.decode_time_s - decode_t0

@@ -76,6 +76,20 @@ class ChatRequest:
 
 
 @dataclass
+class Served:
+    """What the ContinuousBatcher measured for one request, and the ids
+    it was given and served — the source of the daemon's per-result
+    ``timing`` and, on request, ``prompt_token_ids`` / ``token_ids``
+    (serve/driver.py). Walls in seconds on the host's clock."""
+
+    prompt_token_ids: object  # sequence of int, as submitted (trimmed)
+    token_ids: object  # sequence of int: the generated ids
+    batcher_queue_s: float = 0.0  # submit -> admission start
+    prefill_s: float = 0.0  # admission start -> first sampled token
+    decode_s: float = 0.0  # this request's share of the decode steps
+
+
+@dataclass
 class Completion:
     """One model's completion; ``error`` set instead of raising so a batch
     can partially fail (parity: reference captures errors into
@@ -91,6 +105,8 @@ class Completion:
     # consumer read everything it needed).
     cancelled: bool = False
     usage: Usage = field(default_factory=Usage)
+    # Set by engines that serve through the batcher; None elsewhere.
+    served: Served | None = None
 
     @property
     def ok(self) -> bool:

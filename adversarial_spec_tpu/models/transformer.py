@@ -271,29 +271,34 @@ def _attn_out_and_ffn(
 
     ``mm``: matmul implementation (see ``_project_qkv``).
     """
-    out = mm(
-        attn_out.reshape(B, S, cfg.n_heads * cfg.head_dim), lp["wo"]
-    )
-    if psum_axis is not None:
-        out = jax.lax.psum(out, psum_axis)
-    if cfg.post_norms:
-        out = rms_norm(
-            out, lp["post_attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one
+    with jax.named_scope("attn"):
+        out = mm(
+            attn_out.reshape(B, S, cfg.n_heads * cfg.head_dim), lp["wo"]
         )
-    x = x + out
+        if psum_axis is not None:
+            out = jax.lax.psum(out, psum_axis)
+        if cfg.post_norms:
+            out = rms_norm(
+                out,
+                lp["post_attn_norm"],
+                cfg.rms_eps,
+                cfg.norm_scale_plus_one,
+            )
+        x = x + out
 
-    h = rms_norm(x, lp["ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
-    ff = _activation(mm(h, lp["w_gate"]), cfg.activation) * mm(
-        h, lp["w_up"]
-    )
-    ff = mm(ff, lp["w_down"])
-    if psum_axis is not None:
-        ff = jax.lax.psum(ff, psum_axis)
-    if cfg.post_norms:
-        ff = rms_norm(
-            ff, lp["post_ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
+        ff = _activation(mm(h, lp["w_gate"]), cfg.activation) * mm(
+            h, lp["w_up"]
         )
-    return x + ff
+        ff = mm(ff, lp["w_down"])
+        if psum_axis is not None:
+            ff = jax.lax.psum(ff, psum_axis)
+        if cfg.post_norms:
+            ff = rms_norm(
+                ff, lp["post_ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one
+            )
+        return x + ff
 
 
 def _layer_window_start(cfg: ModelConfig, layer_id, base_start, q_pos):
@@ -453,6 +458,11 @@ def forward(
         return out, out["k"], out["v"]
 
     def layer_body(x, scanned):
+        with jax.named_scope("attn"):
+            out, cache_l = attn_block(x, scanned)
+        return _attn_out_and_ffn(x, out, scanned[0], cfg, B, S, mm=mm), cache_l
+
+    def attn_block(x, scanned):
         lp, layer_id, cache_l = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
         q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
@@ -548,18 +558,20 @@ def forward(
                 attn_softcap=cfg.attn_softcap,
                 scale=cfg.attn_scale,
             )
-        x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm)
-        return x, cache_l
+        return out, cache_l
 
     # The cache dict scans as a pytree: every leaf carries a leading
     # n_layers axis, so one scan serves both cache layouts. Decode spans
     # unroll (see _DECODE_UNROLL) so weight DMA pipelines across layers.
-    x, new_cache = jax.lax.scan(
-        layer_body,
-        x,
-        (params["layers"], layer_ids, cache),
-        unroll=_DECODE_UNROLL if S <= _DECODE_UNROLL_MAX_SPAN else 1,
-    )
+    # "layers" names the scan itself: the slicing of each layer's
+    # weights out of the stacked arrays has no other owner.
+    with jax.named_scope("layers"):
+        x, new_cache = jax.lax.scan(
+            layer_body,
+            x,
+            (params["layers"], layer_ids, cache),
+            unroll=_DECODE_UNROLL if S <= _DECODE_UNROLL_MAX_SPAN else 1,
+        )
 
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
     return logits, new_cache
@@ -568,6 +580,11 @@ def forward(
 def _lm_head_logits(
     params: Params, cfg: ModelConfig, x, lm_head_last_only: bool, mm=matmul
 ):
+    with jax.named_scope("head"):
+        return _lm_head(params, cfg, x, lm_head_last_only, mm)
+
+
+def _lm_head(params: Params, cfg: ModelConfig, x, lm_head_last_only, mm):
     x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
     if lm_head_last_only:
         # Prompt chunks only ever need the final position's logits; skip
@@ -685,13 +702,19 @@ def forward_paged_decode(
     heads = jnp.arange(cfg.n_kv_heads)
 
     def layer_body(carry, scanned):
+        x, pool = carry
+        with jax.named_scope("attn"):
+            out, pool = attn_block(x, pool, scanned)
+        x = _attn_out_and_ffn(x, out, scanned[0], cfg, B, S, mm=mm)
+        return (x, pool), None
+
+    def attn_block(x, pool, scanned):
         # The WHOLE pool rides the scan carry and every layer updates and
         # reads it in place, addressed by layer index. Scanning it as
         # per-layer xs/ys instead makes XLA slice one layer's pages out
         # (a copy), restack them into a second pool-sized buffer, and
         # copy that back over the carried pool every step: temporaries
         # of twice the pool, which a pool sized to the chip cannot pay.
-        x, pool = carry
         lp, layer_id = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
         q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
@@ -841,17 +864,17 @@ def forward_paged_decode(
                 attn_softcap=cfg.attn_softcap,
                 scale=cfg.attn_scale,
             )
-        x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm)
-        return (x, pool), None
+        return out, pool
 
     # Always a decode step here (short S) → always unrolled for
     # weight-DMA pipelining.
-    (x, new_pool), _ = jax.lax.scan(
-        layer_body,
-        (x, pool),
-        (params["layers"], layer_ids),
-        unroll=_DECODE_UNROLL,
-    )
+    with jax.named_scope("layers"):
+        (x, new_pool), _ = jax.lax.scan(
+            layer_body,
+            (x, pool),
+            (params["layers"], layer_ids),
+            unroll=_DECODE_UNROLL,
+        )
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)
     return logits, new_pool
 

@@ -31,6 +31,7 @@ is off the serving path pays a single attribute load per site.
 from __future__ import annotations
 
 import os
+import sys
 import time
 from dataclasses import dataclass
 
@@ -67,6 +68,35 @@ from adversarial_spec_tpu.obs.metrics import (  # noqa: F401 (re-export)
 from adversarial_spec_tpu.obs.retrace import RetraceWatch
 
 DEFAULT_RECORDER_SIZE = 512
+
+# THE closed vocabulary of ``phase()``: every layer boundary of the
+# serving path that is timed, from the daemon's coalesced dispatch down
+# to the drive loop's iteration. One name = one label of
+# ``advspec_phase_seconds`` = one ``advspec.<name>`` span in a profile
+# (docs/observability.md lists what each covers).
+PHASES = (
+    "serve.dispatch",
+    "engine.tokenize",
+    "engine.acquire_batcher",
+    "engine.run_all",
+    "engine.finish",
+    "drive.iteration",
+    "drive.admit",
+    "drive.prefill",
+    "drive.prepare",
+    "drive.dispatch",
+    "drive.fetch",
+    "drive.apply",
+    "drive.stream",
+    "drive.collect",
+)
+
+# The device side's names: the ``jax.named_scope``s of the step programs
+# (models/transformer.py, ops/quant.py, engine/scheduler.py), which reach
+# every operation's ``op_name`` in the compiled program. ``layers`` is the
+# layer scan (and its slicing of the stacked weights); ``attn`` and ``mlp``
+# nest in it, ``qmm`` (the dequant-matmul) in those and in ``head``.
+DEVICE_SCOPES = ("layers", "attn", "qmm", "mlp", "head", "sample")
 
 
 @dataclass
@@ -178,9 +208,10 @@ class HotMetrics:
     """
 
     __slots__ = (
-        "ttft",
+        "prefill_wall",
+        "batcher_queue_wait",
+        "serve_ttft",
         "step_wall",
-        "inter_token",
         "prefill_chunk",
         "pool_util",
         "hit_ratio",
@@ -199,7 +230,12 @@ class HotMetrics:
         "serve_queue_wait",
         "weight_resident",
         "handoff_latency",
+        "batcher_runs",
+        "batcher_rows",
+        "batcher_distinct_prompts",
+        "batcher_builds",
         "_m",
+        "_phase",
         "_sync",
         "_fault",
         "_breaker",
@@ -219,17 +255,26 @@ class HotMetrics:
 
     def __init__(self, m: MetricsRegistry) -> None:
         self._m = m
-        self.ttft = m.histogram(
-            "advspec_ttft_seconds",
-            help="admission prefill through first sampled token",
+        # A request's stages, beside the SpanEvents that mark them:
+        # batcher queue (submit -> admission start), prefill wall
+        # (admission start -> first sampled token) and, in the daemon,
+        # accept -> the unit's first delivery handed to ``on_stream``.
+        self.batcher_queue_wait = m.histogram(
+            "advspec_batcher_queue_wait_seconds",
+            help="batcher submit through admission start",
+        )
+        self.prefill_wall = m.histogram(
+            "advspec_prefill_wall_seconds",
+            help="admission start through first sampled token",
+        )
+        self.serve_ttft = m.histogram(
+            "advspec_serve_ttft_seconds",
+            help="daemon accept through the opponent unit's first "
+            "stream delivery",
         )
         self.step_wall = m.histogram(
             "advspec_step_wall_seconds",
             help="drive-loop iteration wall (dispatch+fetch)",
-        )
-        self.inter_token = m.histogram(
-            "advspec_inter_token_seconds",
-            help="step wall / decode-chunk budget",
         )
         self.prefill_chunk = m.histogram(
             "advspec_prefill_chunk_wall_seconds",
@@ -342,6 +387,27 @@ class HotMetrics:
             help="cross-replica KV handoff wall (prefill publish "
             "through decode adoption)",
         )
+        # What one ``ContinuousBatcher.run_all`` drained, counted where
+        # the work happens, and how often a batcher was BUILT (a rebuild
+        # drops the prefix cache).
+        self.batcher_runs = m.counter(
+            "advspec_batcher_runs_total",
+            help="ContinuousBatcher.run_all drains",
+        )
+        self.batcher_rows = m.counter(
+            "advspec_batcher_rows_total",
+            help="requests queued at the start of a run_all drain",
+        )
+        self.batcher_distinct_prompts = m.counter(
+            "advspec_batcher_distinct_prompts_total",
+            help="different prompts among a run_all drain's requests",
+        )
+        self.batcher_builds = m.counter(
+            "advspec_batcher_builds_total",
+            help="ContinuousBatcher constructions by the engine "
+            "(a rebuild drops the prefix cache)",
+        )
+        self._phase: dict = {}
         self._sync: dict = {}
         self._fault: dict = {}
         self._breaker: dict = {}
@@ -357,6 +423,22 @@ class HotMetrics:
         self._handoff: dict = {}
         self._lock_hold: dict = {}
         self._lock_wait: dict = {}
+
+    def phase(self, name: str):
+        """Wall histogram of one ``phase()`` name (closed vocabulary:
+        ``PHASES``)."""
+        h = self._phase.get(name)
+        if h is None:
+            if name not in PHASES:
+                raise ValueError(
+                    f"unknown phase {name!r} (declared: {', '.join(PHASES)})"
+                )
+            h = self._phase[name] = self._m.histogram(
+                "advspec_phase_seconds",
+                help="wall of one serving-path phase (obs.phase)",
+                phase=name,
+            )
+        return h
 
     def sync(self, reason: str):
         c = self._sync.get(reason)
@@ -549,6 +631,66 @@ class HotMetrics:
 
 
 hot = HotMetrics(metrics)
+
+
+class _NoPhase:
+    """The shared no-op ``phase()`` returns while obs is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_PHASE = _NoPhase()
+
+
+class _Phase:
+    """One timed use of a phase: the profiler annotation and the
+    histogram observation share the same two clock readings."""
+
+    __slots__ = ("_hist", "_ann", "_t0")
+
+    def __init__(self, hist, ann) -> None:
+        self._hist = hist
+        self._ann = ann
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._hist.observe(time.perf_counter() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def phase(name: str):
+    """THE timing primitive: ``with obs.phase("drive.fetch"): ...``.
+
+    On exit the elapsed ``time.perf_counter()`` lands in
+    ``advspec_phase_seconds{phase=name}``; while a jax profile is being
+    taken the same interval is an ``advspec.<name>`` span on the calling
+    thread's line of the profile, beside the device's operations (one
+    clock: the phase table and the profile cannot disagree). jax is
+    taken from ``sys.modules`` and never imported here (mock-only flows
+    stay off it); its annotation is inert while no profile is taken.
+    With obs off this is a shared no-op."""
+    if not _config.enabled:
+        return _NO_PHASE
+    jax = sys.modules.get("jax")
+    return _Phase(
+        hot.phase(name),
+        jax.profiler.TraceAnnotation("advspec." + name)
+        if jax is not None
+        else None,
+    )
 
 
 def config() -> ObsConfig:

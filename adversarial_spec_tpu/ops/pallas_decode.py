@@ -341,6 +341,7 @@ def decode_attention_mq(
         ],
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
+        name="decode_attention_mq",
     )(*operands)
 
     out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)
@@ -489,6 +490,7 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(bounds, *operands)
 
     return out[:, :, :g, :].reshape(B, Hq, D)

@@ -217,6 +217,7 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(bounds, page_table, layer, *operands)
 
     return out[:, :, :g, :].reshape(B, Hq, D)
@@ -394,6 +395,7 @@ def paged_decode_attention_mq(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention_mq",
     )(page_table, layer, *operands)
 
     out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)

@@ -206,6 +206,7 @@ def matmul_int8(
             (Mp, N), _out_dtype(x, preferred_element_type)
         ),
         interpret=interpret,
+        name="matmul_int8",
     )(x2, q, scale.reshape(1, N).astype(jnp.float32))
     return out[:M].reshape(lead + (N,))
 
@@ -252,6 +253,7 @@ def matmul_int4(
             (Mp, N), _out_dtype(x, preferred_element_type)
         ),
         interpret=interpret,
+        name="matmul_int4",
     )(xe, xo, q4, scale.reshape(1, N).astype(jnp.float32))
     return out[:M].reshape(lead + (N,))
 

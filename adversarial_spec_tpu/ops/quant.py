@@ -152,38 +152,47 @@ def matmul(
     ``interpret=True`` runs those kernels in Pallas interpret mode (the
     CPU-parity harness; flag-gated exactly like ``use_pallas_decode``).
     """
-    if use_pallas and (is_quantized(w) or is_quantized_int4(w)):
-        from adversarial_spec_tpu.ops import pallas_quant
+    # One device-side name for the (dequant-)matmul wherever a layer
+    # calls it: ``.../attn/qmm``, ``.../mlp/qmm``, ``head/qmm``.
+    with jax.named_scope("qmm"):
+        if use_pallas and (is_quantized(w) or is_quantized_int4(w)):
+            from adversarial_spec_tpu.ops import pallas_quant
 
-        if pallas_quant.fused_supported(x, w):
-            return pallas_quant.quant_matmul(
+            if pallas_quant.fused_supported(x, w):
+                return pallas_quant.quant_matmul(
+                    x,
+                    w,
+                    preferred_element_type=preferred_element_type,
+                    interpret=interpret,
+                )
+        if is_quantized_int4(w):
+            q = unpack_int4(w["q4"], x.shape[-1])
+            y = jnp.matmul(
                 x,
-                w,
+                q.astype(x.dtype),
                 preferred_element_type=preferred_element_type,
-                interpret=interpret,
             )
-    if is_quantized_int4(w):
-        q = unpack_int4(w["q4"], x.shape[-1])
-        y = jnp.matmul(
-            x,
-            q.astype(x.dtype),
-            preferred_element_type=preferred_element_type,
+            scale = w["scale"][..., 0, :]
+            return y * (
+                scale
+                if preferred_element_type is not None
+                else scale.astype(x.dtype)
+            )
+        if is_quantized(w):
+            y = jnp.matmul(
+                x,
+                w["q"].astype(x.dtype),
+                preferred_element_type=preferred_element_type,
+            )
+            scale = w["scale"][..., 0, :]
+            return y * (
+                scale
+                if preferred_element_type is not None
+                else scale.astype(x.dtype)
+            )
+        return jnp.matmul(
+            x, w, preferred_element_type=preferred_element_type
         )
-        scale = w["scale"][..., 0, :]
-        return y * (
-            scale if preferred_element_type is not None else scale.astype(x.dtype)
-        )
-    if is_quantized(w):
-        y = jnp.matmul(
-            x,
-            w["q"].astype(x.dtype),
-            preferred_element_type=preferred_element_type,
-        )
-        scale = w["scale"][..., 0, :]
-        return y * (
-            scale if preferred_element_type is not None else scale.astype(x.dtype)
-        )
-    return jnp.matmul(x, w, preferred_element_type=preferred_element_type)
 
 
 def has_quantized_weights(params) -> bool:

@@ -109,6 +109,15 @@ class ServeClient:
     def refill(self, tenant: str, tokens: int) -> dict:
         return self.call({"op": "refill", "tenant": tenant, "tokens": tokens})
 
+    def profile(self, seconds: float, out_dir: str) -> dict:
+        """Have the daemon take a jax profile of itself for ``seconds``
+        into ``out_dir``; returns when the window has closed and the
+        file is written (``path`` in the reply)."""
+        return self.call(
+            {"op": "profile", "seconds": seconds, "dir": out_dir},
+            timeout_s=seconds + 120.0,
+        )
+
     def submit_debate(
         self,
         spec: str,
@@ -120,6 +129,7 @@ class ServeClient:
         session: str | None = None,
         stream: bool = False,
         max_new_tokens: int | None = None,
+        return_token_ids: bool = False,
     ) -> str:
         """Fire-and-forget submit (the open-loop storm's primitive);
         collect the outcome later with ``collect``."""
@@ -137,4 +147,6 @@ class ServeClient:
             obj["stream"] = True
         if max_new_tokens is not None:
             obj["max_new_tokens"] = max_new_tokens
+        if return_token_ids:
+            obj["return_token_ids"] = True
         return self.send(obj)

@@ -35,6 +35,7 @@ import asyncio
 import json
 import os
 import signal
+import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -98,6 +99,7 @@ class ServeDaemon:
         self._writers: set[asyncio.StreamWriter] = set()
         self._draining = False
         self._drain_reason = ""
+        self._profiling = False  # one ``profile`` op at a time
         self._done = asyncio.Event()
         self._t_start = time.monotonic()
         self.drain_report: dict | None = None
@@ -359,8 +361,69 @@ class ServeDaemon:
         elif op == "drain":
             self.begin_drain("drain_op")
             self._send(writer, {"id": req_id, "event": "ok"})
+        elif op == "profile":
+            self._handle_profile(obj, writer)
         elif op == "debate":
             self._handle_debate(obj, writer)
+
+    def _handle_profile(self, obj: dict, writer: asyncio.StreamWriter) -> None:
+        """Take a jax profile of this process: the ``advspec.*`` phases
+        of every serving thread and the device's operations, on one
+        clock (``tools/trace_view.py --xplane`` reads it). Only the
+        process that holds the chip can trace it, hence an op."""
+        req_id = obj["id"]
+        problem = None
+        if self._profiling:
+            problem = "a profile is already being taken"
+        elif "jax" not in sys.modules:
+            problem = (
+                "nothing to profile: no tpu:// engine has served in this "
+                "process yet (jax is not loaded)"
+            )
+        if problem is not None:
+            self._send(writer, protocol.error_event(req_id, [problem]))
+            return
+        self._profiling = True
+        assert self._loop is not None
+        task = self._loop.create_task(
+            self._profile_task(
+                req_id, float(obj["seconds"]), obj["dir"], writer
+            )
+        )
+        self._inflight.add(task)
+        task.add_done_callback(self._inflight.discard)
+
+    async def _profile_task(self, req_id, seconds, out_dir, writer) -> None:
+        import jax
+
+        assert self._loop is not None
+        try:
+            jax.profiler.start_trace(out_dir)
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                # Writing the file takes seconds for a long window:
+                # off the loop, so admissions and streams keep flowing.
+                await self._loop.run_in_executor(
+                    None, jax.profiler.stop_trace
+                )
+            files = sorted(
+                Path(out_dir).glob("plugins/profile/*/*.xplane.pb"),
+                key=lambda f: f.stat().st_mtime,
+            )
+            event = {
+                "id": req_id,
+                "event": "ok",
+                "seconds": seconds,
+                "path": str(files[-1]) if files else "",
+            }
+        except Exception as e:  # a failed profile must not kill the daemon
+            event = protocol.error_event(
+                req_id, [f"profile failed: {type(e).__name__}: {e}"]
+            )
+        finally:
+            self._profiling = False
+        self._send(writer, event)
 
     def _check_event(self, req_id: str) -> dict:
         """Allocator/tier invariants across every live inner engine —
@@ -421,16 +484,20 @@ class ServeDaemon:
         )
         on_stream = None
         if obj.get("stream"):
-            def on_stream(index: int, text: str, _w=writer, _id=req_id):
-                self._send_threadsafe(
-                    _w,
-                    {
-                        "id": _id,
-                        "event": "stream",
-                        "index": index,
-                        "text": text,
-                    },
-                )
+            with_ids = bool(obj.get("return_token_ids"))
+
+            def on_stream(
+                index: int, text: str, n_tokens=None, _w=writer, _id=req_id
+            ):
+                event = {
+                    "id": _id,
+                    "event": "stream",
+                    "index": index,
+                    "text": text,
+                }
+                if with_ids and n_tokens is not None:
+                    event["n_tokens"] = int(n_tokens)
+                self._send_threadsafe(_w, event)
         assert self._loop is not None
         task = self._loop.create_task(
             self._await_debate(

@@ -67,6 +67,41 @@ def _params_from_payload(payload: dict) -> SamplingParams:
     )
 
 
+def _result_entry(r, unit, with_ids: bool) -> dict:
+    """One opponent's entry of the result payload. ``unit`` is the
+    resolved serve unit of the request (None when no engine dispatch
+    served it: a journal replay, a breaker skip)."""
+    comp = unit.completion if unit is not None else None
+    served = comp.served if comp is not None else None
+    entry = {
+        "model": r.model,
+        "agreed": r.agreed,
+        "response": r.critique,
+        "spec": r.revised_spec,
+        "error": r.error,
+        "span_id": r.span_id,
+        "input_tokens": r.usage.input_tokens,
+        "output_tokens": r.usage.output_tokens,
+        "cached_tokens": r.usage.cached_tokens,
+        # Where this request's wall went, each stage measured where it
+        # happens: the serve scheduler's queue (enqueue -> dispatch),
+        # then inside the batcher (submit -> admission start -> first
+        # sampled token -> last). Zero where the engine reports none.
+        "timing": {
+            "serve_queue_s": round(getattr(unit, "queue_wait_s", 0.0), 6),
+            "batcher_queue_s": round(
+                getattr(served, "batcher_queue_s", 0.0), 6
+            ),
+            "prefill_s": round(getattr(served, "prefill_s", 0.0), 6),
+            "decode_s": round(getattr(served, "decode_s", 0.0), 6),
+        },
+    }
+    if with_ids and served is not None:
+        entry["prompt_token_ids"] = [int(t) for t in served.prompt_token_ids]
+        entry["token_ids"] = [int(t) for t in served.token_ids]
+    return entry
+
+
 def run_debate(
     payload: dict,
     sched: ServeScheduler,
@@ -143,17 +178,11 @@ def run_debate(
                 result.tracer.counters.get("journal.served", 0)
             ),
             "results": [
-                {
-                    "model": r.model,
-                    "agreed": r.agreed,
-                    "response": r.critique,
-                    "spec": r.revised_spec,
-                    "error": r.error,
-                    "span_id": r.span_id,
-                    "input_tokens": r.usage.input_tokens,
-                    "output_tokens": r.usage.output_tokens,
-                    "cached_tokens": r.usage.cached_tokens,
-                }
+                _result_entry(
+                    r,
+                    sub.served.get(r.span_id),
+                    bool(payload.get("return_token_ids")),
+                )
                 for r in result.responses
             ],
             # The per-debate breaker snapshot at round commit: the

@@ -43,6 +43,7 @@ import threading
 import time
 from contextlib import contextmanager
 
+from adversarial_spec_tpu import obs as obs_mod
 from adversarial_spec_tpu.engine import streaming as stream_mod
 from adversarial_spec_tpu.engine.types import Completion
 from adversarial_spec_tpu.resilience import faults as faults_mod
@@ -56,7 +57,9 @@ class Submission:
     and the TTFT probe (first delivery or first completion, whichever
     lands first — the drill's interactive-SLO measurement)."""
 
-    __slots__ = ("tenant", "tier", "debate", "on_stream", "t0", "ttft_s")
+    __slots__ = (
+        "tenant", "tier", "debate", "on_stream", "t0", "ttft_s", "served",
+    )
 
     def __init__(
         self,
@@ -72,6 +75,10 @@ class Submission:
         self.on_stream = on_stream
         self.t0 = time.monotonic() if t0 is None else t0
         self.ttft_s: float | None = None
+        # span id -> the resolved Unit of each opponent request this
+        # debate's rounds issued (the driver reads its queue wait and
+        # what the batcher served from here).
+        self.served: dict[str, Unit] = {}
 
     def note_first_token(self) -> None:
         if self.ttft_s is None:
@@ -173,6 +180,7 @@ class GatedEngine:
                 # No streaming armed: TTFT falls back to the first
                 # resolved opponent.
                 u.submission.note_first_token()
+            sub.served[u.request.span_id] = u
         return [u.completion for u in units]
 
 
@@ -180,13 +188,24 @@ def _composed_consumer(batch: list[Unit]):
     """One consumer for one engine dispatch, multiplexing the batch's
     units by row index. See the module docstring for the precedence
     contract."""
-    def consume(row: int, text: str) -> bool:
+    first = [True] * len(batch)
+
+    def consume(row: int, text: str, n_tokens: int | None = None) -> bool:
         u = batch[row]
         if u.submission is not None:
             u.submission.note_first_token()
         if u.on_stream is not None:
             try:
-                u.on_stream(u.index, text)
+                u.on_stream(u.index, text, n_tokens)
+                if first[row] and u.submission is not None:
+                    # Daemon accept -> this unit's first delivery
+                    # handed to the client's stream: the TTFT the
+                    # program itself can see (the socket is not in it).
+                    first[row] = False
+                    if obs_mod.config().enabled:
+                        obs_mod.hot.serve_ttft.observe(
+                            max(0.0, time.monotonic() - u.submission.t0)
+                        )
             except Exception:
                 # A broken client callback disables itself; the decode
                 # and the round are unharmed (the batcher's own
@@ -208,6 +227,7 @@ def _composed_consumer(batch: list[Unit]):
             return False
         return True
 
+    consume.wants_n_tokens = True
     return consume
 
 
@@ -234,14 +254,16 @@ class EnginePump(threading.Thread):
         requests = [u.request for u in batch]
         params = batch[0].params
         try:
-            if stream_mod.config().enabled and stream_mod.consumer_supported(
-                engine
-            ):
-                comps = engine.chat(
-                    requests, params, consumer=_composed_consumer(batch)
-                )
-            else:
-                comps = engine.chat(requests, params)
+            with obs_mod.phase("serve.dispatch"):
+                if (
+                    stream_mod.config().enabled
+                    and stream_mod.consumer_supported(engine)
+                ):
+                    comps = engine.chat(
+                        requests, params, consumer=_composed_consumer(batch)
+                    )
+                else:
+                    comps = engine.chat(requests, params)
         except Exception as e:  # the engine seam's containment rule
             kind = faults_mod.classify(e)
             faults_mod.record(kind, "serve_dispatch")

@@ -19,6 +19,10 @@ Request ops (``REQUEST_FIELDS`` is the schema contract, validated by
 - ``ping`` / ``stats`` / ``check`` — liveness, the ``perf.serve``-
   shaped counters + scheduler state, and engine allocator/tier
   invariants (the chaos drill's clean-survivor probe).
+- ``profile`` — take a jax profile of this process for ``seconds``
+  into ``dir`` (host ``advspec.*`` phases and the device's operations
+  on one clock, docs/observability.md); answers ``ok`` with the
+  ``.xplane.pb`` path when the window closes.
 - ``refill`` — add tokens to a tenant's quota (the admission ledger).
 - ``drain`` — begin the graceful drain (the SIGTERM path, reachable
   over the wire for harnesses that cannot signal).
@@ -39,7 +43,9 @@ import json
 
 PROTOCOL_VERSION = 1
 
-REQUEST_OPS = ("debate", "ping", "stats", "check", "refill", "drain")
+REQUEST_OPS = (
+    "debate", "ping", "stats", "check", "refill", "drain", "profile",
+)
 
 # Typed load-shed reasons (the admission contract docs/serving.md
 # documents; every refusal names exactly one):
@@ -52,6 +58,10 @@ REQUEST_OPS = ("debate", "ping", "stats", "check", "refill", "drain")
 SHED_REASONS = ("queue_full", "backlog", "quota", "brownout", "draining")
 
 TIERS = ("interactive", "batch")
+
+# A profile holds every device operation of its window in memory until
+# it is written: the ``profile`` op refuses longer windows.
+MAX_PROFILE_SECONDS = 120
 
 RESPONSE_EVENTS = (
     "accepted",
@@ -82,6 +92,9 @@ REQUEST_FIELDS: dict[str, dict[str, tuple]] = {
         "stream": (bool, False),  # per-opponent text-so-far events
         "max_new_tokens": (int, False),
         "greedy": (bool, False),
+        # prompt_token_ids / token_ids per result, n_tokens per stream
+        # event (engines that serve through the batcher; default off).
+        "return_token_ids": (bool, False),
     },
     "ping": {},
     "stats": {},
@@ -91,6 +104,10 @@ REQUEST_FIELDS: dict[str, dict[str, tuple]] = {
         "tokens": (int, True),
     },
     "drain": {},
+    "profile": {
+        "seconds": ((int, float), True),
+        "dir": (str, True),
+    },
 }
 
 
@@ -114,6 +131,10 @@ def decode(line: bytes | str) -> dict | None:
     return obj if isinstance(obj, dict) else None
 
 
+def _type_name(py) -> str:
+    return py.__name__ if isinstance(py, type) else "number"
+
+
 def validate_request(obj: dict) -> list[str]:
     """Schema-check one decoded request line; returns human-readable
     problems (empty = valid). Malformed requests are answered with an
@@ -134,17 +155,25 @@ def validate_request(obj: dict) -> list[str]:
                 errors.append(f"{op}: missing field {name!r}")
             continue
         v = obj[name]
-        ok = isinstance(v, py) and not (
-            py is int and isinstance(v, bool)
+        ok = isinstance(v, py) and (
+            py is bool or not isinstance(v, bool)
         )
         if not ok:
             errors.append(
-                f"{op}: field {name!r} expected {py.__name__}, "
+                f"{op}: field {name!r} expected {_type_name(py)}, "
                 f"got {type(v).__name__}"
             )
     for name in obj:
         if name not in fields and name not in ("op", "id"):
             errors.append(f"{op}: unknown field {name!r}")
+    if op == "profile":
+        seconds = obj.get("seconds")
+        if isinstance(seconds, (int, float)) and not (
+            0 < seconds <= MAX_PROFILE_SECONDS
+        ):
+            errors.append(
+                f"profile: 'seconds' must be in (0, {MAX_PROFILE_SECONDS}]"
+            )
     if op == "debate":
         tier = obj.get("tier", "interactive")
         if tier not in TIERS:

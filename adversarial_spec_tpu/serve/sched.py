@@ -132,6 +132,7 @@ class Unit:
         "submission",
         "est_tokens",
         "enqueued_t",
+        "queue_wait_s",
         "attempts",
         "preempt_requested",
         "cancelled_by_caller",
@@ -168,6 +169,9 @@ class Unit:
         self.submission = submission
         self.est_tokens = estimate_tokens(request, params)
         self.enqueued_t = 0.0
+        # Enqueue -> dispatch, summed over this unit's dispatches (a
+        # preempted unit queues again): the result's ``serve_queue_s``.
+        self.queue_wait_s = 0.0
         self.attempts = 0
         self.preempt_requested = False
         self.cancelled_by_caller = False
@@ -570,10 +574,10 @@ class ServeScheduler:
         unit.attempts += 1
         self._running[id(unit)] = unit
         serve_mod.stats.units_dispatched += 1
+        wait = max(0.0, self._clock() - unit.enqueued_t)
+        unit.queue_wait_s += wait
         if obs_mod.config().enabled:
-            obs_mod.hot.serve_queue_wait.observe(
-                max(0.0, self._clock() - unit.enqueued_t)
-            )
+            obs_mod.hot.serve_queue_wait.observe(wait)
         self._emit(
             "running", tenant=unit.tenant, tier=unit.tier,
             debate=unit.debate, index=unit.index, tokens=unit.est_tokens,

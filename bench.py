@@ -2727,7 +2727,7 @@ def _run_obs_overhead(platform: str) -> dict:
                 )
             )
             hot.prefill_chunk.observe(0.012695)
-            hot.ttft.observe(0.012695)
+            hot.prefill_wall.observe(0.012695)
             # _emit_lifecycle: 5 transitions + outcome counter + the
             # causal-trace span set (request envelope + stage walls)
             # + the two SLO gates, exactly the mock's per-request

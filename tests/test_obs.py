@@ -108,7 +108,9 @@ class TestMetricsRegistry:
         objects the registry returns for the same name+labels, and must
         survive reset() live (reset-in-place contract) — otherwise the
         hot emit sites would record into orphaned series."""
-        assert obs.hot.ttft is obs.metrics.histogram("advspec_ttft_seconds")
+        assert obs.hot.prefill_wall is obs.metrics.histogram(
+            "advspec_prefill_wall_seconds"
+        )
         assert obs.hot.req_finished is obs.metrics.counter(
             "advspec_requests_total", outcome="finished"
         )
@@ -393,10 +395,10 @@ class TestSchedulerInstrumentation:
             assert states.index("admitted") < states.index("decode")
         steps = [e for e in events if e["type"] == "step"]
         assert steps, "drive loop emitted no StepEvents"
-        # Metrics: TTFT observed once per admission, steps timed, pool
+        # Metrics: prefill wall observed once per admission, steps timed, pool
         # utilization gauge live, sanctioned syncs labeled.
         snap = obs.metrics.snapshot()
-        assert snap["advspec_ttft_seconds"]["count"] == 2
+        assert snap["advspec_prefill_wall_seconds"]["count"] == 2
         assert snap["advspec_step_wall_seconds"]["count"] >= 1
         assert "advspec_page_pool_utilization" in snap
         assert (
@@ -500,7 +502,7 @@ class TestCliObs:
         text = m1.decode()
         for family in (
             "advspec_engine_chat_requests_total",
-            "advspec_ttft_seconds_bucket",
+            "advspec_prefill_wall_seconds_bucket",
             "advspec_prefill_chunk_wall_seconds_sum",
             "advspec_requests_total",
         ):

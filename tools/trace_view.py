@@ -16,19 +16,28 @@ each handed-off request's waterfall head with the handoff path —
 ``prefill@r0 -> decode@r2 (N blocks shipped)`` — so the cross-replica
 KV handoff is readable straight off the view.
 
+``--xplane`` reads a jax profile instead (the ``profile`` op of
+``advspec serve``, or any ``jax.profiler`` trace of the serving
+process): the host's ``advspec.*`` phases (``obs.phase``) sit in it on
+the device's clock, so every second the device was idle is attributed
+to the innermost phase that was open on the host at that moment.
+
 Usage:
     python tools/trace_view.py events.jsonl               # waterfalls + check
     python tools/trace_view.py events.jsonl --trace ID    # one round only
     python tools/trace_view.py events.jsonl --json        # machine-readable
+    python tools/trace_view.py --xplane t.xplane.pb       # device idle by phase
 
-Exit codes: 0 = every request's decomposition checks out; 1 = a sum
-violation or schema error; 2 = unreadable input.
+Exit codes: 0 = every request's decomposition checks out (``--xplane``:
+a device plane was read); 1 = a sum violation or schema error (no
+device plane); 2 = unreadable input.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -284,9 +293,202 @@ def membership_changes(events: list[dict]) -> str:
     return "\n".join(lines)
 
 
+# -- device idle time by host phase (--xplane) ------------------------------
+
+PHASE_PREFIX = "advspec."
+NO_PHASE = "(no phase)"
+
+
+def innermost_segments(phases: list[tuple]) -> list[tuple]:
+    """Flatten nested ``(name, start, end)`` phase events into disjoint,
+    sorted ``(start, end, name)`` segments, each named by the innermost
+    phase open over it (phases of one thread nest; across threads the
+    later-opened one wins, which is still the most specific answer)."""
+    cuts = sorted({t for _, a, b in phases for t in (a, b)})
+    events = sorted(phases, key=lambda p: (p[1], -p[2]))
+    out: list[tuple] = []
+    stack: list[tuple] = []  # open phases, outermost first
+    i = 0
+    for lo, hi in zip(cuts, cuts[1:]):
+        while i < len(events) and events[i][1] <= lo:
+            stack.append(events[i])
+            i += 1
+        stack = [p for p in stack if p[2] > lo]
+        if stack:
+            name = stack[-1][0]
+            if out and out[-1][2] == name and out[-1][1] == lo:
+                out[-1] = (out[-1][0], hi, name)
+            else:
+                out.append((lo, hi, name))
+    return out
+
+
+IDLE_PARTS = ("head", "between", "tail", "whole")
+
+
+def idle_by_phase(busy: list[tuple], phases: list[tuple]) -> dict:
+    """Seconds of device idle time (the gaps between the sorted,
+    disjoint ``busy`` intervals, in ns) under each innermost host phase
+    and under no phase at all, split by where in the phase's stretch the
+    gap lies: at its ``head`` (the device had nothing yet: the host was
+    still enqueueing, or launch latency), ``between`` two operations
+    inside it (eager dispatches), at its ``tail`` (the device was done
+    and the host had not moved on: completion latency), or over the
+    ``whole`` stretch (no operation ran in it)."""
+    gaps = [(b0, a1) for (_, b0), (a1, _) in zip(busy, busy[1:]) if a1 > b0]
+    segs = innermost_segments(phases)
+    idle: dict[str, list[float]] = {}
+
+    def add(name: str, part: str, ns: float) -> None:
+        row = idle.setdefault(name, [0.0] * len(IDLE_PARTS))
+        row[IDLE_PARTS.index(part)] += ns / 1e9
+
+    j = 0
+    for a, b in gaps:
+        covered = 0.0
+        while j < len(segs) and segs[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(segs) and segs[k][0] < b:
+            lo, hi, name = segs[k]
+            ns = min(hi, b) - max(lo, a)
+            if ns > 0:
+                head, tail = a <= lo, b >= hi
+                add(
+                    name,
+                    "whole" if head and tail
+                    else "head" if head
+                    else "tail" if tail
+                    else "between",
+                    ns,
+                )
+                covered += ns
+            k += 1
+        rest = (b - a) - covered
+        if rest > 0:
+            add(NO_PHASE, "whole", rest)
+    return {
+        name: {**dict(zip(IDLE_PARTS, row)), "idle_s": sum(row)}
+        for name, row in idle.items()
+    }
+
+
+def _union(intervals: list[tuple]) -> list[tuple]:
+    out: list[list[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+def xplane_report(path: str) -> dict | None:
+    """Read a ``.xplane.pb``: the first device plane's busy intervals
+    (its ``XLA Ops`` line) and every ``advspec.*`` host event. None when
+    the file holds no device plane."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    device = re.compile(r"^/device:(TPU|GPU):\d+$")
+    busy = None
+    phases: list[tuple] = []
+    for plane in sorted(data.planes, key=lambda pl: pl.name):
+        if device.match(plane.name) and busy is None:
+            lines = {ln.name: ln for ln in plane.lines}
+            line = lines.get("XLA Ops") or next(iter(lines.values()), None)
+            if line is not None:
+                busy = _union(
+                    [
+                        (e.start_ns, e.start_ns + e.duration_ns)
+                        for e in line.events
+                        if e.duration_ns > 0
+                    ]
+                )
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(PHASE_PREFIX) and e.duration_ns > 0:
+                        phases.append(
+                            (
+                                e.name[len(PHASE_PREFIX):],
+                                e.start_ns,
+                                e.start_ns + e.duration_ns,
+                            )
+                        )
+    if not busy:
+        return None
+    window_s = (busy[-1][1] - busy[0][0]) / 1e9
+    busy_s = sum(b - a for a, b in busy) / 1e9
+    wall: dict[str, float] = {}
+    count: dict[str, int] = {}
+    for name, a, b in phases:
+        wall[name] = wall.get(name, 0.0) + (b - a) / 1e9
+        count[name] = count.get(name, 0) + 1
+    return {
+        "window_s": window_s,
+        "busy_s": busy_s,
+        "idle_s": window_s - busy_s,
+        "idle_by_phase_s": idle_by_phase(busy, phases),
+        "phase_wall_s": wall,
+        "phase_count": count,
+    }
+
+
+def render_xplane(rep: dict) -> str:
+    idle_s = rep["idle_s"]
+    lines = [
+        f"device window {rep['window_s']:.3f} s, busy {rep['busy_s']:.3f} s, "
+        f"idle {idle_s:.3f} s "
+        f"({100.0 * idle_s / rep['window_s']:.1f}%)",
+        "",
+        f"{'phase':<24}{'idle_s':>8}{'of idle':>9}"
+        + "".join(f"{part:>9}" for part in IDLE_PARTS)
+        + f"{'wall_s':>9}{'uses':>6}",
+    ]
+    by = rep["idle_by_phase_s"]
+    for name in sorted(by, key=lambda n: -by[n]["idle_s"]):
+        row = by[name]
+        share = 100.0 * row["idle_s"] / idle_s if idle_s > 0 else 0.0
+        wall = rep["phase_wall_s"].get(name)
+        lines.append(
+            f"{name:<24}{row['idle_s']:>8.3f}{share:>8.1f}%"
+            + "".join(f"{row[part]:>9.3f}" for part in IDLE_PARTS)
+            + (f"{wall:>9.3f}" if wall is not None else f"{'':>9}")
+            + f"{rep['phase_count'].get(name, 0):>6}"
+        )
+    return "\n".join(lines)
+
+
+def main_xplane(path: str, as_json: bool) -> int:
+    try:
+        rep = xplane_report(path)
+    except OSError as e:
+        print(f"trace_view: {e}", file=sys.stderr)
+        return 2
+    if rep is None:
+        print(
+            "trace_view: no device plane in this profile (it was not "
+            "taken by the process that holds the chip)",
+            file=sys.stderr,
+        )
+        return 1
+    print(json.dumps(rep, indent=2, sort_keys=True) if as_json
+          else render_xplane(rep))
+    return 0
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("path", help="events JSONL file to render")
+    ap.add_argument(
+        "path", nargs="?", help="events JSONL file to render"
+    )
+    ap.add_argument(
+        "--xplane",
+        metavar="FILE",
+        help="a jax profile (.xplane.pb): device idle seconds by the "
+        "advspec.* phase open on the host",
+    )
     ap.add_argument(
         "--trace", help="restrict to one trace id (one debate round)"
     )
@@ -301,6 +503,10 @@ def main(argv: list[str] | None = None) -> int:
         help="render only; skip the stage-sum consistency check",
     )
     args = ap.parse_args(argv)
+    if args.xplane:
+        return main_xplane(args.xplane, args.json)
+    if not args.path:
+        ap.error("an events JSONL file, or --xplane FILE")
     try:
         events, errors = load_events(args.path)
     except OSError as e:
