@@ -8,6 +8,8 @@ never returns 0 for a share of a roofline or of a peak.
 What a reader sees (`Reading`): the traced window's length, the counters
 at its start and end, the client's statistics, the tokens the clients were
 delivered in the window (each with the context length of its row), the
+verify steps' deliveries in it (one a row a step, each with the row's
+length after it: what the keys and values of decode are counted by), the
 prompt spans that were prefilled in it, the configuration's sizes, the
 chip's peaks and, in a traced run, the trace.
 """
@@ -27,6 +29,7 @@ class Reading:
     counters_end: dict
     client: dict  # statistic name -> value
     token_contexts: list[int]  # one per token delivered in the window
+    row_step_contexts: list[int]  # one per row per verify step that delivered in it
     prefill_spans: list[tuple]  # (first, end) prompt positions prefilled in it
     rows: int  # opponents of one debate: the rows of one dispatch
     config: dict
@@ -117,15 +120,17 @@ def _work(r: Reading, p: dict, n_steps: float | None):
     if kind == "decode":
         if not n_steps or not r.token_contexts:
             return None
-        return shapes.decode_work(r.config, r.quant, int(round(n_steps)), r.token_contexts)
+        return shapes.decode_work(
+            r.config, r.quant, int(round(n_steps)), r.token_contexts, r.row_step_contexts
+        )
     if kind == "prefill":
         if not r.prefill_spans:
             return None
         return shapes.prefill_work(r.config, r.prefill_spans)
     if kind == "paged_attention":
-        if not r.token_contexts:
+        if not r.row_step_contexts:
             return None
-        return shapes.paged_attention_work(r.config, r.token_contexts)
+        return shapes.paged_attention_work(r.config, r.token_contexts, r.row_step_contexts)
     if kind == "qmm":
         if not n_steps:
             return None

@@ -193,6 +193,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
     say(f"weight_bytes: tree={json.dumps(_tree_summary(tree_bytes))} shapes={json.dumps(expect)}")
     for note in ws.notes:
         say(f"NOTE {note}")
+    say(f"NOTE {_row_steps_note(ws, c0, c1)}")
 
     # -- metrics --------------------------------------------------------------
     client = dict(ws.client)
@@ -230,6 +231,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
             counters_end=c1,
             client=client,
             token_contexts=ws.token_contexts,
+            row_step_contexts=ws.row_step_contexts,
             prefill_spans=ws.prefill_spans,
             rows=rows,
             config=cell.config,
@@ -279,6 +281,23 @@ def _compiles(c0: dict, c1: dict) -> int:
     or loaded there from the persistent cache."""
     keys = ("device.compile.backend_compiles", "device.compile.persistent_cache_hits")
     return int(sum(c1.get(k, 0) - c0.get(k, 0) for k in keys))
+
+
+def _row_steps_note(ws, c0: dict, c1: dict) -> str:
+    """The deliveries that the decode work's keys and values are counted by,
+    beside the program's own count of the same thing (`spec.spec_steps`: +1
+    a live row a verify program). The two clocks differ at the window's
+    two edges by a step or two of every row. No reader depends on this."""
+    own = c1.get("spec.spec_steps", 0) - c0.get("spec.spec_steps", 0)
+    per_step, per_token = sum(ws.row_step_contexts), sum(ws.token_contexts)
+    ratio = f"{per_token / per_step:.4f}" if per_step else "n/a"
+    return (
+        f"row_steps: {ws.counts['row_steps']} verify-step deliveries counted in the window "
+        f"({ws.counts['handoff_deliveries']} handoff deliveries of a first token left out) "
+        f"against spec.spec_steps close - open = {own}; positions of keys and values summed "
+        f"once a delivery {per_step}, once a token {per_token} (x{ratio}: what a count per "
+        f"emitted token would charge)"
+    )
 
 
 def _tree_summary(tree_bytes: dict) -> dict:

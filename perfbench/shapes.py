@@ -3,8 +3,10 @@ and FLOPs of a decode step and of a prefill, and the least time a chip
 with given peaks could take for them.
 
 Nothing here looks at how the program does the work: a decode step reads
-each weight once and each live row's keys and values once, and computes
-the tokens it emits, not the positions a verify step spends. A dense
+each weight once and each live row's keys and values once (once a step
+in which the row took part, however many tokens that step emitted for
+it), and computes the tokens it emits, not the positions a verify step
+spends. A dense
 decoder of another size needs no code, only its configuration file (the
 Hugging Face key names). A new kind of layer (experts, a latent cache, a
 linear-attention state) has to add its terms to `layer_params`,
@@ -116,18 +118,22 @@ def token_flops(cfg: dict, context: int, with_head: bool = True) -> int:
     return flops
 
 
-def decode_work(cfg: dict, quant: str, n_steps: int, token_contexts: list[int]) -> dict:
+def decode_work(
+    cfg: dict, quant: str, n_steps: int, token_contexts: list[int], row_step_contexts: list[int]
+) -> dict:
     """Bytes and FLOPs of `n_steps` decode steps that emitted one token at
     each of `token_contexts` (the context length of the row that emitted
-    it): the weights once a step, each token's row's keys and values once,
-    the emitted tokens' arithmetic."""
+    it), a row taking part in a step once for each of `row_step_contexts`
+    (the row's length after that step): the weights once a step, each
+    row's keys and values once a step in which it took part, the emitted
+    tokens' arithmetic."""
     w = weight_bytes(cfg, quant)
     per_step = w["layers_matmul"] + w["layers_small"] + w["final_norm"] + w["lm_head"]
     s = sizes(cfg)
     kv = kv_bytes_per_token(cfg)
     return {
         "bytes": n_steps * per_step
-        + sum(token_contexts) * kv
+        + sum(row_step_contexts) * kv
         + len(token_contexts) * (2 * s["D"] + kv),  # its embedding row, its own K/V written
         "flops": sum(token_flops(cfg, c) for c in token_contexts),
     }
@@ -151,11 +157,14 @@ def prefill_work(cfg: dict, spans: list[tuple]) -> dict:
     return {"flops": flops, "tokens": tokens, "bytes": 0}
 
 
-def paged_attention_work(cfg: dict, token_contexts: list[int]) -> dict:
-    """The decode attention kernel alone: each emitted token's row reads
-    its live keys and values once."""
+def paged_attention_work(
+    cfg: dict, token_contexts: list[int], row_step_contexts: list[int]
+) -> dict:
+    """The decode attention kernel alone: each row's live keys and values
+    once a step in which it took part (no kernel has to read them twice
+    for a step's second token), and the emitted tokens' arithmetic."""
     return {
-        "bytes": sum(token_contexts) * kv_bytes_per_token(cfg),
+        "bytes": sum(row_step_contexts) * kv_bytes_per_token(cfg),
         "flops": sum(attention_flops(cfg, c) for c in token_contexts),
     }
 

@@ -9,6 +9,11 @@ delivery (one gap of g: where a stall shows undiluted). How many tokens a stream
 carries text, and most ids of a random model print as nothing): the tap's
 count of ids at the same delivery says it, and where the two disagree in
 number every event stands for one token and the run says so.
+
+A delivery is also what the decode work is counted by: one row's share of
+one verify step, which reads that row's keys and values once however many
+tokens it emits. A reply's first delivery, of one token, is not one: the
+prefill's last position sampled it (the handoff), no verify step.
 """
 
 from __future__ import annotations
@@ -45,7 +50,8 @@ class Finished:
 class WindowStats:
     client: dict = field(default_factory=dict)
     counts: dict = field(default_factory=dict)
-    token_contexts: list[int] = field(default_factory=list)
+    token_contexts: list[int] = field(default_factory=list)  # one per token delivered
+    row_step_contexts: list[int] = field(default_factory=list)  # one per verify step's delivery
     prefill_spans: list[tuple] = field(default_factory=list)
     finished: list[Finished] = field(default_factory=list)
     notes: list[str] = field(default_factory=list)
@@ -69,7 +75,7 @@ def window_stats(records, tap_requests: dict, t0: float, t1: float, chips: int) 
     rounds: list[float] = []
     late: list[float] = []
     tokens_in_window = 0
-    attempted = failed = ended_early = unmatched = not_batched = 0
+    attempted = failed = ended_early = unmatched = not_batched = handoffs = 0
     for client_records in records:
         for rec in client_records:
             d = rec.debate
@@ -129,6 +135,10 @@ def window_stats(records, tap_requests: dict, t0: float, t1: float, chips: int) 
                     if t0 <= t <= t1:
                         tokens_in_window += new
                         ws.token_contexts.extend(n_in + prev_n + j for j in range(new))
+                        if prev_n == 0 and new == 1:
+                            handoffs += 1  # the prefill's own sample: no verify step
+                        else:
+                            ws.row_step_contexts.append(n_in + n)
                         if prev_t is not None:
                             gaps.extend([(t - prev_t) / new] * new)
                             delivery_gaps.append(t - prev_t)
@@ -156,6 +166,8 @@ def window_stats(records, tap_requests: dict, t0: float, t1: float, chips: int) 
         "not_served_by_batcher": not_batched,
         "stream_events_unmatched": unmatched,
         "tokens": tokens_in_window,
+        "row_steps": len(ws.row_step_contexts),
+        "handoff_deliveries": handoffs,
         "gaps": len(gaps),
         "ttft_samples": len(ttft),
         "rounds": len(rounds),

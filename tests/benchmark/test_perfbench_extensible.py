@@ -64,8 +64,8 @@ def test_a_throw_away_config_mix_cell_and_metric_are_data_only(tmp_path):
         window_s=1.0,
         counters_start={"stream.streamed_tokens": 10, "stream.requests_streamed": 2},
         counters_end={"stream.streamed_tokens": 58, "stream.requests_streamed": 8},
-        client={}, token_contexts=[], prefill_spans=[], rows=2, config=cell.config,
-        quant="int8", peaks=None,
+        client={}, token_contexts=[], row_step_contexts=[], prefill_spans=[], rows=2,
+        config=cell.config, quant="int8", peaks=None,
     )
     for spec in cell.per_layer:
         if spec["name"].startswith("toy."):
@@ -100,7 +100,8 @@ def test_every_manifest_entry_has_its_files_and_they_agree():
 def test_a_reader_that_finds_nothing_returns_nothing():
     reading = reducers.Reading(
         window_s=1.0, counters_start={}, counters_end={}, client={}, token_contexts=[],
-        prefill_spans=[], rows=4, config={}, quant="int8", peaks=None, trace=None,
+        row_step_contexts=[], prefill_spans=[], rows=4, config={}, quant="int8", peaks=None,
+        trace=None,
     )
     bench = manifest.load_manifest(ROOT)
     for w in bench["workloads"]:

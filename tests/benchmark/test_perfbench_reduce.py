@@ -100,8 +100,9 @@ def test_readers_on_the_hand_trace():
     cfg = json.loads((BENCH / "configs/mistral-7b-int8.json").read_text())
     reading = reducers.Reading(
         window_s=230e-6, counters_start={}, counters_end={}, client={},
-        token_contexts=[100] * 8, prefill_spans=[], rows=4, config=cfg, quant="int8",
-        peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}, trace=tr,
+        token_contexts=[100] * 8, row_step_contexts=[100] * 8, prefill_spans=[], rows=4,
+        config=cfg, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        trace=tr,
     )
     assert reducers.trace_mean_ms(reading, {"line": rd.MODULES_LINE, "pattern": "jit_step"}) == pytest.approx(0.05)
     assert reducers.trace_idle_share(reading, {}) == pytest.approx(100 * (1 - 140 / 230))
@@ -111,6 +112,33 @@ def test_readers_on_the_hand_trace():
     # no such kernel in the trace: nothing to read, never a zero
     assert reducers.least_time_share(
         reading, {"work": "paged_attention", "over": {"pattern": "^gone"}}) is None
+
+
+def test_a_kernel_that_streams_its_rows_kv_at_the_peak_reads_100_at_two_tokens_a_step():
+    """Two steps of 4 rows, 1,000 positions each, 2 tokens a row a step. The
+    kernel takes exactly the time the chip needs to read the 8 row-steps'
+    keys and values once: 100%. Counted once an emitted token the same
+    kernel would read 200%, which no chip can do."""
+    cfg = json.loads((BENCH / "configs/mistral-7b-int8.json").read_text())
+    kv_seconds = 8 * 1000 * 131072 / 819e9
+    each = kv_seconds / 2 * 1e9  # nanoseconds of one step's kernel
+    tr = rd.Trace(devices={"/device:TPU:0": {
+        rd.OPS_LINE: [("attn.1", 0.0, each), ("attn.1", 2 * each, each)],
+        rd.MODULES_LINE: [("jit_step(1)", 0.0, each), ("jit_step(1)", 2 * each, each)],
+    }})
+    reading = reducers.Reading(
+        window_s=3 * each / 1e9, counters_start={}, counters_end={}, client={},
+        token_contexts=[998, 999] * 8, row_step_contexts=[1000] * 8, prefill_spans=[], rows=4,
+        config=cfg, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        trace=tr,
+    )
+    params = {"work": "paged_attention", "over": {"pattern": "^attn", "within": "jit_step"}}
+    assert reducers.least_time_share(reading, params) == pytest.approx(100.0)
+    per_token = sum(reading.token_contexts) * 131072 / 819e9
+    assert 100 * per_token / kv_seconds == pytest.approx(200.0, rel=2e-3)
+    # no verify step delivered in the window: nothing to read, never a zero
+    reading.row_step_contexts = []
+    assert reducers.least_time_share(reading, params) is None
 
 
 def test_trim_and_json_round_trip():

@@ -81,18 +81,44 @@ def test_weight_bytes_equal_the_tree_the_program_builds(cfg):
     assert mc.rope_theta == cfg["rope_theta"]
 
 
+def _weights_a_step(cfg):
+    wb = shapes.weight_bytes(cfg, "int8")
+    return wb["layers_matmul"] + wb["layers_small"] + wb["final_norm"] + wb["lm_head"]
+
+
 def test_decode_work_counts_useful_work_only():
-    # 2 steps, 4 rows each at context 1000: weights twice, every row's KV once a token
-    ctx = [1000] * 8
-    w = shapes.decode_work(MISTRAL, "int8", 2, ctx)
-    wb = shapes.weight_bytes(MISTRAL, "int8")
-    per_step = wb["layers_matmul"] + wb["layers_small"] + wb["final_norm"] + wb["lm_head"]
-    assert w["bytes"] == 2 * per_step + 8 * 1000 * 131072 + 8 * (2 * 4096 + 131072)
-    per_token = 2 * (32 * shapes.layer_params(MISTRAL) + shapes.head_params(MISTRAL)) + 4 * 1000 * 4096 * 32
-    assert w["flops"] == 8 * per_token
+    # 2 steps of 4 rows, every row emitting 2 tokens a step: the weights twice, every row's
+    # keys and values once a step (8 row-steps, the row 1002 long after its first, 1004
+    # after its second), and 16 tokens' arithmetic, embedding rows and own K/V written
+    tokens = [1000, 1001] * 4 + [1002, 1003] * 4
+    row_steps = [1002] * 4 + [1004] * 4
+    w = shapes.decode_work(MISTRAL, "int8", 2, tokens, row_steps)
+    kv_read = (4 * 1002 + 4 * 1004) * 131072
+    assert w["bytes"] == 2 * _weights_a_step(MISTRAL) + kv_read + 16 * (2 * 4096 + 131072)
+    stack_and_head = 2 * (32 * shapes.layer_params(MISTRAL) + shapes.head_params(MISTRAL))
+    assert w["flops"] == 16 * stack_and_head + 4 * sum(tokens) * 4096 * 32
     # bandwidth-bound by far: the least time is bytes over 819 GB/s
     least, bound = shapes.least_seconds(w, shapes.peaks_for("TPU v5 lite"))
     assert bound == "bytes" and least == pytest.approx(w["bytes"] / 819e9)
+
+
+def test_a_steps_second_token_costs_arithmetic_and_no_second_read_of_the_rows_kv():
+    """The same steps, rows and lengths, emitting 1 token a delivery or 2:
+    the same keys and values to read, twice the arithmetic."""
+    row_steps = [5000, 5000, 6000, 6000] * 3  # 3 steps of 4 rows
+    one = [c - 1 for c in row_steps]
+    two = [c - 1 for c in row_steps for _ in range(2)]  # both tokens at the row's length
+    a1 = shapes.paged_attention_work(QWEN, one, row_steps)
+    a2 = shapes.paged_attention_work(QWEN, two, row_steps)
+    assert a1["bytes"] == a2["bytes"] == sum(row_steps) * 57344
+    assert a2["flops"] == 2 * a1["flops"]
+    d1 = shapes.decode_work(QWEN, "int8", 3, one, row_steps)
+    d2 = shapes.decode_work(QWEN, "int8", 3, two, row_steps)
+    per_token = 2 * 3584 + 57344  # its embedding row, its own K/V written
+    kv_term = sum(row_steps) * 57344
+    assert d1["bytes"] - len(one) * per_token == d2["bytes"] - len(two) * per_token
+    assert d1["bytes"] - len(one) * per_token == 3 * _weights_a_step(QWEN) + kv_term
+    assert d2["flops"] == 2 * d1["flops"]
 
 
 def test_prefill_work_by_hand():
@@ -105,7 +131,11 @@ def test_prefill_work_by_hand():
 
 
 def test_kernel_work_by_hand():
-    assert shapes.paged_attention_work(MISTRAL, [10, 20])["bytes"] == 30 * 131072
+    # two tokens of one row in one step, the row 21 long after it: its K/V once
+    a = shapes.paged_attention_work(MISTRAL, [19, 20], [21])
+    assert a["bytes"] == 21 * 131072
+    assert a["flops"] == 4 * (19 + 20) * 4096 * 32
+    assert shapes.paged_attention_work(MISTRAL, [10, 20], [11, 21])["bytes"] == 32 * 131072
     q = shapes.qmm_work(MISTRAL, "int8", 3, 4)
     acts = 32 * 2 * sum(i + o for _, i, o in shapes.layer_matmuls(MISTRAL))
     assert q["bytes"] == 3 * (shapes.weight_bytes(MISTRAL, "int8")["layers_matmul"] + 4 * acts)

@@ -53,6 +53,10 @@ def test_tokens_gaps_ttft_and_rounds_of_the_window_by_hand():
     assert ws.client["itl_p50_ms"] == pytest.approx(150.0)  # six gaps of 0.1, six of 0.2
     # every delivered token carries its row's context: the prompt and what came before it
     assert sorted(ws.token_contexts)[:2] == [100, 100] and max(ws.token_contexts) == 103
+    # and every delivery but a reply's first (the handoff's one token) is a row's share of a
+    # verify step, at the row's length after it
+    assert sorted(ws.row_step_contexts) == [102] * 4 + [103] * 4 + [104] * 4
+    assert ws.counts["row_steps"] == 12 and ws.counts["handoff_deliveries"] == 4
     assert ws.prefill_spans == [(64, 100)] * 4
     assert len(ws.finished) == 4 and ws.finished[0].length == 104
 
@@ -90,6 +94,45 @@ def test_a_delivery_of_several_tokens_counts_each_token():
     # the gap between deliveries, undiluted: where a stall shows
     assert ws.client["delivery_gap_p95_ms"] == pytest.approx(200.0)
     assert ws.counts["stream_events_unmatched"] == 0
+
+
+def _reply(deliveries, times, n_in=100):
+    """One reply whose tap saw `deliveries` (ids so far) at `times`."""
+    rec = DebateRecord(debate=_debate(0, opponents=1, max_new=deliveries[-1]), t_submit=times[0] - 1.0)
+    rec.stream_times[0] = list(times)
+    rec.t_result = times[-1]
+    rec.final = {"event": "result", "error": None, "results": [
+        {"span_id": "s", "output_tokens": deliveries[-1], "input_tokens": n_in,
+         "cached_tokens": 0, "error": None}]}
+    tap = {"s": {"span_id": "s", "prompt_ids": list(range(n_in)),
+                 "tokens": list(range(deliveries[-1])), "deliveries": list(deliveries)}}
+    return rec, tap
+
+
+def test_a_rows_kv_is_counted_once_a_delivery_at_its_length_after_it():
+    rec, tap = _reply([2, 4, 5], [11.0, 11.1, 11.2])
+    ws = stats.window_stats([[rec]], tap, t0=10.0, t1=20.0, chips=1)
+    assert ws.row_step_contexts == [102, 104, 105]
+    assert ws.token_contexts == [100, 101, 102, 103, 104]
+    assert ws.counts["row_steps"] == 3 and ws.counts["handoff_deliveries"] == 0
+    # a delivery outside [t0, t1] is left out, with its tokens
+    ws = stats.window_stats([[rec]], tap, t0=11.05, t1=11.15, chips=1)
+    assert ws.row_step_contexts == [104] and ws.token_contexts == [102, 103]
+    ws = stats.window_stats([[rec]], tap, t0=11.15, t1=20.0, chips=1)
+    assert ws.row_step_contexts == [105] and ws.token_contexts == [104]
+
+
+def test_the_handoffs_first_token_is_a_token_and_no_verify_step():
+    # as the program streams: the prefill's own sample alone, then a verify step a delivery
+    rec, tap = _reply([1, 3, 4, 6], [11.0, 11.1, 11.2, 11.3])
+    ws = stats.window_stats([[rec]], tap, t0=10.0, t1=20.0, chips=1)
+    assert ws.counts["tokens"] == 6 and len(ws.token_contexts) == 6
+    assert ws.row_step_contexts == [103, 104, 106]
+    assert ws.counts["row_steps"] == 3 and ws.counts["handoff_deliveries"] == 1
+    # where the tap saw nothing, every stream event stands for one delivery of one token
+    ws = stats.window_stats([[rec]], {}, t0=10.0, t1=20.0, chips=1)
+    assert ws.counts["tokens"] == 6  # what the stream did not bring came with the result
+    assert ws.row_step_contexts == [102, 103, 104, 106]
 
 
 def test_a_reply_that_ends_early_or_skips_the_batcher_is_counted():
