@@ -28,6 +28,7 @@ def flash_update_heads(
     *,
     scale: float,
     attn_softcap: float,
+    live=None,  # bool [1, Tb] or None: see flash_update
 ) -> None:
     """One online-softmax accumulation over a HEAD-FOLDED K/V tile.
 
@@ -63,6 +64,7 @@ def flash_update_heads(
             l_ref[h],
             acc_ref[h],
             attn_softcap=attn_softcap,
+            live=live,
         )
         m_ref[h] = m
         l_ref[h] = l
@@ -81,6 +83,8 @@ def flash_update(
     acc: jnp.ndarray,  # [G, D] running weighted values
     *,
     attn_softcap: float,
+    live=None,  # bool [1, Tb] or None: slots that hold a mapped page (a
+    # tile of several pages masks the page its row has not mapped)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One online-softmax accumulation over a K/V block; returns (m, l, acc)."""
     G, Tb = q.shape[0], k.shape[0]
@@ -90,7 +94,10 @@ def flash_update(
     if attn_softcap > 0.0:
         s = jnp.tanh(s / attn_softcap) * attn_softcap
     slot = t0 + jax.lax.broadcasted_iota(jnp.int32, (G, Tb), 1)
-    s = jnp.where((slot >= start) & (slot < end), s, -jnp.inf)
+    valid = (slot >= start) & (slot < end)
+    if live is not None:
+        valid = valid & live
+    s = jnp.where(valid, s, -jnp.inf)
 
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     # Fully-masked-so-far rows keep m = -inf; m_safe pins the exp argument

@@ -12,23 +12,36 @@ pages, refcounted by engine/prefix_cache.py (shipped in PR 2 — rows
 whose tables alias a cached prefix read it through this kernel like any
 other page).
 
-Kernel shape: grid (B, n_pages_per_seq); the page table rides in as a
-scalar-prefetch operand so each grid step's BlockSpec ``index_map`` selects
-the physical page to DMA next — the gather happens in the pipeline, not in
-the kernel body. One physical page id selects the whole heads-major
-[Hkv, page_size, D] slab, so each program folds ALL KV heads (static
-per-head loop), mirroring ops/pallas_decode.py's short-context redesign:
-Hkv× fewer sequential programs and Hkv× larger DMAs than the round-2
-(B, Hkv, P) grid. Online-softmax state (m, l, acc) persists in VMEM
-scratch across the sequential innermost grid dimension: initialized at
-page 0, finalized and written at the last page.
+Two entry shapes: ``paged_decode_attention`` (S=1, one query token per
+row) and ``paged_decode_attention_mq`` (a short S=γ+1 query span per row
+with per-position causal bounds — speculative verify reads the pool ONCE
+for the whole span instead of flattening the span into the batch axis and
+re-gathering γ+1 times; every decode step of the serving path is one).
+In both, one physical page id selects the whole heads-major
+[Hkv, page_size, D] slab, so a program folds ALL KV heads (static
+per-head loop), mirroring ops/pallas_decode.py's short-context redesign,
+and the online-softmax state (m, l, acc) of a row lives in VMEM scratch.
 
-Two entry shapes share that design: ``paged_decode_attention`` (S=1, one
-query token per row — the decode hot loop) and
-``paged_decode_attention_mq`` (a short S=γ+1 query span per row with
-per-position causal bounds — speculative verify reads the pool ONCE for
-the whole span instead of flattening the span into the batch axis and
-re-gathering γ+1 times).
+The span kernel WALKS a row's pages (``_paged_mq_attn_kernel``): grid
+(B,), one program a row. Its work is the row's live range alone — the
+logical pages [min(starts) // page_size, ceil(max(ends) / page_size))
+over the span's queries — in blocks of K pages, K from the bytes of one
+page slab (``_pages_per_block``: two megabytes of K and of V a block,
+16 pages x 8 heads or 32 x 4 at head dim 128). The pools stay in HBM
+(``memory_space=ANY``); a block's pages are copied through the page
+table, each to its place in one [Hkv, K·page_size, D] tile of a
+double-buffered VMEM scratch, and ``flash_update_heads`` folds the tile
+while the next block — or after the row's last, the next row's first —
+is in flight. A table of 512 pages costs a row of 85 pages 3 or 6
+fetches, not 512 grid steps of one 64-128 KB page each.
+
+The single-query kernel, and the span kernel over a pool Mosaic cannot
+cut pages out of by hand (``_sliceable``: a head dim off the 128 lanes,
+an int8 pool's [page_size, 1] scale pages), keep the pipelined gather:
+grid (B, n_pages_per_seq), the page table rides in as a scalar-prefetch
+operand so each grid step's BlockSpec ``index_map`` selects the physical
+page to DMA next, one page a step whatever the row holds; state is
+initialized at page 0, finalized and written at the last page.
 
 Tested under ``interpret=True`` on CPU against the dense jnp reference
 (tests/test_pallas.py).
@@ -223,7 +236,182 @@ def paged_decode_attention(
     return out[:, :, :g, :].reshape(B, Hq, D)
 
 
+# What one fetch of K (or of V) should move. A page slab alone is 64-128 KB
+# at 7B widths: too small a copy to reach the chip's streaming rate, too
+# small a tile to fold efficiently. Two megabytes do both (chip runs at
+# Mistral-7B's and Qwen2-7B's shapes: PERF.md, PR 30), and two buffers each
+# of K and V then take 8 MiB, half of the VMEM a kernel gets by default;
+# flash_update_heads' temporaries need the rest.
+_BLOCK_BYTES = 2 << 20
+_LANES = 128
+
+
+def _pages_per_block(
+    n_kv: int, page_size: int, head_dim: int, itemsize: int, table_width: int
+) -> int:
+    """How many logical pages ``_paged_mq_attn_kernel`` fetches and folds
+    at a time: as many page slabs [Hkv, page_size, D] as make
+    ``_BLOCK_BYTES``, no more than the table holds. From the operands'
+    shapes alone."""
+    slab = n_kv * page_size * head_dim * itemsize
+    return max(1, min(_BLOCK_BYTES // slab, table_width))
+
+
+def _sliceable(pools) -> bool:
+    """Whether a kernel can copy one page slab out of each pool by itself.
+    Mosaic slices a ref in HBM only in whole lanes, along the minor
+    dimension too where the slice takes all of it ("Slice shape along
+    dimension 4 must be aligned to tiling (128)"): a head dim that is no
+    multiple of 128 and an int8 pool's [page_size, 1] scale pages reach
+    VMEM through BlockSpecs alone."""
+    return all(x.shape[-1] % _LANES == 0 for x in pools)
+
+
 def _paged_mq_attn_kernel(
+    table_ref,  # SMEM [B, P]: physical page id per (row, logical page)
+    layer_ref,  # SMEM [1]: pool layer
+    bounds_ref,  # VMEM [1, G8, 2]: per query-row [start, end). VMEM, not
+    # SMEM scalar-prefetch: Mosaic only loads SCALARS from SMEM and this
+    # kernel needs the whole per-query bounds vector (the _mq_attn_kernel
+    # pattern from ops/pallas_decode.py).
+    next_bounds_ref,  # VMEM [1, G8, 2]: the same of row b + 1
+    q_ref,  # VMEM [1, Hkv, G8, D] — G8 = pad(S·g) query rows per head
+    k_hbm,  # HBM [L, n_pages, Hkv, page, D]: the pools stay where they are
+    v_hbm,
+    o_ref,  # VMEM [1, Hkv, G8, D]
+    m_ref,  # VMEM scratch: the online softmax of the row, as elsewhere
+    l_ref,
+    acc_ref,
+    k_buf,  # VMEM scratch [2, Hkv, K·page, D]: a block's tile, twice
+    v_buf,
+    sem,  # DMA semaphores [2]: one a buffer
+    slot_ref,  # SMEM scratch [1]: the buffer the row's first block is in
+    *,
+    scale: float,
+    page_size: int,
+    pages_per_block: int,
+    attn_softcap: float,
+):
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    n_kv, G8, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    P = table_ref.shape[1]
+    K = pages_per_block
+    layer = layer_ref[0]
+
+    def live_range(ref):
+        """Logical pages [lo, hi) some query of the row attends into,
+        and the blocks of K pages that cover them from ``lo`` up."""
+        first = jnp.clip(jnp.min(ref[0, :, 0]), 0, P * page_size)
+        last = jnp.clip(jnp.max(ref[0, :, 1]), 0, P * page_size)
+        lo = jax.lax.div(first, page_size)
+        hi = jax.lax.div(last + page_size - 1, page_size)
+        return lo, hi, jax.lax.div(jnp.maximum(hi - lo, 0) + K - 1, K)
+
+    def block_copies(row, lo, hi, blk, slot, *, wait):
+        """Start, or wait for, the copy of every live page of the row's
+        block ``blk`` into buffer ``slot``. Pages are not contiguous in
+        the pool: each goes through the page table, to its place in the
+        block's [Hkv, K·page, D] tile."""
+        p0 = lo + blk * K
+
+        def one(j, carry):
+            page_id = jnp.maximum(table_ref[row, p0 + j], 0)
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                copy = pltpu.make_async_copy(
+                    pool.at[layer, page_id],
+                    buf.at[slot, :, at, :],
+                    sem.at[slot],
+                )
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(hi - p0, 0, K), one, 0)
+
+    lo, hi, n_blocks = live_range(bounds_ref)
+
+    @pl.when(b == 0)
+    def _first_row():
+        # A tile's slots past the row's last page are never copied into
+        # and are masked, but 0 · NaN would still reach acc: what they
+        # hold must be finite from the start.
+        for buf in (k_buf, v_buf):
+            buf[:] = jnp.zeros(buf.shape, buf.dtype)
+        slot_ref[0] = 0
+        block_copies(b, lo, hi, 0, 0, wait=False)
+
+    # Every later row's first block was started by the row before it.
+    slot0 = slot_ref[0]
+
+    m_ref[:] = jnp.full((n_kv, G8, 1), -jnp.inf, jnp.float32)
+    l_ref[:] = jnp.zeros((n_kv, G8, 1), jnp.float32)
+    acc_ref[:] = jnp.zeros((n_kv, G8, D), jnp.float32)
+    starts = bounds_ref[0, :, 0][:, None]  # [G8, 1]: per-query bounds
+    ends = bounds_ref[0, :, 1][:, None]  # broadcast inside flash_update
+
+    def next_row_first_block(slot):
+        @pl.when(b + 1 < n_rows)
+        def _():
+            nlo, nhi, _ = live_range(next_bounds_ref)
+            block_copies(b + 1, nlo, nhi, 0, slot, wait=False)
+
+    def fold_block(blk, carry):
+        slot = (slot0 + blk) % 2
+
+        # Keep one block in flight behind the one being folded: this
+        # row's next, or after its last the next row's first.
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            block_copies(b, lo, hi, blk + 1, 1 - slot, wait=False)
+
+        @pl.when(blk + 1 == n_blocks)
+        def _():
+            next_row_first_block(1 - slot)
+
+        block_copies(b, lo, hi, blk, slot, wait=True)
+
+        # Unmapped pages (id <= 0: trash page or table padding — the same
+        # sentinel convention as _paged_attn_kernel) inside the range and
+        # the tile's slots past it are masked out of every query.
+        p0 = lo + blk * K
+        at = jax.lax.broadcasted_iota(jnp.int32, (1, K * page_size), 1)
+        live = jnp.zeros((1, K * page_size), jnp.bool_)
+        for j in range(K):
+            mapped = (p0 + j < hi) & (
+                table_ref[b, jnp.minimum(p0 + j, P - 1)] > 0
+            )
+            here = (at >= j * page_size) & (at < (j + 1) * page_size)
+            live = live | (here & mapped)
+        flash_update_heads(
+            q_ref,
+            k_buf.at[pl.ds(slot, 1)],
+            v_buf.at[pl.ds(slot, 1)],
+            None,
+            None,
+            m_ref,
+            l_ref,
+            acc_ref,
+            p0 * page_size,  # logical token offset of the tile
+            starts,
+            ends,
+            scale=scale,
+            attn_softcap=attn_softcap,
+            live=live,
+        )
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, fold_block, 0)
+
+    @pl.when(n_blocks == 0)
+    def _():
+        next_row_first_block(slot0)
+
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_mq_attn_grid_kernel(
     table_ref,  # SMEM [B, P]: physical page id per (row, logical page)
     layer_ref,  # SMEM [1]: pool layer (consumed by the index_maps only)
     bounds_ref,  # VMEM [1, G8, 2]: per query-row [start, end). VMEM, not
@@ -315,12 +503,26 @@ def paged_decode_attention_mq(
     The speculative-verification shape over the PAGED pool: γ+1 query
     positions per row, each attending through the row's page table under
     its OWN [start, end) window (end grows by one per position — in-span
-    causality). Same (B, n_pages) grid and scalar-prefetch page gather
-    as ``paged_decode_attention``; the span's queries stack into the
-    sublane dimension (row r = query r//g, group lane r%g), so the whole
-    span costs ONE pass over the row's pages instead of the batch-axis
-    flatten paying the gather γ+1 times. Page-table sentinel convention
-    unchanged: entries <= 0 are unmapped and masked.
+    causality). The span's queries stack into the sublane dimension
+    (row r = query r//g, group lane r%g), so the whole span costs ONE
+    pass over the row's pages instead of the batch-axis flatten paying
+    the gather γ+1 times. Page-table sentinel convention unchanged:
+    entries <= 0 are unmapped and masked; a row no query reaches into
+    returns zeros.
+
+    The walk (``_paged_mq_attn_kernel``): one program a row, over the
+    row's LIVE pages only — the logical pages [min(starts) // page_size,
+    ceil(max(ends) / page_size)), so a windowed layer skips its leading
+    pages and nothing is paid for the table's width — K =
+    ``_pages_per_block`` pages at a time. The pools stay in HBM; a
+    block's pages are copied through the page table into one
+    [Hkv, K·page_size, D] tile, double-buffered, and the next block (or
+    the next row's first) is in flight while ``flash_update_heads`` folds
+    this one. Mosaic cuts a page out of an HBM pool only in whole lanes
+    (``_sliceable``); a pool it cannot cut — a head dim off the 128 lanes,
+    an int8 pool's [page_size, 1] scale pages — keeps the pipelined
+    gather of ``paged_decode_attention``: a (B, P) grid, one page a
+    step (``_paged_mq_attn_grid_kernel``).
     """
     layer, (k_pages, v_pages, k_scale, v_scale) = _layered(
         layer, k_pages, v_pages, k_scale, v_scale
@@ -334,6 +536,7 @@ def paged_decode_attention_mq(
     T = P * page_size  # logical slot horizon of the table
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     quantized = k_scale is not None
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
 
     # [B, Hkv, S·g, D]: row r = query (r // g), group lane (r % g).
     qg = jnp.transpose(
@@ -350,48 +553,71 @@ def paged_decode_attention_mq(
     ).astype(jnp.int32)  # [B, rows, 2]
     if G8 != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - rows), (0, 0)))
-        # Pad rows get the empty window [T, 0): a zero start would feed
-        # the min(starts) page-skip guard and disable leading-page
+        # Pad rows get the empty window [T, 0): a zero start would pull
+        # the row's live range down to page 0 and disable leading-page
         # skipping for windowed layers (same trap as decode_attention_mq).
         bnd = jnp.pad(bnd, ((0, 0), (0, G8 - rows), (0, 0)))
         bnd = bnd.at[:, rows:, 0].set(T)
 
-    def page_map(b, p, table_ref, layer_ref):
-        return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
-
-    page_spec = pl.BlockSpec((None, 1, Hkv, page_size, D), page_map)
-    in_specs = [
-        pl.BlockSpec((1, G8, 2), lambda b, p, *_: (b, 0, 0)),
-        pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
-        page_spec,
-        page_spec,
+    static = dict(scale=scale, page_size=page_size, attn_softcap=attn_softcap)
+    accumulators = [
+        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+        pltpu.VMEM((Hkv, G8, D), jnp.float32),
     ]
-    operands = [bnd, qg, k_pages, v_pages]
-    if quantized:
-        scale_spec = pl.BlockSpec((None, 1, Hkv, page_size, 1), page_map)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+    if _sliceable(pools):
+        K = _pages_per_block(Hkv, page_size, D, k_pages.dtype.itemsize, P)
+        kernel = functools.partial(
+            _paged_mq_attn_kernel, pages_per_block=K, **static
+        )
+        grid = (B,)
+        in_specs = [
+            pl.BlockSpec((1, G8, 2), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(
+                (1, G8, 2), lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0)
+            ),
+            pl.BlockSpec((1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ]
+        operands = [bnd, bnd, qg, k_pages, v_pages]
+        tile = pltpu.VMEM((2, Hkv, K * page_size, D), k_pages.dtype)
+        scratch = accumulators + [
+            tile,
+            tile,
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
+    else:
+        kernel = functools.partial(
+            _paged_mq_attn_grid_kernel, quantized=quantized, **static
+        )
+        grid = (B, P)
+
+        def page_map(b, p, table_ref, layer_ref):
+            return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
+
+        # The layer dim is squeezed: the kernel sees [1, Hkv, page_size, *].
+        in_specs = [
+            pl.BlockSpec((1, G8, 2), lambda b, p, *_: (b, 0, 0)),
+            pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
+        ] + [
+            pl.BlockSpec((None, 1, Hkv, page_size, x.shape[-1]), page_map)
+            for x in pools
+        ]
+        operands = [bnd, qg, *pools]
+        scratch = accumulators
 
     out = pl.pallas_call(
-        functools.partial(
-            _paged_mq_attn_kernel,
-            scale=scale,
-            page_size=page_size,
-            attn_softcap=attn_softcap,
-            quantized=quantized,
-        ),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, P),
+            grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)
+                (1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)
             ),
-            scratch_shapes=[
-                pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-                pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-                pltpu.VMEM((Hkv, G8, D), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
         interpret=interpret,

@@ -654,7 +654,7 @@ class TestPagedMqKernel:
             vp = vp.at[0].set(1e9)
         return kp, vp
 
-    def _ref(self, q, kp, vp, table, starts, ends):
+    def _ref(self, q, kp, vp, table, starts, ends, softcap=0.0):
         """Dense gather + per-position masked softmax (numpy, f64)."""
         qn, kn, vn = (np.asarray(a, np.float64) for a in (q, kp, vp))
         tb, st, en = (np.asarray(a) for a in (table, starts, ends))
@@ -672,8 +672,11 @@ class TestPagedMqKernel:
                 ok = mapped & (slot >= st[b, s]) & (slot < en[b, s])
                 for h in range(Hq):
                     logits = kd[h // g] @ qn[b, s, h] / math.sqrt(D)
+                    if softcap > 0:
+                        logits = np.tanh(logits / softcap) * softcap
                     logits[~ok] = -np.inf
-                    p = np.exp(logits - logits.max())
+                    top = logits.max() if ok.any() else 0.0
+                    p = np.exp(logits - top)
                     p[~ok] = 0.0
                     out[b, s, h] = (p @ vd[h // g]) / max(p.sum(), 1e-30)
         return out
@@ -809,6 +812,106 @@ class TestPagedMqKernel:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
         )
+
+    # The walk of _paged_mq_attn_kernel: the live range of each row, K pages
+    # at a time (K from the slab's bytes: _pages_per_block). Pages of 64
+    # tokens, 4 KV heads and D = 256 in float32 make K = 8, so tables of a
+    # dozen pages already hold several blocks. Each case, from K: the
+    # table's width, the mapped pages of each row, and what it changes.
+    WALK_CASES = {
+        "rows_of_unequal_length": lambda K: dict(
+            P=2 * K + 2, pages=[1, K - 1, K, K + 1, 2 * K + 2]
+        ),
+        "table_narrower_than_a_block": lambda K: dict(P=K - 1, pages=[1, K - 1]),
+        "table_width_no_multiple_of_a_block": lambda K: dict(
+            P=K + 3, pages=[K + 3, K + 1]
+        ),
+        "unmapped_entries_inside_the_range": lambda K: dict(
+            P=2 * K + 2, pages=[2 * K + 2, K + 3],
+            holes=[(0, 2, 0), (0, K + 1, -1), (1, K, 0)],
+        ),
+        "window_starts_past_the_first_block": lambda K: dict(
+            P=2 * K + 2, pages=[2 * K + 2, 2 * K],
+            first=[(K + 1) * 64 + 3, K * 64],
+        ),
+        "row_with_no_live_page": lambda K: dict(P=K + 2, pages=[K + 2, 0, 3]),
+        # scale pages are [page, 1]: the (B, P) grid kernel takes these
+        "int8_pages_with_scales": lambda K: dict(
+            P=K + 2, pages=[K + 2, 3], int8=True
+        ),
+        "pad_query_rows": lambda K: dict(P=K + 2, pages=[K + 1, 2], S=5, Hq=4),
+        "softcap": lambda K: dict(P=K + 2, pages=[K + 2, 2], softcap=30.0),
+    }
+
+    @pytest.mark.parametrize("case", WALK_CASES)
+    def test_live_range_walk_matches_gathered_dense(self, case):
+        from adversarial_spec_tpu.ops.pallas_paged import (
+            _pages_per_block,
+            paged_decode_attention_mq,
+        )
+
+        Hkv, D, page = 4, 256, 64
+        K = _pages_per_block(Hkv, page, D, 4, 1 << 20)
+        assert K >= 4, K
+        spec = self.WALK_CASES[case](K)
+        S, Hq = spec.get("S", 3), spec.get("Hq", 8)  # S·g = 6 or 5: padded
+        int8 = spec.get("int8", False)
+        P, pages = spec["P"], spec["pages"]
+        B = len(pages)
+        assert _pages_per_block(Hkv, page, D, 4, P) == min(K, P)
+
+        rng = np.random.default_rng(31)
+        n_pages = 1 + sum(pages)
+        perm = 1 + rng.permutation(n_pages - 1)  # pages scattered in the pool
+        table = np.full((B, P), -1, np.int32)
+        table[:, P // 2 :] = 0  # both kinds of padding past a row's pages
+        used = 0
+        for b, n in enumerate(pages):
+            table[b, :n] = perm[used : used + n]
+            used += n
+        for b, p, v in spec.get("holes", []):
+            table[b, p] = v
+        kp, vp = (
+            jax.random.normal(k, (n_pages, Hkv, page, D), jnp.float32)
+            for k in jax.random.split(jax.random.key(32), 2)
+        )
+        # The trash page is never read into a result.
+        kp, vp = kp.at[0].set(1e9), vp.at[0].set(1e9)
+        q = jax.random.normal(jax.random.key(33), (B, S, Hq, D), jnp.float32)
+        # The span ends a few slots short of the row's last page's end.
+        last = np.maximum(np.asarray(pages) * page - S - 2, 0)[:, None]
+        ends = np.where(
+            np.asarray(pages)[:, None] > 0, last + 1 + np.arange(S)[None, :], 0
+        ).astype(np.int32)
+        starts = np.zeros((B, S), np.int32)
+        starts[:] = np.asarray(spec.get("first", [0] * B))[:, None]
+        softcap = spec.get("softcap", 0.0)
+
+        kw = {}
+        kd, vd = kp, vp
+        if int8:
+            def quantize(x):
+                sc = jnp.maximum(
+                    jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8
+                ) / 127.0
+                return jnp.clip(jnp.round(x / sc), -127, 127).astype(jnp.int8), sc
+
+            (kp, ksc), (vp, vsc) = quantize(kp.at[0].set(1.0)), quantize(vp.at[0].set(1.0))
+            kw = dict(k_scale=ksc.at[0].set(1e9), v_scale=vsc.at[0].set(1e9))
+            kd, vd = kp * ksc, vp * vsc
+        out = paged_decode_attention_mq(
+            q, kp, vp, jnp.asarray(table), jnp.asarray(starts),
+            jnp.asarray(ends), attn_softcap=softcap, interpret=True, **kw,
+        )
+        assert np.all(np.isfinite(np.asarray(out)))
+        np.testing.assert_allclose(
+            np.asarray(out),
+            self._ref(q, kd, vd, table, starts, ends, softcap=softcap),
+            rtol=2e-5, atol=2e-5,
+        )
+        for b, n in enumerate(pages):
+            if n == 0:  # no live page: zeros, not NaN (l clamped at 1e-30)
+                assert not np.asarray(out[b]).any()
 
 
 class TestFusedMatmulInGenerate:

@@ -879,6 +879,31 @@ class TestBatcherSpecPallasVerify:
                 err_msg=f"gamma={gamma} req {i} vs dense reference",
             )
 
+    def test_walk_kernel_parity_at_head_dim_128(self):
+        """The tiny presets have head dim 64, which the span kernel's
+        (B, P) grid form takes (Mosaic cuts pages out of a pool by hand
+        only in whole lanes). At the head dim of the 7B models the
+        batcher's verify goes through the walk over the live pages: the
+        same greedy tokens as the XLA gather verify."""
+        import dataclasses
+
+        from adversarial_spec_tpu.ops import pallas_paged
+
+        cfg = dataclasses.replace(get_config("llama", "tiny"), head_dim=128)
+        params = T.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+        assert pallas_paged._sliceable(
+            [jnp.zeros((1, 1, cfg.n_kv_heads, 64, cfg.head_dim))]
+        )
+        prompts = [_repetitive_prompt(150), _repetitive_prompt(52, period=5)]
+        budgets = [6, 6]
+        _, xla, _ = _drain(
+            params, cfg, prompts, budgets, speculative=True, gamma=4
+        )
+        kern = self._drain_kernel(
+            params, cfg, prompts, budgets, speculative=True, gamma=4
+        )
+        assert xla == kern
+
     def test_eos_inside_span_kernel_verify(self, tiny_model):
         """An EOS accepted mid-span through the kernel verify must stop
         the row exactly where the XLA verify (and plain decode) stops."""

@@ -24,6 +24,7 @@ from adversarial_spec_tpu.ops import pallas_paged, quant
 PAGE = 64  # ContinuousBatcher's page size
 B, GAMMA = 4, 8  # four opponents; default draft length
 N_PAGES = 257  # a 16k-token pool + the trash page
+TABLE_WIDTH = 32768 // PAGE  # the benchmark's entries ask max_seq_len 32768
 # decode rows, verify rows (B·(γ+1)), one admission prefill chunk
 ROW_COUNTS = {"decode": B, "verify": B * (GAMMA + 1), "prefill": 512}
 MODELS = {
@@ -69,17 +70,20 @@ def _pool(chip, cfg, dtype):
 
 
 @pytest.mark.parametrize("model", MODELS)
-@pytest.mark.parametrize("entry", ["decode", "verify_span", "decode_int8_kv"])
+@pytest.mark.parametrize(
+    "entry", ["decode", "verify_span", "decode_int8_kv", "verify_span_int8_kv"]
+)
 def test_paged_attention_compiles(chip, model, entry):
-    """The three paged-attention entry points, reading one layer's pages
-    out of the whole pool by index — as forward_paged_decode calls them."""
+    """The paged-attention entry points over a bf16 pool and over an int8
+    pool with its scale pages, reading one layer's pages out of the whole
+    pool by index — as forward_paged_decode calls them."""
     cfg = MODELS[model]
-    span = GAMMA + 1 if entry == "verify_span" else 0
-    int8_kv = entry == "decode_int8_kv"
+    span = GAMMA + 1 if entry.startswith("verify_span") else 0
+    int8_kv = entry.endswith("int8_kv")
     q_shape = (B, span) if span else (B,)
     q = _shape(chip, q_shape + (cfg.n_heads, cfg.head_dim), jnp.bfloat16)
     pool = _pool(chip, cfg, jnp.int8 if int8_kv else jnp.bfloat16)
-    table = _shape(chip, (B, cfg.max_seq_len // PAGE), jnp.int32)
+    table = _shape(chip, (B, TABLE_WIDTH), jnp.int32)
     # verify: per-position (starts, ends); decode: one (start, end) a row
     windows = (
         [_shape(chip, (B, span), jnp.int32)] * 2

@@ -167,6 +167,7 @@ class GraftlintConfig:
             "adversarial_spec_tpu.ops.pallas_quant._qmm_int8_kernel",
             "adversarial_spec_tpu.ops.pallas_quant._qmm_int4_kernel",
             "adversarial_spec_tpu.ops.pallas_paged._paged_mq_attn_kernel",
+            "adversarial_spec_tpu.ops.pallas_paged._paged_mq_attn_grid_kernel",
             "adversarial_spec_tpu.ops.quant.matmul",
             "adversarial_spec_tpu.ops.quant.unpack_int4",
         ]
