@@ -12,23 +12,16 @@ program serves the reference's own best token, or one that rounding put
 level with it.
 
 The limit is data: `limits/<configuration>.json`, with the readings it was
-set from (PERF.md gives them too).
+set from (PERF.md gives them too); `manifest.load_cell` reads it with the
+cell's other files.
 """
 
 from __future__ import annotations
 
-import json
 import random
 from dataclasses import dataclass
-from pathlib import Path
 
 import numpy as np
-
-HERE = Path(__file__).resolve().parent
-
-
-def load_limits(config_name: str) -> dict:
-    return json.loads((HERE / "limits" / f"{config_name}.json").read_text())
 
 
 @dataclass
@@ -80,13 +73,12 @@ def gaps(logits: np.ndarray, tokens: list[int]) -> np.ndarray:
     return (best - logits[idx, np.asarray(tokens)]) / np.maximum(std, 1e-12)
 
 
-def served_logits(cfg: dict, weights: dict, prompt: list[int], served: list[int]) -> np.ndarray:
-    """One reference pass over prompt + served tokens: the logits that chose
-    each served token, [len(served), vocabulary]."""
-    from perfbench import reference
-
+def served_logits(arch, cfg: dict, weights, prompt: list[int], served: list[int]) -> np.ndarray:
+    """One pass of the architecture's plain reference (`arch.logits_for`)
+    over prompt + served tokens: the logits that chose each served token,
+    [len(served), vocabulary]."""
     ids = list(prompt) + list(served[:-1])
-    return reference.logits_for(cfg, weights, ids, len(prompt) - 1)
+    return arch.logits_for(cfg, weights, ids, len(prompt) - 1)
 
 
 def compare_request(logits: np.ndarray, served: list[int], low_logits: np.ndarray | None = None) -> dict:

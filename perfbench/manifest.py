@@ -2,17 +2,23 @@
 
 A cell names a configuration and a traffic mix; both are files found by
 that name. A per-layer metric's reader is a file found by the metric's name;
-its unit, layer, cells and what it moves are BENCHMARK.json's alone. No
-other part of the harness knows the name of a cell, a configuration, a
-mix or a metric.
+its unit, layer, cells and what it moves are BENCHMARK.json's alone. What
+the harness has to know of an architecture (its plain reference, its weight
+bytes, its work counts) is a module found by the `model_type` that the
+configuration's file states: `architectures/<model_type>.py`. No other part
+of the harness knows the name of a cell, a configuration, a mix, a metric
+or an architecture.
 """
 
 from __future__ import annotations
 
 import copy
+import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -48,6 +54,8 @@ class Cell:
     traffic_name: str
     config: dict  # the configuration's file, as run
     traffic: dict  # the mix's parameters
+    limits: dict  # limits/<configuration>.json: what `correct` holds each number to
+    arch: ModuleType  # architectures/<the configuration's model_type>.py
     end_to_end: list[dict]  # the manifest's entries this cell reports
     per_layer: list[dict]  # manifest entry merged with the metric's file
 
@@ -67,6 +75,34 @@ def metric_file(bench_dir: Path, name: str) -> Path:
         if path.exists():
             return path
     raise ManifestError(f"metric {name}: no file under {bench_dir / 'metrics'}")
+
+
+ARCH_GIVES = ("make_weights", "logits_for", "weight_bytes", "work")
+
+
+def load_architecture(bench_dir: Path, config: dict) -> ModuleType:
+    """The module `architectures/<model_type>.py` of `bench_dir`, loaded by
+    its path: the plain reference (`make_weights`, `logits_for`), the bytes
+    of the parameter tree (`weight_bytes`) and the bytes and FLOPs of each
+    work a metric's file can name (`work`). There is no default and no table
+    of names: a `model_type` without its file is a bad cell."""
+    model_type = config.get("model_type")
+    if not isinstance(model_type, str) or not model_type:
+        raise ManifestError(f"configuration {config.get('name')!r} states no model_type")
+    path = Path(bench_dir) / "architectures" / f"{model_type}.py"
+    if not path.is_file():
+        raise ManifestError(
+            f"configuration {config.get('name')!r}: model_type {model_type!r} has no file {path}"
+        )
+    name = f"perfbench_architecture.{model_type}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module  # dataclasses and pickling look a module up by its name
+    spec.loader.exec_module(module)
+    missing = [n for n in ARCH_GIVES if not callable(getattr(module, n, None))]
+    if missing:
+        raise ManifestError(f"{path} lacks {', '.join(missing)}")
+    return module
 
 
 def load_manifest(root: Path = ROOT) -> dict:
@@ -115,6 +151,8 @@ def load_cell(
         traffic_name=entry["traffic"],
         config=config,
         traffic=traffic,
+        limits=_read(bench_dir / "limits" / f"{entry['config']}.json"),
+        arch=load_architecture(bench_dir, config),
         end_to_end=[m for m in manifest["end_to_end"] if applies(m, workload)],
         per_layer=per_layer,
     )

@@ -10,13 +10,16 @@ at its start and end, the client's statistics, the tokens the clients were
 delivered in the window (each with the context length of its row), the
 verify steps' deliveries in it (one a row a step, each with the row's
 length after it: what the keys and values of decode are counted by), the
-prompt spans that were prefilled in it, the configuration's sizes, the
-chip's peaks and, in a traced run, the trace.
+prompt spans that were prefilled in it, the configuration's sizes, its
+architecture's module (`architectures/<model_type>.py`: what a "work" a
+metric's file names costs in bytes and FLOPs), the chip's peaks and, in a
+traced run, the trace.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from types import ModuleType
 
 from perfbench import reduce as rd
 from perfbench import shapes
@@ -33,6 +36,7 @@ class Reading:
     prefill_spans: list[tuple]  # (first, end) prompt positions prefilled in it
     rows: int  # opponents of one debate: the rows of one dispatch
     config: dict
+    arch: ModuleType  # the configuration's architecture: `work(kind, reading, n_steps)`
     quant: str
     peaks: dict | None
     trace: rd.Trace | None = None
@@ -115,41 +119,19 @@ def trace_idle_share(r: Reading, p: dict):
     return rd.idle_share(r.trace, r.window_s)
 
 
-def _work(r: Reading, p: dict, n_steps: float | None):
-    kind = p["work"]
-    if kind == "decode":
-        if not n_steps or not r.token_contexts:
-            return None
-        return shapes.decode_work(
-            r.config, r.quant, int(round(n_steps)), r.token_contexts, r.row_step_contexts
-        )
-    if kind == "prefill":
-        if not r.prefill_spans:
-            return None
-        return shapes.prefill_work(r.config, r.prefill_spans)
-    if kind == "paged_attention":
-        if not r.row_step_contexts:
-            return None
-        return shapes.paged_attention_work(r.config, r.token_contexts, r.row_step_contexts)
-    if kind == "qmm":
-        if not n_steps:
-            return None
-        return shapes.qmm_work(r.config, r.quant, int(round(n_steps)), r.rows)
-    raise KeyError(f"unknown work {kind!r}")
-
-
 def least_time_share(r: Reading, p: dict):
-    """The least time the chip needs for the window's useful work (a shape
-    function, "work"), over the window's seconds ("over": "window") or over
-    the summed device time of the operations that match ("over": {...}).
-    "steps" says how the trace counts the decode steps."""
+    """The least time the chip needs for the window's useful work ("work": a
+    name the architecture's module knows, an unknown one is its KeyError),
+    over the window's seconds ("over": "window") or over the summed device
+    time of the operations that match ("over": {...}). "steps" says how the
+    trace counts the decode steps."""
     if r.trace is None or r.peaks is None:
         return None
     n_steps = None
     if "steps" in p:
         steps = _events(r, p["steps"])
         n_steps = rd.count(steps) if steps else None
-    work = _work(r, p, n_steps)
+    work = r.arch.work(p["work"], r, n_steps)
     if work is None:
         return None
     least, bound = shapes.least_seconds(work, r.peaks)

@@ -189,7 +189,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
         say(f"WARNING failed={ws.counts['failed']} of {ws.counts['attempted']} requests failed, were shed or timed out")
     if ws.counts["stream_events_unmatched"]:
         say(f"NOTE stream_events_unmatched={ws.counts['stream_events_unmatched']}: those replies' events counted as one token each")
-    expect = shapes.weight_bytes(cell.config, cell.config["serving"].get("quant", ""))
+    expect = cell.arch.weight_bytes(cell.config, cell.config["serving"].get("quant", ""))
     say(f"weight_bytes: tree={json.dumps(_tree_summary(tree_bytes))} shapes={json.dumps(expect)}")
     for note in ws.notes:
         say(f"NOTE {note}")
@@ -235,6 +235,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
             prefill_spans=ws.prefill_spans,
             rows=rows,
             config=cell.config,
+            arch=cell.arch,
             quant=cell.config["serving"].get("quant", ""),
             peaks=peaks,
             trace=tr if (tr is not None and tr.devices) else None,
@@ -334,12 +335,11 @@ def _dump_trace_summary(tr, run_dir: Path) -> None:
 
 
 def check_outputs(cell, ws, seed: int, *, control: bool):
-    """The plain reference over a sample of what the window finished."""
+    """The architecture's plain reference (`cell.arch`) over a sample of
+    what the window finished."""
     from perfbench import correct as correct_mod
-    from perfbench import reference
 
     t_start = time.monotonic()
-    limits = correct_mod.load_limits(cell.config_name)
     lines = []
     by_debate: dict = {}
     for f in ws.finished:
@@ -357,16 +357,20 @@ def check_outputs(cell, ws, seed: int, *, control: bool):
                 unique.setdefault((tuple(r.prompt_ids), tuple(r.tokens)), None)
     logits: dict = {}
     low_logits: dict = {}
-    weights = reference.make_weights(cell.config, weights_seed, bits=8) if unique else None
+    weights = cell.arch.make_weights(cell.config, weights_seed, bits=8) if unique else None
     t_weights = time.monotonic()
     for key in unique:
-        logits[key] = correct_mod.served_logits(cell.config, weights, list(key[0]), list(key[1]))
+        logits[key] = correct_mod.served_logits(
+            cell.arch, cell.config, weights, list(key[0]), list(key[1])
+        )
     del weights
     if control and unique:
         # one set of weights on the chip at a time
-        low = reference.make_weights(cell.config, weights_seed, bits=4)
+        low = cell.arch.make_weights(cell.config, weights_seed, bits=4)
         for key in unique:
-            low_logits[key] = correct_mod.served_logits(cell.config, low, list(key[0]), list(key[1]))
+            low_logits[key] = correct_mod.served_logits(
+                cell.arch, cell.config, low, list(key[0]), list(key[1])
+            )
         del low
     n_passes = len(unique)
     gap_max, n_tokens, n_match = 0.0, 0, 0
@@ -390,7 +394,7 @@ def check_outputs(cell, ws, seed: int, *, control: bool):
     compared = {
         "served_token_gap_over_std_max": {
             "value": gap_max if n_tokens else 1e9,  # nothing to compare is not correct
-            "limit": limits["served_token_gap_over_std_max"]["limit"],
+            "limit": cell.limits["served_token_gap_over_std_max"]["limit"],
         }
     }
     extra = {}

@@ -8,10 +8,12 @@ in which the row took part, however many tokens that step emitted for
 it), and computes the tokens it emits, not the positions a verify step
 spends. A dense
 decoder of another size needs no code, only its configuration file (the
-Hugging Face key names). A new kind of layer (experts, a latent cache, a
-linear-attention state) has to add its terms to `layer_params`,
-`kv_bytes_per_token` and `attention_flops` here, in a new function beside
-them that a new metric file names under "work".
+Hugging Face key names). These are the dense decoder's terms, and
+`architectures/dense.py` is what calls them for the harness. A new kind of
+layer (experts, a latent cache, a linear-attention state) brings its own
+terms in its own `architectures/<model_type>.py` (its `weight_bytes` and the
+branches of its `work`), and imports from here what is common:
+`matmul_weight_bytes`, `least_seconds`, `peaks_for`.
 """
 
 from __future__ import annotations

@@ -35,7 +35,7 @@ def main(paths: list[str]) -> int:
                 vals = [r["result"]["metrics"][name]["value"] for r in rs
                         if name in r["result"]["metrics"]]
                 line = f"  {name}: " + " ".join(f"{v:.6g}" for v in vals)
-                if len(vals) >= 2:
+                if len(vals) >= 2 and statistics.median(vals):  # a count that reads 0 has no share
                     sp = spread(vals)
                     line += f" | median {statistics.median(vals):.6g} spread {sp:.5f}"
                     key = (cell, trace, name)

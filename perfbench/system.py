@@ -60,20 +60,24 @@ def prepare_run_dir(root: Path = ROOT) -> Path:
 
 
 def write_registry(run_dir: Path, serving: dict) -> dict:
-    """The cell's model as a registry entry, in the run's own registry file."""
+    """The cell's model as a registry entry, in the run's own registry file:
+    the configuration's whole `serving` group laid over the defaults, so
+    that every key it gives (a mesh, a share of a deployment) reaches the
+    program. `family` and `size` have no default."""
     entry = {
         "alias": ALIAS,
         "family": serving["family"],
         "checkpoint": "random",
         "tokenizer": "",
         "size": serving["size"],
-        "dtype": serving.get("dtype", "bfloat16"),
+        "dtype": "bfloat16",
         "mesh": {"dp": 1, "tp": 1, "sp": 1},
-        "max_seq_len": int(serving.get("max_seq_len", 0)),
-        "n_layers": int(serving.get("n_layers", 0)),
-        "quant": serving.get("quant", ""),
-        "kv": serving.get("kv", "paged"),
-        "kv_dtype": serving.get("kv_dtype", ""),
+        "max_seq_len": 0,
+        "n_layers": 0,
+        "quant": "",
+        "kv": "paged",
+        "kv_dtype": "",
+        **serving,
     }
     path = run_dir / "home/.config/adversarial-spec-tpu/registry.json"
     path.parent.mkdir(parents=True, exist_ok=True)

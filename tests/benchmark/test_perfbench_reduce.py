@@ -8,6 +8,7 @@ import pytest
 
 from perfbench import reduce as rd
 from perfbench import reducers
+from perfbench.architectures import dense
 
 BENCH = Path(__file__).resolve().parents[2] / "perfbench"
 US = 1000.0  # the trace's times are nanoseconds
@@ -101,7 +102,7 @@ def test_readers_on_the_hand_trace():
     reading = reducers.Reading(
         window_s=230e-6, counters_start={}, counters_end={}, client={},
         token_contexts=[100] * 8, row_step_contexts=[100] * 8, prefill_spans=[], rows=4,
-        config=cfg, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        config=cfg, arch=dense, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
         trace=tr,
     )
     assert reducers.trace_mean_ms(reading, {"line": rd.MODULES_LINE, "pattern": "jit_step"}) == pytest.approx(0.05)
@@ -129,7 +130,7 @@ def test_a_kernel_that_streams_its_rows_kv_at_the_peak_reads_100_at_two_tokens_a
     reading = reducers.Reading(
         window_s=3 * each / 1e9, counters_start={}, counters_end={}, client={},
         token_contexts=[998, 999] * 8, row_step_contexts=[1000] * 8, prefill_spans=[], rows=4,
-        config=cfg, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        config=cfg, arch=dense, quant="int8", peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
         trace=tr,
     )
     params = {"work": "paged_attention", "over": {"pattern": "^attn", "within": "jit_step"}}
