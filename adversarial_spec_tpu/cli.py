@@ -543,7 +543,7 @@ def create_parser() -> argparse.ArgumentParser:
     r.add_argument("--checkpoint", help="HF checkpoint dir (registry add-model)")
     r.add_argument(
         "--family",
-        choices=["llama", "mistral", "gemma2", "qwen2"],
+        choices=["llama", "mistral", "gemma2", "qwen2", "mistral4"],
         default="llama",
     )
     r.add_argument("--size", default="tiny", help="Named size config")

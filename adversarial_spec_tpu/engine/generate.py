@@ -144,6 +144,9 @@ def _prefill_chunk_impl(
     pad_lens: jnp.ndarray,  # [B]
     cache: Cache,
     cache_index: jnp.ndarray,  # scalar: slot of this chunk's first token
+    *,
+    use_pallas_matmul: bool = False,
+    pallas_interpret: bool = False,
 ) -> tuple[Cache, jnp.ndarray]:
     """Run ONE prompt chunk through the model.
 
@@ -175,6 +178,8 @@ def _prefill_chunk_impl(
         cache,
         cache_index,
         kv_valid,
+        use_pallas_matmul=use_pallas_matmul,
+        pallas_interpret=pallas_interpret,
         lm_head_last_only=True,
     )
     return cache, logits[:, -1]
@@ -183,7 +188,9 @@ def _prefill_chunk_impl(
 # The public jitted entry point — the same body, not a hand-forwarded
 # wrapper (see scheduler_decode_chunk for the rationale).
 prefill_chunk = partial(
-    jax.jit, static_argnames=("cfg",), donate_argnames=("cache",)
+    jax.jit,
+    static_argnames=("cfg", "use_pallas_matmul", "pallas_interpret"),
+    donate_argnames=("cache",),
 )(_prefill_chunk_impl)
 
 
@@ -618,6 +625,12 @@ def generate(
                 prefill_pads[:1] if one_row else prefill_pads,
                 cache,
                 jnp.int32(ci),
+                # as the batcher's admissions: expert stacks are read
+                # by the grouped kernel, dense weights by XLA's
+                # dequant-matmul (engine/scheduler.py)
+                use_pallas_matmul=use_pallas_matmul
+                and cfg.ffn_kind == "routed",
+                pallas_interpret=pallas_interpret,
             )
         if shared_until:
             from adversarial_spec_tpu.engine import prefix_cache as _pc

@@ -43,7 +43,12 @@ class PagedCacheLayout:
     page_size: int
     n_layers: int
     n_kv_heads: int
-    head_dim: int
+    head_dim: int  # width of a "k" row
+    # Width of a "v" row where it differs (0 = head_dim). A latent layout
+    # (models/config.py ``kv_layout``) has one head, "k" = the rotated key
+    # all heads share, zero-padded to whole lanes, and "v" = the
+    # compressed vector that is the values and the rest of the keys.
+    v_dim: int = 0
 
     @property
     def tokens_capacity(self) -> int:
@@ -325,7 +330,10 @@ def init_page_pool(
         layout.page_size,
         layout.head_dim,
     )
+    vshape = shape[:-1] + (layout.v_dim or layout.head_dim,)
     if kv_dtype == "int8":
+        if vshape != shape:
+            raise NotImplementedError("a latent pool is stored in the model dtype")
         sshape = shape[:-1] + (1,)
         return {
             "k": jnp.zeros(shape, jnp.int8),
@@ -333,7 +341,7 @@ def init_page_pool(
             "ks": jnp.zeros(sshape, jnp.float32),
             "vs": jnp.zeros(sshape, jnp.float32),
         }
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(vshape, dtype)}
 
 
 def _row_index(layers, pool_array, page_ids, offsets):

@@ -340,7 +340,9 @@ def _random_params(
     from adversarial_spec_tpu.parallel.sharding import param_shardings
 
     def build(key):
-        p = init_params(key, cfg, dtype=dtype)
+        # expert stacks are quantized piece by piece as they are drawn
+        # (transformer._expert_stack): no full-precision stack exists
+        p = init_params(key, cfg, dtype=dtype, expert_quant=quant)
         return quant_mod.quantize_params(p, fmt=quant) if quant else p
 
     def is_weight(node) -> bool:  # a quantized {q|q4, scale} pair is ONE weight
@@ -380,6 +382,8 @@ def materialize_params(
     mesh=None,
     quant: str = "",
     n_layers: int = 0,
+    experts_held: tuple[int, int] | list[int] = (),
+    vocab_rows: int = 0,
 ) -> tuple[Params, ModelConfig]:
     """checkpoint == "random" → synthetic init; else HF safetensors dir.
 
@@ -396,9 +400,16 @@ def materialize_params(
     from adversarial_spec_tpu.ops.quant import quantize_params
     from adversarial_spec_tpu.parallel.sharding import make_device_put
 
-    cfg = get_config(family, size, max_seq_len, n_layers)
+    cfg = get_config(
+        family, size, max_seq_len, n_layers, experts_held, vocab_rows
+    )
     if checkpoint == "random":
         return _random_params(cfg, dtype, seed, quant, mesh), cfg
+    if cfg.latent is not None or cfg.experts is not None:
+        raise NotImplementedError(
+            f"{family}: only the synthetic checkpoint is wired; the "
+            "published tensors' names are not mapped yet"
+        )
     params = load_hf_checkpoint(
         checkpoint,
         cfg,

@@ -55,6 +55,13 @@ class ModelSpec:
     quant: str = ""
     kv: str = "dense"  # "dense" | "paged" — KV-cache layout for decode
     kv_dtype: str = ""  # "" = model dtype, "int8" = quantized KV cache
+    # This chip's share of an expert-parallel deployment (widths are
+    # never cut): [first, count] of the routed experts it holds ([] =
+    # all; the router still scores every published expert), and how many
+    # rows of the vocabulary (0 = all; logits, sampling and token ids
+    # are then over the slice).
+    experts_held: list[int] = field(default_factory=list)
+    vocab_rows: int = 0
 
     def to_dict(self) -> dict:
         return asdict(self)

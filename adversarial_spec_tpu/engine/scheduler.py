@@ -95,6 +95,7 @@ from adversarial_spec_tpu.engine.kvcache import (
     write_tokens,
 )
 from adversarial_spec_tpu.engine.sampling import sample_tokens
+from adversarial_spec_tpu.models import moe as moe_mod
 from adversarial_spec_tpu.models.config import ModelConfig
 from adversarial_spec_tpu.ops import quant
 from adversarial_spec_tpu.models.transformer import (
@@ -291,7 +292,7 @@ def _decode_chunk_impl(
         write_off = q_pos % page_size
         bounds = jnp.stack([pad_lens, q_pos + 1], axis=1).astype(jnp.int32)
         positions = (q_pos - pad_lens)[:, None]
-        logits, pool = forward_paged_decode(
+        logits, pool, _ = forward_paged_decode(
             params,
             cfg,
             cur[:, None],
@@ -376,6 +377,7 @@ scheduler_decode_chunk = partial(
         "use_top_p",
         "use_pallas",
         "use_pallas_matmul",
+        "prefill_pallas_matmul",
         "pallas_interpret",
         "mesh",
     ),
@@ -408,6 +410,7 @@ def fused_prefill_decode_chunk(
     use_top_p: bool = True,
     use_pallas: bool = False,
     use_pallas_matmul: bool = False,
+    prefill_pallas_matmul: bool = False,
     pallas_interpret: bool = False,
     mesh=None,
 ):
@@ -431,7 +434,9 @@ def fused_prefill_decode_chunk(
     are a single-device batcher concern today).
     """
     adm_cache, adm_logits = _prefill_chunk_impl(
-        params, cfg, adm_tokens, adm_pads, adm_cache, adm_cache_index
+        params, cfg, adm_tokens, adm_pads, adm_cache, adm_cache_index,
+        use_pallas_matmul=prefill_pallas_matmul,
+        pallas_interpret=pallas_interpret,
     )
     pool, cur, cur_len, n_emitted, out_buf, active = _decode_chunk_impl(
         params,
@@ -468,6 +473,27 @@ def fused_prefill_decode_chunk(
         out_buf,
         active,
     )
+
+
+# Rows a routed model's step appends to ``counts`` [5, B] (each a scalar
+# broadcast over B): pairs / active experts over the emitted tokens'
+# positions, the same over every position the program ran, and the
+# busiest expert's pairs; all summed over layers.
+N_ROUTING_COUNTS = 5
+
+
+def _routing_counts(cfg: ModelConfig, routing, emitted_mask) -> jnp.ndarray:
+    """int32 [N_ROUTING_COUNTS] of one program's routing [L, T, k]."""
+    stats = lambda mask: jnp.sum(  # noqa: E731
+        jax.vmap(lambda idx: moe_mod.routing_stats(idx, cfg.experts, mask))(
+            routing
+        ),
+        axis=0,
+    )
+    emitted, every = stats(emitted_mask), stats(None)
+    return jnp.stack(
+        [emitted[0], emitted[1], every[0], every[1], every[2]]
+    ).astype(jnp.int32)
 
 
 def _spec_chunk_impl(
@@ -575,7 +601,7 @@ def _spec_chunk_impl(
     positions = q_pos - pad_lens[:, None]
 
     # --- Verify: the paged forward, span-native ([B, γ+1] positions). ---
-    logits, pool = forward_paged_decode(
+    logits, pool, routing = forward_paged_decode(
         params,
         cfg,
         toks,
@@ -659,6 +685,21 @@ def _spec_chunk_impl(
     counts = jnp.stack(
         [n_allowed, n_acc, n_emit, active.astype(jnp.int32), cur_len]
     )
+    if routing is not None:
+        # Routed layers: the step's routing counts ride the same fetch,
+        # one scalar a row of ``counts`` (broadcast over B). Span position
+        # j of a row fed an emitted token iff j < n_emit.
+        counts = jnp.concatenate(
+            [
+                counts,
+                jnp.broadcast_to(
+                    _routing_counts(
+                        cfg, routing, (j < n_emit[:, None]).reshape(-1)
+                    )[:, None],
+                    (N_ROUTING_COUNTS, B),
+                ),
+            ]
+        )
     return (
         pool,
         ctx_buf,
@@ -703,6 +744,7 @@ scheduler_spec_chunk = partial(
         "use_top_p",
         "use_pallas",
         "use_pallas_matmul",
+        "prefill_pallas_matmul",
         "pallas_interpret",
         "mesh",
     ),
@@ -739,6 +781,7 @@ def fused_prefill_spec_chunk(
     use_top_p: bool = True,
     use_pallas: bool = False,
     use_pallas_matmul: bool = False,
+    prefill_pallas_matmul: bool = False,
     pallas_interpret: bool = False,
     mesh=None,
 ):
@@ -751,7 +794,9 @@ def fused_prefill_spec_chunk(
     / ``_spec_chunk_impl``), so greedy tokens are byte-identical either
     way."""
     adm_cache, adm_logits = _prefill_chunk_impl(
-        params, cfg, adm_tokens, adm_pads, adm_cache, adm_cache_index
+        params, cfg, adm_tokens, adm_pads, adm_cache, adm_cache_index,
+        use_pallas_matmul=prefill_pallas_matmul,
+        pallas_interpret=pallas_interpret,
     )
     (
         pool,
@@ -1037,12 +1082,14 @@ class ContinuousBatcher:
             if prefix_cache
             else None
         )
+        kv_heads, k_dim, v_dim = cfg.kv_layout
         layout = PagedCacheLayout(
             n_pages=n_pages + 1,
             page_size=page_size,
             n_layers=cfg.n_layers,
-            n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim,
+            n_kv_heads=kv_heads,
+            head_dim=k_dim,
+            v_dim=v_dim,
         )
         self._dtype = jax.tree.leaves(params)[0].dtype
         self.pool = init_page_pool(
@@ -1060,10 +1107,10 @@ class ContinuousBatcher:
                 1 if kv_dtype == "int8" else np.dtype(self._dtype).itemsize
             )
             block_bytes = (
-                cfg.n_layers * cfg.n_kv_heads * page_size * cfg.head_dim
-            ) * kv_bytes * 2
+                cfg.n_layers * kv_heads * page_size * (k_dim + v_dim)
+            ) * kv_bytes
             if kv_dtype == "int8":  # per-(token, head) f32 scale pages
-                block_bytes += cfg.n_layers * cfg.n_kv_heads * page_size * 4 * 2
+                block_bytes += cfg.n_layers * kv_heads * page_size * 4 * 2
             self.tiers = kvtier_mod.build_for(
                 block_bytes,
                 (cfg, page_size, kv_dtype, self._dtype),
@@ -1085,6 +1132,14 @@ class ContinuousBatcher:
             use_pallas_matmul = jax.default_backend() == "tpu"
         self._use_pallas_matmul = bool(use_pallas_matmul) and (
             quant.has_quantized_weights(params)
+        )
+        # The admission prefill (``forward``) of a dense family keeps
+        # XLA's dequant-matmul, as its cells were measured. Expert stacks
+        # have no XLA path fit for a model's size (every row tile would
+        # gather a whole expert matrix out of the stack), so a routed
+        # family's prefill takes the kernels as its decode does.
+        self._prefill_pallas_matmul = (
+            self._use_pallas_matmul and cfg.ffn_kind == "routed"
         )
 
         B, cap = self.B, max_new_cap
@@ -1687,6 +1742,8 @@ class ContinuousBatcher:
             adm.pads,
             adm.cache,
             jnp.int32(adm.pos),
+            use_pallas_matmul=self._prefill_pallas_matmul,
+            pallas_interpret=self._pallas_interpret,
         )
         adm.pos += chunk_len
         # Block before stamping: async dispatch would otherwise push this
@@ -1755,6 +1812,8 @@ class ContinuousBatcher:
                     adm.pads,
                     cache,
                     jnp.int32(adm.S_real - 1),
+                    use_pallas_matmul=self._prefill_pallas_matmul,
+                    pallas_interpret=self._pallas_interpret,
                 )
                 if obs_mod.config().enabled:
                     # Same jitted callable as the chunked-prefill site:
@@ -2799,6 +2858,7 @@ class ContinuousBatcher:
             use_top_p=self._use_top_p,
             use_pallas=self._use_pallas,
             use_pallas_matmul=self._use_pallas_matmul,
+            prefill_pallas_matmul=self._prefill_pallas_matmul,
             pallas_interpret=self._pallas_interpret,
         )
         adm.cache, adm.last_logits = adm_cache, adm_logits
@@ -3004,6 +3064,7 @@ class ContinuousBatcher:
                 use_top_p=self._use_top_p,
                 use_pallas=self._use_pallas,
                 use_pallas_matmul=self._use_pallas_matmul,
+                prefill_pallas_matmul=self._prefill_pallas_matmul,
                 pallas_interpret=self._pallas_interpret,
             )
             adm.cache, adm.last_logits = adm_cache, adm_logits
@@ -3125,6 +3186,16 @@ class ContinuousBatcher:
                     )
                 )
             self._active_np[slot] = act
+            if self.cfg.latent is not None and obs_mod.config().enabled:
+                # the row's cached tokens, read once this step
+                obs_mod.hot.latent_tokens_read.inc(new_cl)
+        if counts_np.shape[0] > 5 and obs_mod.config().enabled:
+            obs_mod.hot.record_routing(
+                "decode",
+                counts_np[5:, 0],
+                self.cfg.n_layers,
+                self.cfg.experts.n_held,
+            )
 
     @staticmethod
     def _entry_ready(entry: tuple) -> bool:

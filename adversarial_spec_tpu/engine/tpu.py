@@ -337,7 +337,8 @@ class TpuEngine:
         from adversarial_spec_tpu.parallel.sharding import param_shardings
 
         cfg = get_config(
-            spec.family, spec.size, spec.max_seq_len, spec.n_layers
+            spec.family, spec.size, spec.max_seq_len, spec.n_layers,
+            spec.experts_held, spec.vocab_rows,
         )
 
         def build():
@@ -684,7 +685,8 @@ class TpuEngine:
         injector.fire("checkpoint_load")
         quantize = bool(spec.quant)
         cfg = get_config(
-            spec.family, spec.size, spec.max_seq_len, spec.n_layers
+            spec.family, spec.size, spec.max_seq_len, spec.n_layers,
+            spec.experts_held, spec.vocab_rows,
         )
         cache_path = None
         if spec.checkpoint != "random":
@@ -747,6 +749,8 @@ class TpuEngine:
             n_layers=spec.n_layers,
             mesh=mesh,
             quant=spec.quant,
+            experts_held=spec.experts_held,
+            vocab_rows=spec.vocab_rows,
         )
         if cache_path is not None:
             try:  # write side is best-effort too

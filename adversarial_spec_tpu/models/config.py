@@ -16,6 +16,85 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 
+# Width of one vector register row on the TPU: a paged pool whose minor
+# dimension is a multiple of it can be cut page by page by a kernel
+# (ops/pallas_paged.py ``_sliceable``).
+LANES = 128
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (HF ``rope_type="yarn"``) plus the position
+    scaling of queries (``llama_4_scaling_beta``)."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    query_scaling_beta: float = 0.0  # q *= 1 + beta * ln(1 + pos // original_max)
+
+
+@dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention: queries through a low-rank
+    bottleneck, keys and values expanded from ONE compressed vector a
+    token (``kv_rank`` wide) plus one rotated key shared by all heads
+    (``rope_dim`` wide). The cache holds those two and nothing per head."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int  # per head, not rotated
+    rope_dim: int  # per head for queries; the one shared key
+    v_dim: int  # per head
+    yarn: YarnRope | None = None
+
+    @property
+    def rope_pad(self) -> int:
+        """Cached width of the shared rotated key: ``rope_dim`` padded
+        with zeros up to whole lanes, so that a kernel can cut its pages."""
+        return -(-self.rope_dim // LANES) * LANES
+
+    @property
+    def softmax_scale(self) -> float:
+        """1/sqrt(qk head dim), times YaRN's mscale squared where the
+        config gives ``mscale_all_dim`` (the DeepSeek-V2 convention the
+        key comes from)."""
+        import math
+
+        scale = 1.0 / math.sqrt(self.nope_dim + self.rope_dim)
+        y = self.yarn
+        if y is not None and y.mscale_all_dim and y.factor > 1.0:
+            m = 0.1 * y.mscale_all_dim * math.log(y.factor) + 1.0
+            scale *= m * m
+        return scale
+
+
+@dataclass(frozen=True)
+class RoutedExperts:
+    """A routed FFN: a softmax router over ALL ``n_routed`` experts picks
+    ``top_k`` a token; this chip holds ``held`` = (first, count) of them
+    (its share of an expert-parallel deployment) and computes their part
+    of the result; ``n_shared`` always-on experts of the same width are
+    computed whole beside them."""
+
+    n_routed: int
+    top_k: int
+    expert_dim: int
+    n_shared: int = 0
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    held: tuple[int, int] = (0, 0)  # (0, 0) = all of them
+
+    @property
+    def first_held(self) -> int:
+        return self.held[0]
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] or self.n_routed
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -54,6 +133,49 @@ class ModelConfig:
     # instead of 1/sqrt(head_dim). 0 = use head_dim (all other families;
     # gemma-2-9b's value equals its head_dim, 27b's does NOT: 4608/32=144).
     query_pre_attn_scalar: float = 0.0
+    # One period of the layer pattern, as (attention kind, FFN kind):
+    # attention "gqa" | "latent", FFN "dense" | "routed". The forwards
+    # scan the stack by this period; a kind's sizes live in its own
+    # group below, not as more flags on this set.
+    layer_kinds: tuple[tuple[str, str], ...] = (("gqa", "dense"),)
+    latent: LatentAttention | None = None
+    experts: RoutedExperts | None = None
+
+    def __post_init__(self):
+        if len(self.layer_kinds) != 1:
+            raise NotImplementedError(
+                "the forwards scan a period of one layer; a longer "
+                f"pattern {self.layer_kinds} needs one scan a position"
+            )
+        attn, ffn = self.layer_kinds[0]
+        if attn not in ("gqa", "latent") or ffn not in ("dense", "routed"):
+            raise ValueError(f"unknown layer kinds {self.layer_kinds[0]}")
+        if (attn == "latent") != (self.latent is not None) or (
+            ffn == "routed"
+        ) != (self.experts is not None):
+            raise ValueError(
+                f"layer kinds {self.layer_kinds[0]} and the latent/experts "
+                "groups disagree"
+            )
+
+    @property
+    def attn_kind(self) -> str:
+        return self.layer_kinds[0][0]
+
+    @property
+    def ffn_kind(self) -> str:
+        return self.layer_kinds[0][1]
+
+    @property
+    def kv_layout(self) -> tuple[int, int, int]:
+        """(heads, "k" width, "v" width) of one cached token a layer.
+        Latent layers cache one head: "k" is the shared rotated key
+        (``rope_dim`` padded to whole lanes with zeros), "v" the
+        compressed vector, which serves as the values AND as the
+        unrotated part of the keys."""
+        if self.latent is not None:
+            return 1, self.latent.rope_pad, self.latent.kv_rank
+        return self.n_kv_heads, self.head_dim, self.head_dim
 
     @property
     def q_per_kv(self) -> int:
@@ -75,6 +197,8 @@ class ModelConfig:
     def attn_scale(self) -> float:
         import math
 
+        if self.latent is not None:
+            return self.latent.softmax_scale
         return 1.0 / math.sqrt(self.query_pre_attn_scalar or self.head_dim)
 
 
@@ -89,6 +213,32 @@ def _llama(dim, n_layers, n_heads, n_kv_heads, ffn_dim, vocab=128256, **kw):
         ffn_dim=ffn_dim,
         rope_theta=500000.0,
         **kw,
+    )
+
+
+def _mistral4(
+    *, vocab, dim, n_layers, n_heads, q_rank, kv_rank, nope, rope, v_dim,
+    n_routed, top_k, expert_dim, yarn, max_seq_len,
+):
+    return ModelConfig(
+        vocab_size=vocab,
+        dim=dim,
+        n_layers=n_layers,
+        n_heads=n_heads,
+        n_kv_heads=n_heads,
+        head_dim=nope + rope,
+        ffn_dim=expert_dim,  # the shared expert's width
+        rope_theta=10000.0,
+        rms_eps=1e-6,
+        max_seq_len=max_seq_len,
+        layer_kinds=(("latent", "routed"),),
+        latent=LatentAttention(
+            q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope, rope_dim=rope,
+            v_dim=v_dim, yarn=yarn,
+        ),
+        experts=RoutedExperts(
+            n_routed=n_routed, top_k=top_k, expert_dim=expert_dim, n_shared=1
+        ),
     )
 
 
@@ -225,6 +375,31 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
         rope_theta=1000000.0,
         qkv_bias=True,
     ),
+    # Mistral-Small-4 (HF model_type "mistral4", the language model only):
+    # every layer latent attention + 128 routed experts (top 4, softmax
+    # router, renormalised) beside one shared expert; YaRN rope on 64 of
+    # a head's 128 query dims, pairs interleaved. ``head_dim`` is the
+    # query/key width (nope + rope); values are ``latent.v_dim`` wide.
+    ("mistral4", "tiny"): _mistral4(
+        vocab=512, dim=256, n_layers=2, n_heads=4, q_rank=64, kv_rank=128,
+        nope=32, rope=32, v_dim=64, n_routed=8, top_k=2, expert_dim=128,
+        yarn=YarnRope(
+            factor=4.0, original_max=64, mscale_all_dim=1.0,
+            query_scaling_beta=0.1,
+        ),
+        # short: off the chip, attention reads the whole table's width
+        max_seq_len=2048,
+    ),
+    ("mistral4", "small-119b"): _mistral4(
+        vocab=131072, dim=4096, n_layers=36, n_heads=32, q_rank=1024,
+        kv_rank=256, nope=64, rope=64, v_dim=128, n_routed=128, top_k=4,
+        expert_dim=2048,
+        yarn=YarnRope(
+            factor=128.0, original_max=8192, mscale_all_dim=1.0,
+            query_scaling_beta=0.1,
+        ),
+        max_seq_len=32768,  # published 1,048,576; the ctx buffer is sized by it
+    ),
     ("gemma2", "27b"): ModelConfig(
         vocab_size=256000,
         dim=4608,
@@ -249,10 +424,17 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
 
 
 def get_config(
-    family: str, size: str, max_seq_len: int = 0, n_layers: int = 0
+    family: str,
+    size: str,
+    max_seq_len: int = 0,
+    n_layers: int = 0,
+    experts_held: tuple[int, int] | list[int] = (),
+    vocab_rows: int = 0,
 ) -> ModelConfig:
     """The named config; nonzero ``max_seq_len`` / ``n_layers`` override
-    its context length / depth (registry ModelSpec fields)."""
+    its context length / depth, ``experts_held`` (first, count) and
+    ``vocab_rows`` give this chip's share of the routed experts and of
+    the vocabulary (registry ModelSpec fields)."""
     key = (family, size)
     if key not in CONFIGS:
         known = ", ".join(f"{f}/{s}" for f, s in sorted(CONFIGS))
@@ -262,4 +444,21 @@ def get_config(
         cfg = replace(cfg, max_seq_len=max_seq_len)
     if n_layers:
         cfg = replace(cfg, n_layers=n_layers)
+    if experts_held:
+        if cfg.experts is None:
+            raise ValueError(f"{family}/{size} has no routed experts to share")
+        first, count = (int(v) for v in experts_held)
+        if not (0 <= first and 0 < count and first + count <= cfg.experts.n_routed):
+            raise ValueError(
+                f"experts_held {tuple(experts_held)} outside the "
+                f"{cfg.experts.n_routed} routed experts"
+            )
+        cfg = replace(cfg, experts=replace(cfg.experts, held=(first, count)))
+    if vocab_rows:
+        if not 0 < vocab_rows <= cfg.vocab_size:
+            raise ValueError(
+                f"vocab_rows {vocab_rows} outside the vocabulary of "
+                f"{cfg.vocab_size}"
+            )
+        cfg = replace(cfg, vocab_size=vocab_rows)
     return cfg

@@ -36,6 +36,7 @@ star).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import os
@@ -44,9 +45,21 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from adversarial_spec_tpu.models import moe
 from adversarial_spec_tpu.models.config import ModelConfig
-from adversarial_spec_tpu.ops.quant import div_const, matmul
-from adversarial_spec_tpu.ops.rope import apply_rope, rope_angles
+from adversarial_spec_tpu.ops.quant import (
+    dequantize,
+    div_const,
+    matmul,
+    quantize_int8,
+)
+from adversarial_spec_tpu.ops.rope import (
+    apply_rope,
+    apply_rope_interleaved,
+    query_position_scale,
+    rope_angles,
+    yarn_angles,
+)
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
@@ -67,9 +80,14 @@ def init_params(
     cfg: ModelConfig,
     dtype: jnp.dtype = jnp.bfloat16,
     transposed_head: bool = True,
+    expert_quant: str = "",
 ) -> Params:
     """Random init with truncated-normal fan-in scaling (for synthetic
     checkpoints and tests; real weights come from engine/loader.py).
+
+    ``expert_quant="int8"``: routed expert stacks are quantized piece by
+    piece as they are made (``_expert_stack``), so that no full-precision
+    stack ever exists; ``quantize_params`` leaves them as they are.
 
     ``transposed_head``: for tied-embedding configs, also store the
     ``[dim, vocab]`` transposed head copy (see the comment at the
@@ -88,17 +106,57 @@ def init_params(
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
     QD = cfg.n_heads * cfg.head_dim
     KD = cfg.n_kv_heads * cfg.head_dim
-    layers: dict[str, jnp.ndarray] = {
-        "attn_norm": jnp.ones((L, D), dtype),
-        "wq": dense(next(keys), (L, D, QD), D),
-        "wk": dense(next(keys), (L, D, KD), D),
-        "wv": dense(next(keys), (L, D, KD), D),
-        "wo": dense(next(keys), (L, QD, D), QD),
-        "ffn_norm": jnp.ones((L, D), dtype),
-        "w_gate": dense(next(keys), (L, D, F), D),
-        "w_up": dense(next(keys), (L, D, F), D),
-        "w_down": dense(next(keys), (L, F, D), F),
-    }
+    # THE RECIPE (perfbench/architectures/*.py follow it): sixteen splits
+    # of the seed's key, taken in the order the weights are named below;
+    # norms one, biases zero.
+    if cfg.latent is not None:
+        # wq_a, wq_b, wkv_a, wkv_b, wo
+        la = cfg.latent
+        OD = cfg.n_heads * la.v_dim
+        layers: dict[str, jnp.ndarray] = {
+            "attn_norm": jnp.ones((L, D), dtype),
+            "wq_a": dense(next(keys), (L, D, la.q_rank), D),
+            "q_norm": jnp.ones((L, la.q_rank), dtype),
+            "wq_b": dense(next(keys), (L, la.q_rank, QD), la.q_rank),
+            "wkv_a": dense(next(keys), (L, D, la.kv_rank + la.rope_dim), D),
+            "kv_norm": jnp.ones((L, la.kv_rank), dtype),
+            "wkv_b": dense(
+                next(keys),
+                (L, la.kv_rank, cfg.n_heads * (la.nope_dim + la.v_dim)),
+                la.kv_rank,
+            ),
+            "wo": dense(next(keys), (L, OD, D), OD),
+        }
+    else:
+        layers = {
+            "attn_norm": jnp.ones((L, D), dtype),
+            "wq": dense(next(keys), (L, D, QD), D),
+            "wk": dense(next(keys), (L, D, KD), D),
+            "wv": dense(next(keys), (L, D, KD), D),
+            "wo": dense(next(keys), (L, QD, D), QD),
+        }
+    # The dense FFN; beside routed experts it is the shared expert.
+    layers.update(
+        {
+            "ffn_norm": jnp.ones((L, D), dtype),
+            "w_gate": dense(next(keys), (L, D, F), D),
+            "w_up": dense(next(keys), (L, D, F), D),
+            "w_down": dense(next(keys), (L, F, D), F),
+        }
+    )
+    if cfg.experts is not None:
+        ex = cfg.experts
+        if ex.n_shared != 1:
+            raise NotImplementedError("one shared expert beside the routed")
+        layers["w_router"] = dense(next(keys), (L, D, ex.n_routed), D)
+        for name, shape, fan_in in (
+            ("we_gate", (D, ex.expert_dim), D),
+            ("we_up", (D, ex.expert_dim), D),
+            ("we_down", (ex.expert_dim, D), ex.expert_dim),
+        ):
+            layers[name] = _expert_stack(
+                next(keys), cfg, shape, fan_in, dtype, expert_quant
+            )
     if cfg.qkv_bias:
         layers["bq"] = jnp.zeros((L, QD), dtype)
         layers["bk"] = jnp.zeros((L, KD), dtype)
@@ -136,6 +194,32 @@ def init_params(
     return params
 
 
+def _expert_stack(key, cfg: ModelConfig, shape, fan_in, dtype, quant: str):
+    """The held experts' weights of every layer, [L, E_held, in, out], made
+    ONE LAYER AT A TIME: piece (layer l, expert e) draws from
+    ``fold_in(key, l * n_routed + e)`` with e the expert's index among ALL
+    routed experts, so every share of a deployment holds the values the
+    uncut model has; a layer's pieces are drawn together and quantized
+    (``quant="int8"``) before the next layer's are: the float32 in flight
+    is one layer's held experts (1.07 GB at 32 x [4096, 2048]), whatever
+    the stack weighs."""
+    ex = cfg.experts
+    ids = (
+        jnp.arange(cfg.n_layers)[:, None] * ex.n_routed
+        + ex.first_held
+        + jnp.arange(ex.n_held)[None, :]
+    )
+
+    def piece(i):
+        w = jax.random.truncated_normal(
+            jax.random.fold_in(key, i), -2.0, 2.0, shape, jnp.float32
+        )
+        w = div_const(w, math.sqrt(fan_in)).astype(dtype)
+        return quantize_int8(w) if quant == "int8" else w
+
+    return jax.lax.map(jax.vmap(piece), ids)
+
+
 def init_cache(
     cfg: ModelConfig,
     batch: int,
@@ -152,8 +236,18 @@ def init_cache(
     (decode is KV-bandwidth-bound at long contexts); dequant fuses into
     the attention matmuls. Presence of "ks" marks a quantized cache.
     """
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
+    heads, k_dim, v_dim = cfg.kv_layout
+    shape = (cfg.n_layers, batch, heads, max_seq, k_dim)
     kw = {"device": device} if device is not None else {}
+    if cfg.latent is not None:
+        # "k": the shared rotated key (zero-padded to whole lanes), "v":
+        # the compressed vector (values, and the keys' unrotated part).
+        if kv_dtype:
+            raise NotImplementedError("the latent cache is stored in the model dtype")
+        return {
+            "k": jnp.zeros(shape, dtype, **kw),
+            "v": jnp.zeros(shape[:-1] + (v_dim,), dtype, **kw),
+        }
     if kv_dtype == "int8":
         sshape = shape[:-1] + (1,)
         return {
@@ -207,7 +301,8 @@ def attention(
     attn_softcap: float = 0.0,
     scale: float | None = None,
 ) -> jnp.ndarray:
-    """Masked GQA attention, f32 softmax. Returns [B, S, Hq, D]."""
+    """Masked GQA attention, f32 softmax. Returns [B, S, Hq, Dv] (values
+    may be narrower or wider than keys: latent attention's are)."""
     B, S, Hq, D = q.shape
     Hkv, T = k.shape[1], k.shape[2]
     g = Hq // Hkv
@@ -233,7 +328,7 @@ def attention(
     out = jnp.einsum(
         "bhgst,bhtd->bshgd", probs.astype(v.dtype), v
     )
-    return out.reshape(B, S, Hq, D)
+    return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
@@ -257,9 +352,85 @@ def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
     return q, k, v
 
 
+def _rope_tables(cfg: ModelConfig, positions):
+    """cos/sin for the layer kind's rotated features: the whole head under
+    plain or llama-3 frequencies, or latent attention's ``rope_dim`` under
+    YaRN."""
+    la = cfg.latent
+    if la is None:
+        return rope_angles(
+            positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        )
+    if la.yarn is None:
+        return rope_angles(positions, la.rope_dim, cfg.rope_theta)
+    return yarn_angles(positions, la.rope_dim, cfg.rope_theta, la.yarn)
+
+
+def _project_latent(lp, cfg: ModelConfig, h, B, S, positions, cos, sin, mm):
+    """Latent attention's projections. Returns queries split into their
+    unrotated and rotated parts ([B, S, H, nope], [B, S, H, rope]), the
+    compressed vector c_kv [B, S, kv_rank] (normed) and the one rotated
+    key all heads share [B, S, rope]: the last two are what is cached."""
+    la = cfg.latent
+    c_q = rms_norm(mm(h, lp["wq_a"]), lp["q_norm"], cfg.rms_eps, False)
+    q = mm(c_q, lp["wq_b"]).reshape(
+        B, S, cfg.n_heads, la.nope_dim + la.rope_dim
+    )
+    kv = mm(h, lp["wkv_a"])
+    c_kv = rms_norm(kv[..., : la.kv_rank], lp["kv_norm"], cfg.rms_eps, False)
+    k_r = apply_rope_interleaved(
+        kv[..., la.kv_rank :][:, :, None, :], cos, sin
+    )[:, :, 0]
+    q_nope = q[..., : la.nope_dim]
+    q_rope = apply_rope_interleaved(q[..., la.nope_dim :], cos, sin)
+    if la.yarn is not None and la.yarn.query_scaling_beta:
+        qs = query_position_scale(positions, la.yarn)[..., None, None]
+        q_nope = (q_nope * qs).astype(q.dtype)
+        q_rope = (q_rope * qs).astype(q.dtype)
+    return q_nope, q_rope, c_kv, k_r
+
+
+def _latent_up(lp, cfg: ModelConfig, dtype):
+    """W_kvb split per head: W_UK [kv_rank, H, nope] (c_kv -> a head's
+    unrotated keys) and W_UV [kv_rank, H, v] (c_kv -> its values)."""
+    la = cfg.latent
+    w = dequantize(lp["wkv_b"], dtype).reshape(
+        la.kv_rank, cfg.n_heads, la.nope_dim + la.v_dim
+    )
+    return w[..., : la.nope_dim], w[..., la.nope_dim :]
+
+
+def _absorbed_attention(cfg, q_nope, q_rope, r_dense, c_dense, mask, w_uk, w_uv):
+    """Latent attention with W_UK folded into the queries and W_UV into
+    the output, over dense [B, 1, T, *] rotated keys (zero-padded to
+    ``rope_pad``) and compressed vectors: one multi-query attention whose
+    keys are [c_kv | k_r] and whose values are c_kv. Returns [B, S, H, v]."""
+    la = cfg.latent
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk)
+    q_rot = jnp.pad(
+        q_rope, ((0, 0), (0, 0), (0, 0), (0, la.rope_pad - la.rope_dim))
+    )
+    o_lat = attention(
+        jnp.concatenate([q_lat, q_rot], axis=-1),
+        jnp.concatenate([c_dense, r_dense], axis=-1),
+        c_dense,
+        mask,
+        scale=cfg.attn_scale,
+    )
+    return jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
+
+
+def _latent_cache_rows(cfg: ModelConfig, c_kv, k_r):
+    """(k, v) rows of the latent cache, [B, S, 1, *]: the rotated key
+    zero-padded to whole lanes, and the compressed vector."""
+    la = cfg.latent
+    k = jnp.pad(k_r, ((0, 0), (0, 0), (0, la.rope_pad - la.rope_dim)))
+    return k[:, :, None, :], c_kv[:, :, None, :]
+
+
 def _attn_out_and_ffn(
     x, attn_out, lp, cfg: ModelConfig, B: int, S: int, psum_axis=None,
-    mm=matmul,
+    mm=matmul, routed=None,
 ):
     """Shared post-attention projection, residuals, and FFN block.
 
@@ -270,11 +441,13 @@ def _attn_out_and_ffn(
     Under GSPMD (jit) leave it None; the compiler inserts the psums.
 
     ``mm``: matmul implementation (see ``_project_qkv``).
+
+    ``routed``: the layer's FFN kind is "routed": a callable h -> the
+    held experts' part of the result (models/moe.py), added to the dense
+    FFN below, which is then the shared expert.
     """
     with jax.named_scope("attn"):
-        out = mm(
-            attn_out.reshape(B, S, cfg.n_heads * cfg.head_dim), lp["wo"]
-        )
+        out = mm(attn_out.reshape(B, S, -1), lp["wo"])
         if psum_axis is not None:
             out = jax.lax.psum(out, psum_axis)
         if cfg.post_norms:
@@ -288,10 +461,17 @@ def _attn_out_and_ffn(
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
-        ff = _activation(mm(h, lp["w_gate"]), cfg.activation) * mm(
-            h, lp["w_up"]
-        )
-        ff = mm(ff, lp["w_down"])
+        with (
+            jax.named_scope("moe.shared")
+            if routed is not None
+            else contextlib.nullcontext()
+        ):
+            ff = _activation(mm(h, lp["w_gate"]), cfg.activation) * mm(
+                h, lp["w_up"]
+            )
+            ff = mm(ff, lp["w_down"])
+        if routed is not None:
+            ff = ff + routed(h)
         if psum_axis is not None:
             ff = jax.lax.psum(ff, psum_axis)
         if cfg.post_norms:
@@ -349,14 +529,19 @@ def forward(
     callers gate on ``mesh is None or mesh.size == 1``.
     """
     B, S = tokens.shape
+    fused_mm = use_pallas_matmul and (mesh is None or mesh.size == 1)
     mm = (
         functools.partial(
             matmul, use_pallas=True, interpret=pallas_interpret
         )
-        if use_pallas_matmul and (mesh is None or mesh.size == 1)
+        if fused_mm
         else matmul
     )
     T = cache["k"].shape[3]  # [L, B, Hkv, T, D]
+    latent = cfg.latent is not None
+    # The dense-cache decode kernels read per-head keys and values; a
+    # latent cache has neither (the batcher's decode is the paged one).
+    use_pallas_decode = use_pallas_decode and not latent
     pallas_decode = use_pallas_decode and S == 1
     # Short multi-query spans (speculative verification: S = γ+1) run
     # the multi-query kernel — one pass over the KV cache for the whole
@@ -372,9 +557,7 @@ def forward(
     if cfg.scale_embeddings:
         x = (x.astype(jnp.float32) * math.sqrt(cfg.dim)).astype(x.dtype)
 
-    cos, sin = rope_angles(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
+    cos, sin = _rope_tables(cfg, positions)
 
     # Masks shared by all layers. Slot j is visible to in-chunk query i iff
     # it holds a real token and j <= cache_index + i (causality in slot
@@ -457,10 +640,51 @@ def forward(
         }
         return out, out["k"], out["v"]
 
+    scanned_layers, experts = _split_experts(params["layers"])
+
     def layer_body(x, scanned):
+        lp, layer_id, _ = scanned
         with jax.named_scope("attn"):
-            out, cache_l = attn_block(x, scanned)
-        return _attn_out_and_ffn(x, out, scanned[0], cfg, B, S, mm=mm), cache_l
+            out, cache_l = (latent_block if latent else attn_block)(x, scanned)
+        routed, _ = _routed_ffn_of(
+            cfg, lp, experts, layer_id, fused_mm, pallas_interpret
+        )
+        x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm, routed=routed)
+        return x, cache_l
+
+    def latent_block(x, scanned):
+        # The EXPANDED form: the cache takes only c_kv and the shared
+        # rotated key, and every cached position's per-head keys and
+        # values are rebuilt from its compressed vector (c_kv W_kvb), so
+        # the chunk attends as plain multi-head attention. The same
+        # mathematics as forward_paged_decode's absorbed form
+        # (tests/test_latent.py).
+        lp, layer_id, cache_l = scanned
+        la = cfg.latent
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, False)
+        with jax.named_scope("attn.latent"):
+            q_nope, q_rope, c_kv, k_r = _project_latent(
+                lp, cfg, h, B, S, positions, cos, sin, mm
+            )
+            cache_l, k_read, v_read = _write_and_read_kv(
+                cache_l, *_latent_cache_rows(cfg, c_kv, k_r), x.dtype
+            )
+            w_uk, w_uv = _latent_up(lp, cfg, x.dtype)
+            c_all = v_read[:, 0]  # [B, T, kv_rank]
+            k_nope = jnp.einsum("btr,rhn->bhtn", c_all, w_uk)
+            v_all = jnp.einsum("btr,rhv->bhtv", c_all, w_uv)
+            k_rope = jnp.broadcast_to(
+                k_read[:, :, :, : la.rope_dim],
+                (B, cfg.n_heads, T, la.rope_dim),
+            )
+            out = attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1),
+                jnp.concatenate([k_nope, k_rope], axis=-1),
+                v_all,
+                base_mask,
+                scale=cfg.attn_scale,
+            )
+        return out, cache_l
 
     def attn_block(x, scanned):
         lp, layer_id, cache_l = scanned
@@ -569,12 +793,43 @@ def forward(
         x, new_cache = jax.lax.scan(
             layer_body,
             x,
-            (params["layers"], layer_ids, cache),
+            (scanned_layers, layer_ids, cache),
             unroll=_DECODE_UNROLL if S <= _DECODE_UNROLL_MAX_SPAN else 1,
         )
 
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
     return logits, new_cache
+
+
+def _split_experts(layers: dict) -> tuple[dict, dict]:
+    """The layer weights the scan slices layer by layer, and the routed
+    expert stacks it must not (models/moe.py ``EXPERT_WEIGHTS``): those
+    stay whole and are read by layer index."""
+    experts = {k: layers[k] for k in moe.EXPERT_WEIGHTS if k in layers}
+    return {k: v for k, v in layers.items() if k not in experts}, experts
+
+
+def _routed_ffn_of(cfg, lp, experts, layer_id, use_pallas, interpret):
+    """(callable h -> the routed part, the list it leaves the layer's
+    routing in), or (None, None) for a dense FFN. ``use_pallas``: the
+    caller's ``use_pallas_matmul`` on one device: int8 stacks are read by
+    the grouped kernel (off it every row tile gathers a whole expert
+    matrix out of the stack: fine at a test's sizes, ruinous at a
+    model's)."""
+    if cfg.ffn_kind != "routed":
+        return None, None
+    routing: list = []
+
+    def routed(h):
+        out, idx = moe.routed_ffn(
+            h, lp["w_router"], experts, layer_id, cfg.experts,
+            functools.partial(_activation, kind=cfg.activation),
+            use_pallas=use_pallas, interpret=interpret,
+        )
+        routing.append(idx)
+        return out
+
+    return routed, routing
 
 
 def _lm_head_logits(
@@ -632,9 +887,14 @@ def forward_paged_decode(
     use_pallas_matmul: bool = False,
     pallas_interpret: bool = False,
     mesh=None,
-) -> tuple[jnp.ndarray, Cache]:
+) -> tuple[jnp.ndarray, Cache, jnp.ndarray | None]:
     """One decode step (or one multi-position verify span) over the
     PAGED KV pool.
+
+    A latent pool ({"k": the shared rotated key, "v": the compressed
+    vector}, one head) is read in the ABSORBED form: W_UK folds into the
+    queries and W_UV into the output, so a page is read once and serves
+    as keys and values of all heads (``paged_latent_attention_mq``).
 
     Same math as ``forward`` with short S (shared helpers), but K/V live
     in pages shared across rows: token (b, j)'s K/V scatters to
@@ -643,7 +903,9 @@ def forward_paged_decode(
     paged_decode_attention; S>1: paged_decode_attention_mq, one pass
     over the pool for the whole span), a gather + masked jnp reference
     path elsewhere (same bounds semantics on every path).
-    Returns (logits [B, S, vocab], updated pool).
+    Returns (logits [B, S, vocab], updated pool, the routed layers'
+    choices: int32 [L, B*S, top_k] expert ids for the caller's routing
+    counters, None for a dense FFN).
 
     In-span causality (S>1, the speculative verify shape) comes from the
     per-query bounds: position j's window ends at its own slot
@@ -682,16 +944,16 @@ def forward_paged_decode(
     bounds = bounds.reshape(B, S, 2)
     if jnp.ndim(q_pos) <= 1:
         q_pos = jnp.broadcast_to(jnp.reshape(q_pos, (-1, 1)), (B, S))
+    fused_mm = use_pallas_matmul and single_device
     mm = (
         functools.partial(
             matmul, use_pallas=True, interpret=pallas_interpret
         )
-        if use_pallas_matmul and single_device
+        if fused_mm
         else matmul
     )
-    cos, sin = rope_angles(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
+    cos, sin = _rope_tables(cfg, positions)
+    latent = cfg.latent is not None
 
     x = params["embed"][tokens]
     if cfg.scale_embeddings:
@@ -699,14 +961,103 @@ def forward_paged_decode(
 
     flat_page = write_page.reshape(-1)
     flat_off = write_off.reshape(-1)
-    heads = jnp.arange(cfg.n_kv_heads)
+    heads = jnp.arange(cfg.kv_layout[0])
+    scanned_layers, experts = _split_experts(params["layers"])
+
+    def scatter(pool, layer_id, new_kv):
+        # Pages are heads-major [L, n_pages, Hkv, page_size, D]. The
+        # scatter addresses (layer, page, head, offset) and moves whole
+        # D-rows — contiguous in that layout, so XLA keeps the pool in
+        # the layout the attention kernels require. (A [Hkv, D] update
+        # window per token makes it re-lay the WHOLE pool out
+        # token-major and convert it back for every kernel call.) One
+        # scatter per pool array per layer regardless of span width
+        # (rejected-draft targets are the trash page, never read).
+        return {
+            name: pool[name]
+            .at[layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]]
+            .set(val)
+            for name, val in new_kv.items()
+        }
 
     def layer_body(carry, scanned):
         x, pool = carry
+        lp, layer_id = scanned
         with jax.named_scope("attn"):
-            out, pool = attn_block(x, pool, scanned)
-        x = _attn_out_and_ffn(x, out, scanned[0], cfg, B, S, mm=mm)
-        return (x, pool), None
+            out, pool = (latent_block if latent else attn_block)(
+                x, pool, scanned
+            )
+        routed, routing = _routed_ffn_of(
+            cfg, lp, experts, layer_id, fused_mm, pallas_interpret
+        )
+        x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm, routed=routed)
+        return (x, pool), (routing[0] if routing else None)
+
+    def latent_block(x, pool, scanned):
+        lp, layer_id = scanned
+        la = cfg.latent
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, False)
+        with jax.named_scope("attn.latent"):
+            q_nope, q_rope, c_kv, k_r = _project_latent(
+                lp, cfg, h, B, S, positions, cos, sin, mm
+            )
+            k_new, v_new = _latent_cache_rows(cfg, c_kv, k_r)
+            pool = scatter(
+                pool,
+                layer_id,
+                {
+                    "k": k_new.reshape(B * S, 1, -1).astype(pool["k"].dtype),
+                    "v": v_new.reshape(B * S, 1, -1).astype(pool["v"].dtype),
+                },
+            )
+            w_uk, w_uv = _latent_up(lp, cfg, x.dtype)
+            start, end = bounds[..., 0], bounds[..., 1]
+            if use_pallas and single_device:
+                from adversarial_spec_tpu.ops.pallas_paged import (
+                    paged_latent_attention_mq,
+                )
+
+                # Absorb W_UK into the queries (q' . c_kv = q_nope .
+                # k_nope) and W_UV into the output ((p c_kv) W_UV = p v).
+                o_lat = paged_latent_attention_mq(
+                    jnp.einsum("bshn,rhn->bshr", q_nope, w_uk),
+                    jnp.pad(
+                        q_rope,
+                        ((0, 0),) * 3 + ((0, la.rope_pad - la.rope_dim),),
+                    ),
+                    pool["k"],
+                    pool["v"],
+                    page_table,
+                    start,
+                    end,
+                    scale=cfg.attn_scale,
+                    interpret=pallas_interpret,
+                    layer=layer_id,
+                )
+                out = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
+            else:
+                # Gather path: the same absorbed mathematics over the
+                # row's pages, densified once.
+                safe_table = jnp.maximum(page_table, 0)
+
+                def to_dense(pages):
+                    g = pages[layer_id, safe_table]  # [B, P, 1, page, *]
+                    return jnp.swapaxes(g, 1, 2).reshape(
+                        B, 1, -1, pages.shape[-1]
+                    )
+
+                c_dense, r_dense = to_dense(pool["v"]), to_dense(pool["k"])
+                slot = jnp.arange(c_dense.shape[2])[None, None, :]
+                mapped = jnp.repeat(
+                    page_table > 0, page_size, axis=1
+                )[:, None, :]
+                mask = (
+                    mapped & (slot >= start[..., None]) & (slot < end[..., None])
+                )
+                out = _absorbed_attention(
+                    cfg, q_nope, q_rope, r_dense, c_dense, mask, w_uk, w_uv
+                )
+        return out, pool
 
     def attn_block(x, pool, scanned):
         # The WHOLE pool rides the scan carry and every layer updates and
@@ -719,14 +1070,6 @@ def forward_paged_decode(
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
         q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
 
-        # Pages are heads-major [L, n_pages, Hkv, page_size, D]. The
-        # scatter addresses (layer, page, head, offset) and moves whole
-        # D-rows — contiguous in that layout, so XLA keeps the pool in
-        # the layout the attention kernels require. (A [Hkv, D] update
-        # window per token makes it re-lay the WHOLE pool out
-        # token-major and convert it back for every kernel call.) One
-        # scatter per pool array per layer regardless of span width
-        # (rejected-draft targets are the trash page, never read).
         kf = k.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
         vf = v.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
         if quant_kv:
@@ -738,12 +1081,7 @@ def forward_paged_decode(
                 "k": kf.astype(pool["k"].dtype),
                 "v": vf.astype(pool["v"].dtype),
             }
-        pool = {
-            name: pool[name]
-            .at[layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]]
-            .set(val)
-            for name, val in new_kv.items()
-        }
+        pool = scatter(pool, layer_id, new_kv)
         qkw = (
             dict(k_scale=pool["ks"], v_scale=pool["vs"]) if quant_kv else {}
         )
@@ -869,14 +1207,14 @@ def forward_paged_decode(
     # Always a decode step here (short S) → always unrolled for
     # weight-DMA pipelining.
     with jax.named_scope("layers"):
-        (x, new_pool), _ = jax.lax.scan(
+        (x, new_pool), routing = jax.lax.scan(
             layer_body,
             (x, pool),
-            (params["layers"], layer_ids),
+            (scanned_layers, layer_ids),
             unroll=_DECODE_UNROLL,
         )
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)
-    return logits, new_pool
+    return logits, new_pool, routing
 
 
 def count_params(params: Params) -> int:

@@ -96,7 +96,14 @@ PHASES = (
 # every operation's ``op_name`` in the compiled program. ``layers`` is the
 # layer scan (and its slicing of the stacked weights); ``attn`` and ``mlp``
 # nest in it, ``qmm`` (the dequant-matmul) in those and in ``head``.
-DEVICE_SCOPES = ("layers", "attn", "qmm", "mlp", "head", "sample")
+# Latent attention's projections, cache write and kernel sit in
+# ``attn.latent`` under ``attn``; a routed FFN's router, held experts and
+# shared expert in ``moe.route``, ``moe.experts``, ``moe.shared`` under
+# ``mlp``.
+DEVICE_SCOPES = (
+    "layers", "attn", "attn.latent", "qmm", "mlp", "moe.route",
+    "moe.experts", "moe.shared", "head", "sample",
+)
 
 
 @dataclass
@@ -234,6 +241,9 @@ class HotMetrics:
         "batcher_rows",
         "batcher_distinct_prompts",
         "batcher_builds",
+        "moe_imbalance",
+        "latent_tokens_read",
+        "_moe",
         "_m",
         "_phase",
         "_sync",
@@ -407,6 +417,20 @@ class HotMetrics:
             help="ContinuousBatcher constructions by the engine "
             "(a rebuild drops the prefix cache)",
         )
+        # Routed experts (models/moe.py; counted by the step programs and
+        # fetched with their counts): how uneven a program's routing was,
+        # and how many cached tokens the latent attention read.
+        self.moe_imbalance = m.histogram(
+            "advspec_moe_imbalance",
+            help="busiest held expert's pairs over the mean, per program",
+            buckets=(1.0, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0, 32.0),
+        )
+        self.latent_tokens_read = m.counter(
+            "advspec_latent_tokens_read_total",
+            help="cached tokens read by paged latent attention "
+            "(a row's length, once a verify step)",
+        )
+        self._moe: dict = {}
         self._phase: dict = {}
         self._sync: dict = {}
         self._fault: dict = {}
@@ -439,6 +463,43 @@ class HotMetrics:
                 phase=name,
             )
         return h
+
+    def moe(self, what: str, positions: str, program: str):
+        """Routing counters ``advspec_moe_<what>_total``: ``pairs``
+        ((token, expert) pairs on held experts), ``active_experts``
+        (held experts, summed over layers, that got a pair) and
+        ``expert_steps`` (held experts x layers, once a program: what
+        ``active_experts`` is a share of). ``positions``: "emitted" (only
+        the positions whose token the step went on to emit) or "all";
+        ``program``: "decode" or "prefill"."""
+        key = (what, positions, program)
+        c = self._moe.get(key)
+        if c is None:
+            c = self._moe[key] = self._m.counter(
+                f"advspec_moe_{what}_total",
+                help="routed-expert work by position kind and program",
+                positions=positions,
+                program=program,
+            )
+        return c
+
+    def record_routing(
+        self, program: str, counts, n_layers: int, n_held: int
+    ) -> None:
+        """One program's routing counts (engine/scheduler.py
+        ``_routing_counts``: pairs and active experts over the emitted
+        positions, then over all, then the busiest expert's pairs; each
+        summed over the ``n_layers`` layers of ``n_held`` experts)."""
+        pe, ae, pa, aa, busiest = (int(v) for v in counts)
+        expert_steps = n_layers * n_held
+        for positions, pairs, active in (
+            ("emitted", pe, ae), ("all", pa, aa)
+        ):
+            self.moe("pairs", positions, program).inc(pairs)
+            self.moe("active_experts", positions, program).inc(active)
+            self.moe("expert_steps", positions, program).inc(expert_steps)
+        if pa:
+            self.moe_imbalance.observe(busiest * n_held / pa)
 
     def sync(self, reason: str):
         c = self._sync.get(reason)

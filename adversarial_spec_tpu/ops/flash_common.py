@@ -85,12 +85,17 @@ def flash_update(
     attn_softcap: float,
     live=None,  # bool [1, Tb] or None: slots that hold a mapped page (a
     # tile of several pages masks the page its row has not mapped)
+    scores=None,  # f32 [G, Tb] or None: the block's scaled scores, where
+    # the caller's keys are not one matrix (latent attention: q and k are
+    # then unused and may be None)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One online-softmax accumulation over a K/V block; returns (m, l, acc)."""
-    G, Tb = q.shape[0], k.shape[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [G, Tb]
+    if scores is None:
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [G, Tb]
+    s = scores
+    G, Tb = s.shape
     if attn_softcap > 0.0:
         s = jnp.tanh(s / attn_softcap) * attn_softcap
     slot = t0 + jax.lax.broadcasted_iota(jnp.int32, (G, Tb), 1)
@@ -106,7 +111,10 @@ def flash_update(
     alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), jnp.zeros_like(m))
     p = jnp.exp(s - m_safe)
     l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    # p meets v in v's own dtype (a no-op for the float32 tiles of the
+    # per-head kernels; bfloat16 on the MXU for the latent kernel).
     acc_new = acc * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
     return m_new, l_new, acc_new

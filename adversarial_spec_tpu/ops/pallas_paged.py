@@ -57,7 +57,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from adversarial_spec_tpu.ops.flash_common import flash_update_heads
+from adversarial_spec_tpu.ops.flash_common import (
+    flash_update,
+    flash_update_heads,
+)
 
 _SUBLANE = 8
 
@@ -246,15 +249,30 @@ _BLOCK_BYTES = 2 << 20
 _LANES = 128
 
 
+# A block's float32 scores [query rows, K·page_size] live in VMEM beside
+# its tiles: the 288 rows of a latent verify span (9 positions x 32 heads
+# on one shared key) would make 4.7 MB of them at the K the tiles' bytes
+# allow. One megabyte of scores caps K there (14 pages) and leaves the
+# per-head kernels' K as it was (their 40-64 rows allow over 60).
+_SCORE_BYTES = 1 << 20
+
+
 def _pages_per_block(
-    n_kv: int, page_size: int, head_dim: int, itemsize: int, table_width: int
+    n_kv: int,
+    page_size: int,
+    head_dim: int,
+    itemsize: int,
+    table_width: int,
+    query_rows: int = _SUBLANE,
 ) -> int:
     """How many logical pages ``_paged_mq_attn_kernel`` fetches and folds
     at a time: as many page slabs [Hkv, page_size, D] as make
-    ``_BLOCK_BYTES``, no more than the table holds. From the operands'
+    ``_BLOCK_BYTES``, no more than keep a head's float32 scores under
+    ``_SCORE_BYTES``, no more than the table holds. From the operands'
     shapes alone."""
     slab = n_kv * page_size * head_dim * itemsize
-    return max(1, min(_BLOCK_BYTES // slab, table_width))
+    by_scores = _SCORE_BYTES // (query_rows * 4 * page_size)
+    return max(1, min(_BLOCK_BYTES // slab, by_scores, table_width))
 
 
 def _sliceable(pools) -> bool:
@@ -275,26 +293,32 @@ def _paged_mq_attn_kernel(
     # kernel needs the whole per-query bounds vector (the _mq_attn_kernel
     # pattern from ops/pallas_decode.py).
     next_bounds_ref,  # VMEM [1, G8, 2]: the same of row b + 1
-    q_ref,  # VMEM [1, Hkv, G8, D] — G8 = pad(S·g) query rows per head
-    k_hbm,  # HBM [L, n_pages, Hkv, page, D]: the pools stay where they are
-    v_hbm,
-    o_ref,  # VMEM [1, Hkv, G8, D]
-    m_ref,  # VMEM scratch: the online softmax of the row, as elsewhere
-    l_ref,
-    acc_ref,
-    k_buf,  # VMEM scratch [2, Hkv, K·page, D]: a block's tile, twice
-    v_buf,
-    sem,  # DMA semaphores [2]: one a buffer
-    slot_ref,  # SMEM scratch [1]: the buffer the row's first block is in
-    *,
+    *refs,  # the ``n_q`` query refs, then the operands named below
     scale: float,
     page_size: int,
     pages_per_block: int,
     attn_softcap: float,
+    n_q: int = 1,
+    fold=None,
 ):
+    # q_refs: VMEM [1, Hkv, G8, D] — G8 = pad(S·g) query rows per head
+    #   (latent attention brings two: the absorbed and the rotated part)
+    # k_hbm, v_hbm: HBM [L, n_pages, Hkv, page, D*]: the pools stay
+    #   where they are (their widths may differ: a latent pool's do)
+    # o_ref: VMEM [1, Hkv, G8, Dv]
+    # m_ref, l_ref, acc_ref: VMEM scratch, the row's online softmax
+    # k_buf, v_buf: VMEM scratch [2, Hkv, K·page, D*]: a block's tile, twice
+    # sem: DMA semaphores [2], one a buffer
+    # slot_ref: SMEM scratch [1]: the buffer the row's first block is in
+    q_refs = refs[:n_q]
+    (
+        k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sem,
+        slot_ref,
+    ) = refs[n_q:]
+    fold = fold or _fold_heads
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    n_kv, G8, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    n_kv, G8, D = acc_ref.shape
     P = table_ref.shape[1]
     K = pages_per_block
     layer = layer_ref[0]
@@ -383,12 +407,10 @@ def _paged_mq_attn_kernel(
             )
             here = (at >= j * page_size) & (at < (j + 1) * page_size)
             live = live | (here & mapped)
-        flash_update_heads(
-            q_ref,
+        fold(
+            q_refs,
             k_buf.at[pl.ds(slot, 1)],
             v_buf.at[pl.ds(slot, 1)],
-            None,
-            None,
             m_ref,
             l_ref,
             acc_ref,
@@ -409,6 +431,37 @@ def _paged_mq_attn_kernel(
 
     slot_ref[0] = (slot0 + n_blocks) % 2
     o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+def _fold_heads(q_refs, k_tile, v_tile, m_ref, l_ref, acc_ref, t0, starts,
+                ends, **kw):
+    """A tile of per-head keys and values: the head loop every kernel shares."""
+    flash_update_heads(
+        q_refs[0], k_tile, v_tile, None, None, m_ref, l_ref, acc_ref, t0,
+        starts, ends, **kw,
+    )
+
+
+def _fold_latent(q_refs, k_tile, v_tile, m_ref, l_ref, acc_ref, t0, starts,
+                 ends, *, scale, attn_softcap, live):
+    """A tile of the latent cache, read ONCE for all heads: the compressed
+    vectors (``v_tile``) are the values and, against the absorbed part of
+    the queries, the unrotated part of the scores; the shared rotated keys
+    (``k_tile``) give the rest. Operands meet in their stored dtype on the
+    MXU, sums in float32."""
+    q_lat_ref, q_rot_ref = q_refs
+    c = v_tile[0, 0]  # [K·page, kv_rank]
+    r = k_tile[0, 0]  # [K·page, rope_pad]
+    contract_last = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(
+        q_lat_ref[0, 0], c, contract_last, preferred_element_type=jnp.float32
+    ) + jax.lax.dot_general(
+        q_rot_ref[0, 0], r, contract_last, preferred_element_type=jnp.float32
+    )
+    m_ref[0], l_ref[0], acc_ref[0] = flash_update(
+        None, None, c, t0, starts, ends, m_ref[0], l_ref[0], acc_ref[0],
+        attn_softcap=attn_softcap, live=live, scores=s * scale,
+    )
 
 
 def _paged_mq_attn_grid_kernel(
@@ -542,57 +595,20 @@ def paged_decode_attention_mq(
     qg = jnp.transpose(
         q.reshape(B, S, Hkv, g, D), (0, 2, 1, 3, 4)
     ).reshape(B, Hkv, rows, D)
-    starts = jnp.broadcast_to(starts, (B, S))
-    ends = jnp.broadcast_to(ends, (B, S))
-    bnd = jnp.stack(
-        [
-            jnp.repeat(starts, g, axis=1),
-            jnp.repeat(ends, g, axis=1),
-        ],
-        axis=2,
-    ).astype(jnp.int32)  # [B, rows, 2]
-    if G8 != rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - rows), (0, 0)))
-        # Pad rows get the empty window [T, 0): a zero start would pull
-        # the row's live range down to page 0 and disable leading-page
-        # skipping for windowed layers (same trap as decode_attention_mq).
-        bnd = jnp.pad(bnd, ((0, 0), (0, G8 - rows), (0, 0)))
-        bnd = bnd.at[:, rows:, 0].set(T)
-
+    bnd = _span_bounds(starts, ends, B, S, g, G8, T)  # [B, G8, 2]
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - rows), (0, 0)))
     static = dict(scale=scale, page_size=page_size, attn_softcap=attn_softcap)
-    accumulators = [
-        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-        pltpu.VMEM((Hkv, G8, D), jnp.float32),
-    ]
     if _sliceable(pools):
-        K = _pages_per_block(Hkv, page_size, D, k_pages.dtype.itemsize, P)
-        kernel = functools.partial(
-            _paged_mq_attn_kernel, pages_per_block=K, **static
+        call, operands = _walk_call(
+            [qg], k_pages, v_pages, page_table, layer, bnd, static
         )
-        grid = (B,)
-        in_specs = [
-            pl.BlockSpec((1, G8, 2), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(
-                (1, G8, 2), lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0)
-            ),
-            pl.BlockSpec((1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ]
-        operands = [bnd, bnd, qg, k_pages, v_pages]
-        tile = pltpu.VMEM((2, Hkv, K * page_size, D), k_pages.dtype)
-        scratch = accumulators + [
-            tile,
-            tile,
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),
-        ]
+        out = pl.pallas_call(
+            **call, interpret=interpret, name="paged_decode_attention_mq"
+        )(*operands)
     else:
         kernel = functools.partial(
             _paged_mq_attn_grid_kernel, quantized=quantized, **static
         )
-        grid = (B, P)
 
         def page_map(b, p, table_ref, layer_ref):
             return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
@@ -605,27 +621,160 @@ def paged_decode_attention_mq(
             pl.BlockSpec((None, 1, Hkv, page_size, x.shape[-1]), page_map)
             for x in pools
         ]
-        operands = [bnd, qg, *pools]
-        scratch = accumulators
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B, P),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)
+                ),
+                scratch_shapes=[
+                    pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+                    pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+                    pltpu.VMEM((Hkv, G8, D), jnp.float32),
+                ],
             ),
-            scratch_shapes=scratch,
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
-        interpret=interpret,
-        name="paged_decode_attention_mq",
-    )(page_table, layer, *operands)
+            out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
+            interpret=interpret,
+            name="paged_decode_attention_mq",
+        )(page_table, layer, bnd, qg, *pools)
 
     out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
+
+
+def _walk_call(
+    qs, k_pages, v_pages, page_table, layer, bnd, static, fold=None
+):
+    """``_paged_mq_attn_kernel`` over the layer-stacked pools, one program
+    a row, as (the ``pallas_call``'s arguments but its name, its
+    operands): the entry point makes the call under its own name.
+    ``qs``: the query operands [B, Hkv, G8, *]; ``bnd`` [B, G8, 2]. The
+    call returns [B, Hkv, G8, Dv], Dv the width of ``v_pages``."""
+    B, Hkv, G8 = qs[0].shape[:3]
+    page_size, Dv = v_pages.shape[3], v_pages.shape[4]
+    P = page_table.shape[1]
+    K = _pages_per_block(
+        Hkv,
+        page_size,
+        max(k_pages.shape[4], Dv),
+        k_pages.dtype.itemsize,
+        P,
+        query_rows=G8,
+    )
+    kernel = functools.partial(
+        _paged_mq_attn_kernel, pages_per_block=K, n_q=len(qs), fold=fold,
+        **static,
+    )
+    in_specs = (
+        [
+            pl.BlockSpec((1, G8, 2), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(
+                (1, G8, 2), lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0)
+            ),
+        ]
+        + [
+            pl.BlockSpec((1, Hkv, G8, q.shape[3]), lambda b, *_: (b, 0, 0, 0))
+            for q in qs
+        ]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    )
+    scratch = [
+        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+        pltpu.VMEM((Hkv, G8, Dv), jnp.float32),
+        pltpu.VMEM((2, Hkv, K * page_size, k_pages.shape[4]), k_pages.dtype),
+        pltpu.VMEM((2, Hkv, K * page_size, Dv), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    call = dict(
+        kernel=kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, Hkv, G8, Dv), lambda b, *_: (b, 0, 0, 0)
+            ),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, Dv), qs[0].dtype),
+    )
+    return call, (page_table, layer, bnd, bnd, *qs, k_pages, v_pages)
+
+
+def _span_bounds(starts, ends, B: int, S: int, g: int, G8: int, T: int):
+    """[B, G8, 2] per query row (row r = query r // g): its [start, end),
+    the pad rows' the empty window [T, 0) — a zero start would pull the
+    row's live range down to page 0 and disable leading-page skipping for
+    windowed layers (same trap as decode_attention_mq)."""
+    starts = jnp.broadcast_to(starts, (B, S))
+    ends = jnp.broadcast_to(ends, (B, S))
+    bnd = jnp.stack(
+        [jnp.repeat(starts, g, axis=1), jnp.repeat(ends, g, axis=1)], axis=2
+    ).astype(jnp.int32)
+    rows = S * g
+    if G8 != rows:
+        bnd = jnp.pad(bnd, ((0, 0), (0, G8 - rows), (0, 0)))
+        bnd = bnd.at[:, rows:, 0].set(T)
+    return bnd
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_latent_attention_mq(
+    q_lat: jnp.ndarray,  # [B, S, H, kv_rank]: queries with W_UK absorbed
+    q_rot: jnp.ndarray,  # [B, S, H, rope_pad]: rotated part, zero-padded
+    r_pages: jnp.ndarray,  # [(L,) n_pages, 1, page, rope_pad] shared keys
+    c_pages: jnp.ndarray,  # [(L,) n_pages, 1, page, kv_rank] compressed
+    page_table: jnp.ndarray,  # [B, P] int32; <= 0 = unmapped
+    starts: jnp.ndarray,  # [B, S]
+    ends: jnp.ndarray,  # [B, S]
+    scale: float,
+    interpret: bool = False,
+    layer: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """Paged latent attention for a verify span, absorbed form. Returns
+    sum_t p_t · c_t per query and head, [B, S, H, kv_rank]; the caller
+    applies W_UV.
+
+    The latent cache has ONE head: a token's compressed vector c (values,
+    and with the absorbed queries the unrotated part of the scores) and
+    its rotated key r, shared by all H heads. So the span's S·H query
+    rows fold into one pass of ``_paged_mq_attn_kernel``'s walk over the
+    row's live pages, each page copied once (``_fold_latent``):
+    s = (q_lat · c + q_rot · r) · scale, p = softmax(s), out = p · c. The
+    zero padding of ``rope_pad`` (whole lanes, so that Mosaic can cut the
+    pages: ``_sliceable``) adds nothing to a score and is no work."""
+    layer, (r_pages, c_pages) = _layered(layer, r_pages, c_pages)
+    if not _sliceable([r_pages, c_pages]):
+        raise ValueError(
+            "a latent pool's widths must be whole lanes: got "
+            f"{r_pages.shape[-1]} and {c_pages.shape[-1]}"
+        )
+    B, S, H, R = q_lat.shape
+    page_size = c_pages.shape[3]
+    rows = S * H
+    G8 = -(-rows // _SUBLANE) * _SUBLANE
+    bnd = _span_bounds(starts, ends, B, S, H, G8, page_table.shape[1] * page_size)
+    qs = [
+        jnp.pad(
+            q.reshape(B, 1, rows, q.shape[-1]),
+            ((0, 0), (0, 0), (0, G8 - rows), (0, 0)),
+        )
+        for q in (q_lat, q_rot)
+    ]
+    call, operands = _walk_call(
+        qs, r_pages, c_pages, page_table, layer, bnd,
+        dict(scale=scale, page_size=page_size, attn_softcap=0.0),
+        fold=_fold_latent,
+    )
+    out = pl.pallas_call(
+        **call, interpret=interpret, name="paged_latent_attention_mq"
+    )(*operands)
+    return out[:, 0, :rows].reshape(B, S, H, R)
 
 
 def paged_decode_attention_dp_tp(

@@ -258,12 +258,132 @@ def matmul_int4(
     return out[:M].reshape(lead + (N,))
 
 
+def _qmm_int8_grouped_kernel(
+    tile_group_ref,  # SMEM [n_tiles]: the group whose rows tile i holds
+    n_live_ref,  # SMEM [1]: tiles that hold rows at all
+    layer_ref,  # SMEM [1]: which layer of the stack (index_maps only)
+    x_ref,  # VMEM [bm, bk] rows of ONE group
+    w_ref,  # VMEM [bk, bn] int8 block of that group's weight
+    s_ref,  # VMEM [1, bn] f32 scales of the same
+    o_ref,  # VMEM [bm, bn]
+    acc_ref,  # VMEM [bm, bn] f32 scratch across the k grid dim
+    *,
+    compute_dtype,
+):
+    i = pl.program_id(0)
+    k = pl.program_id(2)
+
+    # Tiles past the last group hold no rows: their blocks were not
+    # fetched anew (the index_maps stay on the last live block) and
+    # nothing is computed or read back from them.
+    @pl.when(i < n_live_ref[0])
+    def _live():
+        @pl.when(k == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[...],
+            w_ref[...].astype(compute_dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        @pl.when(k == pl.num_programs(2) - 1)
+        def _finalize():
+            o_ref[:] = (acc_ref[:] * s_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+def matmul_int8_grouped(
+    x: jnp.ndarray,  # [n_tiles * bm, K]: rows grouped, a group a whole number of tiles
+    q: jnp.ndarray,  # [L, G, K, N] int8: the stacked weights of every group
+    scale: jnp.ndarray,  # [L, G, 1, N] f32
+    layer: jnp.ndarray,  # int32 scalar: the layer to read
+    tile_group: jnp.ndarray,  # [n_tiles] int32: group of each row tile
+    n_live: jnp.ndarray,  # int32 scalar: tiles that hold rows
+    *,
+    bm: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Row tile i of ``x`` times the weight of ITS group:
+    ``x[i] @ (q[layer, tile_group[i]] * scale[layer, tile_group[i]])``.
+
+    The stacked array is the operand: no layer and no group is sliced
+    out of it beforehand. ``layer`` and ``tile_group`` are
+    scalar-prefetched and the weight's index_map follows them, so a
+    group that got no rows is never read, and the tiles after the last
+    live one (``tile_group`` repeats the last live group there) neither
+    fetch nor compute. Rows of tiles past ``n_live`` come back unwritten.
+    Returns [n_tiles * bm, N] in ``x.dtype``."""
+    L, G, K, N = q.shape
+    M = x.shape[0]
+    n_tiles = M // bm
+    plan = _plan_blocks(bm, K, N, x.dtype.itemsize, 1)
+    if plan is None or M % bm:
+        raise ValueError(
+            f"matmul_int8_grouped: no unpadded block assignment for "
+            f"rows {M} (tile {bm}) x [{K}, {N}]"
+        )
+    _, bk, bn = plan
+    nj, nk = N // bn, K // bk
+
+    def hold(i, v, last, n_live_ref):
+        # a dead tile stays on the last live tile's last block: no copy
+        return jnp.where(i < n_live_ref[0], v, last)
+
+    def w_map(i, j, k, tile_group_ref, n_live_ref, layer_ref):
+        return (
+            layer_ref[0],
+            tile_group_ref[i],
+            hold(i, k, nk - 1, n_live_ref),
+            hold(i, j, nj - 1, n_live_ref),
+        )
+
+    def s_map(i, j, k, tile_group_ref, n_live_ref, layer_ref):
+        return (
+            layer_ref[0], tile_group_ref[i], 0, hold(i, j, nj - 1, n_live_ref)
+        )
+
+    def x_map(i, j, k, tile_group_ref, n_live_ref, layer_ref):
+        live = jnp.maximum(n_live_ref[0] - 1, 0)
+        return (jnp.minimum(i, live), hold(i, k, nk - 1, n_live_ref))
+
+    return pl.pallas_call(
+        functools.partial(_qmm_int8_grouped_kernel, compute_dtype=x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles, nj, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), x_map),
+                pl.BlockSpec((None, None, bk, bn), w_map),
+                pl.BlockSpec((None, None, 1, bn), s_map),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        interpret=interpret,
+        name="matmul_int8_grouped",
+    )(
+        tile_group.astype(jnp.int32),
+        jnp.asarray(n_live, jnp.int32).reshape(1),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        x,
+        q,
+        scale.astype(jnp.float32),
+    )
+
+
 def fused_supported(x, w) -> bool:
     """True iff the fused kernel covers this (activation, weight) pair:
-    a flat (non-layer-stacked) quantized weight whose dims admit an
-    unpadded block assignment. The caller (ops.quant.matmul) falls back
-    to the XLA dequant-fusion path otherwise — same math, weaker
-    streaming guarantee."""
+    a flat quantized weight whose dims admit an unpadded block
+    assignment. The caller (ops.quant.matmul) falls back to the XLA
+    dequant-fusion path otherwise — same math, weaker streaming
+    guarantee. A STACKED quantized weight is never turned away without a
+    word: a stack is read by ``matmul_int8_grouped`` through a prefetched
+    index (models/moe.py), and one that arrives here is a caller's
+    mistake."""
     from adversarial_spec_tpu.ops.quant import is_quantized, is_quantized_int4
 
     if is_quantized(w):
@@ -272,7 +392,12 @@ def fused_supported(x, w) -> bool:
         q = w["q4"]
     else:
         return False
-    if q.ndim != 2 or x.ndim < 1 or x.size == 0:
+    if q.ndim != 2:
+        raise ValueError(
+            f"fused dequant-matmul got a stacked weight {q.shape}: slice "
+            "one matrix out, or read the stack with matmul_int8_grouped"
+        )
+    if x.ndim < 1 or x.size == 0:
         return False
     M = 1
     for d in x.shape[:-1]:

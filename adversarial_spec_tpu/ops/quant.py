@@ -34,7 +34,14 @@ import jax
 import jax.numpy as jnp
 
 QUANTIZABLE = frozenset(
-    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head", "lm_head_t"}
+    {
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
+        "lm_head_t",
+        # latent attention's projections and the routed experts' stacks
+        # ([L, E, in, out]: scales per layer, expert and output channel);
+        # the router stays full precision (its top-k is rounding-sensitive)
+        "wq_a", "wq_b", "wkv_a", "wkv_b", "we_gate", "we_up", "we_down",
+    }
 )
 
 # The registry's ``quant`` vocabulary lives jax-free in

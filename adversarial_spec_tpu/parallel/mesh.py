@@ -26,6 +26,11 @@ from jax.sharding import Mesh
 
 DP, TP, SP = "dp", "tp", "sp"
 MeshAxes = (DP, TP, SP)
+# Expert parallel: the axis a routed layer's expert stack is split over
+# (parallel/sharding.py). No registry mesh spec builds it yet (meshes
+# through the batcher: ROADMAP D1); a mesh without it holds every expert
+# on every device.
+EP = "ep"
 
 
 def maybe_initialize_distributed() -> None:

@@ -22,7 +22,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from adversarial_spec_tpu.parallel.mesh import DP, TP
+from adversarial_spec_tpu.parallel.mesh import DP, EP, TP
 
 # Pytree path suffix → PartitionSpec. Layer-stacked params carry a leading
 # n_layers dim (never sharded).
@@ -45,6 +45,20 @@ _PARAM_RULES: dict[str, P] = {
     "w_gate": P(None, None, TP),
     "w_up": P(None, None, TP),
     "w_down": P(None, TP, None),
+    # Latent attention: the query path and the up-projection split by
+    # head like wq/wo; the compressed vector is one "head" and stays whole.
+    "wq_a": P(None, None, None),
+    "q_norm": P(None, None),
+    "wq_b": P(None, None, TP),
+    "wkv_a": P(None, None, None),
+    "kv_norm": P(None, None),
+    "wkv_b": P(None, None, TP),
+    # Routed experts [L, E, in, out]: the expert axis over ``ep``, each
+    # expert whole on its device; the router is everyone's.
+    "w_router": P(None, None, None),
+    "we_gate": P(None, EP, None, None),
+    "we_up": P(None, EP, None, None),
+    "we_down": P(None, EP, None, None),
 }
 
 
@@ -78,10 +92,19 @@ def param_sharding_rules(path) -> P:
     return _PARAM_RULES[name]
 
 
+def _on_mesh(mesh: Mesh, spec: P) -> NamedSharding:
+    """``spec`` over the axes this mesh has: an axis it lacks (``ep`` on a
+    dp/sp/tp mesh, ``tp`` on an expert-parallel one) leaves that dimension
+    whole on every device."""
+    return NamedSharding(
+        mesh, P(*(a if a in mesh.axis_names else None for a in spec))
+    )
+
+
 def param_shardings(mesh: Mesh, params) -> dict:
     """NamedSharding pytree matching ``params``."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: NamedSharding(mesh, param_sharding_rules(path)),
+        lambda path, _: _on_mesh(mesh, param_sharding_rules(path)),
         params,
     )
 
@@ -90,7 +113,7 @@ def shard_params(mesh: Mesh, params):
     """Place a host/any-device param pytree onto the mesh per the rules."""
     return jax.tree_util.tree_map_with_path(
         lambda path, x: jax.device_put(
-            x, NamedSharding(mesh, param_sharding_rules(path))
+            x, _on_mesh(mesh, param_sharding_rules(path))
         ),
         params,
     )
@@ -113,7 +136,7 @@ def make_device_put(mesh: Mesh, dtype):
         spec = _PARAM_RULES.get(path_names[-1], P())
         if isinstance(arr, np.ndarray) and arr.dtype != np_dtype:
             arr = arr.astype(np_dtype)
-        return jax.device_put(arr, NamedSharding(mesh, spec))
+        return jax.device_put(arr, _on_mesh(mesh, spec))
 
     return put
 

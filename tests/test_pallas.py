@@ -613,13 +613,16 @@ class TestFusedQuantMatmul:
             ),
         )
         # Layer-stacked leaves (3-D q) have no flat [K, N] operand to
-        # stream: not fused (the model scans per-layer slices, so the
-        # dispatcher only ever sees 2-D weights — this pins the guard).
+        # stream. The model scans per-layer slices, so the dispatcher
+        # only ever sees 2-D weights; a stack that reaches it is a
+        # caller's mistake and is refused aloud, never handed to XLA
+        # without a word (stacks are matmul_int8_grouped's operand).
         stacked = {
             "q4": jnp.stack([w4["q4"]] * 2),
             "scale": jnp.stack([w4["scale"]] * 2),
         }
-        assert not pallas_quant.fused_supported(x, stacked)
+        with pytest.raises(ValueError, match="stacked weight"):
+            pallas_quant.fused_supported(x, stacked)
         assert not pallas_quant.fused_supported(x, w)  # plain array
 
     def test_preferred_element_type(self):

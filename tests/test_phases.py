@@ -234,7 +234,8 @@ def test_every_pallas_call_is_named_after_its_entry_point():
     assert _kernel_names() == {
         "matmul_int8", "matmul_int4", "paged_decode_attention",
         "paged_decode_attention_mq", "decode_attention",
-        "decode_attention_mq",
+        "decode_attention_mq", "matmul_int8_grouped",
+        "paged_latent_attention_mq",
     }
 
 
@@ -279,9 +280,21 @@ def _scoped_op_names(lowered_text: str) -> list[str]:
     return out
 
 
-def test_step_programs_run_under_declared_scopes(tiny, spec_on, monkeypatch):
+DENSE_SCOPES = {"attn", "mlp", "qmm", "head"}
+MODEL_SCOPES = {
+    "mistral": DENSE_SCOPES,
+    "mistral4": DENSE_SCOPES
+    | {"attn.latent", "moe.route", "moe.experts", "moe.shared"},
+}
+
+
+@pytest.mark.parametrize("family", MODEL_SCOPES)
+def test_step_programs_run_under_declared_scopes(family, spec_on, monkeypatch):
     """Every matmul and custom call of the verify step and of the
-    prefill chunk lies under one of the declared device scopes."""
+    prefill chunk lies under one of the declared device scopes, and every
+    scope a layer kind brings is met in both."""
+    cfg = get_config(family, "tiny")
+    tiny = init_params(jax.random.PRNGKey(0), cfg), cfg
     seen: dict = {}
 
     def shapes(tree):
@@ -309,7 +322,7 @@ def test_step_programs_run_under_declared_scopes(tiny, spec_on, monkeypatch):
         for op in names:
             assert set(op.split("/")) & set(obs.DEVICE_SCOPES), (name, op)
         found = {s for op in names for s in op.split("/")}
-        assert {"attn", "mlp", "qmm", "head"} <= found, name
+        assert MODEL_SCOPES[family] <= found, (name, found)
 
 
 # -- every key a new metric file reads is one the program produces -----------

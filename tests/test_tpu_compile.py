@@ -191,3 +191,167 @@ def test_whole_decode_chunk_compiles_in_place(chip):
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= cfg.n_layers
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
+# -- Mistral-Small-4: the latent cache and the routed experts ---------------
+
+M4 = get_config(
+    "mistral4", "small-119b", n_layers=9, experts_held=(0, 32),
+    vocab_rows=32768,
+)
+
+
+def test_paged_latent_attention_compiles(chip):
+    """The latent verify kernel at the published shapes (32 heads on one
+    shared 256 + 64-wide latent, a span of γ+1) over a 512-page table: the
+    walk cuts [page, 256] and [page, 128] pages out of the two pools."""
+    la = M4.latent
+    heads, k_dim, v_dim = M4.kv_layout
+    span = GAMMA + 1
+    q_lat = _shape(chip, (B, span, M4.n_heads, la.kv_rank), jnp.bfloat16)
+    q_rot = _shape(chip, (B, span, M4.n_heads, la.rope_pad), jnp.bfloat16)
+    r_pool = _shape(chip, (M4.n_layers, N_PAGES, heads, PAGE, k_dim), jnp.bfloat16)
+    c_pool = _shape(chip, (M4.n_layers, N_PAGES, heads, PAGE, v_dim), jnp.bfloat16)
+    window = _shape(chip, (B, span), jnp.int32)
+
+    def fn(q_lat, q_rot, r, c, table, layer, starts, ends):
+        return pallas_paged.paged_latent_attention_mq(
+            q_lat, q_rot, r, c, table, starts, ends,
+            scale=M4.attn_scale, layer=layer,
+        )
+
+    text = _compiled_text(
+        fn, q_lat, q_rot, r_pool, c_pool,
+        _shape(chip, (B, TABLE_WIDTH), jnp.int32),
+        _shape(chip, (), jnp.int32), window, window,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", ["verify", "prefill"])
+@pytest.mark.parametrize("weight", ["we_up", "we_down"])
+def test_grouped_dequant_matmul_compiles(chip, rows, weight):
+    """The grouped int8 matmul over the held experts' stack of every
+    layer ([9, 32, in, out]), at the row tiles the routed layer picks for
+    a verify span and for a prefill chunk."""
+    from adversarial_spec_tpu.models import moe
+    from adversarial_spec_tpu.ops import pallas_quant
+
+    ex = M4.experts
+    k, n = {
+        "we_up": (M4.dim, ex.expert_dim), "we_down": (ex.expert_dim, M4.dim)
+    }[weight]
+    pairs = ROW_COUNTS[rows] * ex.top_k
+    bm = moe._tile_rows(pairs, ex.n_held)
+    n_tiles = -(-pairs // bm) + ex.n_held
+    text = _compiled_text(
+        lambda x, q, s, layer, tg, nl: pallas_quant.matmul_int8_grouped(
+            x, q, s, layer, tg, nl, bm=bm
+        ),
+        _shape(chip, (n_tiles * bm, k), jnp.bfloat16),
+        _shape(chip, (M4.n_layers, ex.n_held, k, n), jnp.int8),
+        _shape(chip, (M4.n_layers, ex.n_held, 1, n), jnp.float32),
+        _shape(chip, (), jnp.int32),
+        _shape(chip, (n_tiles,), jnp.int32),
+        _shape(chip, (), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_latent_verify_step_compiles_in_place(chip):
+    """The batcher's whole verify program at the cell's share of
+    Mistral-Small-4 (9 layers, 32 of 128 experts, int8): both new kernels
+    are in every layer, the expert stacks are read by index (no value of
+    the program is a layer's or a block of layers' slice of a stack, and
+    the temporaries stay under one layer's 805 MB of experts), and the
+    donated latent pool is updated in place."""
+    import re
+
+    from adversarial_spec_tpu.engine import scheduler
+    from adversarial_spec_tpu.engine.kvcache import (
+        PagedCacheLayout,
+        init_page_pool,
+    )
+    from adversarial_spec_tpu.models.transformer import init_params
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _shape(chip, s.shape, s.dtype), tree)
+
+    params = on_chip(
+        jax.eval_shape(
+            lambda: quant.quantize_params(
+                init_params(
+                    jax.random.key(0), M4, jnp.bfloat16, expert_quant="int8"
+                ),
+                fmt="int8",
+            )
+        )
+    )
+    heads, k_dim, v_dim = M4.kv_layout
+    layout = PagedCacheLayout(
+        n_pages=513, page_size=PAGE, n_layers=M4.n_layers,
+        n_kv_heads=heads, head_dim=k_dim, v_dim=v_dim,
+    )
+    pool = on_chip(jax.eval_shape(lambda: init_page_pool(layout, jnp.bfloat16)))
+    row = _shape(chip, (B,), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = scheduler.scheduler_spec_chunk.lower(
+        params, M4, pool,
+        _shape(chip, (B, M4.max_seq_len // PAGE), jnp.int32),
+        _shape(chip, (B, M4.max_seq_len), jnp.int32),
+        row, row, row, row, row, row, row, row,
+        _shape(chip, (B,), jnp.bool_),
+        _shape(chip, (B, 128), jnp.int32),
+        _shape(chip, (1,), jnp.int32),
+        _shape(chip, key.shape, key.dtype),
+        _shape(chip, (), jnp.float32),
+        _shape(chip, (), jnp.float32),
+        gamma=GAMMA, greedy=True, top_k=0, use_top_p=False,
+        use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
+    ).compile()
+    text = compiled.as_text()
+    # per layer: one latent attention + three grouped matmuls at least
+    assert text.count("tpu_custom_call") >= 4 * M4.n_layers
+    stacks = set(re.findall(r"s8\[((?:\d+,)*32,(?:4096,2048|2048,4096))\]", text))
+    assert stacks == {"9,32,4096,2048", "9,32,2048,4096"}, stacks
+    one_layer_of_experts = 32 * 3 * M4.dim * M4.experts.expert_dim
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_of_experts
+
+
+def test_latent_admission_prefill_reads_the_stacks_through_the_kernel(chip):
+    """The batcher's admission prefill chunk at the same share, traced
+    with the flag the scheduler gives a routed family
+    (`_prefill_pallas_matmul`): a chunk of 64 tokens after a cached prefix
+    in a 6,144-slot admission cache. The layer scan's body (rolled: it is
+    in the text once) holds the three grouped matmuls and the dense int8
+    ones, and the temporaries stay under one layer's experts (44 MB
+    here). Without the flag there is no kernel in it and every row tile
+    gathers a whole expert matrix out of the stack: 3.6 GB of temporaries
+    here, 150-250 ms a chunk on the chip (PERF.md section 6, PR 32)."""
+    from adversarial_spec_tpu.engine.generate import prefill_chunk
+    from adversarial_spec_tpu.models.transformer import init_cache, init_params
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _shape(chip, s.shape, s.dtype), tree)
+
+    params = on_chip(
+        jax.eval_shape(
+            lambda: quant.quantize_params(
+                init_params(
+                    jax.random.key(0), M4, jnp.bfloat16, expert_quant="int8"
+                ),
+                fmt="int8",
+            )
+        )
+    )
+    cache = on_chip(
+        jax.eval_shape(lambda: init_cache(M4, 1, 6144, dtype=jnp.bfloat16))
+    )
+    compiled = prefill_chunk.lower(
+        params, M4, _shape(chip, (1, 64), jnp.int32),
+        _shape(chip, (1,), jnp.int32), cache, _shape(chip, (), jnp.int32),
+        use_pallas_matmul=True,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3 + 8
+    one_layer_of_experts = 32 * 3 * M4.dim * M4.experts.expert_dim
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_of_experts

@@ -166,6 +166,7 @@ class GraftlintConfig:
         default_factory=lambda: [
             "adversarial_spec_tpu.ops.pallas_quant._qmm_int8_kernel",
             "adversarial_spec_tpu.ops.pallas_quant._qmm_int4_kernel",
+            "adversarial_spec_tpu.ops.pallas_quant._qmm_int8_grouped_kernel",
             "adversarial_spec_tpu.ops.pallas_paged._paged_mq_attn_kernel",
             "adversarial_spec_tpu.ops.pallas_paged._paged_mq_attn_grid_kernel",
             "adversarial_spec_tpu.ops.quant.matmul",
