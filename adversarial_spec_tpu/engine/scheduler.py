@@ -101,6 +101,7 @@ from adversarial_spec_tpu.ops import quant
 from adversarial_spec_tpu.models.transformer import (
     forward_paged_decode,
     init_cache,
+    n_indexed_stacks,
 )
 from adversarial_spec_tpu.resilience import faults, injector
 
@@ -1141,6 +1142,17 @@ class ContinuousBatcher:
         self._prefill_pallas_matmul = (
             self._use_pallas_matmul and cfg.ffn_kind == "routed"
         )
+        # How the decode step reads its layer weights is fixed when it
+        # is traced, so it is told once, here: the quantized stacks the
+        # fused kernel reads by layer index instead of a slice's copy.
+        if obs_mod.config().enabled:
+            obs_mod.hot.qmm_indexed_stacks.set(
+                n_indexed_stacks(
+                    params,
+                    self._use_pallas_matmul,
+                    self.B * (self.gamma + 1 if self.speculative else 1),
+                )
+            )
 
         B, cap = self.B, max_new_cap
         self.cap = cap

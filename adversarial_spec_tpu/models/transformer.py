@@ -9,7 +9,18 @@ TPU-first design decisions (why this is not a torch translation):
 - **Layer-stacked params + ``lax.scan``.** Every per-layer weight carries a
   leading ``n_layers`` dim and the layer loop is one ``scan`` — one traced
   layer body regardless of depth, which keeps XLA compile time flat from
-  2-layer test configs to 80-layer 70B.
+  2-layer test configs to 80-layer 70B. What a Pallas kernel reads is
+  NOT among the scan's sliced operands (``_split_layers``): a slice that
+  XLA cannot fold into the kernel's operand is a copy of the layer every
+  step, so the quantized matmul stacks (with the fused dequant-matmul
+  on) and the routed expert stacks stay whole, and the kernels read
+  layer ``l`` of them through a scalar-prefetched index, as the paged
+  attention kernels read the pool. The scan is ROLLED for decode spans
+  too: it used to unroll them by four so that XLA could start layer
+  i+1's weight DMA under layer i's compute, but every kernel now streams
+  its own weights, and on the chip the rolled verify step is as fast
+  (17.07 against 17.28 ms at Mistral-7B, 14.35 against 14.47 at
+  Qwen2-7B; PERF.md section 6, PR 33) in a quarter of the program.
 - **Static shapes everywhere.** Batches are left-padded to a bucketed length
   (engine/generate.py); the KV cache is a dense preallocated
   ``[L, B, H_kv, S_max, D]`` buffer written with ``dynamic_update_slice``.
@@ -39,7 +50,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-import os
 from typing import Any
 
 import jax
@@ -48,6 +58,7 @@ import jax.numpy as jnp
 from adversarial_spec_tpu.models import moe
 from adversarial_spec_tpu.models.config import ModelConfig
 from adversarial_spec_tpu.ops.quant import (
+    StackedLayer,
     dequantize,
     div_const,
     matmul,
@@ -63,16 +74,6 @@ from adversarial_spec_tpu.ops.rope import (
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
-
-# Unroll factor for the scan-over-layers during DECODE (token spans ≤ this
-# many positions). Single-token layers are HBM-bound (stream the layer's
-# weights, tiny compute); a rolled scan serializes layer i's compute behind
-# layer i's weight fetch, while a modest unroll lets XLA software-pipeline
-# layer i+1's weight DMA under layer i's compute. Prefill keeps the rolled
-# scan: its per-layer compute is MXU-bound and compile time stays flat for
-# 80-layer configs.
-_DECODE_UNROLL = int(os.environ.get("ADVSPEC_DECODE_UNROLL", "4"))
-_DECODE_UNROLL_MAX_SPAN = 16
 
 
 def init_params(
@@ -640,12 +641,17 @@ def forward(
         }
         return out, out["k"], out["v"]
 
-    scanned_layers, experts = _split_experts(params["layers"])
+    scanned_layers, indexed, experts = _split_layers(
+        params["layers"], fused_mm, B * S, x.dtype
+    )
 
     def layer_body(x, scanned):
-        lp, layer_id, _ = scanned
+        lp, layer_id, cache_l = scanned
+        lp = _layer_weights(lp, indexed, layer_id)
         with jax.named_scope("attn"):
-            out, cache_l = (latent_block if latent else attn_block)(x, scanned)
+            out, cache_l = (latent_block if latent else attn_block)(
+                x, (lp, layer_id, cache_l)
+            )
         routed, _ = _routed_ffn_of(
             cfg, lp, experts, layer_id, fused_mm, pallas_interpret
         )
@@ -785,28 +791,81 @@ def forward(
         return out, cache_l
 
     # The cache dict scans as a pytree: every leaf carries a leading
-    # n_layers axis, so one scan serves both cache layouts. Decode spans
-    # unroll (see _DECODE_UNROLL) so weight DMA pipelines across layers.
-    # "layers" names the scan itself: the slicing of each layer's
-    # weights out of the stacked arrays has no other owner.
+    # n_layers axis, so one scan serves both cache layouts. The scan is
+    # rolled for every span (see the module docstring). "layers" names
+    # the scan itself: the slicing of each layer's weights out of the
+    # stacked arrays has no other owner.
     with jax.named_scope("layers"):
         x, new_cache = jax.lax.scan(
             layer_body,
             x,
             (scanned_layers, layer_ids, cache),
-            unroll=_DECODE_UNROLL if S <= _DECODE_UNROLL_MAX_SPAN else 1,
         )
 
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
     return logits, new_cache
 
 
-def _split_experts(layers: dict) -> tuple[dict, dict]:
-    """The layer weights the scan slices layer by layer, and the routed
-    expert stacks it must not (models/moe.py ``EXPERT_WEIGHTS``): those
-    stay whole and are read by layer index."""
+# The leaves of ``params["layers"]`` that a layer consumes through ``mm``:
+# attention's and the (shared) FFN's projections, latent attention's down
+# and up projections of the queries and its down projection of the keys
+# and values. Not ``wkv_b``, which ``_latent_up`` dequantizes whole, nor
+# the router, the norms and the biases.
+_MM_WEIGHTS = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    "wq_a", "wq_b", "wkv_a",
+)
+
+
+def _split_layers(layers: dict, fused_mm: bool, rows: int, dtype):
+    """(the leaves of ``params["layers"]`` that the layer scan slices
+    layer by layer, the matmul stacks it must not, the expert stacks it
+    must not): the one place that decides.
+
+    A sliced operand that a Pallas kernel reads is a COPY of the layer
+    every step (XLA cannot fold the slice into the kernel's operand), so
+    what a kernel reads stays whole and is read by layer index: the
+    routed expert stacks (models/moe.py ``EXPERT_WEIGHTS``) always, and
+    with the fused dequant-matmul on (``fused_mm``) every quantized
+    ``_MM_WEIGHTS`` stack that the kernel covers for ``rows`` activation
+    rows of ``dtype``. Everything else, and every matmul weight with the
+    kernel off, goes on as the scan's operand, where XLA folds the slice
+    into its own dot."""
     experts = {k: layers[k] for k in moe.EXPERT_WEIGHTS if k in layers}
-    return {k: v for k, v in layers.items() if k not in experts}, experts
+    indexed = {}
+    if fused_mm:
+        from adversarial_spec_tpu.ops.pallas_quant import fused_supported
+
+        # the plan asks the activation's row count and item size alone
+        x = jax.ShapeDtypeStruct((rows, 1), dtype)
+        indexed = {
+            k: layers[k]
+            for k in _MM_WEIGHTS
+            if k in layers and fused_supported(x, layers[k], layer=0)
+        }
+    scanned = {
+        k: v for k, v in layers.items() if k not in experts and k not in indexed
+    }
+    return scanned, indexed, experts
+
+
+def _layer_weights(lp: dict, indexed: dict, layer_id) -> dict:
+    """One layer's weights as its body reads them: the scan's slices,
+    and under their own names the whole matmul stacks with the layer to
+    read (``mm`` takes a ``StackedLayer`` where it takes a weight)."""
+    return {
+        **lp, **{k: StackedLayer(v, layer_id) for k, v in indexed.items()}
+    }
+
+
+def n_indexed_stacks(params: Params, fused_mm: bool, rows: int) -> int:
+    """How many quantized matmul stacks the decode step of ``rows``
+    positions reads by layer index instead of slicing (0 with the fused
+    dequant-matmul off; the expert stacks are not counted)."""
+    _, indexed, _ = _split_layers(
+        params["layers"], fused_mm, rows, params["embed"].dtype
+    )
+    return len(indexed)
 
 
 def _routed_ffn_of(cfg, lp, experts, layer_id, use_pallas, interpret):
@@ -962,7 +1021,9 @@ def forward_paged_decode(
     flat_page = write_page.reshape(-1)
     flat_off = write_off.reshape(-1)
     heads = jnp.arange(cfg.kv_layout[0])
-    scanned_layers, experts = _split_experts(params["layers"])
+    scanned_layers, indexed, experts = _split_layers(
+        params["layers"], fused_mm, B * S, x.dtype
+    )
 
     def scatter(pool, layer_id, new_kv):
         # Pages are heads-major [L, n_pages, Hkv, page_size, D]. The
@@ -983,9 +1044,10 @@ def forward_paged_decode(
     def layer_body(carry, scanned):
         x, pool = carry
         lp, layer_id = scanned
+        lp = _layer_weights(lp, indexed, layer_id)
         with jax.named_scope("attn"):
             out, pool = (latent_block if latent else attn_block)(
-                x, pool, scanned
+                x, pool, (lp, layer_id)
             )
         routed, routing = _routed_ffn_of(
             cfg, lp, experts, layer_id, fused_mm, pallas_interpret
@@ -1204,14 +1266,11 @@ def forward_paged_decode(
             )
         return out, pool
 
-    # Always a decode step here (short S) → always unrolled for
-    # weight-DMA pipelining.
     with jax.named_scope("layers"):
         (x, new_pool), routing = jax.lax.scan(
             layer_body,
             (x, pool),
             (scanned_layers, layer_ids),
-            unroll=_DECODE_UNROLL,
         )
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)
     return logits, new_pool, routing

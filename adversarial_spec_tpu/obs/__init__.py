@@ -241,6 +241,7 @@ class HotMetrics:
         "batcher_rows",
         "batcher_distinct_prompts",
         "batcher_builds",
+        "qmm_indexed_stacks",
         "moe_imbalance",
         "latent_tokens_read",
         "_moe",
@@ -416,6 +417,11 @@ class HotMetrics:
             "advspec_batcher_builds_total",
             help="ContinuousBatcher constructions by the engine "
             "(a rebuild drops the prefix cache)",
+        )
+        self.qmm_indexed_stacks = m.gauge(
+            "advspec_qmm_indexed_stacks",
+            help="quantized layer stacks the newest batcher's decode step "
+            "reads by a prefetched layer index (none is sliced or copied)",
         )
         # Routed experts (models/moe.py; counted by the step programs and
         # fetched with their counts): how uneven a program's routing was,

@@ -42,6 +42,7 @@ tests/test_quant.py). See docs/kernels.md for the full inventory.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -166,12 +167,68 @@ def _out_dtype(x: jnp.ndarray, preferred_element_type):
     )
 
 
-def _pad_rows(x2: jnp.ndarray, bm: int) -> tuple[jnp.ndarray, int]:
+def _pad_rows(x2: jnp.ndarray, bm: int) -> jnp.ndarray:
     M = x2.shape[0]
     Mp = -(-M // bm) * bm
     if Mp != M:
         x2 = jnp.pad(x2, ((0, Mp - M), (0, 0)))
-    return x2, Mp
+    return x2
+
+
+def _blocked(kernel, xs, w, scale, layer, plan, out_dtype):
+    """(kernel, ``pallas_call`` keywords, operands) of one grid
+    (M/bm, N/bn, K/bk) over the row-padded activation halves ``xs``
+    ([Mp, K] each) and a weight that is flat ``[K, N]`` (``layer`` None)
+    or a layer stack ``[L, K, N]`` read at the scalar-prefetched
+    ``layer``: the weight's and the scale's index_maps follow it, so no
+    layer is sliced out of the stack beforehand. Same blocks in the same
+    order either way."""
+    bm, bk, bn = plan
+    Mp = xs[0].shape[0]
+    K, N = w.shape[-2:]
+    stacked = layer is not None
+    lead = (None,) if stacked else ()
+
+    def at(refs):  # the leading block index of the weight and its scale
+        return (refs[0][0],) if stacked else ()
+
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=int(stacked),
+        grid=(Mp // bm, N // bn, K // bk),
+        in_specs=[x_spec] * len(xs)
+        + [
+            pl.BlockSpec(lead + (bk, bn), lambda i, j, k, *r: at(r) + (k, j)),
+            pl.BlockSpec(lead + (1, bn), lambda i, j, k, *r: at(r) + (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    operands = [jnp.asarray(layer, jnp.int32).reshape(1)] if stacked else []
+    operands += [
+        *xs, w, scale.reshape(w.shape[:-2] + (1, N)).astype(jnp.float32)
+    ]
+    return (
+        (lambda _layer_ref, *refs: kernel(*refs)) if stacked else kernel,
+        dict(
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+        ),
+        operands,
+    )
+
+
+def _check_stack(q, layer) -> None:
+    """A flat ``[K, N]`` weight, or a ``[L, K, N]`` layer stack WITH the
+    layer to read. Anything else is a caller's mistake and is refused
+    aloud, never handed on without a word."""
+    if q.ndim != (2 if layer is None else 3):
+        raise ValueError(
+            f"fused dequant-matmul got a weight {q.shape} with "
+            f"layer={layer!r}: a stacked weight [L, K, N] needs the layer "
+            "to read (or slice one matrix out), a flat one takes none, and "
+            "an expert stack [L, E, K, N] is matmul_int8_grouped's"
+        )
 
 
 @functools.partial(
@@ -179,35 +236,31 @@ def _pad_rows(x2: jnp.ndarray, bm: int) -> tuple[jnp.ndarray, int]:
 )
 def matmul_int8(
     x: jnp.ndarray,  # [..., K] activations
-    q: jnp.ndarray,  # [K, N] int8
-    scale: jnp.ndarray,  # [1, N] f32
+    q: jnp.ndarray,  # [K, N] int8, or the layer stack [L, K, N]
+    scale: jnp.ndarray,  # [1, N] f32, or [L, 1, N]
+    layer=None,  # int32 scalar (traced): the layer of the stack to read
     preferred_element_type=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """``x @ (q * scale)`` with the int8 weight streamed packed and
-    dequantized in-kernel. Returns [..., N]."""
-    K, N = q.shape
+    dequantized in-kernel. Returns [..., N]. With ``layer``, ``q`` and
+    ``scale`` are whole layer stacks and the kernel reads layer
+    ``layer`` of them in place: bit for bit what the flat call gives on
+    ``q[layer]``, ``scale[layer]``, without that slice's copy."""
+    _check_stack(q, layer)
+    K, N = q.shape[-2:]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, K)
     M = x2.shape[0]
-    bm, bk, bn = _plan_blocks(M, K, N, x2.dtype.itemsize, 1)
-    x2, Mp = _pad_rows(x2, bm)
-    out = pl.pallas_call(
+    plan = _plan_blocks(M, K, N, x2.dtype.itemsize, 1)
+    x2 = _pad_rows(x2, plan[0])
+    kernel, kw, operands = _blocked(
         functools.partial(_qmm_int8_kernel, compute_dtype=x.dtype),
-        grid=(Mp // bm, N // bn, K // bk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct(
-            (Mp, N), _out_dtype(x, preferred_element_type)
-        ),
-        interpret=interpret,
-        name="matmul_int8",
-    )(x2, q, scale.reshape(1, N).astype(jnp.float32))
+        [x2], q, scale, layer, plan, _out_dtype(x, preferred_element_type),
+    )
+    out = pl.pallas_call(
+        kernel, interpret=interpret, name="matmul_int8", **kw
+    )(*operands)
     return out[:M].reshape(lead + (N,))
 
 
@@ -216,14 +269,17 @@ def matmul_int8(
 )
 def matmul_int4(
     x: jnp.ndarray,  # [..., K] activations (K = true contraction width)
-    q4: jnp.ndarray,  # [ceil(K/2), N] int8 nibble-packed
-    scale: jnp.ndarray,  # [1, N] f32
+    q4: jnp.ndarray,  # [ceil(K/2), N] int8 nibble-packed, or [L, .., N]
+    scale: jnp.ndarray,  # [1, N] f32, or [L, 1, N]
+    layer=None,  # int32 scalar (traced): the layer of the stack to read
     preferred_element_type=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """``x @ dequant(q4)`` with the nibble-packed weight streamed as-is
-    and unpacked in-kernel by pure shifts. Returns [..., N]."""
-    K2, N = q4.shape
+    and unpacked in-kernel by pure shifts. Returns [..., N]. ``layer``:
+    as ``matmul_int8``."""
+    _check_stack(q4, layer)
+    K2, N = q4.shape[-2:]
     K = x.shape[-1]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, K)
@@ -234,27 +290,16 @@ def matmul_int4(
     xe = x2[:, 0::2]  # rows 2k of the unpacked weight
     xo = x2[:, 1::2]  # rows 2k+1
     M = x2.shape[0]
-    bm, bk, bn = _plan_blocks(M, K2, N, 2 * x2.dtype.itemsize, 1)
-    xe, Mp = _pad_rows(xe, bm)
-    xo, _ = _pad_rows(xo, bm)
-    half_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
-    out = pl.pallas_call(
+    plan = _plan_blocks(M, K2, N, 2 * x2.dtype.itemsize, 1)
+    xe = _pad_rows(xe, plan[0])
+    xo = _pad_rows(xo, plan[0])
+    kernel, kw, operands = _blocked(
         functools.partial(_qmm_int4_kernel, compute_dtype=x.dtype),
-        grid=(Mp // bm, N // bn, K2 // bk),
-        in_specs=[
-            half_spec,
-            half_spec,
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct(
-            (Mp, N), _out_dtype(x, preferred_element_type)
-        ),
-        interpret=interpret,
-        name="matmul_int4",
-    )(xe, xo, q4, scale.reshape(1, N).astype(jnp.float32))
+        [xe, xo], q4, scale, layer, plan, _out_dtype(x, preferred_element_type),
+    )
+    out = pl.pallas_call(
+        kernel, interpret=interpret, name="matmul_int4", **kw
+    )(*operands)
     return out[:M].reshape(lead + (N,))
 
 
@@ -375,15 +420,17 @@ def matmul_int8_grouped(
     )
 
 
-def fused_supported(x, w) -> bool:
+def fused_supported(x, w, layer=None) -> bool:
     """True iff the fused kernel covers this (activation, weight) pair:
-    a flat quantized weight whose dims admit an unpadded block
-    assignment. The caller (ops.quant.matmul) falls back to the XLA
-    dequant-fusion path otherwise — same math, weaker streaming
-    guarantee. A STACKED quantized weight is never turned away without a
-    word: a stack is read by ``matmul_int8_grouped`` through a prefetched
-    index (models/moe.py), and one that arrives here is a caller's
-    mistake."""
+    a quantized weight, flat or a ``[L, K, N]`` layer stack given WITH
+    the ``layer`` to read, whose dims admit an unpadded block
+    assignment. ``x`` may be a ``jax.ShapeDtypeStruct``. The caller
+    (ops.quant.matmul) falls back to the XLA dequant-fusion path
+    otherwise — same math, weaker streaming guarantee. A stack WITHOUT a
+    layer index is never turned away without a word: nothing can read
+    it whole, and one that arrives here is a caller's mistake (the
+    routed experts' ``[L, E, K, N]`` stacks are
+    ``matmul_int8_grouped``'s operand, models/moe.py)."""
     from adversarial_spec_tpu.ops.quant import is_quantized, is_quantized_int4
 
     if is_quantized(w):
@@ -392,18 +439,12 @@ def fused_supported(x, w) -> bool:
         q = w["q4"]
     else:
         return False
-    if q.ndim != 2:
-        raise ValueError(
-            f"fused dequant-matmul got a stacked weight {q.shape}: slice "
-            "one matrix out, or read the stack with matmul_int8_grouped"
-        )
-    if x.ndim < 1 or x.size == 0:
+    _check_stack(q, layer)
+    if len(x.shape) < 1 or math.prod(x.shape) == 0:
         return False
-    M = 1
-    for d in x.shape[:-1]:
-        M *= d
+    K, N = q.shape[-2:]
     return (
-        _plan_blocks(M, q.shape[0], q.shape[1], x.dtype.itemsize, 1)
+        _plan_blocks(math.prod(x.shape[:-1]), K, N, x.dtype.itemsize, 1)
         is not None
     )
 
@@ -411,25 +452,22 @@ def fused_supported(x, w) -> bool:
 def quant_matmul(
     x: jnp.ndarray,
     w: dict,
+    layer=None,
     preferred_element_type=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Format dispatch for a quantized dict leaf (caller has already
-    checked ``fused_supported``)."""
+    """Format dispatch for a quantized dict leaf, flat or a layer stack
+    with its ``layer`` (caller has already checked ``fused_supported``)."""
     from adversarial_spec_tpu.ops.quant import is_quantized_int4
 
-    if is_quantized_int4(w):
-        return matmul_int4(
-            x,
-            w["q4"],
-            w["scale"],
-            preferred_element_type=preferred_element_type,
-            interpret=interpret,
-        )
-    return matmul_int8(
+    fn, q = (
+        (matmul_int4, w["q4"]) if is_quantized_int4(w) else (matmul_int8, w["q"])
+    )
+    return fn(
         x,
-        w["q"],
+        q,
         w["scale"],
+        layer,
         preferred_element_type=preferred_element_type,
         interpret=interpret,
     )

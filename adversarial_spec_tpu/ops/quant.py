@@ -30,6 +30,8 @@ bandwidth-trivial and precision-sensitive).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -110,6 +112,20 @@ def quantize_int4(w: jnp.ndarray) -> dict[str, jnp.ndarray]:
     return {"q4": pack_int4(q), "scale": scale.astype(jnp.float32)}
 
 
+class StackedLayer(NamedTuple):
+    """Layer ``layer`` of a layer-stacked quantized weight, NOT sliced
+    out of it: ``stack`` is the whole dict leaf (``q``/``q4`` [L, in,
+    out], ``scale`` [L, 1, out]) and ``layer`` a traced int32 scalar.
+    :func:`matmul` takes it where it takes a weight: the fused kernels
+    read the layer in place through a prefetched index
+    (ops/pallas_quant.py), which a slice made beforehand, as a layer
+    scan makes of its operands, would turn into a copy of the layer
+    every step."""
+
+    stack: dict
+    layer: jnp.ndarray
+
+
 def is_quantized(leaf) -> bool:
     return isinstance(leaf, dict) and set(leaf) == {"q", "scale"}
 
@@ -153,25 +169,33 @@ def matmul(
     the operand read. ``use_pallas=True`` routes supported quantized
     shapes through the fused Pallas kernels (ops/pallas_quant.py), which
     make the stream-packed-once contract explicit instead of relying on
-    the fusion heuristic; unsupported shapes (layer-stacked weights,
-    dims with no unpadded block assignment) silently keep the XLA path —
-    same math either way (docs/kernels.md pins the parity).
+    the fusion heuristic; unsupported shapes (dims with no unpadded
+    block assignment) silently keep the XLA path — same math either way
+    (docs/kernels.md pins the parity). ``w`` may be a
+    :class:`StackedLayer`: the kernels read that layer of the stack in
+    place; off them the layer is sliced out here, for XLA's dot.
     ``interpret=True`` runs those kernels in Pallas interpret mode (the
     CPU-parity harness; flag-gated exactly like ``use_pallas_decode``).
     """
+    layer = None
+    if isinstance(w, StackedLayer):
+        w, layer = w
     # One device-side name for the (dequant-)matmul wherever a layer
     # calls it: ``.../attn/qmm``, ``.../mlp/qmm``, ``head/qmm``.
     with jax.named_scope("qmm"):
         if use_pallas and (is_quantized(w) or is_quantized_int4(w)):
             from adversarial_spec_tpu.ops import pallas_quant
 
-            if pallas_quant.fused_supported(x, w):
+            if pallas_quant.fused_supported(x, w, layer):
                 return pallas_quant.quant_matmul(
                     x,
                     w,
+                    layer,
                     preferred_element_type=preferred_element_type,
                     interpret=interpret,
                 )
+        if layer is not None:
+            w = jax.tree.map(lambda a: a[layer], w)
         if is_quantized_int4(w):
             q = unpack_int4(w["q4"], x.shape[-1])
             y = jnp.matmul(

@@ -408,6 +408,28 @@ class TestSchedulerInstrumentation:
             k.startswith("advspec_host_syncs_total") for k in snap
         )
 
+    @pytest.mark.parametrize(
+        "quantized,fused,want", [(True, True, 7), (True, False, 0), (False, True, 0)]
+    )
+    def test_indexed_stacks_gauge_is_set_where_the_batcher_is_built(
+        self, tiny_model, quantized, fused, want
+    ):
+        """`advspec_qmm_indexed_stacks`: the quantized stacks the decode
+        step reads by layer index: a dense family's seven with the fused
+        dequant-matmul on, none with it off or with nothing quantized."""
+        from adversarial_spec_tpu.engine.scheduler import ContinuousBatcher
+        from adversarial_spec_tpu.ops import quant
+
+        params, cfg = tiny_model
+        if quantized:
+            params = quant.quantize_params(params)
+        obs.reset_stats()
+        ContinuousBatcher(
+            params, cfg, max_batch=2, max_new_cap=8, chunk=4,
+            use_pallas_matmul=fused,
+        )
+        assert obs.metrics.snapshot()["advspec_qmm_indexed_stacks"] == want
+
     def test_retrace_watch_sees_scheduler_programs(self, tiny_model):
         params, cfg = tiny_model
         obs.reset_stats()

@@ -625,6 +625,83 @@ class TestFusedQuantMatmul:
             pallas_quant.fused_supported(x, stacked)
         assert not pallas_quant.fused_supported(x, w)  # plain array
 
+    # A [L, K, N] stack read at a prefetched layer index: (K, N) of a
+    # plan that keeps the contraction whole, and of one that splits it
+    # (K x bn alone is over the whole-K budget).
+    STACK_PLANS = {"whole_k": (256, 128), "split_k": (8192, 512)}
+
+    def _stack(self, fmt, plan, L=3):
+        K, N = self.STACK_PLANS[plan]
+        kq, ks = jax.random.split(jax.random.key(K), 2)
+        # int8 values (for int4: packed bytes) and scales drawn as they
+        # are stored: quantizing an [L, 8192, 512] float stack is slow
+        q = jax.random.randint(kq, (L, K, N), -127, 128, jnp.int8)
+        scale = jax.random.uniform(ks, (L, 1, N), jnp.float32, 1e-3, 1e-2)
+        return {"q" if fmt == "int8" else "q4": q, "scale": scale}
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    @pytest.mark.parametrize("plan", list(STACK_PLANS))
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("fmt", ["int8", "int4"])
+    def test_layer_of_a_stack_is_the_flat_call_bit_for_bit(
+        self, fmt, dtype, plan, layer
+    ):
+        """The stacked operand changes where a block is fetched from,
+        not what is computed: layer l of the stack through the
+        prefetched index equals the flat kernel on the slice, for the
+        first, a middle and the last layer, rows that need padding
+        (5 -> 8), and both kinds of plan."""
+        from adversarial_spec_tpu.ops import pallas_quant
+
+        w = self._stack(fmt, plan)
+        name, fn = (
+            ("q", pallas_quant.matmul_int8)
+            if fmt == "int8"
+            else ("q4", pallas_quant.matmul_int4)
+        )
+        K = w[name].shape[1] * (1 if fmt == "int8" else 2)
+        x = jax.random.normal(jax.random.key(7), (5, K), jnp.dtype(dtype))
+        bk = pallas_quant._plan_blocks(
+            5, w[name].shape[1], w[name].shape[2],
+            x.dtype.itemsize * (1 if fmt == "int8" else 2), 1,
+        )[1]
+        assert (bk == w[name].shape[1]) == (plan == "whole_k")
+        got = fn(x, w[name], w["scale"], layer=jnp.int32(layer), interpret=True)
+        flat = fn(x, w[name][layer], w["scale"][layer], interpret=True)
+        assert got.dtype == x.dtype and got.shape == (5, w[name].shape[2])
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(flat, np.float32)
+        )
+
+    @pytest.mark.parametrize("fmt", ["int8", "int4"])
+    def test_stack_dispatch_with_and_without_a_layer(self, fmt):
+        """`fused_supported` takes a stack WITH its layer index and still
+        refuses one without; `quant.matmul` on a `StackedLayer` is the
+        kernel with it and a slice into XLA's dot without."""
+        from adversarial_spec_tpu.ops import pallas_quant, quant
+
+        w = self._stack(fmt, "whole_k")
+        x = jax.random.normal(jax.random.key(8), (2, 3, 256 if fmt == "int8" else 512))
+        assert pallas_quant.fused_supported(x, w, layer=0)
+        assert pallas_quant.fused_supported(
+            jax.ShapeDtypeStruct((6, 1), jnp.bfloat16), w, layer=0
+        )
+        with pytest.raises(ValueError, match="stacked weight"):
+            pallas_quant.fused_supported(x, w)
+        flat = jax.tree.map(lambda a: a[1], w)
+        with pytest.raises(ValueError, match="stacked weight"):
+            pallas_quant.fused_supported(x, flat, layer=1)
+        with pytest.raises(ValueError, match="layer"):
+            pallas_quant.matmul_int8(x, w["scale"], w["scale"], interpret=True)
+        lw = quant.StackedLayer(w, jnp.int32(1))
+        np.testing.assert_array_equal(
+            np.asarray(quant.matmul(x, lw, use_pallas=True, interpret=True)),
+            np.asarray(quant.matmul(x, flat, use_pallas=True, interpret=True)),
+        )
+        np.testing.assert_array_equal(
+            np.asarray(quant.matmul(x, lw)), np.asarray(quant.matmul(x, flat))
+        )
+
     def test_preferred_element_type(self):
         from adversarial_spec_tpu.ops import pallas_quant, quant
 

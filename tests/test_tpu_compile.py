@@ -142,6 +142,127 @@ def test_dequant_matmul_compiles(chip, model, fmt, rows, weight):
     assert "tpu_custom_call" in text
 
 
+def _on_chip(chip, tree):
+    return jax.tree.map(lambda s: _shape(chip, s.shape, s.dtype), tree)
+
+
+def _int8_params(chip, cfg, **init_kw):
+    """Shapes of the int8 checkpoint as the registry serves it."""
+    from adversarial_spec_tpu.models.transformer import init_params
+
+    return _on_chip(
+        chip,
+        jax.eval_shape(
+            lambda: quant.quantize_params(
+                init_params(jax.random.key(0), cfg, jnp.bfloat16, **init_kw),
+                fmt="int8",
+            )
+        ),
+    )
+
+
+def _compiled_verify_step(chip, cfg, params, n_pages):
+    """The batcher's whole verify program (`scheduler_spec_chunk`: draft,
+    a span of γ+1 through every layer, accept) over a bfloat16 pool of
+    `n_pages`, compiled for the described chip."""
+    from adversarial_spec_tpu.engine import scheduler
+    from adversarial_spec_tpu.engine.kvcache import (
+        PagedCacheLayout,
+        init_page_pool,
+    )
+
+    heads, k_dim, v_dim = cfg.kv_layout
+    layout = PagedCacheLayout(
+        n_pages=n_pages, page_size=PAGE, n_layers=cfg.n_layers,
+        n_kv_heads=heads, head_dim=k_dim, v_dim=v_dim,
+    )
+    pool = _on_chip(
+        chip, jax.eval_shape(lambda: init_page_pool(layout, jnp.bfloat16))
+    )
+    row = _shape(chip, (B,), jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return scheduler.scheduler_spec_chunk.lower(
+        params, cfg, pool,
+        _shape(chip, (B, cfg.max_seq_len // PAGE), jnp.int32),
+        _shape(chip, (B, cfg.max_seq_len), jnp.int32),
+        row, row, row, row, row, row, row, row,
+        _shape(chip, (B,), jnp.bool_),
+        _shape(chip, (B, 128), jnp.int32),
+        _shape(chip, (1,), jnp.int32),
+        _shape(chip, key.shape, key.dtype),
+        _shape(chip, (), jnp.float32),
+        _shape(chip, (), jnp.float32),
+        gamma=GAMMA, greedy=True, top_k=0, use_top_p=False,
+        use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
+    ).compile()
+
+
+def _layer_weight_shapes(cfg):
+    """(in, out) of a dense layer's distinct int8 matrices."""
+    qd, kd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    return {
+        "wq": (cfg.dim, qd), "wk": (cfg.dim, kd),
+        "w_up": (cfg.dim, cfg.ffn_dim), "w_down": (cfg.ffn_dim, cfg.dim),
+    }
+
+
+@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("rows", ["decode", "verify"])
+@pytest.mark.parametrize("weight", ["wq", "wk", "w_up", "w_down"])
+def test_dequant_matmul_reads_a_layer_of_the_stack_in_place(
+    chip, model, rows, weight
+):
+    """The decode step's form of the kernel: the operand is the whole
+    [L, K, N] stack and the layer a prefetched scalar. Every distinct
+    matrix of a layer (whole-K and split-K plans, K 3584 .. 18944); no
+    int8 value of the program but the stack itself: nothing is sliced
+    out of it."""
+    import re
+
+    cfg = MODELS[model]
+    k, n = _layer_weight_shapes(cfg)[weight]
+    w = {
+        "q": _shape(chip, (cfg.n_layers, k, n), jnp.int8),
+        "scale": _shape(chip, (cfg.n_layers, 1, n), jnp.float32),
+    }
+    text = _compiled_text(
+        lambda x, w, layer: quant.matmul(
+            x, quant.StackedLayer(w, layer), use_pallas=True
+        ),
+        _shape(chip, (ROW_COUNTS[rows], k), jnp.bfloat16), w,
+        _shape(chip, (), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+    assert set(re.findall(r"s8\[([\d,]+)\]", text)) == {f"{cfg.n_layers},{k},{n}"}
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_dense_verify_step_reads_its_weight_stacks_in_place(chip, model):
+    """The batcher's whole verify program at full depth, int8: the only
+    int8 values in it are the seven whole stacks and the head (no
+    layer's, and no block of layers', slice of a stack: those copies
+    were 57-66% of the busy device, PERF.md section 6, PR 33), every
+    layer's seven matmuls and its attention are kernels, and the
+    temporaries are a few MB (a four-layer block of one FFN stack was
+    235 MB)."""
+    import re
+
+    cfg = MODELS[model]
+    compiled = _compiled_verify_step(
+        chip, cfg, _int8_params(chip, cfg), N_PAGES
+    )
+    text = compiled.as_text()
+    # the rolled scan's body is in the text once
+    assert text.count("tpu_custom_call") >= 8
+    L = cfg.n_layers
+    stacks = {
+        f"{L},{k},{n}" for k, n in _layer_weight_shapes(cfg).values()
+    } | {f"{L},{cfg.n_heads * cfg.head_dim},{cfg.dim}"}
+    head = {f"{cfg.dim},{cfg.vocab_size}", f"{cfg.dim},{cfg.vocab_size},1"}
+    assert set(re.findall(r"s8\[([\d,]+)\]", text)) == stacks | head
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 @pytest.mark.slow
 def test_whole_decode_chunk_compiles_in_place(chip):
     """The batcher's whole decode program at Mistral-7B int8, full depth:
@@ -189,7 +310,8 @@ def test_whole_decode_chunk_compiles_in_place(chip):
         chunk=32, greedy=False, top_k=0, use_top_p=False,
         use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= cfg.n_layers
+    # the rolled scan's body, once: attention and the seven matmuls
+    assert compiled.as_text().count("tpu_custom_call") >= 8
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
@@ -267,51 +389,13 @@ def test_latent_verify_step_compiles_in_place(chip):
     donated latent pool is updated in place."""
     import re
 
-    from adversarial_spec_tpu.engine import scheduler
-    from adversarial_spec_tpu.engine.kvcache import (
-        PagedCacheLayout,
-        init_page_pool,
+    compiled = _compiled_verify_step(
+        chip, M4, _int8_params(chip, M4, expert_quant="int8"), 513
     )
-    from adversarial_spec_tpu.models.transformer import init_params
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: _shape(chip, s.shape, s.dtype), tree)
-
-    params = on_chip(
-        jax.eval_shape(
-            lambda: quant.quantize_params(
-                init_params(
-                    jax.random.key(0), M4, jnp.bfloat16, expert_quant="int8"
-                ),
-                fmt="int8",
-            )
-        )
-    )
-    heads, k_dim, v_dim = M4.kv_layout
-    layout = PagedCacheLayout(
-        n_pages=513, page_size=PAGE, n_layers=M4.n_layers,
-        n_kv_heads=heads, head_dim=k_dim, v_dim=v_dim,
-    )
-    pool = on_chip(jax.eval_shape(lambda: init_page_pool(layout, jnp.bfloat16)))
-    row = _shape(chip, (B,), jnp.int32)
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = scheduler.scheduler_spec_chunk.lower(
-        params, M4, pool,
-        _shape(chip, (B, M4.max_seq_len // PAGE), jnp.int32),
-        _shape(chip, (B, M4.max_seq_len), jnp.int32),
-        row, row, row, row, row, row, row, row,
-        _shape(chip, (B,), jnp.bool_),
-        _shape(chip, (B, 128), jnp.int32),
-        _shape(chip, (1,), jnp.int32),
-        _shape(chip, key.shape, key.dtype),
-        _shape(chip, (), jnp.float32),
-        _shape(chip, (), jnp.float32),
-        gamma=GAMMA, greedy=True, top_k=0, use_top_p=False,
-        use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
-    ).compile()
     text = compiled.as_text()
-    # per layer: one latent attention + three grouped matmuls at least
-    assert text.count("tpu_custom_call") >= 4 * M4.n_layers
+    # the rolled scan's body, once: the latent attention, three grouped
+    # matmuls and the seven dense int8 ones
+    assert text.count("tpu_custom_call") >= 1 + 3 + 7
     stacks = set(re.findall(r"s8\[((?:\d+,)*32,(?:4096,2048|2048,4096))\]", text))
     assert stacks == {"9,32,4096,2048", "9,32,2048,4096"}, stacks
     one_layer_of_experts = 32 * 3 * M4.dim * M4.experts.expert_dim

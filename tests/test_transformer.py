@@ -401,3 +401,139 @@ class TestTransposedHead:
             np.asarray(params["lm_head_t"]),
             np.asarray(params["embed"]).T,
         )
+
+
+class TestIndexedWeightStacks:
+    """With the fused dequant-matmul on, the layer scan slices no
+    quantized matmul stack: the kernels read layer l of the whole stack
+    through a prefetched index (`_split_layers`). The step's results are
+    the unfused path's; with the kernel off the stacks go on as the
+    scan's operands, as before."""
+
+    PAGE = 8
+    # a dense GQA family with QKV bias; the routed, latent family
+    CASES = {
+        "qwen2": dict(),
+        "mistral4": dict(experts_held=[2, 4], vocab_rows=384),
+    }
+    # int8 stacks: attention's four and the FFN's three; latent
+    # attention's wq_a, wq_b, wkv_a, wkv_b, wo and the shared expert's
+    # three (the routed experts' stacks are never the scan's)
+    N_QUANT = {"qwen2": 7, "mistral4": 8}
+
+    @pytest.fixture(scope="class", params=list(CASES))
+    def model(self, request):
+        from adversarial_spec_tpu.ops import quant
+
+        cfg = get_config(request.param, "tiny", **self.CASES[request.param])
+        params = quant.quantize_params(
+            T.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+        )
+        if cfg.qkv_bias:  # born zero: make them count
+            for i, b in enumerate(("bq", "bk", "bv")):
+                params["layers"][b] = 0.1 * jax.random.normal(
+                    jax.random.key(i), params["layers"][b].shape
+                )
+        return request.param, cfg, params
+
+    def _step_args(self, cfg, S, B=2, start=11):
+        heads, k_dim, v_dim = cfg.kv_layout
+        table = jnp.asarray([[1, 2, 3, -1], [4, 5, 6, -1]], jnp.int32)
+        kk, kv, kt = jax.random.split(jax.random.key(3), 3)
+        shape = (cfg.n_layers, 7, heads, self.PAGE)
+        pool = {
+            "k": jax.random.normal(kk, shape + (k_dim,), jnp.float32),
+            "v": jax.random.normal(kv, shape + (v_dim,), jnp.float32),
+        }
+        tokens = jax.random.randint(kt, (B, S), 3, 259)
+        q_pos = jnp.broadcast_to(start + jnp.arange(S), (B, S))
+        wp = jnp.take_along_axis(table, q_pos // self.PAGE, axis=1)
+        bounds = jnp.stack([jnp.zeros_like(q_pos), q_pos + 1], -1)
+        return tokens, q_pos, pool, table, wp, q_pos % self.PAGE, bounds, q_pos
+
+    @pytest.mark.parametrize("S", [1, 9])
+    def test_paged_step_equals_the_unfused_path(self, model, S):
+        _, cfg, params = model
+        args = self._step_args(cfg, S)
+        want, want_pool, _ = T.forward_paged_decode(params, cfg, *args)
+        got, got_pool, _ = T.forward_paged_decode(
+            params, cfg, *args, use_pallas_matmul=True, pallas_interpret=True
+        )
+        assert np.abs(np.asarray(want)).max() > 0.1
+        # float32 from the same int8 weights: summation order alone
+        np.testing.assert_allclose(got, want, atol=2e-4)
+        for name in want_pool:
+            np.testing.assert_allclose(got_pool[name], want_pool[name], atol=2e-4)
+
+    def test_prefill_forward_equals_the_unfused_path(self, model):
+        _, cfg, params = model
+        tokens = jax.random.randint(jax.random.key(5), (2, 12), 3, 259)
+        pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
+
+        def run(**kw):
+            cache = T.init_cache(cfg, 2, 16, dtype=jnp.float32)
+            return T.forward(
+                params, cfg, tokens, pos, cache, jnp.int32(0),
+                jnp.ones((2, 16), bool), **kw,
+            )
+
+        want, want_cache = run()
+        got, got_cache = run(use_pallas_matmul=True, pallas_interpret=True)
+        np.testing.assert_allclose(got, want, atol=2e-4)
+        for name in want_cache:
+            np.testing.assert_allclose(got_cache[name], want_cache[name], atol=2e-4)
+
+    @staticmethod
+    def _scanned_int8(fn, *args):
+        """Shapes of the int8 operands that the program's layer scans
+        slice (their `xs`)."""
+        out = []
+        for eqn in jax.make_jaxpr(fn)(*args).jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                first = eqn.params["num_consts"] + eqn.params["num_carry"]
+                out += [
+                    v.aval.shape
+                    for v in eqn.invars[first:]
+                    if v.aval.dtype == jnp.int8
+                ]
+        return out
+
+    @pytest.mark.parametrize("program", ["paged_step", "prefill"])
+    def test_the_layer_scan_slices_no_stack_a_kernel_reads(self, model, program):
+        name, cfg, params = model
+        if program == "paged_step":
+            args = self._step_args(cfg, 9)
+            call = lambda p, **kw: T.forward_paged_decode(p, cfg, *args, **kw)  # noqa: E731
+        else:
+            tokens = jnp.ones((2, 12), jnp.int32)
+            pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
+            cache = T.init_cache(cfg, 2, 16, dtype=jnp.float32)
+            call = lambda p, **kw: T.forward(  # noqa: E731
+                p, cfg, tokens, pos, cache, jnp.int32(0),
+                jnp.ones((2, 16), bool), **kw,
+            )
+        fused = self._scanned_int8(
+            lambda p: call(p, use_pallas_matmul=True, pallas_interpret=True),
+            params,
+        )
+        # what is left is what no kernel reads: latent attention's
+        # wkv_b, which `_latent_up` dequantizes
+        left = [params["layers"]["wkv_b"]["q"].shape] if cfg.latent else []
+        assert fused == left
+        off = self._scanned_int8(call, params)
+        assert len(off) == self.N_QUANT[name]
+        # a mesh over several devices turns the kernel off, flag or no flag
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+        sharded = self._scanned_int8(
+            lambda p: call(p, use_pallas_matmul=True, mesh=mesh), params
+        )
+        assert len(sharded) == self.N_QUANT[name]
+
+    def test_count_of_indexed_stacks(self, model):
+        name, cfg, params = model
+        assert T.n_indexed_stacks(params, True, 36) == self.N_QUANT[name] - (
+            cfg.latent is not None
+        )
+        assert T.n_indexed_stacks(params, False, 36) == 0
+        plain = T.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+        assert T.n_indexed_stacks(plain, True, 36) == 0

@@ -25,14 +25,11 @@ stage failed):
    BENCH_capacity.json payload that bench_trend's capacity schema
    accepts (>=2 knob arms, numeric frontier): the load harness and
    the capacity gate can never drift apart unnoticed.
-5. **unroll compile check** (``--full`` only — it jit-compiles an
-   80-layer config three times, minutes of CPU) — the decode-scan
-   unroll cost measurement, tools/unroll_compile_check.py.
 
 Usage:
     python tools/lint_all.py            # graftlint + mutmut sanity
     python tools/lint_all.py --changed  # lint only files changed vs main
-    python tools/lint_all.py --full     # + bench trend + unroll check
+    python tools/lint_all.py --full     # + bench trend + replay smoke
 """
 
 from __future__ import annotations
@@ -307,25 +304,12 @@ def _stage_replay_smoke() -> bool:
     return ok
 
 
-def _stage_unroll() -> bool:
-    r = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "unroll_compile_check.py")],
-        cwd=REPO,
-    )
-    ok = r.returncode == 0
-    print(
-        f"lint_all: unroll-compile-check {'OK' if ok else 'FAILED'}",
-        file=sys.stderr,
-    )
-    return ok
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "--full",
         action="store_true",
-        help="also run the (slow) unroll compile check",
+        help="also run the (slow) bench-trend and replay-smoke stages",
     )
     ap.add_argument(
         "--changed",
@@ -368,7 +352,6 @@ def main(argv: list[str] | None = None) -> int:
     if args.full:
         ok = _stage_bench_trend() and ok
         ok = _stage_replay_smoke() and ok
-        ok = _stage_unroll() and ok
     print(
         f"lint_all: {'ALL OK' if ok else 'FAILURES'}",
         file=sys.stderr,
