@@ -317,22 +317,6 @@ def create_parser() -> argparse.ArgumentParser:
         "ADVSPEC_WEIGHT_HOST_MB sets the process default)",
     )
     d.add_argument(
-        "--interleave",
-        action=argparse.BooleanOptionalAction,
-        default=None,  # None = inherit ADVSPEC_INTERLEAVE (default on)
-        help="Fused prefill+decode steps and the two-deep pipelined "
-        "scheduler drive loop (default on; --no-interleave restores "
-        "the legacy serialized loop, ADVSPEC_INTERLEAVE=0 sets the "
-        "process default)",
-    )
-    d.add_argument(
-        "--pipeline-depth",
-        type=int,
-        default=None,
-        help="Scheduler steps kept in flight (1-2; default 2; 1 = fused "
-        "but synchronous)",
-    )
-    d.add_argument(
         "--speculative",
         action=argparse.BooleanOptionalAction,
         default=None,  # None = inherit ADVSPEC_SPECULATIVE (default on)
@@ -724,16 +708,13 @@ def _configure_prefix_cache(args: argparse.Namespace):
     return prefix_cache
 
 
-def _configure_interleave(args: argparse.Namespace):
-    """Arm the fused/pipelined drive loop from flags; returns the module
-    for reporting. Stats reset per invocation (one invocation = one
-    round) so ``perf.interleave`` accounts exactly this round's steps;
-    the batcher itself persists on the engine across rounds."""
+def _configure_interleave():
+    """Reset the drive loop's counters; returns the module for
+    reporting. One invocation = one round, so ``perf.interleave``
+    accounts exactly this round's steps; the batcher itself persists on
+    the engine across rounds."""
     from adversarial_spec_tpu.engine import interleave
 
-    interleave.configure(
-        enabled=args.interleave, pipeline_depth=args.pipeline_depth
-    )
     interleave.reset_stats()
     return interleave
 
@@ -950,7 +931,7 @@ def handle_serve(args: argparse.Namespace) -> int:
     # One-time arming of the same knobs a critique round would arm.
     _configure_resilience(args)
     _configure_prefix_cache(args)
-    _configure_interleave(args)
+    _configure_interleave()
     _configure_speculative(args)
     _configure_kv_tier(args)
     _configure_weightres(args)
@@ -1009,7 +990,7 @@ def run_critique(args: argparse.Namespace) -> int:
     tracer = Tracer()
     breakers = _configure_resilience(args)
     prefix_cache = _configure_prefix_cache(args)
-    interleave = _configure_interleave(args)
+    interleave = _configure_interleave()
     spec_cfg = _configure_speculative(args)
     kv_tier = _configure_kv_tier(args)
     weightres = _configure_weightres(args)
@@ -1365,7 +1346,7 @@ def handle_export_tasks(args: argparse.Namespace) -> int:
     EXPORT_TASKS_PROMPT, low temperature, ``extract_tasks``, ``--json``.
     """
     _configure_prefix_cache(args)
-    _configure_interleave(args)
+    _configure_interleave()
     _configure_speculative(args)
     _configure_kv_tier(args)
     _configure_weightres(args)
@@ -1660,6 +1641,10 @@ def handle_save_profile(args: argparse.Namespace) -> int:
 def main(argv: list[str] | None = None) -> int:
     parser = create_parser()
     args, rest = parser.parse_known_args(argv)
+    if rest and args.action != "registry":
+        # Only ``registry`` takes positional operands of its own; an
+        # option this parser does not know is refused, not ignored.
+        parser.error("unrecognized arguments: " + " ".join(rest))
 
     try:
         if args.profile and args.action in ("critique", "export-tasks"):

@@ -1,23 +1,16 @@
-"""Fused-step / pipelined-drive-loop config and telemetry (host side).
+"""Drive-loop telemetry (host side).
 
-The ContinuousBatcher's drive loop (engine/scheduler.py) can run in two
-modes:
+The ContinuousBatcher's drive loop (engine/scheduler.py ``_drive``)
+issues, each iteration, ONE device program that advances the in-flight
+admission's prompt chunk AND every resident row's decode (or verify)
+step together (Sarathi-style piggybacked chunked prefill), and syncs
+the host only at admission handoff, the speculative counts fetch, the
+double buffer's depth bound, slot completion, and fault/timeout
+decision points.
 
-- **fused + pipelined** (default): each iteration issues ONE device
-  program that advances the in-flight admission's prompt chunk AND every
-  resident row's decode chunk together (Sarathi-style piggybacked
-  chunked prefill), and the host keeps up to two steps in flight —
-  inspecting step N-1's fetched ``active`` flags while step N runs, so
-  queue admission, prefix-cache lookups, page allocation, and result
-  collection all overlap device compute. The host syncs only at
-  admission handoff, slot completion, and fault/timeout decision points.
-- **legacy** (``--no-interleave`` / ``ADVSPEC_INTERLEAVE=0``): the
-  original serialized loop — prompt chunk, full host sync, decode chunk,
-  full host sync — kept as the escape hatch and the bench baseline.
-
-This module is the process-wide switchboard for that choice plus the
-telemetry both engines (TPU scheduler and the mock's deterministic CPU
-accounting) record into, à la ``resilience.faults`` / ``prefix_cache``:
+This module holds the process-wide counters both engines (TPU scheduler
+and the mock's deterministic CPU accounting) record into, à la
+``resilience.faults`` / ``prefix_cache``:
 
 - ``stalled_prefill_s``: admission prefill wall-clock the batch actually
   waited on (standalone chunks with nothing to overlap, and the
@@ -31,32 +24,16 @@ snapshot computes it), so ``stalled + overlapped == prefill`` holds
 exactly — the invariant tier-1 pins on the mock engine's deterministic
 numbers. Deliberately imports no jax: the mock engine uses it on CPU.
 
-The config/stats mechanics live in ``engine/procconfig.py`` (shared
-with ``spec``, ``prefix_cache``, ``kvtier``); this module keeps only
-what is interleave-specific — the knobs, the counters, and the
-depth clamp.
+The reset/as_dict mechanics live in ``engine/procconfig.py``
+(``StatsBase``, shared with ``spec``, ``prefix_cache``, ``kvtier``);
+there is nothing to configure here.
 """
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 from adversarial_spec_tpu.engine import procconfig
-
-# The drive loop keeps at most this many device steps in flight. Depth 1
-# degenerates to "fused but synchronous" (fetch each step right after
-# dispatch); depth 2 is the double buffer — deeper would only delay
-# fault/EOS detection by more chunks for no extra overlap.
-MAX_PIPELINE_DEPTH = 2
-
-
-@dataclass
-class InterleaveConfig:
-    """Process-wide knobs, set once per CLI round (or by tests)."""
-
-    enabled: bool = True
-    pipeline_depth: int = MAX_PIPELINE_DEPTH
 
 
 @dataclass
@@ -101,44 +78,13 @@ class InterleaveStats(procconfig.StatsBase):
         return out
 
 
-def _depth_from_env() -> int:
-    try:
-        d = int(os.environ.get("ADVSPEC_PIPELINE_DEPTH", MAX_PIPELINE_DEPTH))
-    except ValueError:
-        d = MAX_PIPELINE_DEPTH
-    return max(1, min(d, MAX_PIPELINE_DEPTH))
-
-
-def _clamp_depth(depth) -> int:
-    return max(1, min(int(depth), MAX_PIPELINE_DEPTH))
-
-
-_state = procconfig.ProcState(
-    InterleaveConfig(
-        enabled=os.environ.get("ADVSPEC_INTERLEAVE", "1") != "0",
-        pipeline_depth=_depth_from_env(),
-    ),
-    InterleaveStats(),
-    coerce={"pipeline_depth": _clamp_depth},
-)
-_config = _state.config
-stats = _state.stats
-
-
-def config() -> InterleaveConfig:
-    return _state.config
-
-
-def configure(
-    enabled: bool | None = None, pipeline_depth: int | None = None
-) -> InterleaveConfig:
-    return _state.configure(enabled=enabled, pipeline_depth=pipeline_depth)
+stats = InterleaveStats()
 
 
 def reset_stats() -> None:
-    _state.reset_stats()
+    stats.reset()
 
 
 def snapshot() -> dict:
-    """Stats + config, the ``perf.interleave`` payload."""
-    return _state.snapshot()
+    """The ``perf.interleave`` payload."""
+    return stats.snapshot()

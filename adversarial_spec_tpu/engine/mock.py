@@ -242,7 +242,6 @@ class MockEngine:
         CPU without a TPU in the loop."""
         from adversarial_spec_tpu.engine import interleave as interleave_mod
 
-        overlapped = overlapped and interleave_mod.config().enabled
         synth_s = n_tokens / 1024.0
         interleave_mod.stats.record_prefill_time(
             synth_s, overlapped=overlapped

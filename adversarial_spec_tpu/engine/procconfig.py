@@ -1,8 +1,9 @@
 """Process-wide config + stats switchboard (the ONE implementation).
 
-Four subsystems follow the same pattern (born in ``resilience.faults``,
-then re-implemented by hand in ``interleave``, ``spec``,
-``prefix_cache``, and now ``kvtier``): a module-level config dataclass
+Three subsystems follow the same pattern (born in ``resilience.faults``,
+then re-implemented by hand in ``spec``, ``prefix_cache``, and now
+``kvtier``; ``interleave`` has counters and no config, so it uses
+:class:`StatsBase` alone): a module-level config dataclass
 the CLI arms once per round, a module-level stats dataclass every engine
 instance records into, and four module functions — ``config()``,
 ``configure(...)``, ``reset_stats()``, ``snapshot()``. Before this
@@ -18,10 +19,10 @@ copy drift risk:
 :class:`StatsBase` carries reset/as_dict (subclasses override
 ``snapshot`` to add derived ratios); :class:`ProcState` carries the
 configure/snapshot mechanics with per-field coercers (the knob
-validation — γ's fail-at-the-knob check, the pipeline-depth clamp —
-stays with the owning module, passed in as a callable). The modules
-keep their explicit ``configure(...)`` signatures: discoverability and
-call-site typos still fail loudly.
+validation — γ's fail-at-the-knob check — stays with the owning
+module, passed in as a callable). The modules keep their explicit
+``configure(...)`` signatures: discoverability and call-site typos
+still fail loudly.
 
 Deliberately imports no jax: every ported module is used by the mock
 engine on CPU.

@@ -14,20 +14,22 @@ while sequences of different lengths join and leave it —
   different positions coexist in the same while_loop (per-row ``q_pos``
   drives page writes, RoPE positions, and window bounds).
 
-Drive loop (engine/interleave.py holds the config + telemetry): the
-default loop keeps up to two fused steps in flight and never calls a
-blanket ``jax.block_until_ready`` — the host applies step N-1's fetched
-``active`` flags (async device→host copy) while step N runs, overlapping
-queue admission, prefix-cache radix lookups, page allocation, and result
-collection with device compute. Sanctioned sync points, and ONLY these
-(enforced by graftlint's GL-SYNC rule, which catches implicit syncs —
-np.asarray/.item()/int()/truthiness on device values — as well as
-explicit block_until_ready; docs/static_analysis.md): admission handoff
-(``_finish_admission``), slot completion (token fetch), fault decisions,
-and timeout expiry. ``interleave=False`` (CLI ``--no-interleave``,
-``ADVSPEC_INTERLEAVE=0``) restores the legacy serialized loop — one
-prefill dispatch, full sync, one decode dispatch, full sync — as the
-escape hatch and bench baseline.
+Drive loop (``_drive``; engine/interleave.py holds its telemetry): one
+loop, whose iteration admits, dispatches one step (fused when an
+admission's prompt chunk can ride the residents' step) and retires it,
+and never calls a blanket ``jax.block_until_ready``. A speculative step
+(the default) is retired one step deep: the host fetches the verify
+step's per-row accepted counts before it can size the next one. A plain
+decode step goes into a double buffer: the host applies step N-1's
+fetched ``active`` flags (async device→host copy) while step N runs,
+overlapping queue admission, prefix-cache radix lookups, page
+allocation, and result collection with device compute. Sanctioned sync
+points, and ONLY these (enforced by graftlint's GL-SYNC rule, which
+catches implicit syncs — np.asarray/.item()/int()/truthiness on device
+values — as well as explicit block_until_ready;
+docs/static_analysis.md): admission handoff (``_finish_admission``),
+the speculative counts fetch, the double buffer's depth bound, slot
+completion (token fetch), fault decisions, and timeout expiry.
 
 Inactive-slot safety: physical page 0 is a reserved TRASH page no
 sequence owns. Allocator ids are shifted +1, the -1 "unmapped" sentinel
@@ -45,7 +47,7 @@ is final, budgeted against the caller's existing deadline. The chaos
 injector's ``scheduler_chunk`` and ``kv_alloc`` seams live here.
 
 Per-request watchdog (``SchedRequest.deadline_s``, docs/resilience.md
-"Durability and recovery"): both drive loops check per-request
+"Durability and recovery"): the drive loop checks per-request
 deadlines once per iteration — pure host clock math — and evict an
 over-deadline slot as ``FaultKind.TIMEOUT`` through the same shared
 surgery, partial text delivered to its stream consumer, co-residents
@@ -110,6 +112,10 @@ TRASH_PAGE = 0
 # PREFILL_CHUNK (1024): smaller chunks mean decode chunks slot in between
 # more often while a newcomer's prompt streams in.
 ADMISSION_CHUNK = 512
+# Steps the plain (non-speculative) branch of the drive loop keeps in
+# flight: 2 is the double buffer — deeper would only delay fault/EOS
+# detection by more chunks for no extra overlap.
+_PIPELINE_DEPTH = 2
 
 
 @dataclass
@@ -118,7 +124,7 @@ class SchedRequest:
     prompt_ids: list[int]
     max_new_tokens: int
     # Per-request watchdog deadline in seconds from submission (0 =
-    # none). Checked by the drive loops' watchdog
+    # none). Checked by the drive loop's watchdog
     # (``_expire_request_deadlines``) — pure host clock math; the
     # eviction itself rides the decode-fault surgery's EXISTING
     # sanctioned fetches, so the watchdog adds zero new sync points.
@@ -992,8 +998,6 @@ class ContinuousBatcher:
         chunk: int = 32,
         kv_dtype: str = "",
         prefix_cache: bool | None = None,
-        interleave: bool | None = None,
-        pipeline_depth: int | None = None,
         step_tokens: int = 0,
         speculative: bool | None = None,
         gamma: int | None = None,
@@ -1019,25 +1023,11 @@ class ContinuousBatcher:
         self.page_size = page_size
         self.chunk = chunk
         self.kv_dtype = kv_dtype
-        # Fused-step + pipelined drive loop (None = process config,
-        # engine/interleave.py). ``step_tokens`` is the Sarathi-style
-        # shared per-step token budget: a fused step's prompt chunk
-        # shrinks so chunk_len + n_live·chunk stays under it. 0 = auto
+        # ``step_tokens`` is the Sarathi-style shared per-step token
+        # budget: a fused step's prompt chunk shrinks so
+        # chunk_len + n_live·chunk stays under it. 0 = auto
         # (ADMISSION_CHUNK + max_batch·chunk — full-size prompt chunks
-        # even with every slot decoding, i.e. legacy chunk sizes).
-        cfg_il = interleave_mod.config()
-        self.interleave = (
-            cfg_il.enabled if interleave is None else bool(interleave)
-        )
-        self.pipeline_depth = max(
-            1,
-            min(
-                cfg_il.pipeline_depth
-                if pipeline_depth is None
-                else int(pipeline_depth),
-                interleave_mod.MAX_PIPELINE_DEPTH,
-            ),
-        )
+        # even with every slot decoding).
         self.step_tokens = step_tokens or (
             ADMISSION_CHUNK + max_batch * chunk
         )
@@ -1177,7 +1167,7 @@ class ContinuousBatcher:
         self.max_new = self._commit(jnp.zeros((B,), jnp.int32))
         self.active = self._commit(jnp.zeros((B,), bool))
         self.out_buf = self._commit(jnp.zeros((B, cap), jnp.int32))
-        # Host-trailing view of ``active``: the pipelined loop dispatches
+        # Host-trailing view of ``active``: the drive loop dispatches
         # against this snapshot (updated at admission handoff, fault
         # eviction, and step N-1's async fetch) instead of syncing on the
         # in-flight device state. A stale True only costs one no-op
@@ -1738,8 +1728,9 @@ class ContinuousBatcher:
 
     def _advance_admission(self) -> None:
         """One STANDALONE prefill chunk of the in-flight admission —
-        used when no resident row is decoding (nothing to fuse with) and
-        by the legacy serialized loop. The fused path dispatches through
+        used when no resident row is decoding (nothing to fuse with), for
+        an admission's final chunk and after a fused dispatch faulted.
+        The fused path dispatches through
         ``_dispatch_fused`` instead, where the chunk rides the decode
         program and its time lands in the OVERLAPPED bucket."""
         import time
@@ -2021,7 +2012,7 @@ class ContinuousBatcher:
         # Host bookkeeping only — no device sync: a slot without an
         # owner is never live (_finish_slot / fault eviction / timeout
         # all clear the trailing view before releasing the slot), so the
-        # pipelined loop can admit while a step is still in flight.
+        # drive loop can admit while a step is still in flight.
         for slot in range(self.B):
             if self._admission is not None or not self.queue:
                 return
@@ -2636,14 +2627,14 @@ class ContinuousBatcher:
             obs_mod.slo_check("round", req.span_id, service_s)
 
     def _collect(self, active_np: np.ndarray | None = None) -> None:
-        """Resolve finished slots. The legacy loop passes nothing (full
-        device sync); the pipelined loop passes its trailing host
-        snapshot so collection never blocks on the step in flight — a
-        row inactive at step N-1 is frozen (masked writes, no count
+        """Resolve finished slots. Timeout expiry passes nothing (full
+        device sync); the drive loop passes its trailing host snapshot
+        so collection never blocks on the step in flight — a row
+        inactive at step N-1 is frozen (masked writes, no count
         advance), so its tokens/counters read the same from any later
         state."""
         if active_np is None:
-            # graftlint: disable=GL-SYNC -- full fetch only on the legacy loop and timeout-expiry paths (the pipelined loop always passes its trailing host snapshot)
+            # graftlint: disable=GL-SYNC -- full fetch only on the timeout-expiry path (the drive loop always passes its trailing host snapshot)
             active_np = np.asarray(self.active)
         for slot in range(self.B):
             if self._slot_req[slot] is not None and not active_np[slot]:
@@ -2653,8 +2644,7 @@ class ContinuousBatcher:
 
     def run_all(self, timeout_s: float = 0.0) -> list[SchedResult]:
         """Drain the queue: admit, step (fused prefill+decode), collect,
-        repeat — pipelined two steps deep by default
-        (``interleave=False`` restores the legacy serialized loop).
+        repeat (``_drive``).
 
         ``timeout_s`` > 0 is a best-effort wall-clock budget (parity with
         generate()'s deadline, checked between chunks): on expiry, resident
@@ -2672,10 +2662,7 @@ class ContinuousBatcher:
             obs_mod.hot.batcher_distinct_prompts.inc(
                 len({tuple(r.prompt_ids) for r in self.queue})
             )
-        if self.interleave:
-            self._drive_pipelined(timeout_s)
-        else:
-            self._drive_legacy(timeout_s)
+        self._drive(timeout_s)
         if self.tiers is not None:
             # Drain-end settle: flush queued disk write-through entries
             # and resolve lazy demotion payloads — every async
@@ -2812,7 +2799,7 @@ class ContinuousBatcher:
                 req, self._watchdog_exc(req, "queued"), "watchdog"
             )
 
-    # -- pipelined drive loop ---------------------------------------------
+    # -- drive loop -------------------------------------------------------
 
     def _fused_chunk_len(
         self, remaining: int, n_live: int, width: int | None = None
@@ -3249,13 +3236,152 @@ class ContinuousBatcher:
             out_np = np.asarray(out_ref)
             self._stream_entry(emitted_np, out_np, live_slots)
 
-    def _drive_pipelined(self, timeout_s: float) -> None:
+    def _dispatch_step(self, live: list, alloc_len, adm, chunk_len: int):
+        """Enqueue one step for the rows in ``live``; ``adm`` is the
+        admission whose ``chunk_len`` prompt tokens ride it, or None.
+        Returns the verify step's counts ref and the (slot, generation)
+        pairs it was dispatched for — ``(None, ())`` on the plain
+        branch, whose flags travel through the double buffer."""
+        if self.speculative:
+            slots = tuple((s, self._slot_gen[s]) for s in live)
+            return self._dispatch_spec(alloc_len, adm, chunk_len), slots
+        if adm is not None:
+            self._dispatch_fused(adm, chunk_len)
+        else:
+            self._dispatch_decode()
+        return None, ()
+
+    def _retire_spec_step(self, spec_counts, spec_slots: tuple) -> tuple:
+        """Counts fetch → apply → stream for the verify step just
+        dispatched; returns the step's (depth, sync reason)."""
+        counts_np = None
+        with obs_mod.phase("drive.fetch"):
+            try:
+                # Start the copy before the blocking fetch — marginal,
+                # but free.
+                spec_counts.copy_to_host_async()
+            except Exception:
+                pass  # optional fast path only
+            try:
+                # The spec path's ONE sanctioned per-step sync: the host
+                # cannot size the next step's page coverage, roll
+                # rejected drafts back, or advance per-row flags without
+                # the accepted counts. A [5, B] int fetch — the γ+1
+                # tokens the step can emit amortize it.
+                # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and size the next step's coverage (the one sanctioned speculative sync)
+                counts_np = np.asarray(spec_counts)
+            except Exception as e:
+                # An async device fault surfaces at the fetch: same
+                # eviction surgery as dispatch-time.
+                self._handle_decode_fault(e)
+        interleave_mod.stats.record_sync()
+        obs_mod.record_sync("spec_counts")
+        if counts_np is not None:
+            with obs_mod.phase("drive.apply"):
+                self._apply_spec_counts(counts_np, spec_slots)
+            if self._stream_armed(s for s, _ in spec_slots):
+                # Stream delivery at the same sanctioned sync: the
+                # counts fetch above already blocked on this step, so
+                # the token fetch adds no new sync point (out_buf is the
+                # step's live output here — its donation happens at the
+                # NEXT dispatch). Emitted counts come from the host
+                # views _apply_spec_counts just advanced.
+                with obs_mod.phase("drive.stream"):
+                    # graftlint: disable=GL-SYNC -- stream token fetch at the sanctioned spec_counts sync (the counts fetch above already blocked on this step)
+                    out_np = np.asarray(self.out_buf)
+                    self._stream_entry(
+                        self._cur_len_np - self._row_len_np,
+                        out_np,
+                        spec_slots,
+                    )
+        return 1, "spec_counts"
+
+    def _retire_plain_step(self, inflight, entry: tuple) -> tuple:
+        """Append the dispatched step's ``entry`` to the double buffer
+        and retire the entries that are ready (or that the depth bound
+        forces); returns the step's (depth, sync reason)."""
+        inflight.append(entry)
+        depth = len(inflight)
+        step_sync = ""
+        try:
+            # Retire completed steps ADAPTIVELY: any entry whose flags
+            # already resolved (is_ready — free to fetch) applies now,
+            # so completions/slot-frees are seen with zero lag whenever
+            # the device keeps up (CPU: effectively every iteration).
+            # Only force a blocking fetch at the depth bound — that is
+            # the double buffer proper, and it only engages when the
+            # device is genuinely still executing step N-1.
+            while inflight and (
+                len(inflight) >= _PIPELINE_DEPTH
+                or self._entry_ready(inflight[0])
+            ):
+                if not self._entry_ready(inflight[0]):
+                    # The double buffer's one sanctioned blocking point,
+                    # made runtime-visible.
+                    obs_mod.record_sync("depth_fetch")
+                    step_sync = "depth_fetch"
+                self._fetch_entry(inflight.popleft())
+        except Exception as e:
+            # An async device fault surfaces at the fetch, one step
+            # late: same eviction surgery as dispatch-time.
+            inflight.clear()
+            self._handle_decode_fault(e)
+        return depth, step_sync
+
+    def _account_step(
+        self, dt, live, width, adm, chunk_len, depth, sync_reason
+    ) -> None:
+        """Book the wall clock ``dt`` of one dispatched-and-retired step
+        over the rows ``live`` at dispatch, ``width`` tokens a row;
+        ``adm`` is the admission whose ``chunk_len`` prompt tokens rode
+        it, or None.
+
+        The halves of a fused program aren't separately measurable
+        without a profiler, so ``dt`` splits by token share (prompt
+        tokens vs the decode/verify half's upper bound) — deterministic
+        given host state. The prefill part lands in the OVERLAPPED
+        bucket and in the riding admission; the rest is decode time,
+        split evenly over the live rows (slot sums reproduce
+        ``decode_time_s`` — the 'decode' trace span's wall)."""
+        fused = adm is not None
+        dec_dt = dt
+        if fused:
+            p = dt * (chunk_len / (chunk_len + len(live) * width))
+            self._record_prefill_time(p, overlapped=True)
+            adm.prefill_s += p
+            dec_dt = dt - p
+        self.decode_time_s += dec_dt
+        for s in live:
+            self._slot_decode_s[s] += dec_dt / len(live)
+        if obs_mod.config().enabled:
+            kind = "spec" if self.speculative else "decode"
+            if fused:
+                kind = "fused_spec" if self.speculative else "fused"
+            obs_mod.hot.step_wall.observe(dt)
+            obs_mod.emit(
+                obs_mod.StepEvent(
+                    kind=kind,
+                    n_live=len(live),
+                    admission_slot=adm.slot if fused else -1,
+                    prefill_tokens=chunk_len if fused else 0,
+                    decode_chunk=width,
+                    pipeline_depth=depth,
+                    sync_reason=sync_reason,
+                    # The riding admission's span; batch-level
+                    # otherwise (trace stamps from ambient).
+                    span_id=adm.req.span_id if fused else "",
+                    trace_id=adm.req.trace_id if fused else "",
+                )
+            )
+
+    def _drive(self, timeout_s: float) -> None:
         """Admit → dispatch (fused when an admission and live rows
-        coexist) → fetch the step before last → collect; the host's own
-        work (queue admission, radix lookups, page allocation,
-        collection) overlaps the step in flight. Host syncs happen only
-        at admission handoff, slot completion, fault decisions, and
-        timeout expiry — never as a blanket per-chunk barrier."""
+        coexist) → retire → collect; the host's own work (queue
+        admission, radix lookups, page allocation, collection) overlaps
+        the step in flight. Host syncs happen only at admission handoff,
+        slot completion, fault decisions, timeout expiry and the
+        speculative branch's counts fetch — never as a blanket
+        per-chunk barrier."""
         import time
         from collections import deque
 
@@ -3282,7 +3408,7 @@ class ContinuousBatcher:
                 adm = self._admission
                 live = [s for s in range(self.B) if self._active_np[s]]
                 t0 = time.monotonic()
-                fused_share = 0.0
+                rider = None  # the admission whose chunk rode the step
                 dispatched = False
                 # Speculation: each iteration's "decode work" becomes one
                 # γ-draft + verify program per live row, and the host MUST
@@ -3294,7 +3420,7 @@ class ContinuousBatcher:
                 # what buy that sync back.
                 spec = self.speculative
                 width = (self.gamma + 1) if spec else self.chunk
-                spec_counts = None
+                alloc_len = spec_counts = None
                 spec_slots: tuple = ()
                 # Fuse only the LEADING prefill chunks (strictly more work
                 # left after this chunk): the FINAL chunk runs standalone so
@@ -3333,25 +3459,10 @@ class ContinuousBatcher:
                         with obs_mod.trace_scope(
                             adm.req.trace_id, adm.req.span_id
                         ), obs_mod.phase("drive.dispatch"):
-                            if spec:
-                                spec_slots = tuple(
-                                    (s, self._slot_gen[s]) for s in live
-                                )
-                                spec_counts = self._dispatch_spec(
-                                    alloc_len, adm, chunk_len
-                                )
-                            else:
-                                self._dispatch_fused(adm, chunk_len)
-                        # Telemetry attribution for the fused program: the
-                        # halves aren't separately measurable without a
-                        # profiler, so split this iteration's wall clock by
-                        # token share (prompt tokens vs the decode/verify
-                        # half's upper bound) — deterministic given host
-                        # state.
-                        fused_share = chunk_len / (
-                            chunk_len + len(live) * width
-                        )
-                        dispatched = True
+                            spec_counts, spec_slots = self._dispatch_step(
+                                live, alloc_len, adm, chunk_len
+                            )
+                        rider, dispatched = adm, True
                     except Exception as e:
                         # A dispatch-time fault (chaos seam, trace error) is
                         # treated as decode-side surgery: the admission's
@@ -3363,7 +3474,6 @@ class ContinuousBatcher:
                         # admission there instead of evicting another
                         # innocent resident every iteration.
                         adm.fuse_deferred = True
-                        spec_counts = None
                         self._handle_decode_fault(e)
                 else:
                     if adm is not None:
@@ -3398,342 +3508,54 @@ class ContinuousBatcher:
                     if live:
                         try:
                             with obs_mod.phase("drive.dispatch"):
-                                if spec:
-                                    spec_slots = tuple(
-                                        (s, self._slot_gen[s]) for s in live
-                                    )
-                                    spec_counts = self._dispatch_spec(
-                                        alloc_len, None, 0
-                                    )
-                                else:
-                                    self._dispatch_decode()
+                                spec_counts, spec_slots = self._dispatch_step(
+                                    live, alloc_len, None, 0
+                                )
                             dispatched = True
                         except Exception as e:
-                            spec_counts = None
                             self._handle_decode_fault(e)
-                if dispatched and spec:
-                    depth = 1
-                    step_sync = "spec_counts"
-                    counts_np = None
-                    if spec_counts is not None:
-                        with obs_mod.phase("drive.fetch"):
-                            try:
-                                # Start the copy before the blocking fetch —
-                                # marginal, but free.
-                                spec_counts.copy_to_host_async()
-                            except Exception:
-                                pass  # optional fast path only
-                            try:
-                                # The spec path's ONE sanctioned per-step sync:
-                                # the host cannot size the next step's page
-                                # coverage, roll rejected drafts back, or
-                                # advance per-row flags without the accepted
-                                # counts. A [5, B] int fetch — the γ+1 tokens
-                                # the step can emit amortize it.
-                                # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and size the next step's coverage (the one sanctioned speculative sync)
-                                counts_np = np.asarray(spec_counts)
-                            except Exception as e:
-                                # An async device fault surfaces at the fetch:
-                                # same eviction surgery as dispatch-time.
-                                self._handle_decode_fault(e)
-                        interleave_mod.stats.record_sync()
-                        obs_mod.record_sync("spec_counts")
-                        if counts_np is not None:
-                            with obs_mod.phase("drive.apply"):
-                                self._apply_spec_counts(counts_np, spec_slots)
-                            if self._stream_armed(
-                                s for s, _ in spec_slots
-                            ):
-                                # Stream delivery at the spec path's ONE
-                                # sanctioned per-step sync: the counts
-                                # fetch above already blocked on this
-                                # step, so the token fetch adds no new
-                                # sync point (out_buf is the step's live
-                                # output here — its donation happens at
-                                # the NEXT dispatch). Emitted counts come
-                                # from the host views _apply_spec_counts
-                                # just advanced.
-                                with obs_mod.phase("drive.stream"):
-                                    # graftlint: disable=GL-SYNC -- stream token fetch at the sanctioned spec_counts sync (the counts fetch above already blocked on this step)
-                                    out_np = np.asarray(self.out_buf)
-                                    self._stream_entry(
-                                        self._cur_len_np - self._row_len_np,
-                                        out_np,
-                                        spec_slots,
-                                    )
-                    dt = time.monotonic() - t0
-                    if fused_share > 0.0:
-                        p = dt * fused_share
-                        self._record_prefill_time(p, overlapped=True)
-                        adm.prefill_s += p
-                        self.decode_time_s += dt - p
-                        spec_dt = dt - p
+                if dispatched:
+                    if spec:
+                        depth, step_sync = self._retire_spec_step(
+                            spec_counts, spec_slots
+                        )
                     else:
-                        self.decode_time_s += dt
-                        spec_dt = dt
-                    if live:
-                        # Per-request decode attribution: this step's decode
-                        # wall splits evenly over the rows live at dispatch
-                        # (slot sums reproduce decode_time_s — the 'decode'
-                        # trace span's wall).
-                        dec_share = spec_dt / len(live)
-                        for s in live:
-                            self._slot_decode_s[s] += dec_share
-                    if obs_mod.config().enabled:
-                        obs_mod.hot.step_wall.observe(dt)
-                        obs_mod.emit(
-                            obs_mod.StepEvent(
-                                kind=(
-                                    "fused_spec"
-                                    if fused_share > 0.0
-                                    else "spec"
-                                ),
-                                n_live=len(live),
-                                admission_slot=(
-                                    adm.slot if fused_share > 0.0 else -1
-                                ),
-                                prefill_tokens=(
-                                    chunk_len if fused_share > 0.0 else 0
-                                ),
-                                decode_chunk=width,
-                                pipeline_depth=depth,
-                                sync_reason=step_sync,
-                                # The riding admission's span; batch-level
-                                # otherwise (trace stamps from ambient).
-                                span_id=(
-                                    adm.req.span_id
-                                    if fused_share > 0.0
-                                    else ""
-                                ),
-                                trace_id=(
-                                    adm.req.trace_id
-                                    if fused_share > 0.0
-                                    else ""
-                                ),
+                        # Streaming consumers ride the double buffer: the
+                        # entry carries the step's emitted counts plus an
+                        # out_buf SNAPSHOT (jnp.copy — out_buf itself is
+                        # donated to the next dispatch, so a raw ref would
+                        # be deleted before the depth-bound fetch; the
+                        # copy is a device-side op that overlaps compute
+                        # and only exists while a consumer is attached).
+                        streaming = self._stream_armed(live)
+                        with obs_mod.phase("drive.dispatch"):
+                            entry = (
+                                self.active,
+                                self.n_emitted if streaming else None,
+                                jnp.copy(self.out_buf) if streaming else None,
+                                tuple((s, self._slot_gen[s]) for s in live),
                             )
+                            for ref in entry[:3]:
+                                if ref is None:
+                                    continue
+                                try:
+                                    # Start the device→host copy now; the
+                                    # fetch one iteration later should find
+                                    # it resolved.
+                                    ref.copy_to_host_async()
+                                except Exception:
+                                    pass  # optional fast path only
+                        depth, step_sync = self._retire_plain_step(
+                            inflight, entry
                         )
-                elif dispatched:
-                    # Streaming consumers ride the double buffer: the entry
-                    # carries the step's emitted counts plus an out_buf
-                    # SNAPSHOT (jnp.copy — out_buf itself is donated to the
-                    # next dispatch, so a raw ref would be deleted before
-                    # the depth-bound fetch; the copy is a device-side op
-                    # that overlaps compute and only exists while a
-                    # consumer is attached).
-                    streaming = self._stream_armed(live)
-                    with obs_mod.phase("drive.dispatch"):
-                        entry = (
-                            self.active,
-                            self.n_emitted if streaming else None,
-                            jnp.copy(self.out_buf) if streaming else None,
-                            tuple((s, self._slot_gen[s]) for s in live),
-                        )
-                        for ref in entry[:3]:
-                            if ref is None:
-                                continue
-                            try:
-                                # Start the device→host copy now; the fetch
-                                # one iteration later should find it resolved.
-                                ref.copy_to_host_async()
-                            except Exception:
-                                pass  # optional fast path only
-                    inflight.append(entry)
-                    depth = len(inflight)
-                    step_sync = ""
-                    try:
-                        # Retire completed steps ADAPTIVELY: any entry whose
-                        # flags already resolved (is_ready — free to fetch)
-                        # applies now, so completions/slot-frees are seen
-                        # with zero lag whenever the device keeps up (CPU:
-                        # effectively every iteration). Only force a
-                        # blocking fetch at the depth bound — that is the
-                        # double buffer proper, and it only engages when the
-                        # device is genuinely still executing step N-1.
-                        while inflight and (
-                            len(inflight) >= self.pipeline_depth
-                            or self._entry_ready(inflight[0])
-                        ):
-                            if not self._entry_ready(inflight[0]):
-                                # Depth bound forced a genuinely blocking
-                                # fetch — the double buffer's one sanctioned
-                                # blocking point, made runtime-visible.
-                                obs_mod.record_sync("depth_fetch")
-                                step_sync = "depth_fetch"
-                            self._fetch_entry(inflight.popleft())
-                    except Exception as e:
-                        # An async device fault surfaces at the fetch, one
-                        # step late: same eviction surgery as dispatch-time.
-                        inflight.clear()
-                        self._handle_decode_fault(e)
-                    dt = time.monotonic() - t0
-                    if fused_share > 0.0:
-                        p = dt * fused_share
-                        self._record_prefill_time(p, overlapped=True)
-                        adm.prefill_s += p
-                        self.decode_time_s += dt - p
-                        dec_dt = dt - p
-                    else:
-                        self.decode_time_s += dt
-                        dec_dt = dt
-                    if live:
-                        # Per-request decode attribution (see the spec
-                        # branch): even split over rows live at dispatch.
-                        dec_share = dec_dt / len(live)
-                        for s in live:
-                            self._slot_decode_s[s] += dec_share
-                    if obs_mod.config().enabled:
-                        obs_mod.hot.step_wall.observe(dt)
-                        obs_mod.emit(
-                            obs_mod.StepEvent(
-                                kind=(
-                                    "fused" if fused_share > 0.0 else "decode"
-                                ),
-                                n_live=len(live),
-                                admission_slot=(
-                                    adm.slot if fused_share > 0.0 else -1
-                                ),
-                                prefill_tokens=(
-                                    chunk_len if fused_share > 0.0 else 0
-                                ),
-                                decode_chunk=self.chunk,
-                                pipeline_depth=depth,
-                                sync_reason=step_sync,
-                                span_id=(
-                                    adm.req.span_id
-                                    if fused_share > 0.0
-                                    else ""
-                                ),
-                                trace_id=(
-                                    adm.req.trace_id
-                                    if fused_share > 0.0
-                                    else ""
-                                ),
-                            )
-                        )
+                    self._account_step(
+                        time.monotonic() - t0,
+                        live,
+                        width,
+                        rider,
+                        chunk_len,
+                        depth,
+                        step_sync,
+                    )
                 with obs_mod.phase("drive.collect"):
                     self._collect(self._active_np)
-
-    # -- legacy serialized loop -------------------------------------------
-
-    def _drive_legacy(self, timeout_s: float) -> None:
-        """The pre-fusion loop (escape hatch + bench baseline): one
-        prompt-chunk dispatch, full host sync, one decode dispatch, full
-        host sync, every iteration."""
-        import time
-
-        deadline = time.monotonic() + timeout_s if timeout_s > 0 else None
-        while self._has_work():
-            if deadline is not None and time.monotonic() > deadline:
-                self._expire_timeout()
-                break
-            # Per-request watchdog (same placement as the pipelined
-            # loop): this loop full-syncs every chunk anyway.
-            self._expire_request_deadlines()
-            self._admit()
-            if self._admission is not None:
-                # One prompt chunk, then fall through to a decode chunk —
-                # resident rows keep emitting while the newcomer prefills.
-                adm = self._admission
-                try:
-                    with obs_mod.trace_scope(
-                        adm.req.trace_id, adm.req.span_id
-                    ):
-                        self._advance_admission()
-                except Exception as e:
-                    self._abort_admission(e)
-            if bool(self.active.any()):
-                t_dec = time.monotonic()
-                if self.speculative:
-                    # Legacy + speculation: fully serialized draft/
-                    # verify steps — dispatch one γ-wide program, block
-                    # on the counts, roll rejected draft pages back.
-                    # Same per-row desync bookkeeping as the pipelined
-                    # path, without the async fetch machinery.
-                    self._active_np[:] = np.asarray(self.active)
-                    live = [
-                        s for s in range(self.B) if self._active_np[s]
-                    ]
-                    alloc_len = self._prepare_spec_step(live)
-                    width = self.gamma + 1
-                    if live:
-                        live_slots = tuple(
-                            (s, self._slot_gen[s]) for s in live
-                        )
-                        try:
-                            counts = self._dispatch_spec(
-                                alloc_len, None, 0
-                            )
-                            counts_np = np.asarray(counts)
-                            self._apply_spec_counts(
-                                counts_np, live_slots
-                            )
-                            if self._stream_armed(live):
-                                # Stream + cancel at the legacy spec
-                                # step's full sync (this whole loop is
-                                # serialized by design).
-                                self._stream_entry(
-                                    self._cur_len_np
-                                    - self._row_len_np,
-                                    np.asarray(self.out_buf),
-                                    live_slots,
-                                )
-                        except Exception as e:
-                            self._handle_decode_fault(e)
-                        finally:
-                            dt = time.monotonic() - t_dec
-                            self.decode_time_s += dt
-                            if live:
-                                dec_share = dt / len(live)
-                                for s in live:
-                                    self._slot_decode_s[s] += dec_share
-                            if obs_mod.config().enabled:
-                                obs_mod.record_sync("legacy_step")
-                                obs_mod.hot.step_wall.observe(dt)
-                                obs_mod.emit(
-                                    obs_mod.StepEvent(
-                                        kind="spec",
-                                        n_live=len(live),
-                                        decode_chunk=width,
-                                        sync_reason="legacy_step",
-                                    )
-                                )
-                else:
-                    live = [
-                        s
-                        for s in range(self.B)
-                        if self._slot_req[s] is not None
-                    ]
-                    try:
-                        self._dispatch_decode()
-                        jax.block_until_ready(self.active)
-                    except Exception as e:
-                        self._handle_decode_fault(e)
-                    finally:
-                        dt = time.monotonic() - t_dec
-                        self.decode_time_s += dt
-                        if live:
-                            dec_share = dt / len(live)
-                            for s in live:
-                                self._slot_decode_s[s] += dec_share
-                        if obs_mod.config().enabled:
-                            obs_mod.record_sync("legacy_step")
-                            obs_mod.hot.step_wall.observe(dt)
-                            obs_mod.emit(
-                                obs_mod.StepEvent(
-                                    kind="decode",
-                                    n_live=int(sum(self._active_np)),
-                                    decode_chunk=self.chunk,
-                                    sync_reason="legacy_step",
-                                )
-                            )
-                    if self._stream_armed(live):
-                        # Stream + cancel at the legacy step's full
-                        # sync (this loop blocks every chunk anyway).
-                        self._active_np[:] = np.asarray(self.active)
-                        self._stream_entry(
-                            np.asarray(self.n_emitted),
-                            np.asarray(self.out_buf),
-                            tuple((s, self._slot_gen[s]) for s in live),
-                        )
-            self._collect()
-        self._active_np[:] = np.asarray(self.active)

@@ -37,7 +37,6 @@ import numpy as np
 
 from adversarial_spec_tpu import obs as obs_mod
 from adversarial_spec_tpu.debate.usage import Usage
-from adversarial_spec_tpu.engine import interleave as interleave_mod
 from adversarial_spec_tpu.engine import kvtier as kvtier_mod
 from adversarial_spec_tpu.engine import prefix_cache as prefix_mod
 from adversarial_spec_tpu.engine import registry as registry_mod
@@ -1055,15 +1054,10 @@ class TpuEngine:
             lm.spec.kv_dtype,
             prefix_mod.config().enabled,
             prefix_mod.config().max_pages,
-            # The batcher snapshots these at construction: a persisted
-            # batcher must rebuild when the operator flips the drive
-            # loop (--no-interleave) or the pipeline depth per round.
-            interleave_mod.config().enabled,
-            interleave_mod.config().pipeline_depth,
-            # Tiered-KV knobs likewise: flipping --no-kv-tier, the host
-            # budget, or the store dir between rounds must rebuild the
-            # tiers (and re-fingerprint the store) rather than keep
-            # serving under the old config.
+            # The batcher snapshots the tiered-KV knobs at construction:
+            # flipping --no-kv-tier, the host budget, or the store dir
+            # between rounds must rebuild the tiers (and re-fingerprint
+            # the store) rather than keep serving under the old config.
             kvtier_mod.config().enabled,
             kvtier_mod.config().host_mb,
             kvtier_mod.config().store_dir,

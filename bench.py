@@ -27,11 +27,6 @@ Modes:
                                   # of a growing spec through the
                                   # continuous batcher, cache on vs off;
                                   # also writes BENCH_prefix.json
-  python bench.py --mode interleave
-                                  # fused+pipelined vs legacy scheduler
-                                  # drive loop on a mixed admit-while-
-                                  # decoding workload; also writes
-                                  # BENCH_interleave.json
   python bench.py --mode obs-overhead
                                   # flight recorder + metrics registry
                                   # emit-path cost over the mock mixed
@@ -117,9 +112,6 @@ Modes:
                                   # binary-searched to the SLO breach
                                   # per knob arm (replicas 1 vs 3);
                                   # writes BENCH_capacity.json
-  --no-interleave                 # escape hatch for any batcher-driven
-                                  # mode: run the legacy serialized loop
-                                  # (equivalent to ADVSPEC_INTERLEAVE=0)
   --no-speculative                # escape hatch: plain token-at-a-time
                                   # decode (ADVSPEC_SPECULATIVE=0)
 """
@@ -475,170 +467,6 @@ def _run_prefix(platform: str) -> dict:
         "decode_tokens": on_dec,
     }
     return payload
-
-
-def _run_interleave(platform: str) -> dict:
-    """Fused-step + pipelined drive loop vs the legacy serialized loop,
-    on a mixed admit-while-decoding workload: more requests than slots,
-    alternating multi-chunk and short prompts, so newcomers' prompt
-    chunks must either ride residents' decode programs (fused) or stall
-    them (legacy). Greedy, prefix cache off (isolates the loop itself),
-    pool sized to the workload (the paged gather reads the WHOLE pool
-    every step on the CPU reference path, so an oversized pool would
-    drown the loop overhead this bench isolates). Each mode warms every
-    compiled program (the fused program is distinct) with one untimed
-    drain, then runs several timed drains; the reported wall is the MIN
-    across repeats — the workload is deterministic, so min is the
-    noise-robust statistic on a shared machine. Greedy tokens must be
-    identical across modes."""
-    from adversarial_spec_tpu.utils.jaxenv import configure_jax
-
-    configure_jax()
-    import random
-
-    import jax
-    import jax.numpy as jnp
-
-    from adversarial_spec_tpu.engine import interleave as interleave_mod
-    from adversarial_spec_tpu.engine.scheduler import (
-        ContinuousBatcher,
-        SchedRequest,
-    )
-    from adversarial_spec_tpu.models import transformer as T
-    from adversarial_spec_tpu.models.config import get_config
-
-    size = "1b" if platform != "cpu" else "tiny"
-    cfg = get_config("llama", size)
-    params = T.init_params(
-        jax.random.key(0),
-        cfg,
-        dtype=jnp.bfloat16 if platform != "cpu" else jnp.float32,
-    )
-    n_req, n_slots = 6, 2
-    # Long prompts get SHORT budgets and short prompts LONG ones, so a
-    # long newcomer's multi-chunk prefill always has a long-running
-    # resident to ride (equal budgets would let co-residents finish in
-    # lockstep and admissions land in an idle batch — no overlap to
-    # measure).
-    # Long prompts span several admission chunks (the leading ones ride
-    # fused steps; the final chunk admits standalone by design); small
-    # decode chunks keep per-program compute low enough that the loop
-    # overhead this bench isolates is visible on CPU at all.
-    long_len, short_len, long_new, short_new, chunk = (
-        (2900, 96, 16, 96, 16)
-        if platform != "cpu"
-        else (1400, 40, 8, 72, 4)
-    )
-    rng = random.Random(7)
-    prompts = [
-        [
-            rng.randrange(3, cfg.vocab_size)
-            for _ in range(long_len if i % 2 == 0 else short_len)
-        ]
-        for i in range(n_req)
-    ]
-    budgets = [long_new if i % 2 == 0 else short_new for i in range(n_req)]
-    max_new = max(long_new, short_new)
-
-    n_repeats = int(os.environ.get("BENCH_INTERLEAVE_REPEATS", "5"))
-
-    def mk(enabled: bool) -> ContinuousBatcher:
-        return ContinuousBatcher(
-            params,
-            cfg,
-            max_batch=n_slots,
-            max_new_cap=max_new,
-            page_size=64,
-            capacity_tokens=4096,
-            greedy=True,
-            chunk=chunk,
-            prefix_cache=False,
-            interleave=enabled,
-        )
-
-    def drain(b):
-        for i, p in enumerate(prompts):
-            b.submit(
-                SchedRequest(
-                    req_id=i,
-                    prompt_ids=list(p),
-                    max_new_tokens=budgets[i],
-                )
-            )
-        t0 = time.monotonic()
-        results = b.run_all()
-        return time.monotonic() - t0, results
-
-    # Warm BOTH modes' compiled programs, capturing tokens for the
-    # parity check, then alternate timed drains (mode A, mode B, A, B,
-    # …) so machine drift hits both modes equally.
-    batchers = {False: mk(False), True: mk(True)}
-    toks = {}
-    for enabled, b in batchers.items():
-        _, results = drain(b)
-        toks[enabled] = [r.tokens.tolist() for r in results]
-        # Telemetry counters are lifetime sums and the warmup pass is
-        # compile-dominated; reset so the report accounts timed passes.
-        b.stalled_prefill_s = b.overlapped_prefill_s = 0.0
-        b.decode_time_s = 0.0
-    # Process-wide interleave stats are accumulated PER MODE (reset
-    # around every drain): a single aggregate would blend the legacy
-    # drains' all-stalled accounting into the fused mode's split and
-    # misrepresent the loop being measured.
-    mode_stats: dict[bool, dict] = {
-        False: {}, True: {},
-    }
-
-    def _accumulate(into: dict) -> None:
-        for k, v in interleave_mod.stats.snapshot().items():
-            into[k] = round(into.get(k, 0) + v, 6)
-
-    walls: dict[bool, list] = {False: [], True: []}
-    for rep in range(n_repeats):
-        # Alternate which mode goes first: under monotonically drifting
-        # machine load, a fixed order would systematically penalize the
-        # second mode of every pair.
-        order = (False, True) if rep % 2 == 0 else (True, False)
-        for enabled in order:
-            interleave_mod.reset_stats()
-            w, _ = drain(batchers[enabled])
-            walls[enabled].append(round(w, 3))
-            _accumulate(mode_stats[enabled])
-
-    def split(b):
-        return {
-            "stalled_prefill_s": round(b.stalled_prefill_s, 4),
-            "overlapped_prefill_s": round(b.overlapped_prefill_s, 4),
-            "decode_time_s": round(b.decode_time_s, 4),
-        }
-
-    legacy_wall, fused_wall = min(walls[False]), min(walls[True])
-    return {
-        "metric": "interleave_wall_speedup",
-        "value": round(legacy_wall / fused_wall, 4) if fused_wall else None,
-        "unit": "legacy wall / fused+pipelined wall (>1 = faster)",
-        "vs_baseline": None,  # no published interleave baseline
-        "platform": platform,
-        "model": f"llama-{size}",
-        "requests": n_req,
-        "slots": n_slots,
-        "prompt_tokens_long": long_len,
-        "prompt_tokens_short": short_len,
-        "decode_tokens_long_prompt": long_new,
-        "decode_tokens_short_prompt": short_new,
-        "chunk": chunk,
-        "repeats": n_repeats,
-        "wall_s_fused": fused_wall,
-        "wall_s_legacy": legacy_wall,
-        "walls_fused": walls[True],
-        "walls_legacy": walls[False],
-        "tokens_identical": toks[True] == toks[False],
-        "fused": split(batchers[True]),
-        "legacy": split(batchers[False]),
-        "interleave_fused": mode_stats[True],
-        "interleave_legacy": mode_stats[False],
-        "escape_hatch": "--no-interleave / ADVSPEC_INTERLEAVE=0",
-    }
 
 
 def _run_spec(platform: str) -> dict:
@@ -2825,14 +2653,6 @@ def _run_obs_overhead(platform: str) -> dict:
 
 def main() -> int:
     args = sys.argv[1:]
-    if "--no-interleave" in args:
-        # Escape hatch: every batcher-driven mode runs the legacy
-        # serialized loop. Env so subprocess drills inherit it.
-        os.environ["ADVSPEC_INTERLEAVE"] = "0"
-        from adversarial_spec_tpu.engine import interleave as _il
-
-        _il.configure(enabled=False)
-
     def _mode(name: str) -> bool:
         return f"--{name}" in args or (
             "--mode" in args
@@ -2840,7 +2660,6 @@ def main() -> int:
         )
 
     prefix_mode = _mode("prefix")
-    interleave_mode = _mode("interleave")
     obs_mode = _mode("obs-overhead")
     spec_mode = _mode("spec")
     tier_mode = _mode("tier")
@@ -2854,8 +2673,7 @@ def main() -> int:
     kernels_mode = _mode("kernels")
     capacity_mode = _mode("capacity")
     if "--no-speculative" in args:
-        # Escape hatch mirror of --no-interleave: batcher-driven modes
-        # decode token-at-a-time.
+        # Escape hatch: batcher-driven modes decode token-at-a-time.
         os.environ["ADVSPEC_SPECULATIVE"] = "0"
         from adversarial_spec_tpu.engine import spec as _sp
 
@@ -2866,8 +2684,6 @@ def main() -> int:
         runner = _run_round_loop
     elif prefix_mode:
         runner = _run_prefix
-    elif interleave_mode:
-        runner = _run_interleave
     elif obs_mode:
         runner = _run_obs_overhead
     elif spec_mode:
@@ -2926,7 +2742,6 @@ def main() -> int:
         )
     if (
         prefix_mode
-        or interleave_mode
         or obs_mode
         or spec_mode
         or tier_mode
@@ -2945,8 +2760,6 @@ def main() -> int:
         name = (
             "BENCH_prefix.json"
             if prefix_mode
-            else "BENCH_interleave.json"
-            if interleave_mode
             else "BENCH_obs.json"
             if obs_mode
             else "BENCH_spec.json"

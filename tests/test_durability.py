@@ -616,13 +616,15 @@ class TestWatchdogDeadline:
         kw.setdefault("chunk", 4)
         return ContinuousBatcher(params, cfg, **kw)
 
-    @pytest.mark.parametrize("interleave", [True, False])
+    @pytest.mark.parametrize("speculative", [True, False])
     def test_deadline_evicts_only_the_expired_slot(
-        self, tiny_model, interleave
+        self, tiny_model, speculative
     ):
+        """Both retirements of the drive loop: the verify step's counts
+        fetch and the plain step's double buffer."""
         from adversarial_spec_tpu.engine.scheduler import SchedRequest
 
-        b = self._batcher(tiny_model, interleave=interleave)
+        b = self._batcher(tiny_model, speculative=speculative)
         total_pages = b.allocator.free_pages
         deliveries = []
         b.submit(

@@ -1,16 +1,16 @@
-"""Fused-step / pipelined-drive-loop telemetry tests (engine/interleave.py).
+"""Drive-loop telemetry tests (engine/interleave.py).
 
-The device-side behavior (fused dispatches, token parity, legacy escape
-hatch) is pinned in tests/test_scheduler.py; this file covers the
-process-wide accounting contract:
+The device-side behavior (fused dispatches, token parity) is pinned in
+tests/test_scheduler.py; this file covers the process-wide accounting
+contract:
 
 - ``stalled_prefill_s + overlapped_prefill_s == prefill_time_s`` holds
   EXACTLY (the mock engine's synthetic seconds are tokens/1024 — exact
   binary fractions — so the pin is ``==``, not approx);
 - the mock engine attributes request 0 of a chat batch as stalled and
   later requests as overlapped, deterministically on CPU;
-- the CLI's ``--json`` carries the ``perf.interleave`` block and the
-  ``--no-interleave`` escape hatch zeroes the overlapped bucket.
+- the CLI's ``--json`` carries the ``perf.interleave`` block, and the
+  drive loop's deleted switches are argparse errors.
 """
 
 import io
@@ -40,10 +40,8 @@ def _spec_off_module(monkeypatch):
 
 @pytest.fixture(autouse=True)
 def _fresh_interleave_state():
-    interleave_mod.configure(enabled=True, pipeline_depth=2)
     interleave_mod.reset_stats()
     yield
-    interleave_mod.configure(enabled=True, pipeline_depth=2)
     interleave_mod.reset_stats()
 
 
@@ -59,11 +57,6 @@ class TestInterleaveModule:
         assert snap["prefill_time_s"] == (
             snap["stalled_prefill_s"] + snap["overlapped_prefill_s"]
         )
-
-    def test_configure_clamps_depth(self):
-        assert interleave_mod.configure(pipeline_depth=9).pipeline_depth == 2
-        assert interleave_mod.configure(pipeline_depth=0).pipeline_depth == 1
-        assert interleave_mod.configure(pipeline_depth=2).pipeline_depth == 2
 
     def test_reset_zeroes_in_place(self):
         s = interleave_mod.stats
@@ -105,16 +98,6 @@ class TestMockEngineOverlapAccounting:
             snap["stalled_prefill_s"] + snap["overlapped_prefill_s"]
         )
 
-    def test_disabled_loop_accounts_everything_stalled(self):
-        interleave_mod.configure(enabled=False)
-        self._chat(3)
-        snap = interleave_mod.snapshot()
-        assert snap["enabled"] is False
-        assert snap["overlapped_prefill_s"] == 0.0
-        assert snap["fused_steps"] == 0
-        assert snap["prefill_steps"] == 3
-        assert snap["stalled_prefill_s"] == snap["prefill_time_s"] > 0
-
     def test_single_request_has_nothing_to_overlap(self):
         self._chat(1)
         snap = interleave_mod.snapshot()
@@ -146,8 +129,8 @@ class TestCliInterleaveFlags:
         )
         assert code == 0
         snap = data["perf"]["interleave"]
-        assert snap["enabled"] is True
-        assert snap["pipeline_depth"] == 2
+        # Counters only: the loop has no switch to report.
+        assert "enabled" not in snap and "pipeline_depth" not in snap
         assert snap["prefill_steps"] == 1
         assert snap["fused_steps"] == 1
         assert snap["overlapped_prefill_s"] > 0
@@ -155,28 +138,20 @@ class TestCliInterleaveFlags:
             snap["prefill_time_s"]
         )
 
-    def test_no_interleave_escape_hatch(self, monkeypatch, capsys):
-        code, data, _ = self._run(
-            [
-                "critique", "--models", "mock://critic,mock://agree",
-                "--json", "--no-interleave",
-            ],
-            monkeypatch, capsys,
-        )
-        assert code == 0
-        snap = data["perf"]["interleave"]
-        assert snap["enabled"] is False
-        assert snap["fused_steps"] == 0
-        assert snap["overlapped_prefill_s"] == 0.0
-        assert snap["stalled_prefill_s"] == snap["prefill_time_s"] > 0
+    @pytest.mark.parametrize(
+        "flag", [["--no-interleave"], ["--pipeline-depth", "1"]]
+    )
+    def test_drive_loop_flags_are_gone(self, monkeypatch, capsys, flag):
+        """One drive loop: its two switches are argparse errors."""
+        from adversarial_spec_tpu import cli
 
-    def test_pipeline_depth_flag_reported(self, monkeypatch, capsys):
-        code, data, _ = self._run(
-            [
-                "critique", "--models", "mock://agree", "--json",
-                "--pipeline-depth", "1",
-            ],
-            monkeypatch, capsys,
-        )
-        assert code == 0
-        assert data["perf"]["interleave"]["pipeline_depth"] == 1
+        monkeypatch.setattr("sys.stdin", io.StringIO(self.SPEC))
+        with pytest.raises(SystemExit) as e:
+            cli.main(
+                [
+                    "critique", "--models", "mock://critic,mock://agree",
+                    "--json", *flag,
+                ]
+            )
+        assert e.value.code == 2
+        assert "unrecognized arguments" in capsys.readouterr().err

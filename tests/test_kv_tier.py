@@ -83,8 +83,9 @@ class TestProcConfig:
         from adversarial_spec_tpu.engine import interleave, spec
 
         il = interleave.snapshot()
-        assert {"fused_steps", "prefill_time_s", "enabled",
-                "pipeline_depth"} <= set(il)
+        assert {"fused_steps", "prefill_time_s", "sync_points"} <= set(il)
+        # Counters only: the drive loop has no switch to report.
+        assert not {"enabled", "pipeline_depth"} & set(il)
         assert il["prefill_time_s"] == (
             il["stalled_prefill_s"] + il["overlapped_prefill_s"]
         )

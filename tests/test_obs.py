@@ -440,15 +440,44 @@ class TestSchedulerInstrumentation:
         # Pow2 chunking bounds the shapes: nothing unexpected.
         assert snap["unexpected_recompiles"] == 0
 
-    def test_legacy_loop_emits_same_schema(self, tiny_model):
+    @pytest.mark.parametrize("speculative", [False, True])
+    def test_both_branches_emit_the_same_schema(
+        self, tiny_model, speculative
+    ):
+        """A plain step (double buffer) and a verify step (counts
+        fetch) emit the same event schema; each names its own sync."""
         params, cfg = tiny_model
         obs.reset_stats()
-        self._drain(params, cfg, interleave=False)
+        self._drain(params, cfg, speculative=speculative)
         events = obs.recorder.events()
+        for e in events:
+            assert validate_event(e) == []
         kinds = {e["type"] for e in events}
         assert {"request", "step"} <= kinds
+        steps = [
+            e for e in events
+            if e["type"] == "step" and e["kind"] != "prefill"
+        ]
         syncs = obs.snapshot()["host_syncs"]
-        assert "legacy_step" in syncs
+        # tools/obs_dump.py renders depth and sync from the one
+        # StepEvent constructor.
+        from tools.obs_dump import occupancy_timeline
+
+        text = occupancy_timeline(events)
+        if speculative:
+            assert "depth=1 sync=spec_counts" in text
+            assert {e["sync_reason"] for e in steps} == {"spec_counts"}
+            assert {e["pipeline_depth"] for e in steps} == {1}
+            assert syncs["spec_counts"] == len(steps)
+        else:
+            # A blocking fetch only at the depth bound, and counted.
+            assert {e["sync_reason"] for e in steps} <= {"", "depth_fetch"}
+            assert {e["pipeline_depth"] for e in steps} <= {1, 2}
+            assert "depth=1" in text or "depth=2" in text
+            assert syncs.get("depth_fetch", 0) == sum(
+                e["sync_reason"] == "depth_fetch" for e in steps
+            )
+            assert not syncs.get("spec_counts")
 
     def test_disabled_obs_records_nothing(self, tiny_model):
         params, cfg = tiny_model

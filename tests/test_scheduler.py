@@ -30,8 +30,8 @@ def _spec_off(monkeypatch):
     """This module pins admission/interleave/slot semantics; speculation
     is default-on and would only multiply the jit programs every batcher
     here compiles (each distinct (B, cap) pair adds a γ-wide verify
-    program). Spec-on coverage of these same paths — parity, legacy
-    loop, slot churn, tp=2 — lives in tests/test_spec_batcher.py."""
+    program). Spec-on coverage of these same paths — parity, slot
+    churn, tp=2 — lives in tests/test_spec_batcher.py."""
     from adversarial_spec_tpu.engine import spec as spec_mod
 
     prev = spec_mod.config()
@@ -251,10 +251,8 @@ def _spy_dispatches(sched_mod, calls):
 
 
 class TestChunkedPrefillInterleave:
-    """Admission prefill no longer pauses decode: a multi-chunk prompt's
-    chunks ride INSIDE the residents' decode program (the fused step),
-    and the legacy --no-interleave loop still interleaves them as
-    separate serialized dispatches."""
+    """Admission prefill does not pause decode: a multi-chunk prompt's
+    chunks ride INSIDE the residents' decode program (the fused step)."""
 
     def _workload(self, params, cfg, **kw):
         b = ContinuousBatcher(
@@ -278,7 +276,7 @@ class TestChunkedPrefillInterleave:
         calls = []
         real = _spy_dispatches(sched_mod, calls)
         try:
-            b, long_prompt = self._workload(params, cfg, interleave=True)
+            b, long_prompt = self._workload(params, cfg)
             results = b.run_all()
         finally:
             (
@@ -309,49 +307,6 @@ class TestChunkedPrefillInterleave:
             b.stalled_prefill_s + b.overlapped_prefill_s
         )
 
-    def test_legacy_loop_interleaves_serialized_dispatches(self, tiny_model):
-        """--no-interleave escape hatch: the original loop — a decode
-        chunk between two standalone admission chunks, never a fused
-        dispatch — and identical greedy tokens."""
-        import adversarial_spec_tpu.engine.scheduler as sched_mod
-
-        params, cfg = tiny_model
-        calls = []
-        real = _spy_dispatches(sched_mod, calls)
-        try:
-            b, long_prompt = self._workload(params, cfg, interleave=False)
-            results = b.run_all()
-        finally:
-            (
-                sched_mod.prefill_chunk,
-                sched_mod.scheduler_decode_chunk,
-                sched_mod.fused_prefill_decode_chunk,
-                sched_mod.scheduler_spec_chunk,
-                sched_mod.fused_prefill_spec_chunk,
-            ) = real
-
-        s = "".join(calls)
-        assert "F" not in s, f"legacy loop dispatched a fused step: {s}"
-        assert "PDP" in s, f"no decode between admission chunks: {s}"
-        ref0 = _reference(params, cfg, [1, 5, 9], 64)
-        ref1 = _reference(params, cfg, long_prompt, 8)
-        np.testing.assert_array_equal(results[0].tokens, np.asarray(ref0))
-        np.testing.assert_array_equal(results[1].tokens, np.asarray(ref1))
-        # Legacy prefill is all stall: nothing rode a fused step.
-        assert b.overlapped_prefill_s == 0
-        assert b.stalled_prefill_s > 0
-
-    def test_fused_and_legacy_loops_token_identical(self, tiny_model):
-        """The bench's acceptance invariant, pinned in-tree: the same
-        mixed admit-while-decoding workload produces byte-identical
-        greedy tokens through both drive loops."""
-        params, cfg = tiny_model
-        outs = {}
-        for enabled in (True, False):
-            b, _ = self._workload(params, cfg, interleave=enabled)
-            outs[enabled] = [r.tokens.tolist() for r in b.run_all()]
-        assert outs[True] == outs[False]
-
     def test_slot_reuse_mid_flight_does_not_truncate(self, tiny_model):
         """Regression: a step dispatched while slot s ran request A,
         fetched AFTER s was freed and re-admitted to request B, must not
@@ -366,7 +321,7 @@ class TestChunkedPrefillInterleave:
         budgets = [8 if i % 2 == 0 else 24 for i in range(6)]
         b = ContinuousBatcher(
             params, cfg, max_batch=2, max_new_cap=32, chunk=8,
-            interleave=True, prefix_cache=False,
+            prefix_cache=False,
         )
         for i, (p, n) in enumerate(zip(prompts, budgets)):
             b.submit(SchedRequest(req_id=i, prompt_ids=p, max_new_tokens=n))
@@ -391,17 +346,69 @@ class TestChunkedPrefillInterleave:
             b.stalled_prefill_s + b.overlapped_prefill_s
         )
 
-    def test_pipeline_depth_one_matches_depth_two(self, tiny_model):
-        """Depth 1 (fused but synchronous) and depth 2 (double-buffered)
-        are scheduling choices only — tokens must be identical."""
+    @pytest.mark.parametrize(
+        "kind", ["decode", "fused", "spec", "fused_spec"]
+    )
+    def test_account_step_contract(self, tiny_model, kind):
+        """The one place a retired step's wall clock is booked: the
+        fused share goes to the overlapped-prefill bucket and to the
+        riding admission, the rest to ``decode_time_s`` in even per-slot
+        shares, and the StepEvent of that kind carries the riding
+        admission's slot and span, or none."""
+        from types import SimpleNamespace
+
+        from adversarial_spec_tpu import obs
+
         params, cfg = tiny_model
-        outs = {}
-        for depth in (1, 2):
-            b, _ = self._workload(
-                params, cfg, interleave=True, pipeline_depth=depth
-            )
-            outs[depth] = [r.tokens.tolist() for r in b.run_all()]
-        assert outs[1] == outs[2]
+        spec, fused = kind.endswith("spec"), kind.startswith("fused")
+        b = ContinuousBatcher(
+            params, cfg, max_batch=4, max_new_cap=16, chunk=8,
+            speculative=spec, gamma=3,
+        )
+        adm = SimpleNamespace(
+            slot=3,
+            prefill_s=0.0,
+            req=SimpleNamespace(span_id="span-7", trace_id="trace-7"),
+        )
+        width = 4 if spec else 8
+        live = [0, 2]
+        share = 64 / (64 + len(live) * width) if fused else 0.0
+        obs.reset_stats()
+        b._account_step(
+            0.5,
+            live,
+            width,
+            adm if fused else None,
+            64,  # sized for a ride every iteration; counted only on one
+            1,
+            "spec_counts" if spec else "",
+        )
+        assert b.overlapped_prefill_s == pytest.approx(0.5 * share)
+        assert adm.prefill_s == pytest.approx(0.5 * share)
+        assert b.stalled_prefill_s == 0.0
+        assert b.decode_time_s == pytest.approx(0.5 * (1.0 - share))
+        assert b._slot_decode_s[0] == b._slot_decode_s[2] > 0.0
+        assert sum(b._slot_decode_s) == pytest.approx(b.decode_time_s)
+        assert b._slot_decode_s[1] == b._slot_decode_s[3] == 0.0
+        snap = obs.metrics.snapshot()["advspec_step_wall_seconds"]
+        assert snap["count"] == 1 and snap["sum"] == pytest.approx(0.5)
+        (ev,) = [e for e in obs.recorder.events() if e["type"] == "step"]
+        assert ev["kind"] == kind
+        assert ev["n_live"] == 2 and ev["decode_chunk"] == width
+        assert ev["pipeline_depth"] == 1
+        assert ev["sync_reason"] == ("spec_counts" if spec else "")
+        assert ev["admission_slot"] == (3 if fused else -1)
+        assert ev["prefill_tokens"] == (64 if fused else 0)
+        assert ev["span_id"] == ("span-7" if fused else "")
+
+    @pytest.mark.parametrize(
+        "knob", [{"interleave": False}, {"pipeline_depth": 1}]
+    )
+    def test_drive_loop_knobs_are_gone(self, tiny_model, knob):
+        """One drive loop: the constructor takes no switch for it."""
+        params, cfg = tiny_model
+        with pytest.raises(TypeError):
+            ContinuousBatcher(params, cfg, max_batch=2, **knob)
 
 
 class TestBatcherInt8Pool:

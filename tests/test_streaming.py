@@ -341,12 +341,16 @@ class TestBatcherCancel:
     @pytest.mark.parametrize(
         "kw",
         [
-            {},  # pipelined, prefix cache on
-            {"interleave": False},  # legacy loop
-            {"prefix_cache": False},  # padded layout
-            {"pipeline_depth": 1},
+            # The drive loop's two retirements (a plain step's double
+            # buffer: this module's default; a verify step's counts
+            # fetch) x the two layouts (prefix cache on; padded).
+            {},
+            {"speculative": True, "gamma": 4},
+            {"prefix_cache": False},
+            {"speculative": True, "gamma": 4, "prefix_cache": False},
         ],
-        ids=["pipelined", "legacy", "no-prefix-cache", "depth1"],
+        ids=["plain", "speculative", "no-prefix-cache",
+             "speculative-no-prefix-cache"],
     )
     def test_cancel_prefix_parity_and_readmission(self, tiny_model, kw):
         ref, _ = _drain(_mk_batcher(tiny_model, **kw), PROMPTS)

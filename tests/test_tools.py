@@ -171,13 +171,10 @@ class TestGraftlint:
         )
         msgs = [f.message for f in findings]
         assert msgs, "emptied allowlist produced no findings"
-        assert any(
-            "block_until_ready" in m and "_advance_admission" in m
-            for m in msgs
-        )
-        assert any(
-            "block_until_ready" in m and "_drive_legacy" in m for m in msgs
-        )
+        # The allowlist names _advance_admission alone: emptied, every
+        # blanket sync the rule finds is in that one method.
+        blanket = [m for m in msgs if "block_until_ready" in m]
+        assert blanket and all("_advance_admission" in m for m in blanket)
 
     def test_sync_fires_when_any_suppression_removed(self):
         """Acceptance pin: every inline GL-SYNC suppression in
@@ -688,16 +685,14 @@ class TestGraftlint:
         assert config_drift(REPO_ROOT) == []
 
     # One shared parametrized pin for the per-module process-config
-    # defaults (interleave / spec / prefix_cache / kvtier / streaming
-    # used to each pin their own): the DATACLASS defaults — what a
+    # defaults (spec / prefix_cache / kvtier / streaming used to each
+    # pin their own): the DATACLASS defaults — what a
     # fresh process arms before any CLI/env override — are part of the
     # serving contract (docs/perf.md's default-on claims) and must not
     # drift silently when a module is touched.
     @pytest.mark.parametrize(
         "modname, cls, knob, expected",
         [
-            ("engine.interleave", "InterleaveConfig", "enabled", True),
-            ("engine.interleave", "InterleaveConfig", "pipeline_depth", 2),
             ("engine.spec", "SpecConfig", "enabled", True),
             ("engine.spec", "SpecConfig", "gamma", 8),
             ("engine.prefix_cache", "PrefixCacheConfig", "enabled", True),
@@ -1503,7 +1498,8 @@ class TestBenchTrend:
         assert problems == []
         assert len(rows) >= 8
         modes = {r["mode"] for r in rows}
-        assert {"obs", "prefix", "spec", "tier", "interleave"} <= modes
+        assert {"obs", "prefix", "spec", "tier"} <= modes
+        assert "interleave" not in modes  # went with the legacy loop
         obs_row = next(r for r in rows if r["mode"] == "obs")
         assert obs_row["within_budget"] is True
 

@@ -469,14 +469,28 @@ class TestBatcherPropagation:
         obs.dump_events(str(path))
         assert trace_view_main([str(path)]) == 0
 
-    def test_legacy_loop_decomposition_holds(self, tiny_model):
+    @pytest.mark.parametrize("speculative", [False, True])
+    def test_decomposition_holds_in_both_branches(
+        self, tiny_model, speculative
+    ):
+        """The step's accounting is written once: the slot shares
+        reproduce the decode counter whether a step is retired through
+        the double buffer or by the verify step's counts fetch."""
         from tools.trace_view import check_decomposition, collect_requests
 
         params, cfg = tiny_model
         obs.reset_stats()
-        b = self._batcher(params, cfg, interleave=False)
+        b = self._batcher(params, cfg, speculative=speculative)
         self._submit_two(b)
         results = b.run_all()
+        steps = [
+            e
+            for e in obs.recorder.events()
+            if e["type"] == "step" and e["kind"] != "prefill"
+        ]
+        assert steps
+        want = {"spec_counts"} if speculative else {"", "depth_fetch"}
+        assert {e["sync_reason"] for e in steps} <= want
         assert abs(
             sum(r.decode_time_s for r in results) - b.decode_time_s
         ) < 1e-9

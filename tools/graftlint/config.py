@@ -76,7 +76,7 @@ class GraftlintConfig:
     # _SCHEDULER_SYNC_ALLOWLIST).
     sync_class: str = "ContinuousBatcher"
     sync_allowlist: list[str] = field(
-        default_factory=lambda: ["_advance_admission", "_drive_legacy"]
+        default_factory=lambda: ["_advance_admission"]
     )
     # Attribute names whose values live on device inside the sync class
     # (``self.active``, ``adm.pads`` …): an np.asarray / int() / bool()
