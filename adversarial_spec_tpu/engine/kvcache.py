@@ -376,8 +376,16 @@ def write_tokens(
     offsets: np.ndarray,  # [B, S] slot within page per token
     ks_new: jnp.ndarray | None = None,  # [L, B, Hkv, S, 1] (int8 pools)
     vs_new: jnp.ndarray | None = None,
+    layer=None,  # a (traced) layer: the arrays above are that layer's
+    # alone, without the L axis
 ) -> dict[str, jnp.ndarray]:
     """Scatter freshly computed K/V into their pages (vectorized).
+
+    The way a prompt's K/V reach the pool, whichever way it is admitted:
+    a dense admission cache at its handoff and a promoted tier block go
+    in whole; a span that runs over the pool (the scheduler's
+    ``paged_admission``) writes a layer at a time as it goes (``layer``;
+    traced into that program, where the pool is the program's own).
 
     The pool is DONATED and updated in place — callers rebind it
     (``pool = write_tokens(pool, ...)``). Dispatched eagerly, every
@@ -394,7 +402,7 @@ def write_tokens(
             )
         new.update(ks=ks_new, vs=vs_new)
     write, _ = _pool_jits()
-    return write(pool, new, page_ids, offsets)
+    return write(pool, new, page_ids, offsets, layer)
 
 
 def read_tokens(
@@ -405,9 +413,13 @@ def read_tokens(
     """Gather per-token K/V (and scales) back out of their pages.
 
     The exact inverse of ``write_tokens``: returns arrays in the
-    heads-major dense-cache layout [L, B, Hkv, S, *]. Used to materialize
-    a cached prefix's KV into a fresh admission's dense prefill cache
-    (engine/scheduler.py) so only the suffix runs through the model.
+    heads-major dense-cache layout [L, B, Hkv, S, *]. Its callers
+    (engine/scheduler.py): the demotion fetch of an evicted block on
+    its way to the host tier, a page at a time, and the one admission
+    that still copies a cached prefix into a dense cache: a hit whose
+    remainder is longer than an ADMISSION_CHUNK, which prefills in
+    chunks. A hit with a shorter remainder reads nothing out of the
+    pool: it runs over the pages it adopted (``paged_admission``).
     """
     _, read = _pool_jits()
     return read(pool, page_ids, offsets)
@@ -420,18 +432,20 @@ def _pool_jits():
     import jax
     import jax.numpy as jnp
 
-    def write(pool, new, page_ids, offsets):
+    def write(pool, new, page_ids, offsets, layer=None):
         # One scatter per layer: a single scatter over every (layer,
         # token, head) row compiles in time proportional to the token
         # count on XLA:TPU (25 s at 5k tokens against 0.2 s this way).
-        def one_layer(layer, pool):
+        def one_layer(l, pool):
             return {
                 name: pool[name]
-                .at[_row_index(layer, pool[name], page_ids, offsets)]
-                .set(new[name][layer])
+                .at[_row_index(l, pool[name], page_ids, offsets)]
+                .set(new[name][l] if layer is None else new[name])
                 for name in pool
             }
 
+        if layer is not None:
+            return one_layer(layer, pool)
         n_layers = pool["k"].shape[0]
         return jax.lax.fori_loop(0, n_layers, one_layer, pool)
 

@@ -67,6 +67,11 @@ class PrefixCacheStats(procconfig.StatsBase):
     cached_tokens: int = 0  # tokens matched by lookups
     prefilled_tokens: int = 0  # tokens actually run through prefill
     saved_tokens: int = 0  # forward tokens skipped thanks to reuse
+    # Batcher admissions that began on cached pages (adopted or promoted),
+    # and those among them that ran their delta over those pages: no dense
+    # copy of the prefix (engine/scheduler.py ``paged_admission``).
+    hit_admissions: int = 0
+    paged_admissions: int = 0
     inserted_blocks: int = 0
     evicted_blocks: int = 0
     evicted_pages: int = 0
@@ -89,6 +94,10 @@ class PrefixCacheStats(procconfig.StatsBase):
         )
         if obs_mod.config().enabled:
             obs_mod.hot.hit_ratio.set(round(self.hits / self.lookups, 6))
+
+    def record_admission(self, cached_tokens: int, over_pages: bool) -> None:
+        self.hit_admissions += cached_tokens > 0
+        self.paged_admissions += bool(over_pages)
 
     def record_prefill(self, computed_tokens: int, saved_tokens: int) -> None:
         self.prefilled_tokens += computed_tokens

@@ -165,7 +165,10 @@ class _Admission:
     seq_id: int
     tokens: object  # [1, S] device array
     pads: object  # [1]
-    cache: object  # 1-row dense cache being prefilled
+    # 1-row dense cache being prefilled; None for a cached prompt admitted
+    # over its pages (``paged_admission``): then ``tokens`` and ``pads``
+    # are None too, and ``[pos, S_real)`` is the delta its one program runs.
+    cache: object
     pos: int  # next chunk start
     S: int  # bucketed token-array length
     last_logits: object = None
@@ -859,6 +862,187 @@ def fused_prefill_spec_chunk(
         active,
         counts,
     )
+
+
+# Names of the small per-row arrays a handoff writes one slot of; the
+# batcher holds each as an attribute of the same name.
+_ROW_STATE = (
+    "page_table", "cur_tok", "cur_len", "pad_lens", "n_emitted", "max_new",
+    "active", "ctx_len", "prev_tok",
+)
+# An admission over its pages pads its delta to one of these widths: one
+# compiled program each. 64 serves an opponent that adopts its sibling's
+# blocks (the match is clamped to whole pages short of the last token:
+# 1-64 tokens at the serving page size), 128 a round's first opponent
+# (the round number changes a block near the prompt's end); the widest
+# is the most ``_admit`` finishes at once.
+_SPAN_WIDTHS = (64, 128, 256, ADMISSION_CHUNK)
+
+
+def _span_width(n_tokens: int) -> int:
+    return next(w for w in _SPAN_WIDTHS if w >= n_tokens)
+
+
+def _activate_slot_impl(
+    rows: dict,  # the ``_ROW_STATE`` arrays, [B, ...] each
+    out_buf: jnp.ndarray,  # [B, cap]
+    ctx_buf,  # [B, C], or None without speculation
+    slot: jnp.ndarray,  # scalar int32
+    first: jnp.ndarray,  # scalar int32: the admission's sampled token
+    row_table: jnp.ndarray,  # [Pmax] physical page ids (0 = unmapped)
+    row_len: jnp.ndarray,  # scalar: KV slots the prompt holds
+    pad: jnp.ndarray,  # scalar: left pad (0 in the canonical layout)
+    max_new: jnp.ndarray,  # scalar: the request's budget
+    eos_ids: jnp.ndarray,
+    ctx_row,  # [C] the REAL prompt ids, zero past them; None as ctx_buf
+    n_ctx,  # scalar: how many
+    prev,  # scalar: the prompt's last token (bigram context)
+):
+    """The handoff's device half: slot ``slot`` of every per-row array
+    takes its new owner, in one program (traced into the paged
+    admission's; jitted alone as ``activate_slot`` for the dense
+    handoff). ``first`` is already in ``out_buf`` and, under
+    speculation, behind the prompt in the draft source; the row is live
+    unless ``first`` ended it or used up its budget."""
+    rows = dict(rows)
+    live = (max_new > 1) & ~(first == eos_ids).any()
+    for name, value in (
+        ("page_table", row_table),
+        ("cur_tok", first),
+        ("cur_len", row_len + 1),
+        ("pad_lens", pad),
+        ("n_emitted", 1),
+        ("max_new", max_new),
+        ("active", live),
+    ):
+        rows[name] = rows[name].at[slot].set(value)
+    out_buf = out_buf.at[slot].set(
+        jnp.zeros_like(out_buf[0]).at[0].set(first)
+    )
+    if ctx_buf is not None:
+        ctx_buf = ctx_buf.at[slot].set(ctx_row.at[n_ctx].set(first))
+        rows["ctx_len"] = rows["ctx_len"].at[slot].set(n_ctx + 1)
+        rows["prev_tok"] = rows["prev_tok"].at[slot].set(prev)
+    return rows, out_buf, ctx_buf
+
+
+activate_slot = partial(jax.jit, donate_argnames=("out_buf", "ctx_buf"))(
+    _activate_slot_impl
+)
+
+
+def _write_span_kv(pool, layer, new_kv, page_ids, offsets):
+    """A layer's K/V of an admission's span into their pages, through the
+    function every admission's K/V reach the pool by (``write_tokens``:
+    where the benchmark plants its ``state_unchanged`` fault)."""
+    return write_tokens(
+        pool, new_kv["k"], new_kv["v"], page_ids, offsets,
+        ks_new=new_kv.get("ks"), vs_new=new_kv.get("vs"), layer=layer,
+    )
+
+
+def _paged_admission_impl(
+    params,
+    cfg: ModelConfig,
+    pool,
+    rows: dict,
+    out_buf: jnp.ndarray,
+    ctx_buf,
+    tokens: jnp.ndarray,  # [1, W] the delta's tokens, zero past ``n_real``
+    start: jnp.ndarray,  # scalar: the delta's first position (= KV slot)
+    n_real: jnp.ndarray,  # scalar: 1..W tokens of the delta
+    row_table: jnp.ndarray,  # [Pmax] the sequence's pages, physical ids
+    slot: jnp.ndarray,
+    max_new: jnp.ndarray,
+    eos_ids: jnp.ndarray,
+    key: jax.Array,
+    temperature: jnp.ndarray,
+    top_p: jnp.ndarray,
+    ctx_row,
+    n_ctx,
+    prev,
+    *,
+    greedy: bool,
+    top_k: int,
+    table_pages: int,
+    use_top_p: bool = True,
+    use_pallas: bool = False,
+    use_pallas_matmul: bool = False,
+    pallas_interpret: bool = False,
+):
+    """A cached prompt's whole admission, over the pages it adopted: the
+    delta ``[start, start + n_real)`` runs through ``forward_paged_decode``
+    as a span of one row — the verify step's function, so its K/V land in
+    the sequence's own pages (an adopted page is never a write target:
+    the delta starts past the last of them) and its attention reads the
+    prefix where it lies, through the page table — the first token is
+    sampled from the last REAL position's logits, and the slot's rows
+    take their owner (``_activate_slot_impl``). No dense cache, nothing
+    gathered out of the pool, one program.
+
+    Positions past ``n_real`` repeat the last real one with token 0 and
+    write to the trash page: finite, never read. The span attends
+    through the first ``table_pages`` entries of the row's table: all of
+    them where a kernel walks the row's live pages, the prompt's bucket
+    where the gather path would densify the table's whole width."""
+    W = tokens.shape[1]
+    page_size = pool["k"].shape[3]
+    j = jnp.arange(W)
+    q_pos = (start + jnp.minimum(j, n_real - 1))[None, :]  # [1, W]
+    write_page = jnp.where(
+        j < n_real, row_table[q_pos // page_size], TRASH_PAGE
+    )
+    bounds = jnp.stack([jnp.zeros_like(q_pos), q_pos + 1], axis=-1)
+    logits, pool, _ = forward_paged_decode(
+        params,
+        cfg,
+        tokens,
+        q_pos,  # canonical layout: rope position = KV slot
+        pool,
+        row_table[None, :table_pages],
+        write_page,
+        q_pos % page_size,
+        bounds.astype(jnp.int32),
+        q_pos,
+        logits_at=(n_real - 1)[None],
+        write_kv=_write_span_kv,
+        use_pallas=use_pallas,
+        use_pallas_matmul=use_pallas_matmul,
+        pallas_interpret=pallas_interpret,
+    )
+    with jax.named_scope("sample"):
+        first = sample_tokens(
+            logits[:, 0],
+            key,
+            greedy=greedy,
+            top_k=top_k,
+            temperature=temperature,
+            top_p=top_p,
+            use_top_p=use_top_p,
+        )[0]
+    rows, out_buf, ctx_buf = _activate_slot_impl(
+        rows, out_buf, ctx_buf, slot, first, row_table, start + n_real,
+        jnp.int32(0), max_new, eos_ids, ctx_row, n_ctx, prev,
+    )
+    return pool, rows, out_buf, ctx_buf, first
+
+
+# Its own name in a trace (``jit__paged_admission_impl``): no verify step,
+# no prefill chunk.
+paged_admission = partial(
+    jax.jit,
+    static_argnames=(
+        "cfg",
+        "greedy",
+        "top_k",
+        "table_pages",
+        "use_top_p",
+        "use_pallas",
+        "use_pallas_matmul",
+        "pallas_interpret",
+    ),
+    donate_argnames=("pool", "out_buf", "ctx_buf"),
+)(_paged_admission_impl)
 
 
 def sharded_scheduler_decode_chunk(
@@ -1575,14 +1759,21 @@ class ContinuousBatcher:
     def _start_admission_cached(self, slot: int, req: SchedRequest) -> bool:
         """Prefix-cache admission: adopt the longest cached prefix and
         set up a CANONICAL-layout (pad 0, slot == logical position)
-        prefill of only the remainder.
+        prefill of only the remainder. The last prompt token is always
+        re-run even on a full-prefix hit: its logits seed sampling.
 
-        The token array is right-padded to the usual power-of-two bucket
-        (compiled shapes unchanged) but prefill only covers
-        [matched, page_ceil(S_real)) — the bucket's garbage tail is never
-        computed or attended (forward's causal mask stops at
-        cache_index). The last prompt token is always re-run even on a
-        full-prefix hit: its logits seed sampling.
+        A hit whose remainder ``_admit`` finishes at once (at most one
+        ADMISSION_CHUNK) is admitted over the pages it adopted: no dense
+        cache, nothing read out of the pool; ``_finish_admission`` runs
+        the delta, the first token and the slot's rows as one program
+        (``paged_admission``). A miss, and a hit with a longer
+        remainder, prefill into a dense cache in chunks that ride the
+        residents' steps: the token array is right-padded to the usual
+        power-of-two bucket (compiled shapes unchanged) but prefill only
+        covers [matched, page_ceil(S_real)) — the bucket's garbage tail
+        is never computed or attended (forward's causal mask stops at
+        cache_index) — and a hit's prefix is gathered into that cache
+        first.
         """
         ids = req.prompt_ids
         S_real = len(ids)
@@ -1603,9 +1794,6 @@ class ContinuousBatcher:
         pages = pages[: matched // ps]
         tier_hits = tier_hits[: (limit - matched) // ps]
         S = bucket_length(S_real)
-        prefill_end = min(-(-S_real // ps) * ps, S)
-        tokens_np = np.zeros((1, S), np.int32)
-        tokens_np[0, :S_real] = np.asarray(ids, np.int32)
         seq_id = self._seq_counter
         self.allocator.new_sequence(seq_id)
         try:
@@ -1630,45 +1818,24 @@ class ContinuousBatcher:
                 else 0
             )
             total = matched + promoted
-            cache = self._commit(
-                init_cache(
-                    self.cfg, 1, S, dtype=self._dtype, kv_dtype=self.kv_dtype
-                )
-            )
-            if total:
-                # Materialize the adopted + promoted prefix KV into the
-                # dense admission cache so the delta's attention sees it
-                # (the promoted blocks' scatter was dispatched above;
-                # this gather queues after it — no host sync).
-                table = (
-                    np.asarray(
-                        self.allocator.table(seq_id)[: total // ps],
-                        np.int32,
-                    )
-                    + 1
-                )  # physical ids
-                slots = np.arange(total, dtype=np.int32)[None, :]
-                gathered = read_tokens(
-                    self.pool, table[slots // ps], slots % ps
-                )
-                for k in cache:
-                    cache[k] = (
-                        cache[k].at[:, :, :, :total, :].set(gathered[k])
-                    )
-            self._admission = _Admission(
+            over_pages = 0 < total and S_real - total <= ADMISSION_CHUNK
+            adm = _Admission(
                 slot=slot,
                 req=req,
                 seq_id=seq_id,
-                tokens=jnp.asarray(tokens_np),
-                pads=jnp.zeros((1,), jnp.int32),
-                cache=cache,
+                tokens=None,
+                pads=None,
+                cache=None,
                 pos=total,
                 S=S,
                 canonical=True,
                 S_real=S_real,
                 matched=total,
-                prefill_end=prefill_end,
+                prefill_end=S_real,
             )
+            if not over_pages:
+                self._dense_admission_cache(adm)
+            self._admission = adm
         except OutOfPages:
             self.allocator.free_sequence(seq_id)
             return False
@@ -1677,6 +1844,7 @@ class ContinuousBatcher:
             raise
         self._seq_counter += 1
         self.prefix_cache.stats.record_lookup(matched)
+        self.prefix_cache.stats.record_admission(total, over_pages)
         if self.tiers is not None:
             self.tiers.record_lookup(tier_hits)
         obs_mod.emit(
@@ -1690,6 +1858,38 @@ class ContinuousBatcher:
         )
         self._emit_admitted_spans(req, slot)
         return True
+
+    def _dense_admission_cache(self, adm: _Admission) -> None:
+        """Give a canonical admission that prefills in chunks its dense
+        cache and its bucketed token array; a matched prefix (one whose
+        remainder is longer than ``_admit`` finishes at once) is gathered
+        out of its pages into that cache, so that the chunks' attention
+        sees it."""
+        S, ps, total = adm.S, self.page_size, adm.pos
+        tokens_np = np.zeros((1, S), np.int32)
+        tokens_np[0, : adm.S_real] = np.asarray(adm.req.prompt_ids, np.int32)
+        cache = self._commit(
+            init_cache(
+                self.cfg, 1, S, dtype=self._dtype, kv_dtype=self.kv_dtype
+            )
+        )
+        if total:
+            # The promoted blocks' scatter was dispatched before this
+            # gather and queues ahead of it — no host sync.
+            table = (
+                np.asarray(
+                    self.allocator.table(adm.seq_id)[: total // ps], np.int32
+                )
+                + 1
+            )  # physical ids
+            slots = np.arange(total, dtype=np.int32)[None, :]
+            gathered = read_tokens(self.pool, table[slots // ps], slots % ps)
+            for k in cache:
+                cache[k] = cache[k].at[:, :, :, :total, :].set(gathered[k])
+        adm.tokens = jnp.asarray(tokens_np)
+        adm.pads = jnp.zeros((1,), jnp.int32)
+        adm.cache = cache
+        adm.prefill_end = min(-(-adm.S_real // ps) * ps, S)
 
     def _emit_admitted_spans(self, req: SchedRequest, slot: int) -> None:
         """Trace-span bookkeeping at admission start: the 'queued' span
@@ -1736,6 +1936,11 @@ class ContinuousBatcher:
         import time
 
         adm = self._admission
+        if adm.cache is None:
+            # Admitted over its pages: the delta is part of the handoff's
+            # one program.
+            self._finish_admission()
+            return
         t0 = time.monotonic()
         chunk_len = _next_chunk_len(adm.remaining)
         adm.cache, adm.last_logits = prefill_chunk(
@@ -1785,24 +1990,14 @@ class ContinuousBatcher:
         if adm.pos >= adm.prefill_end:
             self._finish_admission()
 
-    def _finish_admission(self) -> None:
-        """Prefill done: scatter the dense cache into this sequence's
-        pages (+1 shift: page 0 is trash) and activate the slot.
-
-        ``self._admission`` stays set until the slot takes ownership of
-        the sequence below: the pool scatter and first-token sampling are
-        real device work that can fault, and ``_abort_admission`` needs
-        the admission record to free its pages and resolve its request.
-        """
-        import time
-
-        t0 = time.monotonic()
-        adm = self._admission
-        slot, req, seq_id, S = adm.slot, adm.req, adm.seq_id, adm.S
+    def _dense_cache_to_pages(
+        self, adm: _Admission, row_table: np.ndarray, key, sampling: dict
+    ):
+        """The device work of a chunked admission's handoff: scatter the
+        dense cache into the sequence's pages (``row_table``: physical
+        ids, page 0 is trash) and sample the first token, returned still
+        on the device."""
         cache, last_logits = adm.cache, adm.last_logits
-        # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: the pool scatter below needs host pads
-        pads_np = np.asarray(adm.pads)
-        table = np.asarray(self.allocator.table(seq_id), np.int32) + 1
         if adm.canonical:
             if adm.prefill_end > adm.S_real:
                 # The final chunk's last slot is bucket garbage; re-run
@@ -1830,75 +2025,161 @@ class ContinuousBatcher:
             # Scatter only the delta: slots [matched, S_real). Adopted
             # prefix pages already hold [0, matched) and must never be
             # rewritten (shared, copy-on-append discipline).
-            scat = np.arange(adm.matched, adm.S_real, dtype=np.int32)
+            lo, hi = adm.matched, adm.S_real
         else:
-            scat = np.arange(S, dtype=np.int32)
-        slots = scat[None, :]
-        page_ids = table[slots // self.page_size]
-        offsets = slots % self.page_size
-        lo, hi = int(scat[0]), int(scat[-1]) + 1
+            lo, hi = 0, adm.S
+        slots = np.arange(lo, hi, dtype=np.int32)[None, :]
         self.pool = write_tokens(
             self.pool,
             cache["k"][..., lo:hi, :],
             cache["v"][..., lo:hi, :],
-            page_ids,
-            offsets,
+            row_table[slots // self.page_size],
+            slots % self.page_size,
             ks_new=cache["ks"][..., lo:hi, :] if "ks" in cache else None,
             vs_new=cache["vs"][..., lo:hi, :] if "ks" in cache else None,
         )
+        return sample_tokens(last_logits, key, **sampling)[0]
 
+    def _finish_admission(self) -> None:
+        """Prefill done (or, for a cached prompt admitted over its
+        pages, about to be: its delta is part of this): the prompt's K/V
+        reach the sequence's pages, the first token is sampled, and the
+        slot takes its owner.
+
+        ``self._admission`` stays set until the slot takes ownership of
+        the sequence below: the device work here can fault, and
+        ``_abort_admission`` needs the admission record to free its
+        pages and resolve its request.
+        """
+        import time
+
+        t0 = time.monotonic()
+        adm = self._admission
+        slot, req, seq_id = adm.slot, adm.req, adm.seq_id
+        # Canonical rows live at pad 0 with their true length; padded
+        # rows keep the bucketed length + left pad. Per-row pad_lens and
+        # cur_len let both layouts coexist in one decode batch.
+        row_len = adm.S_real if adm.canonical else adm.S
+        row_table = np.zeros((self.max_pages_per_seq,), np.int32)
+        table = self.allocator.table(seq_id)
+        row_table[: len(table)] = np.asarray(table, np.int32) + 1
+        ids_np = np.asarray(req.prompt_ids, np.int32)
+        handoff = dict(
+            rows={name: getattr(self, name) for name in _ROW_STATE},
+            out_buf=self.out_buf,
+            ctx_buf=None,
+            slot=jnp.int32(slot),
+            row_table=jnp.asarray(row_table),
+            max_new=jnp.int32(req.max_new_tokens),
+            eos_ids=self._eos,
+            ctx_row=None,
+            n_ctx=None,
+            prev=None,
+        )
+        if self.speculative:
+            # The draft source: the row's REAL (unpadded) prompt ids,
+            # then its first sampled token. ctx coordinates are
+            # independent of the KV layout — padded rows draft from the
+            # same clean token stream canonical rows do.
+            ctx_row = np.zeros((self._ctx_cap,), np.int32)
+            ctx_row[: len(ids_np)] = ids_np
+            handoff.update(
+                ctx_buf=self.ctx_buf,
+                ctx_row=jnp.asarray(ctx_row),
+                n_ctx=jnp.int32(len(ids_np)),
+                prev=jnp.int32(ids_np[-1] if len(ids_np) else 0),
+            )
         self._key, sub = jax.random.split(self._key)
-        first = sample_tokens(
-            last_logits,
-            sub,
+        sampling = dict(
             greedy=self.greedy,
             top_k=self.top_k,
             temperature=self._temp,
             top_p=self._top_p,
             use_top_p=self._use_top_p,
-        )[0]
-
-        row_table = np.zeros((self.max_pages_per_seq,), np.int32)
-        row_table[: len(table)] = table
-        self.page_table = self.page_table.at[slot].set(jnp.asarray(row_table))
-        self.cur_tok = self.cur_tok.at[slot].set(first)
-        # Canonical rows live at pad 0 with their true length; padded
-        # rows keep the bucketed length + left pad. Per-row pad_lens and
-        # cur_len let both layouts coexist in one decode batch.
-        row_len = adm.S_real if adm.canonical else S
-        self.cur_len = self.cur_len.at[slot].set(row_len + 1)
-        self.pad_lens = self.pad_lens.at[slot].set(
-            0 if adm.canonical else int(pads_np[0])
         )
-        self.out_buf = self.out_buf.at[slot].set(0)
-        self.out_buf = self.out_buf.at[slot, 0].set(first)
+        if adm.cache is None:
+            n_real = adm.S_real - adm.pos
+            width = _span_width(n_real)
+            delta = np.zeros((1, width), np.int32)
+            delta[0, :n_real] = ids_np[adm.pos :]
+            # The walk kernel skips what a row does not hold; the gather
+            # path (off the TPU) reads the table's whole width, so it
+            # gets the prompt's bucket, as a dense cache would be sized.
+            table_pages = (
+                self.max_pages_per_seq
+                if self._use_pallas
+                else min(self.max_pages_per_seq, -(-adm.S // self.page_size))
+            )
+            self.pool, rows, self.out_buf, ctx_buf, first = paged_admission(
+                self.params,
+                self.cfg,
+                self.pool,
+                tokens=jnp.asarray(delta),
+                start=jnp.int32(adm.pos),
+                n_real=jnp.int32(n_real),
+                key=sub,
+                table_pages=table_pages,
+                use_pallas=self._use_pallas,
+                use_pallas_matmul=self._use_pallas_matmul,
+                pallas_interpret=self._pallas_interpret,
+                **handoff,
+                **sampling,
+            )
+            interleave_mod.stats.record_step(fused=False, prefill_only=True)
+            prefix_mod.stats.record_prefill(n_real, 0)
+            if obs_mod.config().enabled:
+                obs_mod.retrace.observe(
+                    "paged_admission",
+                    (
+                        "paged_admission", width, table_pages, self.B,
+                        self.cap, self.greedy,
+                    ),
+                    fn=paged_admission,
+                )
+                obs_mod.emit(
+                    obs_mod.StepEvent(
+                        kind="prefill",
+                        n_live=int(sum(self._active_np)),
+                        admission_slot=slot,
+                        prefill_tokens=n_real,
+                    )
+                )
+                obs_mod.emit(
+                    obs_mod.RequestEvent(
+                        req_id=req.req_id,
+                        state="prefill",
+                        slot=slot,
+                        tokens=n_real,
+                    )
+                )
+        else:
+            first = self._dense_cache_to_pages(adm, row_table, sub, sampling)
+            # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: a padded row's left pad is a host number from here on
+            pad = 0 if adm.canonical else int(np.asarray(adm.pads)[0])
+            rows, self.out_buf, ctx_buf = activate_slot(
+                first=first,
+                row_len=jnp.int32(row_len),
+                pad=jnp.int32(pad),
+                **handoff,
+            )
+        for name in _ROW_STATE:
+            setattr(self, name, rows[name])
+        if self.speculative:
+            self.ctx_buf = ctx_buf
         # Admission handoff is a sanctioned sync point: ``first`` was
         # fetched above, blocking on every step in flight.
         interleave_mod.stats.record_sync()
         obs_mod.record_sync("admission_handoff")
         # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: the first sampled token decides slot activation (and seeds the slot's stream delivery)
         first_np = np.asarray(first)
-        first_is_eos = bool(np.isin(first_np, self._eos_np))
-        self.n_emitted = self.n_emitted.at[slot].set(1)
-        self.max_new = self.max_new.at[slot].set(req.max_new_tokens)
-        row_active = (req.max_new_tokens > 1) and not first_is_eos
-        self.active = self.active.at[slot].set(row_active)
+        # What ``_activate_slot_impl`` decided on the device, from the
+        # same token.
+        row_active = (req.max_new_tokens > 1) and not bool(
+            np.isin(first_np, self._eos_np)
+        )
         self._active_np[slot] = row_active
         self._slot_gen[slot] += 1  # new owner: expire in-flight flags
         if self.speculative:
-            # Seed the draft source: the row's REAL (unpadded) prompt
-            # ids followed by its first sampled token. ctx coordinates
-            # are independent of the KV layout — padded rows draft from
-            # the same clean token stream canonical rows do.
-            ids_np = np.asarray(req.prompt_ids, np.int32)
-            row_ctx = np.zeros((self._ctx_cap,), np.int32)
-            row_ctx[: len(ids_np)] = ids_np
-            self.ctx_buf = self.ctx_buf.at[slot].set(jnp.asarray(row_ctx))
-            self.ctx_buf = self.ctx_buf.at[slot, len(ids_np)].set(first)
-            self.ctx_len = self.ctx_len.at[slot].set(len(ids_np) + 1)
-            self.prev_tok = self.prev_tok.at[slot].set(
-                int(ids_np[-1]) if len(ids_np) else 0
-            )
             self._cur_len_np[slot] = row_len + 1
             self._row_len_np[slot] = row_len
             self._max_new_np[slot] = req.max_new_tokens

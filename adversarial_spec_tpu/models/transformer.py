@@ -942,12 +942,18 @@ def forward_paged_decode(
     bounds: jnp.ndarray,  # [B(, S), 2] (start, end) valid-slot window
     q_pos: jnp.ndarray,  # scalar, [B], or [B, S]: logical slot per token
     *,
+    logits_at: jnp.ndarray | None = None,  # [B] int32: the one span
+    # position a row's logits are wanted for (None: every position)
+    write_kv=None,  # (pool, layer_id, {name: [B, Hkv, S, *]}, write_page,
+    # write_off) -> pool: the caller's own way of putting a layer's new
+    # K/V into their pages (None: the scatter below)
     use_pallas: bool = False,
     use_pallas_matmul: bool = False,
     pallas_interpret: bool = False,
     mesh=None,
 ) -> tuple[jnp.ndarray, Cache, jnp.ndarray | None]:
-    """One decode step (or one multi-position verify span) over the
+    """One decode step (or one multi-position span: a verify step's γ+1
+    positions, an admission's delta over its adopted pages) over the
     PAGED KV pool.
 
     A latent pool ({"k": the shared rotated key, "v": the compressed
@@ -962,7 +968,9 @@ def forward_paged_decode(
     paged_decode_attention; S>1: paged_decode_attention_mq, one pass
     over the pool for the whole span), a gather + masked jnp reference
     path elsewhere (same bounds semantics on every path).
-    Returns (logits [B, S, vocab], updated pool, the routed layers'
+    Returns (logits [B, S, vocab] — [B, 1, vocab] with ``logits_at``:
+    an admission samples from its last real position alone, and the head
+    is the widest matmul of the step — updated pool, the routed layers'
     choices: int32 [L, B*S, top_k] expert ids for the caller's routing
     counters, None for a dense FFN).
 
@@ -1034,6 +1042,12 @@ def forward_paged_decode(
         # token-major and convert it back for every kernel call.) One
         # scatter per pool array per layer regardless of span width
         # (rejected-draft targets are the trash page, never read).
+        if write_kv is not None:
+            heads_major = {
+                name: jnp.swapaxes(val.reshape(B, S, *val.shape[1:]), 1, 2)
+                for name, val in new_kv.items()
+            }
+            return write_kv(pool, layer_id, heads_major, write_page, write_off)
         return {
             name: pool[name]
             .at[layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]]
@@ -1272,6 +1286,8 @@ def forward_paged_decode(
             (x, pool),
             (scanned_layers, layer_ids),
         )
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)
     return logits, new_pool, routing
 

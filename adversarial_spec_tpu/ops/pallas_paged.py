@@ -275,6 +275,56 @@ def _pages_per_block(
     return max(1, min(_BLOCK_BYTES // slab, by_scores, table_width))
 
 
+# What the walk keeps in VMEM for every query row, whatever the block: the
+# row's bounds (its own and the next program's, each [G8, 2] int32 in whole
+# lanes, double-buffered), and a head its queries and its output
+# (double-buffered), the running max and normalizer (one lane used of 128)
+# and the float32 accumulator. Of the 16 MiB a kernel gets by default the
+# tiles take up to 8 (``_BLOCK_BYTES``) and a block's scores, masks and
+# float32 head slices about 3 more; the rows' state gets 3 (compiled for a
+# v5e at Mistral-7B's 8 KV heads: 128 rows a head fit, 176 do not).
+_ROW_STATE_BYTES = 3 << 20
+
+
+def _queries_per_call(
+    n_queries: int,
+    rows_per_query: int,
+    n_kv: int,
+    q_width: int,
+    v_width: int,
+    itemsize: int,
+) -> int:
+    """How many of a span's queries one call of the walk takes: all of
+    them while their state fits ``_ROW_STATE_BYTES`` (a verify span's 9
+    always do), else the span in equal parts that do (an admission's 64
+    positions x 4 query heads a KV head are 256 rows a head: two calls).
+    From the operands' shapes alone."""
+    per_row = 2 * 2 * _LANES * 4 + n_kv * (
+        2 * q_width * itemsize  # the queries, double-buffered
+        + 2 * v_width * itemsize  # the output, double-buffered
+        + 2 * _LANES * 4  # m and l
+        + v_width * 4  # the accumulator
+    )
+    fit = max(1, _ROW_STATE_BYTES // (rows_per_query * per_row))
+    n_calls = -(-n_queries // fit)
+    return -(-n_queries // n_calls)
+
+
+def _span_calls(one_call, n_queries: int, per_call: int, *spans):
+    """``one_call`` over the span's queries, ``per_call`` at a time (axis 1
+    of every array in ``spans``), joined again; one call where it takes
+    them all."""
+    if per_call >= n_queries:
+        return one_call(*spans)
+    return jnp.concatenate(
+        [
+            one_call(*(x[:, s0 : s0 + per_call] for x in spans))
+            for s0 in range(0, n_queries, per_call)
+        ],
+        axis=1,
+    )
+
+
 def _sliceable(pools) -> bool:
     """Whether a kernel can copy one page slab out of each pool by itself.
     Mosaic slices a ref in HBM only in whole lanes, along the minor
@@ -584,65 +634,81 @@ def paged_decode_attention_mq(
     Hkv, page_size = k_pages.shape[2], k_pages.shape[3]
     P = page_table.shape[1]
     g = Hq // Hkv
-    rows = S * g
-    G8 = -(-rows // _SUBLANE) * _SUBLANE
     T = P * page_size  # logical slot horizon of the table
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     quantized = k_scale is not None
     pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
-
-    # [B, Hkv, S·g, D]: row r = query (r // g), group lane (r % g).
-    qg = jnp.transpose(
-        q.reshape(B, S, Hkv, g, D), (0, 2, 1, 3, 4)
-    ).reshape(B, Hkv, rows, D)
-    bnd = _span_bounds(starts, ends, B, S, g, G8, T)  # [B, G8, 2]
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - rows), (0, 0)))
     static = dict(scale=scale, page_size=page_size, attn_softcap=attn_softcap)
-    if _sliceable(pools):
-        call, operands = _walk_call(
-            [qg], k_pages, v_pages, page_table, layer, bnd, static
-        )
-        out = pl.pallas_call(
-            **call, interpret=interpret, name="paged_decode_attention_mq"
-        )(*operands)
-    else:
-        kernel = functools.partial(
-            _paged_mq_attn_grid_kernel, quantized=quantized, **static
-        )
 
-        def page_map(b, p, table_ref, layer_ref):
-            return (layer_ref[0], jnp.maximum(table_ref[b, p], 0), 0, 0, 0)
+    def one_call(q, starts, ends):
+        """One ``pallas_call`` over the span's queries, or a part of them."""
+        S = q.shape[1]
+        rows = S * g
+        G8 = -(-rows // _SUBLANE) * _SUBLANE
+        # [B, Hkv, S·g, D]: row r = query (r // g), group lane (r % g).
+        qg = jnp.transpose(
+            q.reshape(B, S, Hkv, g, D), (0, 2, 1, 3, 4)
+        ).reshape(B, Hkv, rows, D)
+        bnd = _span_bounds(starts, ends, B, S, g, G8, T)  # [B, G8, 2]
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - rows), (0, 0)))
+        if _sliceable(pools):
+            call, operands = _walk_call(
+                [qg], k_pages, v_pages, page_table, layer, bnd, static
+            )
+            out = pl.pallas_call(
+                **call, interpret=interpret, name="paged_decode_attention_mq"
+            )(*operands)
+        else:
+            kernel = functools.partial(
+                _paged_mq_attn_grid_kernel, quantized=quantized, **static
+            )
 
-        # The layer dim is squeezed: the kernel sees [1, Hkv, page_size, *].
-        in_specs = [
-            pl.BlockSpec((1, G8, 2), lambda b, p, *_: (b, 0, 0)),
-            pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
-        ] + [
-            pl.BlockSpec((None, 1, Hkv, page_size, x.shape[-1]), page_map)
-            for x in pools
-        ]
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B, P),
-                in_specs=in_specs,
-                out_specs=pl.BlockSpec(
-                    (1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)
+            def page_map(b, p, table_ref, layer_ref):
+                page = jnp.maximum(table_ref[b, p], 0)
+                return (layer_ref[0], page, 0, 0, 0)
+
+            # The layer dim is squeezed: the kernel sees
+            # [1, Hkv, page_size, *].
+            in_specs = [
+                pl.BlockSpec((1, G8, 2), lambda b, p, *_: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv, G8, D), lambda b, p, *_: (b, 0, 0, 0)),
+            ] + [
+                pl.BlockSpec((None, 1, Hkv, page_size, x.shape[-1]), page_map)
+                for x in pools
+            ]
+            out = pl.pallas_call(
+                kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=2,
+                    grid=(B, P),
+                    in_specs=in_specs,
+                    out_specs=pl.BlockSpec(
+                        (1, Hkv, G8, D), lambda b, *_: (b, 0, 0, 0)
+                    ),
+                    scratch_shapes=[
+                        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+                        pltpu.VMEM((Hkv, G8, 1), jnp.float32),
+                        pltpu.VMEM((Hkv, G8, D), jnp.float32),
+                    ],
                 ),
-                scratch_shapes=[
-                    pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-                    pltpu.VMEM((Hkv, G8, 1), jnp.float32),
-                    pltpu.VMEM((Hkv, G8, D), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
-            interpret=interpret,
-            name="paged_decode_attention_mq",
-        )(page_table, layer, bnd, qg, *pools)
+                out_shape=jax.ShapeDtypeStruct((B, Hkv, G8, D), q.dtype),
+                interpret=interpret,
+                name="paged_decode_attention_mq",
+            )(page_table, layer, bnd, qg, *pools)
 
-    out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)
-    return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
+        out = out[:, :, :rows, :].reshape(B, Hkv, S, g, D)
+        return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, S, Hq, D)
+
+    # A span wider than the walk's VMEM holds (an admission's delta) goes
+    # through it in parts; a verify span is one call.
+    return _span_calls(
+        one_call,
+        S,
+        _queries_per_call(S, g, Hkv, D, D, q.dtype.itemsize),
+        q,
+        jnp.broadcast_to(starts, (B, S)),
+        jnp.broadcast_to(ends, (B, S)),
+    )
 
 
 def _walk_call(
@@ -756,25 +822,44 @@ def paged_latent_attention_mq(
         )
     B, S, H, R = q_lat.shape
     page_size = c_pages.shape[3]
-    rows = S * H
-    G8 = -(-rows // _SUBLANE) * _SUBLANE
-    bnd = _span_bounds(starts, ends, B, S, H, G8, page_table.shape[1] * page_size)
-    qs = [
-        jnp.pad(
-            q.reshape(B, 1, rows, q.shape[-1]),
-            ((0, 0), (0, 0), (0, G8 - rows), (0, 0)),
+
+    def one_call(q_lat, q_rot, starts, ends):
+        S = q_lat.shape[1]
+        rows = S * H
+        G8 = -(-rows // _SUBLANE) * _SUBLANE
+        bnd = _span_bounds(
+            starts, ends, B, S, H, G8, page_table.shape[1] * page_size
         )
-        for q in (q_lat, q_rot)
-    ]
-    call, operands = _walk_call(
-        qs, r_pages, c_pages, page_table, layer, bnd,
-        dict(scale=scale, page_size=page_size, attn_softcap=0.0),
-        fold=_fold_latent,
+        qs = [
+            jnp.pad(
+                q.reshape(B, 1, rows, q.shape[-1]),
+                ((0, 0), (0, 0), (0, G8 - rows), (0, 0)),
+            )
+            for q in (q_lat, q_rot)
+        ]
+        call, operands = _walk_call(
+            qs, r_pages, c_pages, page_table, layer, bnd,
+            dict(scale=scale, page_size=page_size, attn_softcap=0.0),
+            fold=_fold_latent,
+        )
+        out = pl.pallas_call(
+            **call, interpret=interpret, name="paged_latent_attention_mq"
+        )(*operands)
+        return out[:, 0, :rows].reshape(B, S, H, R)
+
+    # The span's S·H query rows share one key: 64 positions are 2,048 rows,
+    # more than the walk's VMEM holds, so a wide span goes in parts.
+    return _span_calls(
+        one_call,
+        S,
+        _queries_per_call(
+            S, H, 1, R + q_rot.shape[-1], R, q_lat.dtype.itemsize
+        ),
+        q_lat,
+        q_rot,
+        jnp.broadcast_to(starts, (B, S)),
+        jnp.broadcast_to(ends, (B, S)),
     )
-    out = pl.pallas_call(
-        **call, interpret=interpret, name="paged_latent_attention_mq"
-    )(*operands)
-    return out[:, 0, :rows].reshape(B, S, H, R)
 
 
 def paged_decode_attention_dp_tp(

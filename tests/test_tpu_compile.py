@@ -69,26 +69,38 @@ def _pool(chip, cfg, dtype):
     )
 
 
+ADMISSION_SPAN = 64  # an unchanged document's delta, padded (one row)
+
+
 @pytest.mark.parametrize("model", MODELS)
 @pytest.mark.parametrize(
-    "entry", ["decode", "verify_span", "decode_int8_kv", "verify_span_int8_kv"]
+    "entry",
+    [
+        "decode", "verify_span", "decode_int8_kv", "verify_span_int8_kv",
+        "admission_span",
+    ],
 )
 def test_paged_attention_compiles(chip, model, entry):
     """The paged-attention entry points over a bf16 pool and over an int8
     pool with its scale pages, reading one layer's pages out of the whole
-    pool by index — as forward_paged_decode calls them."""
+    pool by index — as forward_paged_decode calls them. An admission's
+    span of 64 positions is 256 (448) query rows a KV head: more than the
+    walk's VMEM holds at once, so it goes in parts (`_queries_per_call`)."""
     cfg = MODELS[model]
-    span = GAMMA + 1 if entry.startswith("verify_span") else 0
+    span = {"verify_span": GAMMA + 1, "admission_span": ADMISSION_SPAN}.get(
+        entry.removesuffix("_int8_kv"), 0
+    )
+    rows = 1 if entry == "admission_span" else B
     int8_kv = entry.endswith("int8_kv")
-    q_shape = (B, span) if span else (B,)
+    q_shape = (rows, span) if span else (rows,)
     q = _shape(chip, q_shape + (cfg.n_heads, cfg.head_dim), jnp.bfloat16)
     pool = _pool(chip, cfg, jnp.int8 if int8_kv else jnp.bfloat16)
-    table = _shape(chip, (B, TABLE_WIDTH), jnp.int32)
+    table = _shape(chip, (rows, TABLE_WIDTH), jnp.int32)
     # verify: per-position (starts, ends); decode: one (start, end) a row
     windows = (
-        [_shape(chip, (B, span), jnp.int32)] * 2
+        [_shape(chip, (rows, span), jnp.int32)] * 2
         if span
-        else [_shape(chip, (B, 2), jnp.int32)]
+        else [_shape(chip, (rows, 2), jnp.int32)]
     )
     scales = (
         [_shape(chip, pool.shape[:-1] + (1,), jnp.float32)] * 2
@@ -323,18 +335,21 @@ M4 = get_config(
 )
 
 
-def test_paged_latent_attention_compiles(chip):
+@pytest.mark.parametrize(
+    "rows,span", [(B, GAMMA + 1), (1, ADMISSION_SPAN)], ids=["verify", "admission"]
+)
+def test_paged_latent_attention_compiles(chip, rows, span):
     """The latent verify kernel at the published shapes (32 heads on one
     shared 256 + 64-wide latent, a span of γ+1) over a 512-page table: the
-    walk cuts [page, 256] and [page, 128] pages out of the two pools."""
+    walk cuts [page, 256] and [page, 128] pages out of the two pools. An
+    admission's 64 positions are 2,048 query rows on the one key: in parts."""
     la = M4.latent
     heads, k_dim, v_dim = M4.kv_layout
-    span = GAMMA + 1
-    q_lat = _shape(chip, (B, span, M4.n_heads, la.kv_rank), jnp.bfloat16)
-    q_rot = _shape(chip, (B, span, M4.n_heads, la.rope_pad), jnp.bfloat16)
+    q_lat = _shape(chip, (rows, span, M4.n_heads, la.kv_rank), jnp.bfloat16)
+    q_rot = _shape(chip, (rows, span, M4.n_heads, la.rope_pad), jnp.bfloat16)
     r_pool = _shape(chip, (M4.n_layers, N_PAGES, heads, PAGE, k_dim), jnp.bfloat16)
     c_pool = _shape(chip, (M4.n_layers, N_PAGES, heads, PAGE, v_dim), jnp.bfloat16)
-    window = _shape(chip, (B, span), jnp.int32)
+    window = _shape(chip, (rows, span), jnp.int32)
 
     def fn(q_lat, q_rot, r, c, table, layer, starts, ends):
         return pallas_paged.paged_latent_attention_mq(
@@ -344,7 +359,7 @@ def test_paged_latent_attention_compiles(chip):
 
     text = _compiled_text(
         fn, q_lat, q_rot, r_pool, c_pool,
-        _shape(chip, (B, TABLE_WIDTH), jnp.int32),
+        _shape(chip, (rows, TABLE_WIDTH), jnp.int32),
         _shape(chip, (), jnp.int32), window, window,
     )
     assert "tpu_custom_call" in text
@@ -378,6 +393,69 @@ def test_grouped_dequant_matmul_compiles(chip, rows, weight):
         _shape(chip, (), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+def _compiled_paged_admission(chip, cfg, params, n_pages, width):
+    """The batcher's whole admission of a cached prompt
+    (`scheduler.paged_admission`: the delta as a span of one row over the
+    pool, the first token, the slot's rows) compiled for the described
+    chip, speculation on."""
+    from adversarial_spec_tpu.engine import scheduler
+    from adversarial_spec_tpu.engine.kvcache import (
+        PagedCacheLayout,
+        init_page_pool,
+    )
+
+    heads, k_dim, v_dim = cfg.kv_layout
+    layout = PagedCacheLayout(
+        n_pages=n_pages, page_size=PAGE, n_layers=cfg.n_layers,
+        n_kv_heads=heads, head_dim=k_dim, v_dim=v_dim,
+    )
+    pool = _on_chip(
+        chip, jax.eval_shape(lambda: init_page_pool(layout, jnp.bfloat16))
+    )
+    table_width = cfg.max_seq_len // PAGE
+    row, one = _shape(chip, (B,), jnp.int32), _shape(chip, (), jnp.int32)
+    rows = {name: row for name in scheduler._ROW_STATE}
+    rows["page_table"] = _shape(chip, (B, table_width), jnp.int32)
+    rows["active"] = _shape(chip, (B,), jnp.bool_)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return scheduler.paged_admission.lower(
+        params, cfg, pool, rows,
+        _shape(chip, (B, 128), jnp.int32),
+        _shape(chip, (B, cfg.max_seq_len), jnp.int32),
+        _shape(chip, (1, width), jnp.int32), one, one,
+        _shape(chip, (table_width,), jnp.int32), one, one,
+        _shape(chip, (1,), jnp.int32),
+        _shape(chip, key.shape, key.dtype),
+        _shape(chip, (), jnp.float32),
+        _shape(chip, (), jnp.float32),
+        _shape(chip, (cfg.max_seq_len,), jnp.int32), one, one,
+        greedy=True, top_k=0, table_pages=table_width, use_top_p=False,
+        use_pallas=True, use_pallas_matmul=True, pallas_interpret=False,
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "model,width",
+    [("mistral-7b", 64), ("qwen2-7b", 64), ("mistral-small-4", 64),
+     ("mistral-7b", 512)],
+)
+def test_paged_admission_compiles_in_place(chip, model, width):
+    """A cached prompt's whole admission at the cells' sizes: the layer's
+    matmuls and its attention are kernels (the walk in
+    `_queries_per_call`'s parts), the donated pool is updated in place and
+    the temporaries are megabytes: no dense cache (1.07 GB at Mistral-7B
+    for a 5.3k-token prompt) and no gathered prefix (0.7 GB)."""
+    cfg = M4 if model == "mistral-small-4" else MODELS[model]
+    init_kw = dict(expert_quant="int8") if cfg is M4 else {}
+    compiled = _compiled_paged_admission(
+        chip, cfg, _int8_params(chip, cfg, **init_kw), 513, width
+    )
+    # the rolled scan's body, once: attention at least once, the dense
+    # int8 matmuls (and the routed family's three grouped ones)
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
 def test_latent_verify_step_compiles_in_place(chip):
