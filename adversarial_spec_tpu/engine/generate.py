@@ -367,6 +367,12 @@ def generate(
     scale pages, in-kernel dequant), and with sp prefill (quantized at
     the reshard-to-decode boundary).
     """
+    if cfg.ssm is not None:
+        from adversarial_spec_tpu.models.config import refuse_beside_state_space
+
+        refuse_beside_state_space(
+            cfg, "generate() (dense KV, or a mesh of more than one device)"
+        )
     # An explicit use_pallas_decode=True records caller intent (it
     # selects a louder fallback when the mesh can't support the kernel).
     explicit_pallas = use_pallas_decode is True

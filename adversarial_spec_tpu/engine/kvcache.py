@@ -37,6 +37,11 @@ class OutOfPages(RuntimeError):
     pass
 
 
+# The pool's leaves that are pages of tokens; the others ("ssm", "conv":
+# a recurrent state a row, beside state-space layers) no position addresses.
+PAGE_LEAVES = ("k", "v", "ks", "vs")
+
+
 @dataclass
 class PagedCacheLayout:
     n_pages: int
@@ -437,11 +442,17 @@ def _pool_jits():
         # token, head) row compiles in time proportional to the token
         # count on XLA:TPU (25 s at 5k tokens against 0.2 s this way).
         def one_layer(l, pool):
+            # the pool's pages alone: beside state-space layers it also
+            # holds the rows' recurrent state, which no token position
+            # addresses
             return {
-                name: pool[name]
-                .at[_row_index(l, pool[name], page_ids, offsets)]
-                .set(new[name][l] if layer is None else new[name])
-                for name in pool
+                **pool,
+                **{
+                    name: pool[name]
+                    .at[_row_index(l, pool[name], page_ids, offsets)]
+                    .set(new[name][l] if layer is None else new[name])
+                    for name in new
+                },
             }
 
         if layer is not None:
@@ -460,6 +471,7 @@ def _pool_jits():
                 )
             ]
             for name, x in pool.items()
+            if name in PAGE_LEAVES
         }
 
     return jax.jit(write, donate_argnames=("pool",)), jax.jit(read)

@@ -403,9 +403,13 @@ def materialize_params(
     cfg = get_config(
         family, size, max_seq_len, n_layers, experts_held, vocab_rows
     )
+    if cfg.ssm is not None and quant:
+        from adversarial_spec_tpu.models.config import refuse_beside_state_space
+
+        refuse_beside_state_space(cfg, f"{quant} weights")
     if checkpoint == "random":
         return _random_params(cfg, dtype, seed, quant, mesh), cfg
-    if cfg.latent is not None or cfg.experts is not None:
+    if cfg.latent is not None or cfg.experts is not None or cfg.ssm is not None:
         raise NotImplementedError(
             f"{family}: only the synthetic checkpoint is wired; the "
             "published tensors' names are not mapped yet"

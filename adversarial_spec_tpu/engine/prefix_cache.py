@@ -75,6 +75,16 @@ class PrefixCacheStats(procconfig.StatsBase):
     inserted_blocks: int = 0
     evicted_blocks: int = 0
     evicted_pages: int = 0
+    # Beside state-space layers a matched prefix is usable only up to the
+    # deepest block that carries a snapshot of the recurrent state:
+    # ``matched_tokens`` is what the radix matched over those admissions,
+    # ``resumed_tokens`` what they began from (the rest was recomputed).
+    matched_tokens: int = 0
+    resumed_tokens: int = 0
+    state_restores: int = 0
+    snapshots_taken: int = 0
+    snapshots_evicted: int = 0
+    snapshot_bytes: int = 0  # held by snapshots now (every cache's)
 
     def record_lookup(self, matched_tokens: int) -> None:
         self.lookups += 1
@@ -95,9 +105,36 @@ class PrefixCacheStats(procconfig.StatsBase):
         if obs_mod.config().enabled:
             obs_mod.hot.hit_ratio.set(round(self.hits / self.lookups, 6))
 
-    def record_admission(self, cached_tokens: int, over_pages: bool) -> None:
+    def record_admission(
+        self, cached_tokens: int, over_pages: bool, matched: int | None = None
+    ) -> None:
+        """One admission that begins on ``cached_tokens`` cached tokens.
+        ``matched`` (a family with a recurrent state alone): what the
+        radix matched; ``cached_tokens`` is then the deepest snapshot
+        under it, whose state the admission restores."""
         self.hit_admissions += cached_tokens > 0
         self.paged_admissions += bool(over_pages)
+        if matched is not None:
+            self.matched_tokens += matched
+            self.resumed_tokens += cached_tokens
+            self.state_restores += cached_tokens > 0
+            if obs_mod.config().enabled:
+                obs_mod.hot.prefix_matched_tokens.inc(matched)
+                obs_mod.hot.prefix_resumed_tokens.inc(cached_tokens)
+                if cached_tokens:
+                    obs_mod.hot.ssm_state_restores.inc()
+
+    def record_snapshot(self, event: str, nbytes: int) -> None:
+        """A snapshot ``taken`` (+nbytes) or ``evicted`` (-nbytes)."""
+        if event == "taken":
+            self.snapshots_taken += 1
+            self.snapshot_bytes += nbytes
+        else:
+            self.snapshots_evicted += 1
+            self.snapshot_bytes -= nbytes
+        if obs_mod.config().enabled:
+            obs_mod.hot.ssm_snapshots[event].inc()
+            obs_mod.hot.ssm_snapshot_bytes.set(self.snapshot_bytes)
 
     def record_prefill(self, computed_tokens: int, saved_tokens: int) -> None:
         self.prefilled_tokens += computed_tokens
@@ -153,6 +190,10 @@ class _Block:
     # cross-process identity, stamped at insert when tiers are
     # attached; None on a tier-less cache (hashing skipped).
     chain: str | None = None
+    # A snapshot of the recurrent state after this block's last token
+    # (families with state-space layers; opaque here) and its bytes.
+    state: object = None
+    state_bytes: int = 0
 
 
 class PrefixCache:
@@ -174,6 +215,13 @@ class PrefixCache:
         self.allocator = allocator
         self.page_size = page_size or allocator.page_size
         self.max_pages = max_pages
+        # Bytes the blocks' state snapshots may hold together (0: the
+        # owner's family keeps no recurrent state, nothing is attached;
+        # the batcher sets it from what its pool leaves free).
+        # A second evictable resource beside the pages: least recently
+        # used first, and always with their block.
+        self.state_budget = 0
+        self.state_bytes = 0
         self.stats = stats if stats is not None else globals()["stats"]
         self._root: dict[tuple, _Block] = {}
         self._by_page: dict[int, _Block] = {}
@@ -272,6 +320,87 @@ class PrefixCache:
                 self.tiers.record_lookup(hits)
         return matched, pages, hits
 
+    def _walk(self, tokens) -> list[_Block]:
+        """The cached blocks along ``tokens``, in order (no LRU touch)."""
+        out: list[_Block] = []
+        children = self._root
+        for key in self._blocks(tokens):
+            node = children.get(key)
+            if node is None:
+                break
+            out.append(node)
+            children = node.children
+        return out
+
+    def lookup_state(self, tokens, limit: int) -> tuple[int, object]:
+        """The deepest boundary at or under ``limit`` tokens of the cached
+        prefix of ``tokens`` whose block carries a state snapshot:
+        (its token count, the snapshot), or (0, None). Pages alone do not
+        restore a prefix beside state-space layers: an admission resumes
+        here and recomputes the rest, K/V and state alike."""
+        self._clock += 1
+        best: tuple[int, object] = (0, None)
+        for depth, node in enumerate(self._walk(tokens), start=1):
+            if depth * self.page_size > limit:
+                break
+            if node.state is not None:
+                node.last_used = self._clock
+                best = (depth * self.page_size, node.state)
+        return best
+
+    def attach_state(self, tokens, n_tokens: int, state, nbytes: int) -> bool:
+        """Hang a snapshot of the recurrent state after the first
+        ``n_tokens`` (a page multiple) of ``tokens`` on that block. The
+        block must be cached (``insert`` first). Least recently used
+        snapshots go to keep the byte budget; one that alone exceeds it,
+        or whose block is gone, is not kept. Returns whether it was."""
+        depth = n_tokens // self.page_size
+        if n_tokens % self.page_size or depth < 1:
+            raise ValueError(f"a snapshot at {n_tokens} is not page aligned")
+        nodes = self._walk(tokens[:n_tokens])
+        if len(nodes) < depth or nbytes > self.state_budget:
+            return False
+        node = nodes[depth - 1]
+        if node.state is not None:
+            return True  # first writer wins: the same state by construction
+        self._clock += 1
+        node.last_used = self._clock
+        holders = sorted(
+            (b for b in self._by_page.values() if b.state is not None),
+            key=lambda b: b.last_used,
+        )
+        while self.state_bytes + nbytes > self.state_budget and holders:
+            self._drop_state(holders.pop(0))
+        node.state, node.state_bytes = state, nbytes
+        self.state_bytes += nbytes
+        self.stats.record_snapshot("taken", nbytes)
+        return True
+
+    def _drop_state(self, block: _Block) -> None:
+        if block.state is None:
+            return
+        self.state_bytes -= block.state_bytes
+        self.stats.record_snapshot("evicted", block.state_bytes)
+        block.state, block.state_bytes = None, 0
+
+    def check_invariants(self) -> None:
+        """Raise RuntimeError when the snapshots' bookkeeping has drifted:
+        bytes that do not add up, a budget exceeded, a snapshot on a block
+        the index no longer holds."""
+        held = sum(b.state_bytes for b in self._by_page.values())
+        if held != self.state_bytes:
+            raise RuntimeError(
+                f"snapshot bytes {self.state_bytes} != {held} on cached blocks"
+            )
+        if self.state_bytes > self.state_budget:
+            raise RuntimeError(
+                f"snapshots hold {self.state_bytes} B over a budget of "
+                f"{self.state_budget}"
+            )
+        for b in self._by_page.values():
+            if (b.state is None) != (b.state_bytes == 0):
+                raise RuntimeError(f"block on page {b.page}: state and bytes disagree")
+
     def insert(self, tokens, pages: list[int]) -> int:
         """Register the full blocks of ``tokens``; ``pages[i]`` is the
         allocator page holding block i's KV. Blocks already cached keep
@@ -346,6 +475,9 @@ class PrefixCache:
         )
         del siblings[block.tokens]
         del self._by_page[block.page]
+        # A snapshot goes with its block: the host tier holds pages, and a
+        # promoted block carries none (``lookup_state`` resumes above it).
+        self._drop_state(block)
         if self.tiers is not None and block.chain is not None:
             self.tiers.demote(
                 block.chain,

@@ -62,7 +62,7 @@ exercised directly in tests/test_scheduler.py.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -100,9 +100,13 @@ from adversarial_spec_tpu.engine.sampling import sample_tokens
 from adversarial_spec_tpu.models import moe as moe_mod
 from adversarial_spec_tpu.models.config import ModelConfig
 from adversarial_spec_tpu.ops import quant
+from adversarial_spec_tpu.models.config import refuse_beside_state_space
 from adversarial_spec_tpu.models.transformer import (
+    STATE_LEAVES,
+    commit_span,
     forward_paged_decode,
     init_cache,
+    init_recurrent_state,
     n_indexed_stacks,
 )
 from adversarial_spec_tpu.resilience import faults, injector
@@ -184,6 +188,11 @@ class _Admission:
     # resident every iteration; a decode-side fault already evicted its
     # slot, and fusion resumes after one clean standalone chunk.
     fuse_deferred: bool = False
+    # Beside state-space layers: the snapshot the admission resumes from
+    # (None: a sequence's start) and those its own chunks leave behind,
+    # by boundary, to hang on the prompt's blocks at the handoff.
+    state0: object = None
+    snapshots: dict = field(default_factory=dict)
 
     @property
     def remaining(self) -> int:
@@ -313,6 +322,10 @@ def _decode_chunk_impl(
             write_off,
             bounds,
             q_pos,
+            # a live row's one position stands; an idle row's state stays
+            state_keep=(
+                active.astype(jnp.int32) if cfg.ssm is not None else None
+            ),
             use_pallas=use_pallas,
             use_pallas_matmul=use_pallas_matmul,
             pallas_interpret=pallas_interpret,
@@ -656,6 +669,18 @@ def _spec_chunk_impl(
     n_emit = jnp.where(any_eos, first_eos + 1, n_acc + 1)
     n_emit = jnp.where(active, n_emit, 0)
     emitted = jnp.where(j < n_emit[:, None], emitted, 0)
+    if cfg.ssm is not None:
+        # State rollback: span position j fed the recurrent state token j
+        # of [cur, drafts]; the row's next step starts at the LAST emitted
+        # token, so exactly the first n_emit positions stand (cur and the
+        # accepted drafts before the new cur). The state was read, not
+        # written, by the forward above; it is advanced over those
+        # positions here and never holds a rejected draft.
+        pool = commit_span(
+            cfg, pool, n_emit,
+            use_pallas=use_pallas and (mesh is None or mesh.size == 1),
+            pallas_interpret=pallas_interpret,
+        )
 
     def append(buf, start_raw, width):
         """Write ``emitted[:n_emit]`` at per-row ``start_raw``, masked so
@@ -931,6 +956,34 @@ activate_slot = partial(jax.jit, donate_argnames=("out_buf", "ctx_buf"))(
 )
 
 
+def _write_state_row_impl(pool, slot, state):
+    """The pool with state row ``slot`` set to ``state`` ({"ssm", "conv"},
+    each [layers, ...] or [layers, 1, ...]): how a sequence's recurrent
+    state reaches its slot, from a prefix block's snapshot or from the
+    dense cache a cold prefill carried it in."""
+    return {
+        **pool,
+        **{
+            k: pool[k].at[:, slot].set(
+                state[k].reshape(pool[k].shape[:1] + pool[k].shape[2:])
+            )
+            for k in STATE_LEAVES
+        },
+    }
+
+
+write_state_row = partial(jax.jit, donate_argnames=("pool",))(
+    _write_state_row_impl
+)
+
+
+@jax.jit
+def snapshot_state(cache):
+    """A copy of a 1-row dense cache's recurrent state, [layers, ...]: the
+    cache itself is donated to the next chunk."""
+    return {k: cache[k][:, 0] for k in STATE_LEAVES}
+
+
 def _write_span_kv(pool, layer, new_kv, page_ids, offsets):
     """A layer's K/V of an admission's span into their pages, through the
     function every admission's K/V reach the pool by (``write_tokens``:
@@ -961,6 +1014,8 @@ def _paged_admission_impl(
     ctx_row,
     n_ctx,
     prev,
+    state0=None,  # {"ssm", "conv"}: the recurrent state at ``start`` (a
+    # prefix block's snapshot), beside state-space layers
     *,
     greedy: bool,
     top_k: int,
@@ -993,6 +1048,12 @@ def _paged_admission_impl(
         j < n_real, row_table[q_pos // page_size], TRASH_PAGE
     )
     bounds = jnp.stack([jnp.zeros_like(q_pos), q_pos + 1], axis=-1)
+    state_kw = {}
+    if cfg.ssm is not None:
+        # The slot's state row starts from the snapshot at ``start`` and
+        # ends after the delta's ``n_real`` positions, the pads not counted.
+        pool = _write_state_row_impl(pool, slot, state0)
+        state_kw = dict(state_rows=slot[None], state_keep=n_real[None])
     logits, pool, _ = forward_paged_decode(
         params,
         cfg,
@@ -1006,6 +1067,7 @@ def _paged_admission_impl(
         q_pos,
         logits_at=(n_real - 1)[None],
         write_kv=_write_span_kv,
+        **state_kw,
         use_pallas=use_pallas,
         use_pallas_matmul=use_pallas_matmul,
         pallas_interpret=pallas_interpret,
@@ -1239,6 +1301,13 @@ class ContinuousBatcher:
         self._use_top_p = float(top_p) < 1.0
         self._key = jax.random.key(seed)
 
+        if cfg.ssm is not None:
+            if kv_dtype:
+                refuse_beside_state_space(cfg, f"{kv_dtype} KV pages")
+            if quant.has_quantized_weights(params):
+                refuse_beside_state_space(cfg, "int8 / int4 weights")
+            if self._replicated is not None and self._replicated.mesh.size > 1:
+                refuse_beside_state_space(cfg, "a mesh of more than one device")
         n_pages = -(-capacity_tokens // page_size)
         # Physical page 0 is the trash page; allocator ids shift +1.
         self.allocator = PageAllocator(n_pages, page_size)
@@ -1258,10 +1327,12 @@ class ContinuousBatcher:
             else None
         )
         kv_heads, k_dim, v_dim = cfg.kv_layout
+        # Pages for the layers that cache keys and values (all of them but
+        # beside state-space layers, which keep a state row a slot instead).
         layout = PagedCacheLayout(
             n_pages=n_pages + 1,
             page_size=page_size,
-            n_layers=cfg.n_layers,
+            n_layers=cfg.n_kv_layers,
             n_kv_heads=kv_heads,
             head_dim=k_dim,
             v_dim=v_dim,
@@ -1270,6 +1341,25 @@ class ContinuousBatcher:
         self.pool = init_page_pool(
             layout, dtype=self._dtype, kv_dtype=kv_dtype
         )
+        # The second kind of per-sequence state: a row of the recurrent
+        # state a slot, in the pool's dict so that every step program
+        # carries, donates and returns it with the pages. ``_state_owner``
+        # is the host's record of which sequence a row belongs to.
+        self._state_owner: list[int | None] = [None] * max_batch
+        self._snapshot_bytes = 0
+        if cfg.ssm is not None:
+            self.pool.update(
+                self._commit(init_recurrent_state(cfg, max_batch, self._dtype))
+            )
+            self._snapshot_bytes = sum(
+                v.nbytes // max_batch
+                for k, v in self.pool.items()
+                if k in STATE_LEAVES
+            )
+            if self.prefix_cache is not None:
+                self.prefix_cache.state_budget = self._snapshot_budget(
+                    n_pages * page_size
+                )
         # Tiered KV (engine/kvtier.py): host-RAM demotion of LRU-evicted
         # prefix blocks + the persistent content-addressed disk store,
         # both below this pool. The host budget is denominated in real
@@ -1282,10 +1372,10 @@ class ContinuousBatcher:
                 1 if kv_dtype == "int8" else np.dtype(self._dtype).itemsize
             )
             block_bytes = (
-                cfg.n_layers * kv_heads * page_size * (k_dim + v_dim)
+                cfg.n_kv_layers * kv_heads * page_size * (k_dim + v_dim)
             ) * kv_bytes
             if kv_dtype == "int8":  # per-(token, head) f32 scale pages
-                block_bytes += cfg.n_layers * kv_heads * page_size * 4 * 2
+                block_bytes += cfg.n_kv_layers * kv_heads * page_size * 4 * 2
             self.tiers = kvtier_mod.build_for(
                 block_bytes,
                 (cfg, page_size, kv_dtype, self._dtype),
@@ -1436,6 +1526,40 @@ class ContinuousBatcher:
         self.stalled_prefill_s = 0.0
         self.overlapped_prefill_s = 0.0
         self.decode_time_s = 0.0
+
+    def _snapshot_budget(self, capacity_tokens: int) -> int:
+        """Bytes the prefix blocks' state snapshots may hold together:
+        one snapshot for every ADMISSION_CHUNK of the pool's capacity (a
+        cold prefill leaves one at each chunk boundary, and the pool holds
+        no more boundaries than that), and no more than half of what the
+        device has free once the weights, the pool and the state rows are
+        on it, where it says (the other half is the prefill's)."""
+        budget = (capacity_tokens // ADMISSION_CHUNK) * self._snapshot_bytes
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        try:
+            stats = next(iter(leaf.devices())).memory_stats() or {}
+        except Exception:
+            stats = {}
+        if stats.get("bytes_limit"):
+            free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+            budget = min(budget, max(free // 2, 0))
+        return budget
+
+    def check_invariants(self) -> None:
+        """The allocator's and the prefix cache's checks, and the state
+        rows': a row is owned by exactly the sequence its slot holds, and
+        by none while the slot is free."""
+        self.allocator.check_invariants()
+        if self.prefix_cache is not None:
+            self.prefix_cache.check_invariants()
+        if self.cfg.ssm is None:
+            return
+        for slot, owner in enumerate(self._state_owner):
+            if owner != self._slot_seq[slot]:
+                raise RuntimeError(
+                    f"state row {slot} belongs to sequence {owner}, the "
+                    f"slot to {self._slot_seq[slot]}"
+                )
 
     @property
     def prefill_time_s(self) -> float:
@@ -1791,6 +1915,16 @@ class ContinuousBatcher:
         # Keep at least the last token to prefill (logits source).
         limit = ((S_real - 1) // ps) * ps
         matched = min(matched, limit)
+        radix_matched, state0 = None, None
+        if self.cfg.ssm is not None:
+            # Pages alone do not restore a prefix: the admission resumes
+            # at the deepest boundary under the match whose block still
+            # carries a snapshot of the recurrent state, and recomputes
+            # the rest, K/V and state alike. A host-tier block carries
+            # none, so nothing is promoted.
+            radix_matched = matched
+            matched, state0 = self.prefix_cache.lookup_state(ids, matched)
+            tier_hits = []
         pages = pages[: matched // ps]
         tier_hits = tier_hits[: (limit - matched) // ps]
         S = bucket_length(S_real)
@@ -1832,6 +1966,7 @@ class ContinuousBatcher:
                 S_real=S_real,
                 matched=total,
                 prefill_end=S_real,
+                state0=state0,
             )
             if not over_pages:
                 self._dense_admission_cache(adm)
@@ -1844,7 +1979,9 @@ class ContinuousBatcher:
             raise
         self._seq_counter += 1
         self.prefix_cache.stats.record_lookup(matched)
-        self.prefix_cache.stats.record_admission(total, over_pages)
+        self.prefix_cache.stats.record_admission(
+            total, over_pages, matched=radix_matched
+        )
         if self.tiers is not None:
             self.tiers.record_lookup(tier_hits)
         obs_mod.emit(
@@ -1884,12 +2021,23 @@ class ContinuousBatcher:
             )  # physical ids
             slots = np.arange(total, dtype=np.int32)[None, :]
             gathered = read_tokens(self.pool, table[slots // ps], slots % ps)
-            for k in cache:
+            for k in gathered:
                 cache[k] = cache[k].at[:, :, :, :total, :].set(gathered[k])
+            if adm.state0 is not None:
+                for k, v in adm.state0.items():
+                    cache[k] = v[:, None]
         adm.tokens = jnp.asarray(tokens_np)
         adm.pads = jnp.zeros((1,), jnp.int32)
         adm.cache = cache
-        adm.prefill_end = min(-(-adm.S_real // ps) * ps, S)
+        # A recurrent state consumes every position it is run over, so
+        # beside state-space layers the chunks stop at the last real
+        # token (a power-of-two tail) instead of the page's end: no
+        # bucket garbage enters the state and no token is run twice.
+        adm.prefill_end = (
+            adm.S_real
+            if self.cfg.ssm is not None
+            else min(-(-adm.S_real // ps) * ps, S)
+        )
 
     def _emit_admitted_spans(self, req: SchedRequest, slot: int) -> None:
         """Trace-span bookkeeping at admission start: the 'queued' span
@@ -1954,6 +2102,7 @@ class ContinuousBatcher:
             pallas_interpret=self._pallas_interpret,
         )
         adm.pos += chunk_len
+        self._keep_snapshot(adm, chunk_len)
         # Block before stamping: async dispatch would otherwise push this
         # chunk's device time into the NEXT decode chunk's blocked wait,
         # billing resident rows for the newcomer's prefill. A standalone
@@ -1989,6 +2138,29 @@ class ContinuousBatcher:
             )
         if adm.pos >= adm.prefill_end:
             self._finish_admission()
+
+    def _keep_snapshot(self, adm: _Admission, chunk_len: int) -> None:
+        """After a chunk of a chunked prefill that ends on a page boundary,
+        keep a copy of the carried recurrent state: the boundaries a
+        prefill reaches anyway, whose state is in hand (every
+        ADMISSION_CHUNK, and the page-aligned ends of the power-of-two
+        tail: 5,120 and 5,248 of a 5,308-token prompt). A paged admission
+        leaves none: its delta passes a prompt's last full block
+        mid-span, and a snapshot there would take a second commit pass
+        in that program and a 76 MB copy (h-micro) an admission. So a
+        sibling of a prompt that was admitted that way resumes at the
+        last chunk boundary under its match: a delta of 188 on every
+        admission of the benchmark's critique mix, where a snapshot at
+        the last full block would make it 60 (PERF.md section 7 (v))."""
+        if (
+            self.cfg.ssm is None
+            or self.prefix_cache is None
+            or not adm.canonical
+            or chunk_len < self.page_size
+            or adm.pos % self.page_size
+        ):
+            return
+        adm.snapshots[adm.pos] = snapshot_state(adm.cache)
 
     def _dense_cache_to_pages(
         self, adm: _Admission, row_table: np.ndarray, key, sampling: dict
@@ -2038,6 +2210,11 @@ class ContinuousBatcher:
             ks_new=cache["ks"][..., lo:hi, :] if "ks" in cache else None,
             vs_new=cache["vs"][..., lo:hi, :] if "ks" in cache else None,
         )
+        if self.cfg.ssm is not None:
+            # the carried state goes to the slot with the pages
+            self.pool = write_state_row(
+                self.pool, jnp.int32(adm.slot), {k: cache[k] for k in STATE_LEAVES}
+            )
         return sample_tokens(last_logits, key, **sampling)[0]
 
     def _finish_admission(self) -> None:
@@ -2118,6 +2295,7 @@ class ContinuousBatcher:
                 start=jnp.int32(adm.pos),
                 n_real=jnp.int32(n_real),
                 key=sub,
+                state0=adm.state0,
                 table_pages=table_pages,
                 use_pallas=self._use_pallas,
                 use_pallas_matmul=self._use_pallas_matmul,
@@ -2197,12 +2375,18 @@ class ContinuousBatcher:
                     list(req.prompt_ids[: n_full * self.page_size]),
                     self.allocator.table(seq_id)[:n_full],
                 )
+            for boundary, snap in adm.snapshots.items():
+                self.prefix_cache.attach_state(
+                    req.prompt_ids, boundary, snap, self._snapshot_bytes
+                )
             prefix_mod.stats.record_prefill(0, adm.matched)
         # Ownership handoff: from here the slot (not the admission)
         # accounts for the sequence.
         self._admission = None
         self._slot_req[slot] = req
         self._slot_seq[slot] = seq_id
+        if self.cfg.ssm is not None:
+            self._state_owner[slot] = seq_id
         self._slot_cached[slot] = adm.matched
         self._slot_trace[slot] = req.trace_id
         self._slot_span[slot] = req.span_id
@@ -2604,6 +2788,9 @@ class ContinuousBatcher:
         self.allocator.free_sequence(self._slot_seq[slot])
         self._slot_req[slot] = None
         self._slot_seq[slot] = None
+        # The state row has no content to free: its next owner's handoff
+        # overwrites it, and an idle row's state is never advanced.
+        self._state_owner[slot] = None
         self._slot_consumer[slot] = None
         self._slot_streamed[slot] = 0
         self.active = self.active.at[slot].set(False)
@@ -3143,6 +3330,7 @@ class ContinuousBatcher:
         )
         adm.cache, adm.last_logits = adm_cache, adm_logits
         adm.pos += chunk_len
+        self._keep_snapshot(adm, chunk_len)
         interleave_mod.stats.record_step(fused=True)
         prefix_mod.stats.record_prefill(chunk_len, 0)
         if obs_mod.config().enabled:
@@ -3349,6 +3537,7 @@ class ContinuousBatcher:
             )
             adm.cache, adm.last_logits = adm_cache, adm_logits
             adm.pos += chunk_len
+            self._keep_snapshot(adm, chunk_len)
             interleave_mod.stats.record_step(fused=True)
             prefix_mod.stats.record_prefill(chunk_len, 0)
             if obs_mod.config().enabled:

@@ -97,6 +97,37 @@ class RoutedExperts:
 
 
 @dataclass(frozen=True)
+class StateSpace:
+    """A Mamba-2 mixer: ``n_heads`` heads of ``head_dim`` channels
+    (together the inner width), each with a [head_dim, state_dim]
+    recurrent state; B and C are shared by the heads of a group; a causal
+    depthwise conv of ``conv_width`` taps over x, B and C precedes the
+    recurrence. What a sequence keeps of such a layer is its state and
+    the conv's last ``conv_width - 1`` inputs, not keys and values."""
+
+    n_heads: int
+    head_dim: int
+    state_dim: int
+    n_groups: int = 1
+    conv_width: int = 4
+    chunk: int = 256  # positions a step of the chunked scan covers
+
+    @property
+    def inner_dim(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the conv runs over: x, then B and C of every group."""
+        return self.inner_dim + 2 * self.n_groups * self.state_dim
+
+    @property
+    def in_dim(self) -> int:
+        """Columns of the input projection: gate z, conv channels, dt."""
+        return self.inner_dim + self.conv_dim + self.n_heads
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for one decoder-only transformer."""
 
@@ -133,38 +164,90 @@ class ModelConfig:
     # instead of 1/sqrt(head_dim). 0 = use head_dim (all other families;
     # gemma-2-9b's value equals its head_dim, 27b's does NOT: 4608/32=144).
     query_pre_attn_scalar: float = 0.0
-    # One period of the layer pattern, as (attention kind, FFN kind):
-    # attention "gqa" | "latent", FFN "dense" | "routed". The forwards
-    # scan the stack by this period; a kind's sizes live in its own
-    # group below, not as more flags on this set.
+    # Granite's multipliers (1 = what every other family computes) and
+    # its "nope" position embedding (``rope`` False: nothing is rotated).
+    embedding_multiplier: float = 1.0  # x = E[token] * this
+    residual_multiplier: float = 1.0  # x = x + this * block(norm(x))
+    attention_multiplier: float = 0.0  # the softmax scale itself; 0 = unset
+    logits_scaling: float = 1.0  # logits / this
+    rope: bool = True
+    # One period of the layer pattern, as (mixer kind, FFN kind): mixer
+    # "gqa" | "latent" | "ssm", FFN "dense" | "routed". The forwards scan
+    # the stack period by period; a kind's sizes live in its own group
+    # below, not as more flags on this set. A period holds one kind of
+    # attention at most, and one kind of FFN.
     layer_kinds: tuple[tuple[str, str], ...] = (("gqa", "dense"),)
     latent: LatentAttention | None = None
     experts: RoutedExperts | None = None
+    ssm: StateSpace | None = None
 
     def __post_init__(self):
-        if len(self.layer_kinds) != 1:
+        for kinds in self.layer_kinds:
+            if kinds[0] not in ("gqa", "latent", "ssm") or kinds[1] not in (
+                "dense", "routed",
+            ):
+                raise ValueError(f"unknown layer kinds {kinds}")
+        mixers = {m for m, _ in self.layer_kinds}
+        ffns = {f for _, f in self.layer_kinds}
+        if len(mixers - {"ssm"}) > 1 or len(ffns) != 1:
             raise NotImplementedError(
-                "the forwards scan a period of one layer; a longer "
-                f"pattern {self.layer_kinds} needs one scan a position"
+                f"one kind of attention and one of FFN a period, not "
+                f"{self.layer_kinds}"
             )
-        attn, ffn = self.layer_kinds[0]
-        if attn not in ("gqa", "latent") or ffn not in ("dense", "routed"):
-            raise ValueError(f"unknown layer kinds {self.layer_kinds[0]}")
-        if (attn == "latent") != (self.latent is not None) or (
-            ffn == "routed"
-        ) != (self.experts is not None):
+        if self.n_layers % len(self.layer_kinds):
             raise ValueError(
-                f"layer kinds {self.layer_kinds[0]} and the latent/experts "
+                f"{self.n_layers} layers are no whole number of periods "
+                f"of {len(self.layer_kinds)}"
+            )
+        if (
+            ("latent" in mixers) != (self.latent is not None)
+            or ("ssm" in mixers) != (self.ssm is not None)
+            or ("routed" in ffns) != (self.experts is not None)
+        ):
+            raise ValueError(
+                f"layer kinds {self.layer_kinds} and the latent/ssm/experts "
                 "groups disagree"
+            )
+        if self.ssm is not None and (
+            self.latent is not None or self.experts is not None
+        ):
+            raise NotImplementedError(
+                "state-space layers stand beside GQA attention and a dense FFN"
             )
 
     @property
+    def period(self) -> int:
+        return len(self.layer_kinds)
+
+    @property
     def attn_kind(self) -> str:
-        return self.layer_kinds[0][0]
+        """The period's kind of attention ("ssm" where it has none)."""
+        return next((m for m, _ in self.layer_kinds if m != "ssm"), "ssm")
 
     @property
     def ffn_kind(self) -> str:
         return self.layer_kinds[0][1]
+
+    @property
+    def mixer_counts(self) -> tuple[int, int]:
+        """(layers that cache keys and values, state-space layers): the
+        depths of the page pool and of the recurrent state."""
+        n_ssm = sum(m == "ssm" for m, _ in self.layer_kinds)
+        periods = self.n_layers // self.period
+        return (self.period - n_ssm) * periods, n_ssm * periods
+
+    @property
+    def n_kv_layers(self) -> int:
+        return self.mixer_counts[0]
+
+    def mixer_slot(self, j: int) -> tuple[str, int, int]:
+        """Position ``j`` of a period: (its mixer kind, its index among
+        the period's layers of that kind, how many of them a period has).
+        Layer p * period + j reads row p * count + index of its kind's
+        weight stack, pool or state."""
+        kind = self.layer_kinds[j][0]
+        same = [i for i, (m, _) in enumerate(self.layer_kinds) if (m == "ssm") == (kind == "ssm")]
+        return kind, same.index(j), len(same)
 
     @property
     def kv_layout(self) -> tuple[int, int, int]:
@@ -175,6 +258,15 @@ class ModelConfig:
         unrotated part of the keys."""
         if self.latent is not None:
             return 1, self.latent.rope_pad, self.latent.kv_rank
+        if self.ssm is not None:
+            # The few attention layers beside state-space layers cache
+            # their heads zero-padded to whole lanes (as ``rope_pad``
+            # does), so that the paged kernel can cut and walk a row's
+            # live pages: a narrower page goes to the grid kernel, which
+            # visits the table's whole width, and XLA re-lays the whole
+            # pool out around every step.
+            width = -(-self.head_dim // LANES) * LANES
+            return self.n_kv_heads, width, width
         return self.n_kv_heads, self.head_dim, self.head_dim
 
     @property
@@ -199,6 +291,8 @@ class ModelConfig:
 
         if self.latent is not None:
             return self.latent.softmax_scale
+        if self.attention_multiplier:
+            return self.attention_multiplier
         return 1.0 / math.sqrt(self.query_pre_attn_scalar or self.head_dim)
 
 
@@ -239,6 +333,37 @@ def _mistral4(
         experts=RoutedExperts(
             n_routed=n_routed, top_k=top_k, expert_dim=expert_dim, n_shared=1
         ),
+    )
+
+
+def _granite_hybrid(
+    *, vocab, dim, n_layers, n_heads, n_kv_heads, head_dim, ffn_dim, ssm,
+    max_seq_len,
+):
+    """Granite-4.0-H (HF ``granitemoehybrid`` with no routed experts):
+    periods of five state-space layers, one attention layer, four
+    state-space layers; the shared SwiGLU MLP after every mixer; no
+    position embedding; tied embeddings; the four multipliers."""
+    return ModelConfig(
+        vocab_size=vocab,
+        dim=dim,
+        n_layers=n_layers,
+        n_heads=n_heads,
+        n_kv_heads=n_kv_heads,
+        head_dim=head_dim,
+        ffn_dim=ffn_dim,
+        rms_eps=1e-5,
+        tied_embeddings=True,
+        max_seq_len=max_seq_len,
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        attention_multiplier=0.015625,
+        logits_scaling=8.0,
+        rope=False,
+        layer_kinds=(("ssm", "dense"),) * 5
+        + (("gqa", "dense"),)
+        + (("ssm", "dense"),) * 4,
+        ssm=ssm,
     )
 
 
@@ -400,6 +525,23 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
         ),
         max_seq_len=32768,  # published 1,048,576; the ctx buffer is sized by it
     ),
+    # Granite-4.0-H-Micro (HF model_type "granitemoehybrid"): 36 Mamba-2
+    # layers beside 4 NoPE GQA layers (5, 15, 25, 35), 3.19 B parameters.
+    # "tiny" is one whole period at lane-aligned toy widths; its chunk of
+    # 32 lets a short prompt cross chunk boundaries.
+    ("granitemoehybrid", "tiny"): _granite_hybrid(
+        vocab=512, dim=128, n_layers=10, n_heads=2, n_kv_heads=1,
+        head_dim=64, ffn_dim=256,
+        ssm=StateSpace(n_heads=4, head_dim=64, state_dim=128, chunk=32),
+        # short: off the chip, attention reads the whole table's width
+        max_seq_len=2048,
+    ),
+    ("granitemoehybrid", "h-micro"): _granite_hybrid(
+        vocab=100352, dim=2048, n_layers=40, n_heads=32, n_kv_heads=8,
+        head_dim=64, ffn_dim=8192,
+        ssm=StateSpace(n_heads=64, head_dim=64, state_dim=128, chunk=256),
+        max_seq_len=32768,  # published 131,072; the ctx buffer is sized by it
+    ),
     ("gemma2", "27b"): ModelConfig(
         vocab_size=256000,
         dim=4608,
@@ -421,6 +563,28 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
         query_pre_attn_scalar=144.0,  # dim / n_heads, NOT head_dim
     ),
 }
+
+
+def family_of(cfg: ModelConfig) -> str:
+    """The family whose presets have ``cfg``'s layer pattern ("" if none):
+    what an error names, where only the config is in hand."""
+    for (family, _), preset in CONFIGS.items():
+        if preset.layer_kinds == cfg.layer_kinds and (
+            preset.latent is None
+        ) == (cfg.latent is None):
+            return family
+    return ""
+
+
+def refuse_beside_state_space(cfg: ModelConfig, what: str):
+    """Refuse aloud what is not wired for a family with state-space
+    layers, by the family's name, rather than serve a wrong state."""
+    raise NotImplementedError(
+        f"{family_of(cfg) or 'this family'}: {what} is not wired beside "
+        "state-space layers (a recurrent state a sequence); the "
+        "ContinuousBatcher serves it on one device, in the model dtype, "
+        "with paged KV in the model dtype"
+    )
 
 
 def get_config(

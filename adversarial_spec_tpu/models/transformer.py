@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from adversarial_spec_tpu.models import moe
 from adversarial_spec_tpu.models.config import ModelConfig
+from adversarial_spec_tpu.ops import ssm as ssm_ops
 from adversarial_spec_tpu.ops.quant import (
     StackedLayer,
     dequantize,
@@ -110,11 +111,51 @@ def init_params(
     # THE RECIPE (perfbench/architectures/*.py follow it): sixteen splits
     # of the seed's key, taken in the order the weights are named below;
     # norms one, biases zero.
-    if cfg.latent is not None:
+    mixers: dict[str, dict] = {}
+    if cfg.ssm is not None:
+        # A period longer than one layer: what every layer has (the
+        # mixer's norm, the FFN) stays in "layers", [n_layers, ...]; each
+        # kind of mixer has a stack of its own depth under "mixers".
+        # wq, wk, wv, wo; w_in, w_out, conv_w, the dt draw.
+        n_kv, n_ssm = cfg.mixer_counts
+        sp = cfg.ssm
+        mixers["gqa"] = {
+            "wq": dense(next(keys), (n_kv, D, QD), D),
+            "wk": dense(next(keys), (n_kv, D, KD), D),
+            "wv": dense(next(keys), (n_kv, D, KD), D),
+            "wo": dense(next(keys), (n_kv, QD, D), QD),
+        }
+        mixers["ssm"] = {
+            "w_in": dense(next(keys), (n_ssm, D, sp.in_dim), D),
+            "w_out": dense(next(keys), (n_ssm, sp.inner_dim, D), sp.inner_dim),
+            "conv_w": dense(
+                next(keys), (n_ssm, sp.conv_width, sp.conv_dim), sp.conv_width
+            ),
+            "conv_b": jnp.zeros((n_ssm, sp.conv_dim), dtype),
+            # dt = softplus(raw + dt_bias) starts log-uniform in
+            # [1e-3, 1e-1]; A = -exp(A_log) = -(1..H), as the published
+            # module initialises both.
+            "dt_bias": _inverse_softplus(
+                jnp.exp(
+                    jax.random.uniform(
+                        next(keys), (n_ssm, sp.n_heads), jnp.float32,
+                        math.log(1e-3), math.log(1e-1),
+                    )
+                )
+            ),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, sp.n_heads + 1, dtype=jnp.float32)),
+                (n_ssm, sp.n_heads),
+            ),
+            "d_skip": jnp.ones((n_ssm, sp.n_heads), jnp.float32),
+            "gate_norm": jnp.ones((n_ssm, sp.inner_dim), dtype),
+        }
+        layers: dict[str, jnp.ndarray] = {"attn_norm": jnp.ones((L, D), dtype)}
+    elif cfg.latent is not None:
         # wq_a, wq_b, wkv_a, wkv_b, wo
         la = cfg.latent
         OD = cfg.n_heads * la.v_dim
-        layers: dict[str, jnp.ndarray] = {
+        layers = {
             "attn_norm": jnp.ones((L, D), dtype),
             "wq_a": dense(next(keys), (L, D, la.q_rank), D),
             "q_norm": jnp.ones((L, la.q_rank), dtype),
@@ -171,8 +212,19 @@ def init_params(
             if name in layers:
                 layers[name] = jnp.zeros_like(layers[name])
 
+    embed = dense(next(keys), (cfg.vocab_size, D), D)
+    if cfg.embedding_multiplier != 1.0:
+        # Rows at 1 / embedding_multiplier of the recipe's scale: the
+        # multiplied embedding then enters the residual stream at the
+        # scale a row of any other weight has. At the recipe's own scale
+        # a tied head puts the current token's logit six deviations over
+        # the rest and a greedy reply is one repeated token whatever the
+        # mixers (and the state they keep) compute.
+        embed = div_const(
+            embed.astype(jnp.float32), cfg.embedding_multiplier
+        ).astype(dtype)
     params: Params = {
-        "embed": dense(next(keys), (cfg.vocab_size, D), D),
+        "embed": embed,
         "layers": layers,
         "final_norm": (
             jnp.zeros((D,), dtype)
@@ -180,6 +232,8 @@ def init_params(
             else jnp.ones((D,), dtype)
         ),
     }
+    if mixers:
+        params["mixers"] = mixers
     if not cfg.tied_embeddings:
         params["lm_head"] = dense(next(keys), (D, cfg.vocab_size), D)
     elif transposed_head:
@@ -193,6 +247,10 @@ def init_params(
         # full-bandwidth matmul every step.
         params["lm_head_t"] = jnp.swapaxes(params["embed"], 0, 1)
     return params
+
+
+def _inverse_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))
 
 
 def _expert_stack(key, cfg: ModelConfig, shape, fan_in, dtype, quant: str):
@@ -238,8 +296,19 @@ def init_cache(
     the attention matmuls. Presence of "ks" marks a quantized cache.
     """
     heads, k_dim, v_dim = cfg.kv_layout
-    shape = (cfg.n_layers, batch, heads, max_seq, k_dim)
+    shape = (cfg.n_kv_layers, batch, heads, max_seq, k_dim)
     kw = {"device": device} if device is not None else {}
+    if cfg.ssm is not None:
+        if kv_dtype:
+            raise NotImplementedError(
+                f"int8 KV beside state-space layers ({cfg.attn_kind} + ssm) "
+                "is not wired: the cache is stored in the model dtype"
+            )
+        return {
+            "k": jnp.zeros(shape, dtype, **kw),
+            "v": jnp.zeros(shape, dtype, **kw),
+            **init_recurrent_state(cfg, batch, dtype, **kw),
+        }
     if cfg.latent is not None:
         # "k": the shared rotated key (zero-padded to whole lanes), "v":
         # the compressed vector (values, and the keys' unrotated part).
@@ -260,6 +329,27 @@ def init_cache(
     return {
         "k": jnp.zeros(shape, dtype, **kw),
         "v": jnp.zeros(shape, dtype, **kw),
+    }
+
+
+# The leaves of a cache or a pool that are a recurrent state a row, not
+# keys and values a token.
+STATE_LEAVES = ("ssm", "conv")
+
+
+def init_recurrent_state(cfg: ModelConfig, rows: int, dtype, **kw) -> Cache:
+    """What ``rows`` sequences keep of the state-space layers: "ssm", the
+    recurrent state, float32 and transposed (ops/ssm.py), and "conv", the
+    conv's last inputs in the model dtype. Zeros are a sequence's start."""
+    sp = cfg.ssm
+    n_ssm = cfg.mixer_counts[1]
+    return {
+        "ssm": jnp.zeros(
+            (n_ssm, rows, sp.state_dim, sp.inner_dim), jnp.float32, **kw
+        ),
+        "conv": jnp.zeros(
+            (n_ssm, rows, sp.conv_width - 1, sp.conv_dim), dtype, **kw
+        ),
     }
 
 
@@ -348,8 +438,16 @@ def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    pad = cfg.kv_layout[1] - cfg.head_dim
+    if pad:
+        # cached zero-padded to whole lanes (``kv_layout``): zeros add
+        # nothing to a score, and the output's are cut off again
+        q, k, v = (
+            jnp.pad(t, ((0, 0), (0, 0), (0, 0), (0, pad))) for t in (q, k, v)
+        )
     return q, k, v
 
 
@@ -358,6 +456,8 @@ def _rope_tables(cfg: ModelConfig, positions):
     plain or llama-3 frequencies, or latent attention's ``rope_dim`` under
     YaRN."""
     la = cfg.latent
+    if not cfg.rope:
+        return None, None
     if la is None:
         return rope_angles(
             positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
@@ -429,6 +529,120 @@ def _latent_cache_rows(cfg: ModelConfig, c_kv, k_r):
     return k[:, :, None, :], c_kv[:, :, None, :]
 
 
+def _ssm_inputs(sp_w, cfg: ModelConfig, h, window, valid, mm):
+    """A state-space layer's projections and conv over a span ``h``
+    [B, W, dim]: (gate z, x [B, W, H, P], B and C [B, W, N], dt
+    [B, W, H], all float32 but z; the conv's inputs in order, window
+    first). ``valid`` [B, W] marks the positions that count: the others'
+    conv inputs are zeros and their dt is 0, so the state passes over
+    them (pads to the left of a row's first token)."""
+    sp = cfg.ssm
+    if sp.n_groups != 1:
+        raise NotImplementedError("one group of B and C for all heads")
+    B_, W = h.shape[:2]
+    zxd = mm(h, sp_w["w_in"])
+    z = zxd[..., : sp.inner_dim]
+    raw = zxd[..., sp.inner_dim : sp.inner_dim + sp.conv_dim]
+    dt = jax.nn.softplus(
+        zxd[..., sp.inner_dim + sp.conv_dim :].astype(jnp.float32)
+        + sp_w["dt_bias"]
+    )
+    if valid is not None:
+        raw = jnp.where(valid[..., None], raw, 0)
+        dt = jnp.where(valid[..., None], dt, 0.0)
+    with jax.named_scope("ssm.conv"):
+        xbc, seq = ssm_ops.causal_conv(
+            raw, window, sp_w["conv_w"], sp_w["conv_b"]
+        )
+    xbc = xbc.astype(jnp.float32)
+    x = xbc[..., : sp.inner_dim].reshape(B_, W, sp.n_heads, sp.head_dim)
+    b_in = xbc[..., sp.inner_dim : sp.inner_dim + sp.state_dim]
+    c_in = xbc[..., sp.inner_dim + sp.state_dim :]
+    return z, x, b_in, c_in, dt, seq
+
+
+def _ssm_gated(sp_w, cfg: ModelConfig, y, z, dtype):
+    """rmsnorm(y * silu(z)) * w over all inner channels (one group), in
+    the model dtype: what the output projection takes."""
+    with jax.named_scope("ssm.gate_norm"):
+        g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+        return rms_norm(g, sp_w["gate_norm"], cfg.rms_eps, False).astype(dtype)
+
+
+def _ssm_chunked(lp, cfg: ModelConfig, h, window, state, valid, mm):
+    """A state-space mixer over a span in the chunked form, from a dense
+    state [B, N, HP] and conv window: (the mixer's output before its
+    projection, the state after the span's valid positions, the conv's
+    inputs in order). A prefill chunk and an admission's wide delta."""
+    with jax.named_scope("ssm"):
+        z, xs, b_in, c_in, dt, seq = _ssm_inputs(lp, cfg, h, window, valid, mm)
+        with jax.named_scope("ssm.scan"):
+            y, state = ssm_ops.chunked_scan(
+                xs, b_in, c_in, dt, -jnp.exp(lp["A_log"]), lp["d_skip"],
+                state, cfg.ssm.chunk,
+            )
+        return _ssm_gated(lp, cfg, y, z, h.dtype), state, seq
+
+
+def _layer_of(stack: dict, index) -> dict:
+    """Row ``index`` (traced) of every leaf of a stack of layers."""
+    return {k: v[index] for k, v in stack.items()}
+
+
+def _commit_layer(pool, cfg, li, rows, saved, n_keep, use_pallas, interpret):
+    """The pool with state-space layer ``li``'s state and conv window of
+    ``rows`` advanced over the first ``n_keep`` [B] positions of a span
+    whose inputs are ``saved``: the one way a span reaches the state."""
+    sp = cfg.ssm
+    B_, W = saved["dt"].shape[:2]
+    # (named inside: a loop's body is lowered apart from its caller's scopes)
+    with jax.named_scope("ssm.commit"):
+        decay, xs = ssm_ops.commit_terms(
+            saved["x"], saved["dt"], saved["cum"], n_keep
+        )
+        xs = xs.reshape(B_, W, sp.inner_dim)
+        decay = jnp.repeat(decay, sp.head_dim, axis=1)
+        if use_pallas:
+            state = ssm_ops.ssm_span_update(
+                pool["ssm"], li, rows, saved["b"], xs, decay, interpret=interpret
+            )
+        else:
+            state = pool["ssm"].at[li, rows].set(
+                ssm_ops.state_update(pool["ssm"][li, rows], saved["b"], xs, decay)
+            )
+        window = ssm_ops.conv_window(saved["seq"], n_keep, sp.conv_width - 1)
+    return {
+        **pool,
+        "ssm": state,
+        "conv": pool["conv"].at[li, rows].set(window.astype(pool["conv"].dtype)),
+    }
+
+
+def commit_span(
+    cfg: ModelConfig, pool: Cache, n_keep, *, rows=None,
+    use_pallas: bool = False, pallas_interpret: bool = False,
+) -> Cache:
+    """Close a span that ``forward_paged_decode`` left open (``state_keep``
+    None: a verify step, whose acceptance is sampled from the span's own
+    logits): every state-space layer's state and conv window of ``rows``
+    become those after the span's first ``n_keep`` [B] positions, and
+    nothing of a later position stays. Returns the pool without the
+    span's saved inputs."""
+    pool = dict(pool)
+    saved = pool.pop("span")
+    n_keep = n_keep.astype(jnp.int32)
+    if rows is None:
+        rows = jnp.arange(n_keep.shape[0], dtype=jnp.int32)
+
+    def one(li, pool):
+        return _commit_layer(
+            pool, cfg, li, rows, _layer_of(saved, li), n_keep,
+            use_pallas, pallas_interpret,
+        )
+
+    return jax.lax.fori_loop(0, cfg.mixer_counts[1], one, pool)
+
+
 def _attn_out_and_ffn(
     x, attn_out, lp, cfg: ModelConfig, B: int, S: int, psum_axis=None,
     mm=matmul, routed=None,
@@ -458,7 +672,7 @@ def _attn_out_and_ffn(
                 cfg.rms_eps,
                 cfg.norm_scale_plus_one,
             )
-        x = x + out
+        x = _residual(cfg, x, out)
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
@@ -479,7 +693,24 @@ def _attn_out_and_ffn(
             ff = rms_norm(
                 ff, lp["post_ffn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one
             )
-        return x + ff
+        return _residual(cfg, x, ff)
+
+
+def _residual(cfg: ModelConfig, x, out):
+    if cfg.residual_multiplier != 1.0:
+        out = (out.astype(jnp.float32) * cfg.residual_multiplier).astype(
+            out.dtype
+        )
+    return x + out
+
+
+def _embed(params: Params, cfg: ModelConfig, tokens):
+    x = params["embed"][tokens]
+    if cfg.scale_embeddings:
+        x = (x.astype(jnp.float32) * math.sqrt(cfg.dim)).astype(x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
+    return x
 
 
 def _layer_window_start(cfg: ModelConfig, layer_id, base_start, q_pos):
@@ -554,9 +785,7 @@ def forward(
         and (mesh is None or mesh.size == 1)
     )
 
-    x = params["embed"][tokens]
-    if cfg.scale_embeddings:
-        x = (x.astype(jnp.float32) * math.sqrt(cfg.dim)).astype(x.dtype)
+    x = _embed(params, cfg, tokens)
 
     cos, sin = _rope_tables(cfg, positions)
 
@@ -795,6 +1024,16 @@ def forward(
     # rolled for every span (see the module docstring). "layers" names
     # the scan itself: the slicing of each layer's weights out of the
     # stacked arrays has no other owner.
+    if cfg.period > 1:
+        return _forward_periods(
+            params, cfg, x, cache, attn_block, mm, lm_head_last_only,
+            # a chunk position counts iff its slot holds a real token
+            valid=jnp.take_along_axis(
+                kv_valid,
+                jnp.minimum(jnp.broadcast_to(q_slot[..., 0], (B, S)), T - 1),
+                axis=1,
+            ),
+        )
     with jax.named_scope("layers"):
         x, new_cache = jax.lax.scan(
             layer_body,
@@ -802,6 +1041,77 @@ def forward(
             (scanned_layers, layer_ids, cache),
         )
 
+    logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
+    return logits, new_cache
+
+
+def _period_layers(cfg: ModelConfig, params: Params, p):
+    """The layers of period ``p`` (traced) in order, each as (mixer kind,
+    its row in its kind's stacks, the layer's weights): what all layers
+    have from ``params["layers"]``, the mixer's own under the same dict.
+    Every weight is read out of its whole stack by a traced index, one
+    layer at a time: no block of layers is ever sliced out together."""
+    out = []
+    for j in range(cfg.period):
+        kind, idx, count = cfg.mixer_slot(j)
+        row = p * count + idx
+        lp = _layer_of(params["layers"], p * cfg.period + j)
+        lp.update(_layer_of(params["mixers"]["ssm" if kind == "ssm" else "gqa"], row))
+        out.append((kind, row, lp))
+    return out
+
+
+def _forward_periods(
+    params, cfg, x, cache, attn_block, mm, lm_head_last_only, valid
+):
+    """``forward``'s layer scan for a period longer than one layer: one
+    step of the scan is one period, its layers in order. The cache's
+    leaves scan by period ([periods, layers of the kind a period, ...])."""
+    B, S = x.shape[:2]
+    n_periods = cfg.n_layers // cfg.period
+
+    def by_period(v):
+        return v.reshape((n_periods, v.shape[0] // n_periods) + v.shape[1:])
+
+    def period_body(x, scanned):
+        p, cache_p = scanned
+        kv = {k: v for k, v in cache_p.items() if k not in STATE_LEAVES}
+        new_kv, new_ssm, new_conv = [], [], []
+        for kind, row, lp in _period_layers(cfg, params, p):
+            if kind == "ssm":
+                i = len(new_ssm)
+                h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, False)
+                out, state, seq = _ssm_chunked(
+                    lp, cfg, h, cache_p["conv"][i], cache_p["ssm"][i], valid, mm
+                )
+                new_ssm.append(state)
+                new_conv.append(seq[:, S:].astype(cache_p["conv"].dtype))
+                lp = {**lp, "wo": lp["w_out"]}
+            else:
+                i = len(new_kv)
+                with jax.named_scope("attn"):
+                    out, cache_l = attn_block(
+                        x, (lp, row, {k: v[i] for k, v in kv.items()})
+                    )
+                    out = out[..., : cfg.head_dim]
+                new_kv.append(cache_l)
+            x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm)
+        new_cache = {
+            k: jnp.stack([c[k] for c in new_kv]) for k in kv
+        }
+        new_cache["ssm"] = jnp.stack(new_ssm)
+        new_cache["conv"] = jnp.stack(new_conv)
+        return x, new_cache
+
+    with jax.named_scope("layers"):
+        x, new_cache = jax.lax.scan(
+            period_body,
+            x,
+            (jnp.arange(n_periods), {k: by_period(v) for k, v in cache.items()}),
+        )
+    new_cache = {
+        k: v.reshape((-1,) + v.shape[2:]) for k, v in new_cache.items()
+    }
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
     return logits, new_cache
 
@@ -923,6 +1233,8 @@ def _lm_head(params: Params, cfg: ModelConfig, x, lm_head_last_only, mm):
         logits = mm(
             x, params["lm_head"], preferred_element_type=jnp.float32
         )
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap > 0.0:
         logits = _softcap(logits, cfg.logit_softcap)
     return logits
@@ -947,6 +1259,11 @@ def forward_paged_decode(
     write_kv=None,  # (pool, layer_id, {name: [B, Hkv, S, *]}, write_page,
     # write_off) -> pool: the caller's own way of putting a layer's new
     # K/V into their pages (None: the scatter below)
+    state_rows: jnp.ndarray | None = None,  # [B] int32: the row of the
+    # pool's recurrent state each span row owns (None: row b is b's)
+    state_keep: jnp.ndarray | None = None,  # [B] int32: the state after
+    # the span's first ``state_keep`` positions is written back (None:
+    # nothing is; the span stays open in the pool for ``commit_span``)
     use_pallas: bool = False,
     use_pallas_matmul: bool = False,
     pallas_interpret: bool = False,
@@ -973,6 +1290,16 @@ def forward_paged_decode(
     is the widest matmul of the step — updated pool, the routed layers'
     choices: int32 [L, B*S, top_k] expert ids for the caller's routing
     counters, None for a dense FFN).
+
+    Beside state-space layers (``cfg.ssm``) the pool also holds a
+    recurrent state and a conv window a row (``init_recurrent_state``),
+    and a row's K/V pages belong to the attention layers alone. Such a
+    layer reads its state once for the whole span; what it writes back is
+    the state after ``state_keep`` positions, and with ``state_keep``
+    None nothing: the span's inputs ride out in ``pool["span"]`` and the
+    caller closes it with ``commit_span`` once it knows how many
+    positions stand (a verify step after ``accept_spans``), so the state
+    never holds a rejected draft.
 
     In-span causality (S>1, the speculative verify shape) comes from the
     per-query bounds: position j's window ends at its own slot
@@ -1022,9 +1349,7 @@ def forward_paged_decode(
     cos, sin = _rope_tables(cfg, positions)
     latent = cfg.latent is not None
 
-    x = params["embed"][tokens]
-    if cfg.scale_embeddings:
-        x = (x.astype(jnp.float32) * math.sqrt(cfg.dim)).astype(x.dtype)
+    x = _embed(params, cfg, tokens)
 
     flat_page = write_page.reshape(-1)
     flat_off = write_off.reshape(-1)
@@ -1049,10 +1374,15 @@ def forward_paged_decode(
             }
             return write_kv(pool, layer_id, heads_major, write_page, write_off)
         return {
-            name: pool[name]
-            .at[layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]]
-            .set(val)
-            for name, val in new_kv.items()
+            **pool,
+            **{
+                name: pool[name]
+                .at[
+                    layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]
+                ]
+                .set(val)
+                for name, val in new_kv.items()
+            },
         }
 
     def layer_body(carry, scanned):
@@ -1146,8 +1476,8 @@ def forward_paged_decode(
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
         q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
 
-        kf = k.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
-        vf = v.reshape(B * S, cfg.n_kv_heads, cfg.head_dim)
+        kf = k.reshape(B * S, cfg.n_kv_heads, k.shape[-1])
+        vf = v.reshape(B * S, cfg.n_kv_heads, v.shape[-1])
         if quant_kv:
             kq, ks = _quantize_kv(kf)  # [B·S, Hkv, D], [B·S, Hkv, 1]
             vq, vs = _quantize_kv(vf)
@@ -1280,12 +1610,104 @@ def forward_paged_decode(
             )
         return out, pool
 
-    with jax.named_scope("layers"):
-        (x, new_pool), routing = jax.lax.scan(
-            layer_body,
-            (x, pool),
-            (scanned_layers, layer_ids),
+    def ssm_block(x, pool, lp, row, rows):
+        """A state-space layer over the span, on row ``row`` of the state
+        stacks: (the mixer's output before its projection, the pool, the
+        span's inputs where it stays open)."""
+        sp = cfg.ssm
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, False)
+        if state_keep is not None and S > sp.chunk:
+            # A span wider than the scan's chunk whose count is known (an
+            # admission's long delta): the chunked form, as a prefill chunk
+            # runs it; the span form's [S, S] decay matrix grows with S^2.
+            keep = state_keep.astype(jnp.int32)
+            out, state, seq = _ssm_chunked(
+                lp, cfg, h, pool["conv"][row, rows], pool["ssm"][row, rows],
+                jnp.arange(S)[None, :] < keep[:, None], mm,
+            )
+            window = ssm_ops.conv_window(seq, keep, sp.conv_width - 1)
+            pool = {
+                **pool,
+                "ssm": pool["ssm"].at[row, rows].set(state),
+                "conv": pool["conv"].at[row, rows].set(
+                    window.astype(pool["conv"].dtype)
+                ),
+            }
+            return out, pool, None
+        kernels = use_pallas and single_device
+        with jax.named_scope("ssm"):
+            z, xs, b_in, c_in, dt, seq = _ssm_inputs(
+                lp, cfg, h, pool["conv"][row, rows], None, mm
+            )
+            with jax.named_scope("ssm.scan"):
+                if kernels:
+                    ys = ssm_ops.ssm_span_read(
+                        pool["ssm"], row, rows, c_in, interpret=pallas_interpret
+                    )
+                else:
+                    ys = ssm_ops.state_read(pool["ssm"][row, rows], c_in)
+                y, cum = ssm_ops.span_outputs(
+                    xs, b_in, c_in, dt, -jnp.exp(lp["A_log"]), lp["d_skip"],
+                    ys.reshape(B, S, sp.n_heads, sp.head_dim),
+                )
+            saved = {"x": xs, "b": b_in, "dt": dt, "cum": cum, "seq": seq}
+            if state_keep is not None:
+                pool = _commit_layer(
+                    pool, cfg, row, rows, saved, state_keep.astype(jnp.int32),
+                    kernels, pallas_interpret,
+                )
+                saved = None
+            return _ssm_gated(lp, cfg, y, z, x.dtype), pool, saved
+
+    def period_body(carry, p):
+        # A period longer than one layer: its layers in order, the
+        # attention layers through ``attn_block`` on their own row of the
+        # page pool, the state-space layers on theirs of the state.
+        x, pool = carry
+        rows = (
+            jnp.arange(B, dtype=jnp.int32) if state_rows is None else state_rows
         )
+        spans = []
+        for kind, row, lp in _period_layers(cfg, params, p):
+            if kind == "ssm":
+                out, pool, saved = ssm_block(x, pool, lp, row, rows)
+                if saved is not None:
+                    spans.append(saved)
+                lp = {**lp, "wo": lp["w_out"]}
+            else:
+                with jax.named_scope("attn"):
+                    out, pool = attn_block(x, pool, (lp, row))
+                    out = out[..., : cfg.head_dim]
+            x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm)
+        span = (
+            {k: jnp.stack([s[k] for s in spans]) for k in spans[0]}
+            if spans
+            else None
+        )
+        return (x, pool), span
+
+    with jax.named_scope("layers"):
+        if cfg.period > 1:
+            (x, new_pool), span = jax.lax.scan(
+                period_body,
+                (x, pool),
+                jnp.arange(cfg.n_layers // cfg.period),
+            )
+            routing = None
+            if span is not None:
+                new_pool = {
+                    **new_pool,
+                    "span": {
+                        k: v.reshape((-1,) + v.shape[2:])
+                        for k, v in span.items()
+                    },
+                }
+        else:
+            (x, new_pool), routing = jax.lax.scan(
+                layer_body,
+                (x, pool),
+                (scanned_layers, layer_ids),
+            )
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only=False)

@@ -103,6 +103,7 @@ PHASES = (
 DEVICE_SCOPES = (
     "layers", "attn", "attn.latent", "qmm", "mlp", "moe.route",
     "moe.experts", "moe.shared", "head", "sample",
+    "ssm", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.commit",
 )
 
 
@@ -244,6 +245,11 @@ class HotMetrics:
         "qmm_indexed_stacks",
         "moe_imbalance",
         "latent_tokens_read",
+        "prefix_matched_tokens",
+        "prefix_resumed_tokens",
+        "ssm_state_restores",
+        "ssm_snapshots",
+        "ssm_snapshot_bytes",
         "_moe",
         "_m",
         "_phase",
@@ -435,6 +441,35 @@ class HotMetrics:
             "advspec_latent_tokens_read_total",
             help="cached tokens read by paged latent attention "
             "(a row's length, once a verify step)",
+        )
+        # State-space layers (engine/prefix_cache.py): a prefix hit is
+        # usable up to the deepest block that carries a snapshot of the
+        # recurrent state; snapshots are a second evictable resource.
+        self.prefix_matched_tokens = m.counter(
+            "advspec_prefix_matched_tokens_total",
+            help="prompt tokens the radix matched, over admissions of a "
+            "family with a recurrent state",
+        )
+        self.prefix_resumed_tokens = m.counter(
+            "advspec_prefix_resumed_tokens_total",
+            help="of those, the tokens under the state snapshot the "
+            "admission resumed from (the rest was recomputed)",
+        )
+        self.ssm_state_restores = m.counter(
+            "advspec_ssm_state_restores_total",
+            help="admissions that restored a recurrent-state snapshot",
+        )
+        self.ssm_snapshots = {
+            event: m.counter(
+                "advspec_ssm_snapshots_total",
+                help="recurrent-state snapshots hung on prefix blocks",
+                event=event,
+            )
+            for event in ("taken", "evicted")
+        }
+        self.ssm_snapshot_bytes = m.gauge(
+            "advspec_ssm_snapshot_bytes",
+            help="device bytes the prefix blocks' state snapshots hold",
         )
         self._moe: dict = {}
         self._phase: dict = {}

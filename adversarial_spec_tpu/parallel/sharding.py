@@ -59,6 +59,16 @@ _PARAM_RULES: dict[str, P] = {
     "we_gate": P(None, EP, None, None),
     "we_up": P(None, EP, None, None),
     "we_down": P(None, EP, None, None),
+    # State-space mixers (models/config.py StateSpace): whole on every
+    # device; the family is served on one (no mesh is wired for it).
+    "w_in": P(None, None, None),
+    "w_out": P(None, None, None),
+    "conv_w": P(None, None, None),
+    "conv_b": P(None, None),
+    "dt_bias": P(None, None),
+    "A_log": P(None, None),
+    "d_skip": P(None, None),
+    "gate_norm": P(None, None),
 }
 
 

@@ -207,7 +207,7 @@ def _kernel_names() -> set[str]:
     """The explicit ``name=`` of every ``pallas_call`` under ops/."""
     names = set()
     ops = ROOT / "adversarial_spec_tpu" / "ops"
-    for path in ops.glob("pallas_*.py"):
+    for path in ops.glob("*.py"):
         for node in ast.walk(ast.parse(path.read_text())):
             if (
                 isinstance(node, ast.Call)
@@ -235,7 +235,7 @@ def test_every_pallas_call_is_named_after_its_entry_point():
         "matmul_int8", "matmul_int4", "paged_decode_attention",
         "paged_decode_attention_mq", "decode_attention",
         "decode_attention_mq", "matmul_int8_grouped",
-        "paged_latent_attention_mq",
+        "paged_latent_attention_mq", "ssm_span_read", "ssm_span_update",
     }
 
 
@@ -285,6 +285,8 @@ MODEL_SCOPES = {
     "mistral": DENSE_SCOPES,
     "mistral4": DENSE_SCOPES
     | {"attn.latent", "moe.route", "moe.experts", "moe.shared"},
+    # (ssm.conv and ssm.gate_norm hold no matmul: elementwise work alone)
+    "granitemoehybrid": {"attn", "mlp", "head", "ssm", "ssm.scan"},
 }
 
 

@@ -517,3 +517,30 @@ def test_latent_admission_prefill_reads_the_stacks_through_the_kernel(chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3 + 8
     one_layer_of_experts = 32 * 3 * M4.dim * M4.experts.expert_dim
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_of_experts
+
+
+@pytest.mark.parametrize("span,rows", [(GAMMA + 1, B), (64, 1), (256, 1)])
+def test_state_space_span_kernels_compile(chip, span, rows):
+    """The two kernels that touch the recurrent state, at granite-4.0-h-micro's
+    shapes: a verify step's span over four rows, and an admission's delta over
+    one, read out of and written into the whole [36, rows, 128, 4096] float32
+    stack by prefetched layer and row indices; the update is in place (no
+    temporary of the stack's size)."""
+    from adversarial_spec_tpu.ops import ssm
+
+    cfg = get_config("granitemoehybrid", "h-micro")
+    sp = cfg.ssm
+    stack = _shape(chip, (cfg.mixer_counts[1], B, sp.state_dim, sp.inner_dim), jnp.float32)
+    idx = [_shape(chip, (), jnp.int32), _shape(chip, (rows,), jnp.int32)]
+    bc = _shape(chip, (rows, span, sp.state_dim), jnp.float32)
+    assert "tpu_custom_call" in _compiled_text(ssm.ssm_span_read, stack, *idx, bc)
+    compiled = (
+        jax.jit(ssm.ssm_span_update.__wrapped__, donate_argnums=0)
+        .lower(
+            stack, *idx, bc, _shape(chip, (rows, span, sp.inner_dim), jnp.float32),
+            _shape(chip, (rows, sp.inner_dim), jnp.float32),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
