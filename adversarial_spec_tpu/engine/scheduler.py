@@ -17,19 +17,24 @@ while sequences of different lengths join and leave it —
 Drive loop (``_drive``; engine/interleave.py holds its telemetry): one
 loop, whose iteration admits, dispatches one step (fused when an
 admission's prompt chunk can ride the residents' step) and retires it,
-and never calls a blanket ``jax.block_until_ready``. A speculative step
-(the default) is retired one step deep: the host fetches the verify
-step's per-row accepted counts before it can size the next one. A plain
-decode step goes into a double buffer: the host applies step N-1's
-fetched ``active`` flags (async device→host copy) while step N runs,
-overlapping queue admission, prefix-cache radix lookups, page
-allocation, and result collection with device compute. Sanctioned sync
+and never calls a blanket ``jax.block_until_ready``. Both branches run
+two steps deep (``_PIPELINE_DEPTH``): step N is enqueued before step
+N-1's results are fetched, so queue admission, prefix-cache radix
+lookups, page allocation, stream delivery and result collection overlap
+device compute. A speculative step (the default) goes into the pipe of
+verify steps: its pages are covered for the spans in flight, and its
+per-row accepted counts and emitted tokens (one stacked array) are
+fetched, applied and streamed while its successor runs; the iteration
+runs one deep when no row is certain to need another step or the second
+span finds no pages. A plain decode step goes into a double buffer: the
+host applies step N-1's fetched ``active`` flags (async device→host
+copy) while step N runs. Sanctioned sync
 points, and ONLY these (enforced by graftlint's GL-SYNC rule, which
 catches implicit syncs — np.asarray/.item()/int()/truthiness on device
 values — as well as explicit block_until_ready;
 docs/static_analysis.md): admission handoff (``_finish_admission``),
-the speculative counts fetch, the double buffer's depth bound, slot
-completion (token fetch), fault decisions, and timeout expiry.
+the speculative counts fetch, the double buffer's depth bound, a plain
+row's completion (token fetch), fault decisions, and timeout expiry.
 
 Inactive-slot safety: physical page 0 is a reserved TRASH page no
 sequence owns. Allocator ids are shifted +1, the -1 "unmapped" sentinel
@@ -62,6 +67,7 @@ exercised directly in tests/test_scheduler.py.
 
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -116,9 +122,11 @@ TRASH_PAGE = 0
 # PREFILL_CHUNK (1024): smaller chunks mean decode chunks slot in between
 # more often while a newcomer's prompt streams in.
 ADMISSION_CHUNK = 512
-# Steps the plain (non-speculative) branch of the drive loop keeps in
-# flight: 2 is the double buffer — deeper would only delay fault/EOS
-# detection by more chunks for no extra overlap.
+# Steps the drive loop keeps in flight, on both of its branches: 2 is the
+# double buffer (step n+1 is enqueued before step n's flags or counts are
+# fetched, so the host's work of an iteration rides under a step on the
+# device) — deeper would only delay fault/EOS detection by more steps for
+# no extra overlap.
 _PIPELINE_DEPTH = 2
 
 
@@ -197,6 +205,17 @@ class _Admission:
     @property
     def remaining(self) -> int:
         return self.prefill_end - self.pos
+
+
+@dataclass
+class _SpecStep:
+    """A verify program in flight: what its retirement needs."""
+
+    counts: object  # the step's stacked counts, still on the device
+    slots: tuple  # (slot, ownership generation) of the rows live at dispatch
+    rider: _Admission | None  # the admission whose prompt chunk rode it
+    chunk_len: int
+    ahead: bool  # enqueued while another verify step was in flight
 
 
 @dataclass
@@ -498,11 +517,14 @@ def fused_prefill_decode_chunk(
     )
 
 
-# Rows a routed model's step appends to ``counts`` [5, B] (each a scalar
+# Rows a routed model's step appends to its ``counts`` (each a scalar
 # broadcast over B): pairs / active experts over the emitted tokens'
 # positions, the same over every position the program ran, and the
 # busiest expert's pairs; all summed over layers.
 N_ROUTING_COUNTS = 5
+# Rows of a verify step's ``counts`` before its emitted tokens: n_allowed,
+# n_acc, n_emit, active, cur_len.
+N_STEP_COUNTS = 5
 
 
 def _routing_counts(cfg: ModelConfig, routing, emitted_mask) -> jnp.ndarray:
@@ -581,9 +603,12 @@ def _spec_chunk_impl(
     plain single-token step inside the SAME program, so the compiled
     shape is one per draft width γ.
 
-    Returns the updated row state plus ``counts`` [5, B] (n_allowed,
-    n_acc, n_emit, active, cur_len) — ONE stacked array so the drive
-    loop's sanctioned accept fetch is a single host copy.
+    Returns the updated row state plus ``counts`` [5 + γ+1, B]
+    (n_allowed, n_acc, n_emit, active, cur_len, then the step's emitted
+    tokens, position-major, zeros past ``n_emit``) — ONE stacked array so
+    the drive loop's sanctioned accept fetch is a single host copy, and
+    the host needs no other device array to deliver or finish a row while
+    the next step runs. A routed family's routing rows follow.
     """
     B = cur_tok.shape[0]
     page_size = pool["k"].shape[3]
@@ -717,8 +742,13 @@ def _spec_chunk_impl(
     ctx_len = ctx_len + n_emit
     done = (any_eos | (n_emitted >= max_new)) & active
     active = active & ~done
-    counts = jnp.stack(
-        [n_allowed, n_acc, n_emit, active.astype(jnp.int32), cur_len]
+    counts = jnp.concatenate(
+        [
+            jnp.stack(
+                [n_allowed, n_acc, n_emit, active.astype(jnp.int32), cur_len]
+            ),
+            emitted.T.astype(jnp.int32),
+        ]
     )
     if routing is not None:
         # Routed layers: the step's routing counts ride the same fetch,
@@ -1384,6 +1414,7 @@ class ContinuousBatcher:
                 self.prefix_cache.attach_tiers(
                     self.tiers, kv_fetch=self._fetch_page_kv
                 )
+        self._demotion_warm = False
         self.max_pages_per_seq = -(-(cfg.max_seq_len) // page_size)
         # Fused paged kernel on real TPUs; gather path elsewhere.
         self._use_pallas = jax.default_backend() == "tpu"
@@ -1471,6 +1502,17 @@ class ContinuousBatcher:
         self._cur_len_np = np.ones((B,), np.int64)
         self._row_len_np = np.zeros((B,), np.int64)
         self._max_new_np = np.zeros((B,), np.int64)
+        # Each slot's emitted tokens as the host has them: the first from
+        # the handoff's fetch, the rest from each verify step's counts. A
+        # speculating row is delivered and finished from here, so neither
+        # touches ``out_buf`` (donated to the step in flight).
+        self._out_np = np.zeros((B, cap), np.int32)
+        # The verify steps in flight, oldest first (``_SpecStep``); at
+        # most ``_PIPELINE_DEPTH`` while one is being enqueued.
+        self._pipe: deque[_SpecStep] = deque()
+        # Wall clock up to which the drive loop has booked (or set aside)
+        # its time: a retired step is booked from here to its retirement.
+        self._step_mark = 0.0
         # Per-slot speculation telemetry [steps, drafted, accepted],
         # stamped onto SchedResult at completion/eviction.
         self._slot_spec: list[list[int]] = [[0, 0, 0] for _ in range(B)]
@@ -2357,6 +2399,7 @@ class ContinuousBatcher:
         )
         self._active_np[slot] = row_active
         self._slot_gen[slot] += 1  # new owner: expire in-flight flags
+        self._out_np[slot, 0] = first_np
         if self.speculative:
             self._cur_len_np[slot] = row_len + 1
             self._row_len_np[slot] = row_len
@@ -2387,6 +2430,16 @@ class ContinuousBatcher:
         self._slot_seq[slot] = seq_id
         if self.cfg.ssm is not None:
             self._state_owner[slot] = seq_id
+        if self.tiers is not None and not self._demotion_warm:
+            # The demotion's one-page read is compiled at a batcher's
+            # first handoff, not by its first eviction: that comes when
+            # the pool first fills, in the middle of serving (0.3 s of
+            # compiling inside a measured window: PERF.md section 6,
+            # PR 37). Here and not at the build: the pool is a program's
+            # output from now on, and a fresh array would be another
+            # signature.
+            self._demotion_warm = True
+            self._fetch_page_kv(0, self.page_size)
         self._slot_cached[slot] = adm.matched
         self._slot_trace[slot] = req.trace_id
         self._slot_span[slot] = req.span_id
@@ -2686,7 +2739,12 @@ class ContinuousBatcher:
         mid-execution abort invalidates the donated pool/out_buf), slot
         surgery is impossible — re-raise and let the engine degrade the
         whole group (the pre-isolation behavior).
+
+        The verify steps in flight are retired first (the reads below
+        wait for them anyway): the victim leaves with every step it
+        took booked, and the rows that stay are seen as they are.
         """
+        self._drain_spec_pipe()
         try:
             # graftlint: disable=GL-SYNC -- fault decision point: eviction surgery needs host lengths to pick the victim
             cur_len_np = np.asarray(self.cur_len)
@@ -2862,10 +2920,9 @@ class ContinuousBatcher:
         The slot frees through the same reference-drop surgery fault
         eviction uses — ``_release_slot``, the ONE shared
         implementation — (pages shared with the prefix cache survive;
-        for a speculating row the per-step counts fetch already rolled
-        draft pages back past the accepted prefix via
-        ``PageAllocator.truncate``, so ``free_sequence`` drops exactly
-        the committed coverage), and the freed capacity re-admits
+        for a speculating row ``free_sequence`` drops the committed
+        coverage and whatever draft pages the rollback, which lags by
+        the spans in flight, still holds), and the freed capacity re-admits
         queued work at the next ``_admit``. Before the refs drop, the
         computed KV is SALVAGED: the full pages covering
         prompt + emitted tokens insert into the prefix cache, so a
@@ -2999,18 +3056,27 @@ class ContinuousBatcher:
 
     # -- completion --------------------------------------------------------
 
-    def _finish_slot(self, slot: int) -> None:
-        # Slot completion is a sanctioned sync point: the token fetch
-        # below blocks on the step in flight (the row itself is frozen —
-        # its values read identically from any later state).
+    def _slot_tokens(self, slot: int) -> tuple[int, np.ndarray]:
+        """How many tokens the slot's row has emitted, and the tokens. A
+        speculating row's come from the host's copy (``_out_np``, exact
+        once the row's last step is retired): no device array is read, so
+        nothing waits for the step in flight. A plain row's are fetched:
+        a sanctioned sync point (the row is frozen — its values read
+        identically from any later state)."""
+        if self.speculative:
+            n = int(self._cur_len_np[slot] - self._row_len_np[slot])
+            return n, self._out_np[slot, :n].copy()
         interleave_mod.stats.record_sync()
         obs_mod.record_sync("slot_complete")
-        self._active_np[slot] = False  # invariant: no owner ⇒ not live
-        req = self._slot_req[slot]
         # graftlint: disable=GL-SYNC -- slot completion is a sanctioned sync point: the row is frozen, its count/tokens read identically from any later state
         n = int(self.n_emitted[slot])
         # graftlint: disable=GL-SYNC -- slot completion token fetch (same sanctioned point as the count above)
-        row = np.asarray(self.out_buf[slot, :n])
+        return n, np.asarray(self.out_buf[slot, :n])
+
+    def _finish_slot(self, slot: int) -> None:
+        self._active_np[slot] = False  # invariant: no owner ⇒ not live
+        req = self._slot_req[slot]
+        n, row = self._slot_tokens(slot)
         st = self._slot_spec[slot]
         # Final-tail stream delivery: an EOS/budget-terminated row hands
         # its consumer the last tokens here (a late cancel is moot —
@@ -3392,12 +3458,98 @@ class ContinuousBatcher:
         (genuine pool exhaustion after prefix-cache LRU eviction) or an
         injected ``kv_alloc`` fault fired mid-decode: evict ONLY this
         row (``_evict_slot``) while co-resident rows keep decoding."""
-        # Emitted count comes from the host view (trailing the counts
-        # fetch) — no device sync needed for the count itself.
-        n = int(self._cur_len_np[slot] - self._row_len_np[slot])
-        # graftlint: disable=GL-SYNC -- fault decision point: the victim's partial tokens must be rescued before the slot is freed
-        partial = np.asarray(self.out_buf[slot, :n])
+        # Count and tokens come from the host's views (exact: the caller
+        # has retired every step in flight first).
+        n, partial = self._slot_tokens(slot)
         self._evict_slot(slot, exc, seam, n, partial)
+
+    def _steps_ahead(self, slot: int) -> int:
+        """Verify steps in flight that hold this slot's current owner."""
+        key = (slot, self._slot_gen[slot])
+        return sum(key in step.slots for step in self._pipe)
+
+    def _may_run_ahead(self) -> bool:
+        """Whether the next verify step may be enqueued before the steps
+        in flight are retired: only if some live row is certain to outlive
+        them (budget left at the trailing view over what they can emit,
+        γ+1 a step; EOS aside), so no program is ever enqueued whose rows
+        are all certain to be finished. And not while a queued request or
+        an admission in flight waits on a row that may finish in them:
+        the slot, the pages and the step-token budget it frees are then
+        seen exactly when the one-deep loop would see them."""
+        span = self.gamma + 1
+        certain = may_finish = False
+        for slot in range(self.B):
+            if not self._active_np[slot]:
+                continue
+            left = int(self._max_new_np[slot]) - int(
+                self._cur_len_np[slot] - self._row_len_np[slot]
+            )
+            if left > self._steps_ahead(slot) * span:
+                certain = True
+            else:
+                may_finish = True
+        waiting = bool(self.queue) or self._admission is not None
+        return certain and not (may_finish and waiting)
+
+    def _extend_row(self, slot: int, n_tokens: int) -> None:
+        """Extend the slot's sequence by ``n_tokens`` (none: a no-op)
+        under its row's trace scope: a cache eviction / tier demotion
+        the extend forces stamps with the request that caused the
+        pressure."""
+        if n_tokens > 0:
+            with obs_mod.trace_scope(
+                self._slot_trace[slot], self._slot_span[slot]
+            ):
+                self._extend_evicting(self._slot_seq[slot], n_tokens)
+
+    def _cover_spec_rows(self, live: list[int]) -> np.ndarray | None:
+        """Extend every row of ``live`` to the coverage its next verify
+        step needs; returns each row's draft bound, or None when a row's
+        extend failed with steps in flight: the caller retires them and
+        sizes the step one deep."""
+        span = self.gamma + 1
+        alloc = np.zeros((self.B,), np.int64)
+        for slot in list(live):
+            seq = self._slot_seq[slot]
+            cl = int(self._cur_len_np[slot])
+            remaining = int(self._max_new_np[slot]) - (
+                cl - int(self._row_len_np[slot])
+            )
+            length = self.allocator.length(seq)
+            # ``cl`` trails the device by the steps in flight, each of
+            # which may add a whole span before this one starts.
+            spans = (self._steps_ahead(slot) + 1) * span
+            want = cl + min(spans, max(remaining, 1))
+            try:
+                self._extend_row(slot, want - length)
+            except Exception as e:
+                if self._pipe:
+                    return None
+                fault = e
+                if isinstance(e, OutOfPages):
+                    try:
+                        self._extend_row(slot, cl + 1 - length)
+                        fault = None
+                    except OutOfPages as e1:
+                        fault = e1
+                if fault is not None:
+                    # No page even for the next token, or a bug at the
+                    # alloc seam: isolate to this row, co-residents keep
+                    # decoding.
+                    self._evict_spec_row(slot, fault, "kv_alloc")
+                    live.remove(slot)
+                    continue
+            alloc[slot] = self.allocator.covered_tokens(seq) - 1
+        return alloc
+
+    def _retire_and_collect(self, live: list[int]) -> None:
+        """Bring a step's preparation to where the one-deep loop would
+        stand: every step in flight retired, the rows that finished in
+        them resolved (their pages are free again) and out of ``live``."""
+        self._drain_spec_pipe()
+        self._collect(self._active_np)
+        live[:] = [s for s in live if self._active_np[s]]
 
     def _prepare_spec_step(self, live: list[int]) -> jnp.ndarray:
         """Size page coverage for ONE speculative step over ``live``
@@ -3409,12 +3561,17 @@ class ContinuousBatcher:
 
         - extend each row to ``cur_len + min(γ+1, budget left)`` KV
           slots — the full draft span, through the prefix cache's
-          LRU-evicting extend so cache pages yield to live decode;
-        - under genuine pressure fall back to ``cur_len + 1`` (the next
-          mandatory single-token write), degrading the row to a plain
-          step INSIDE the same compiled program (``n_allowed`` clamps
-          to 0); if even that page cannot be found, evict the row with
-          a classified OOM (transient → one requeue);
+          LRU-evicting extend so cache pages yield to live decode. A
+          row with a step in flight is extended by a span more for each:
+          the host's ``cur_len`` is then the view before that step,
+          which may add up to γ+1 before this one writes its own;
+        - under genuine pressure the steps in flight are retired first
+          (the iteration runs one deep: their rollback returns the
+          second span's pages), then fall back to ``cur_len + 1`` (the
+          next mandatory single-token write), degrading the row to a
+          plain step INSIDE the same compiled program (``n_allowed``
+          clamps to 0); if even that page cannot be found, evict the row
+          with a classified OOM (transient → one requeue);
         - the device receives ``covered_tokens - 1`` as its draft
           bound: the −1 reserves the slot the step's LAST emitted token
           (bonus or rejection draw) will need for its own KV write next
@@ -3422,50 +3579,34 @@ class ContinuousBatcher:
           ``_apply_spec_counts`` NEVER has to allocate — rollback is
           the only page operation after a verify, and it cannot fail.
 
+        An injected ``kv_alloc`` fault (the seam fires once a live row)
+        evicts ONLY its row, after the steps in flight are retired, so
+        the victim keeps every token the device had made for it.
+
         The device page table is re-pushed from the allocator's
         authoritative host tables every step: draft pages released by
         one row's rollback may have been re-acquired by another row
         since the last push, so tail entries can go stale across steps
         (never within one — writes/reads are bounded by ``alloc_len``).
         """
-        span = self.gamma + 1
-        alloc = np.zeros((self.B,), np.int64)
-        for slot in list(live):
-            seq = self._slot_seq[slot]
-            cl = int(self._cur_len_np[slot])
-            remaining = int(self._max_new_np[slot]) - (
-                cl - int(self._row_len_np[slot])
-            )
-            length = self.allocator.length(seq)
-            want = cl + min(span, max(remaining, 1))
+        faulted = []
+        for slot in live:
             try:
                 injector.fire("kv_alloc", slot)
-                if want > length:
-                    # This row's trace scope: a cache eviction / tier
-                    # demotion its extend forces stamps with the
-                    # request that caused the pressure.
-                    with obs_mod.trace_scope(
-                        self._slot_trace[slot], self._slot_span[slot]
-                    ):
-                        self._extend_evicting(seq, want - length)
-            except OutOfPages:
-                try:
-                    if cl + 1 > length:
-                        with obs_mod.trace_scope(
-                            self._slot_trace[slot], self._slot_span[slot]
-                        ):
-                            self._extend_evicting(seq, cl + 1 - length)
-                except OutOfPages as e:
+            except Exception as e:
+                # Injected fault at the alloc seam: isolate to this row,
+                # co-residents keep decoding.
+                faulted.append((slot, e))
+        if faulted:
+            self._retire_and_collect(live)
+            for slot, e in faulted:
+                if slot in live:
                     self._evict_spec_row(slot, e, "kv_alloc")
                     live.remove(slot)
-                    continue
-            except Exception as e:
-                # Injected/bug fault at the alloc seam: isolate to this
-                # row, co-residents keep decoding.
-                self._evict_spec_row(slot, e, "kv_alloc")
-                live.remove(slot)
-                continue
-            alloc[slot] = self.allocator.covered_tokens(seq) - 1
+        alloc = self._cover_spec_rows(live)
+        if alloc is None:
+            self._retire_and_collect(live)
+            alloc = self._cover_spec_rows(live)
         tables = np.zeros((self.B, self.max_pages_per_seq), np.int32)
         for slot in live:
             t = self.allocator.table(self._slot_seq[slot])
@@ -3603,18 +3744,31 @@ class ContinuousBatcher:
         return counts
 
     def _apply_spec_counts(
-        self, counts_np: np.ndarray, live_slots: tuple
+        self, counts_np: np.ndarray, step: _SpecStep
     ) -> None:
         """Apply one fetched spec step's per-row counts to the host
-        state: advance the trailing cur_len/active views, ROLL BACK
-        draft pages past each row's accepted prefix
-        (``PageAllocator.truncate`` — the pages the step reserved but
-        the rejection sampler didn't commit), and record telemetry.
+        state: advance the trailing cur_len/active views, keep the
+        step's tokens (``_out_np``), ROLL BACK draft pages past each
+        row's accepted prefix (``PageAllocator.truncate`` — the pages
+        the step reserved but the rejection sampler didn't commit), and
+        record telemetry.
+
+        The rollback lags with the view: a page is released only when no
+        step in flight can commit a token onto it. With a successor in
+        flight the row keeps γ+1 slots over its new length for each (the
+        successor starts at ``new_cl`` and writes at most γ+1 slots from
+        ``new_cl - 1``, the last of them reserved); with the pipe empty
+        it is truncated to ``new_cl`` as ever.
+
         Rows whose ownership generation changed since dispatch are
         skipped — the multi-token analog of ``_fetch_entry``'s guard (a
         freed-and-readmitted slot must not have the old step's counts
-        corrupt its new owner's bookkeeping)."""
-        for slot, gen in live_slots:
+        corrupt its new owner's bookkeeping). So is a row that had
+        already finished when the step ran (live in the trailing view at
+        the enqueue, inactive on the device: it emitted nothing): that
+        is no speculative step of its."""
+        span = self.gamma + 1
+        for slot, gen in step.slots:
             if gen != self._slot_gen[slot] or self._slot_seq[slot] is None:
                 continue
             n_allowed = int(counts_np[0, slot])
@@ -3622,22 +3776,32 @@ class ContinuousBatcher:
             n_emit = int(counts_np[2, slot])
             act = bool(counts_np[3, slot])
             new_cl = int(counts_np[4, slot])
+            if not n_emit:
+                self._active_np[slot] = False
+                continue
+            n0 = int(self._cur_len_np[slot] - self._row_len_np[slot])
+            self._out_np[slot, n0 : n0 + n_emit] = counts_np[
+                N_STEP_COUNTS : N_STEP_COUNTS + n_emit, slot
+            ]
             seq = self._slot_seq[slot]
             length = self.allocator.length(seq)
+            keep = new_cl + self._steps_ahead(slot) * span
             released = 0
             if new_cl > length:
                 # Fully accepted span: a pure length bump within the
                 # pages already held (the draft bound's −1 reserve
                 # guarantees coverage) — never allocates, cannot fail.
                 self.allocator.extend(seq, new_cl - length)
-            else:
-                released = len(self.allocator.truncate(seq, new_cl))
+            elif keep < length:
+                released = len(self.allocator.truncate(seq, keep))
             self._cur_len_np[slot] = new_cl
             st = self._slot_spec[slot]
             st[0] += 1
             st[1] += n_allowed
             st[2] += n_acc
-            spec_mod.stats.record_step(n_allowed, n_acc, n_emit)
+            spec_mod.stats.record_step(
+                n_allowed, n_acc, n_emit, pipelined=step.ahead
+            )
             if released:
                 spec_mod.stats.record_rollback(released)
             if obs_mod.config().enabled:
@@ -3658,10 +3822,11 @@ class ContinuousBatcher:
             if self.cfg.latent is not None and obs_mod.config().enabled:
                 # the row's cached tokens, read once this step
                 obs_mod.hot.latent_tokens_read.inc(new_cl)
-        if counts_np.shape[0] > 5 and obs_mod.config().enabled:
+        n_rows = N_STEP_COUNTS + span
+        if counts_np.shape[0] > n_rows and obs_mod.config().enabled:
             obs_mod.hot.record_routing(
                 "decode",
-                counts_np[5:, 0],
+                counts_np[n_rows:, 0],
                 self.cfg.n_layers,
                 self.cfg.experts.n_held,
             )
@@ -3708,63 +3873,117 @@ class ContinuousBatcher:
 
     def _dispatch_step(self, live: list, alloc_len, adm, chunk_len: int):
         """Enqueue one step for the rows in ``live``; ``adm`` is the
-        admission whose ``chunk_len`` prompt tokens ride it, or None.
-        Returns the verify step's counts ref and the (slot, generation)
-        pairs it was dispatched for — ``(None, ())`` on the plain
-        branch, whose flags travel through the double buffer."""
-        if self.speculative:
-            slots = tuple((s, self._slot_gen[s]) for s in live)
-            return self._dispatch_spec(alloc_len, adm, chunk_len), slots
-        if adm is not None:
-            self._dispatch_fused(adm, chunk_len)
-        else:
-            self._dispatch_decode()
-        return None, ()
+        admission whose ``chunk_len`` prompt tokens ride it, or None. A
+        verify step joins the pipe (``_SpecStep``: its counts ref and
+        the (slot, generation) pairs it was dispatched for); the plain
+        branch's flags travel through its double buffer."""
+        if not self.speculative:
+            if adm is not None:
+                self._dispatch_fused(adm, chunk_len)
+            else:
+                self._dispatch_decode()
+            return
+        slots = tuple((s, self._slot_gen[s]) for s in live)
+        counts = self._dispatch_spec(alloc_len, adm, chunk_len)
+        try:
+            # Start the copy at the enqueue: the fetch, an iteration
+            # later when a successor runs ahead, should find it landed.
+            counts.copy_to_host_async()
+        except Exception:
+            pass  # optional fast path only
+        self._pipe.append(
+            _SpecStep(counts, slots, adm, chunk_len, ahead=bool(self._pipe))
+        )
 
-    def _retire_spec_step(self, spec_counts, spec_slots: tuple) -> tuple:
-        """Counts fetch → apply → stream for the verify step just
-        dispatched; returns the step's (depth, sync reason)."""
-        counts_np = None
+    def _retire_spec_step(self) -> None:
+        """Counts fetch → apply → stream for the OLDEST verify step in
+        flight, and its wall clock booked. While a successor runs on the
+        device all of this rides under it; the fetch waits only for what
+        is left of the retired step itself."""
+        import time
+
+        step = self._pipe.popleft()
+        counts_ref, counts_np = step.counts, None
         with obs_mod.phase("drive.fetch"):
             try:
-                # Start the copy before the blocking fetch — marginal,
-                # but free.
-                spec_counts.copy_to_host_async()
-            except Exception:
-                pass  # optional fast path only
-            try:
-                # The spec path's ONE sanctioned per-step sync: the host
-                # cannot size the next step's page coverage, roll
-                # rejected drafts back, or advance per-row flags without
-                # the accepted counts. A [5, B] int fetch — the γ+1
-                # tokens the step can emit amortize it.
-                # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and size the next step's coverage (the one sanctioned speculative sync)
-                counts_np = np.asarray(spec_counts)
+                # The spec path's ONE sanctioned sync a step: the host
+                # rolls rejected drafts back, advances its trailing
+                # views and sees completion from the accepted counts. A
+                # [5 + γ+1, B] int fetch — the γ+1 tokens the step can
+                # emit amortize it, and ride it.
+                # graftlint: disable=GL-SYNC -- spec accept fetch: the host must know each row's accepted length to roll draft pages back and advance its trailing views (the one sanctioned speculative sync)
+                counts_np = np.asarray(counts_ref)
             except Exception as e:
                 # An async device fault surfaces at the fetch: same
-                # eviction surgery as dispatch-time.
+                # eviction surgery as dispatch-time. The counts of the
+                # steps behind it are lost with it.
+                self._pipe.clear()
                 self._handle_decode_fault(e)
+                self._resync_spec_views()
         interleave_mod.stats.record_sync()
         obs_mod.record_sync("spec_counts")
         if counts_np is not None:
             with obs_mod.phase("drive.apply"):
-                self._apply_spec_counts(counts_np, spec_slots)
-            if self._stream_armed(s for s, _ in spec_slots):
-                # Stream delivery at the same sanctioned sync: the
-                # counts fetch above already blocked on this step, so
-                # the token fetch adds no new sync point (out_buf is the
-                # step's live output here — its donation happens at the
-                # NEXT dispatch). Emitted counts come from the host
-                # views _apply_spec_counts just advanced.
+                self._apply_spec_counts(counts_np, step)
+            if self._stream_armed(s for s, _ in step.slots):
+                # Stream delivery at the same sanctioned sync, from the
+                # tokens that rode the counts: no device array is read.
+                # Emitted counts come from the host views
+                # _apply_spec_counts just advanced; the consumer gets a
+                # copy (the host's buffer is overwritten by the slot's
+                # next owner).
                 with obs_mod.phase("drive.stream"):
-                    # graftlint: disable=GL-SYNC -- stream token fetch at the sanctioned spec_counts sync (the counts fetch above already blocked on this step)
-                    out_np = np.asarray(self.out_buf)
                     self._stream_entry(
                         self._cur_len_np - self._row_len_np,
-                        out_np,
-                        spec_slots,
+                        self._out_np.copy(),
+                        step.slots,
                     )
-        return 1, "spec_counts"
+        now = time.monotonic()
+        dt, self._step_mark = now - self._step_mark, now
+        # Rows the step still belongs to (a row that finished, was
+        # cancelled or evicted since the enqueue has its result out).
+        rows = [s for s, gen in step.slots if gen == self._slot_gen[s]]
+        if rows:
+            self._account_step(
+                dt,
+                rows,
+                self.gamma + 1,
+                step.rider,
+                step.chunk_len,
+                len(self._pipe) + 1,
+                "spec_counts",
+            )
+
+    def _drain_spec_pipe(self) -> None:
+        """Retire every verify step in flight: the host's trailing views
+        are then the device's (one-deep semantics from here)."""
+        while self._pipe:
+            self._retire_spec_step()
+
+    def _resync_spec_views(self) -> None:
+        """After a fault at the counts fetch that the device state
+        survived: the counts of the steps in flight are lost, so the
+        host's trailing views (lengths, flags, tokens, coverage) are
+        read back from the device."""
+        # graftlint: disable=GL-SYNC -- fault decision point: the lost counts' views are read back whole
+        cur_len, active = np.asarray(self.cur_len), np.asarray(self.active)
+        # graftlint: disable=GL-SYNC -- fault decision point (the tokens of the lost steps, same sanctioned sync)
+        out = np.asarray(self.out_buf)
+        for slot, seq in enumerate(self._slot_seq):
+            if seq is None:
+                continue
+            new_cl = int(cur_len[slot])
+            n = new_cl - int(self._row_len_np[slot])
+            self._out_np[slot, :n] = out[slot, :n]
+            self._cur_len_np[slot] = new_cl
+            self._active_np[slot] = bool(active[slot])
+            # Within the pages held: every lost step's span was covered
+            # before it was enqueued.
+            length = self.allocator.length(seq)
+            if new_cl > length:
+                self.allocator.extend(seq, new_cl - length)
+            else:
+                self.allocator.truncate(seq, new_cl)
 
     def _retire_plain_step(self, inflight, entry: tuple) -> tuple:
         """Append the dispatched step's ``entry`` to the double buffer
@@ -3846,29 +4065,54 @@ class ContinuousBatcher:
 
     def _drive(self, timeout_s: float) -> None:
         """Admit → dispatch (fused when an admission and live rows
-        coexist) → retire → collect; the host's own work (queue
-        admission, radix lookups, page allocation, collection) overlaps
-        the step in flight. Host syncs happen only at admission handoff,
-        slot completion, fault decisions, timeout expiry and the
-        speculative branch's counts fetch — never as a blanket
-        per-chunk barrier."""
+        coexist) → retire → collect, two steps deep on both branches:
+        step n+1 is enqueued before step n's flags (plain) or counts
+        (speculative) are fetched, so the host's own work (queue
+        admission, radix lookups, page allocation, the counts' apply,
+        stream delivery, collection) overlaps the step in flight. Host
+        syncs happen only at admission handoff, fault decisions, timeout
+        expiry, a plain row's completion and the one fetch a step —
+        never as a blanket per-chunk barrier.
+
+        The speculative branch: the step program holds a row's length,
+        budget, draft source and tokens on the device and takes from the
+        host only the page table, a coverage bound and a key, so a verify
+        step needs nothing of its predecessor's counts to be ENQUEUED —
+        only its pages covered for two spans (``_prepare_spec_step``).
+        The counts are what the host rolls draft pages back by, advances
+        its trailing views with and sees completion from; all of that
+        happens while the successor runs (``_retire_spec_step``), and the
+        rollback lags by the spans in flight (``_apply_spec_counts``). A
+        step is enqueued ahead only when it is certain to have work
+        (``_may_run_ahead``); otherwise, and under page pressure, the
+        iteration retires first and runs one deep."""
         import time
-        from collections import deque
 
         deadline = time.monotonic() + timeout_s if timeout_s > 0 else None
         inflight: deque[tuple] = deque()  # (active_ref, live_slots)
+        self._pipe.clear()
+        self._step_mark = time.monotonic()
         while self._has_work():
             # The whole body is ONE phase; every call it makes lies in
             # exactly one drive.* phase below, and what is left (the
             # ``live`` lists, the telemetry itself) is the remainder.
             with obs_mod.phase("drive.iteration"):
                 if deadline is not None and time.monotonic() > deadline:
-                    # Entries in flight resolve through the same lazy
-                    # arrays _collect reads; their per-step flags are
-                    # moot now.
+                    # Plain entries in flight resolve through the same
+                    # lazy arrays _collect reads; their per-step flags
+                    # are moot now. Verify steps are retired: resident
+                    # rows finish with what the steps in flight emitted.
                     inflight.clear()
+                    self._drain_spec_pipe()
                     self._expire_timeout()
                     break
+                if self._pipe and not self._may_run_ahead():
+                    # No row is certain to need another step (or a
+                    # waiting request may get a finishing row's place):
+                    # this iteration runs one deep.
+                    self._drain_spec_pipe()
+                    with obs_mod.phase("drive.collect"):
+                        self._collect(self._active_np)
                 # Per-request watchdog: evict over-deadline work before
                 # admitting/dispatching more (host clock math; evictions
                 # ride the fault surgery's existing sanctioned fetches).
@@ -3877,21 +4121,18 @@ class ContinuousBatcher:
                 self._admit()
                 adm = self._admission
                 live = [s for s in range(self.B) if self._active_np[s]]
-                t0 = time.monotonic()
+                # Collection and admission are not decode time: the clock
+                # a retired step is booked from starts here.
+                self._step_mark = t0 = time.monotonic()
                 rider = None  # the admission whose chunk rode the step
                 dispatched = False
                 # Speculation: each iteration's "decode work" becomes one
-                # γ-draft + verify program per live row, and the host MUST
-                # learn each row's accepted length before it can dispatch
-                # the next step (draft pages roll back, coverage re-sizes,
-                # flags advance per-row) — so the spec path runs one step
-                # deep with a sanctioned counts fetch per iteration instead
-                # of the double buffer; the γ+1 tokens a step can emit are
-                # what buy that sync back.
+                # γ-draft + verify program per live row, retired through
+                # the pipe (``_retire_spec_step``) where the plain branch
+                # retires through the double buffer.
                 spec = self.speculative
                 width = (self.gamma + 1) if spec else self.chunk
-                alloc_len = spec_counts = None
-                spec_slots: tuple = ()
+                alloc_len = None
                 # Fuse only the LEADING prefill chunks (strictly more work
                 # left after this chunk): the FINAL chunk runs standalone so
                 # the handoff happens before this iteration's decode chunk
@@ -3929,7 +4170,7 @@ class ContinuousBatcher:
                         with obs_mod.trace_scope(
                             adm.req.trace_id, adm.req.span_id
                         ), obs_mod.phase("drive.dispatch"):
-                            spec_counts, spec_slots = self._dispatch_step(
+                            self._dispatch_step(
                                 live, alloc_len, adm, chunk_len
                             )
                         rider, dispatched = adm, True
@@ -3953,6 +4194,11 @@ class ContinuousBatcher:
                         # inside _advance_admission — which also performs
                         # the handoff when the prefill completes, so the
                         # new row is live for the decode dispatch below.
+                        if any(step.rider is adm for step in self._pipe):
+                            # A fused step's prefill share is booked to
+                            # its admission when the step retires, and
+                            # the handoff reads the admission's books.
+                            self._drain_spec_pipe()
                         try:
                             with obs_mod.trace_scope(
                                 adm.req.trace_id, adm.req.span_id
@@ -3974,50 +4220,49 @@ class ContinuousBatcher:
                         # are already in the stalled-prefill bucket — the
                         # decode dt below must not re-count them (their sum
                         # is what the engine subtracts from total wall).
-                        t0 = time.monotonic()
+                        self._step_mark = t0 = time.monotonic()
                     if live:
                         try:
                             with obs_mod.phase("drive.dispatch"):
-                                spec_counts, spec_slots = self._dispatch_step(
-                                    live, alloc_len, None, 0
-                                )
+                                self._dispatch_step(live, alloc_len, None, 0)
                             dispatched = True
                         except Exception as e:
                             self._handle_decode_fault(e)
-                if dispatched:
-                    if spec:
-                        depth, step_sync = self._retire_spec_step(
-                            spec_counts, spec_slots
+                if spec:
+                    # The double buffer proper: with the successor
+                    # enqueued, the step before it is fetched, applied
+                    # and streamed while the device runs on.
+                    while len(self._pipe) >= _PIPELINE_DEPTH:
+                        self._retire_spec_step()
+                elif dispatched:
+                    # Streaming consumers ride the double buffer: the
+                    # entry carries the step's emitted counts plus an
+                    # out_buf SNAPSHOT (jnp.copy — out_buf itself is
+                    # donated to the next dispatch, so a raw ref would
+                    # be deleted before the depth-bound fetch; the
+                    # copy is a device-side op that overlaps compute
+                    # and only exists while a consumer is attached).
+                    streaming = self._stream_armed(live)
+                    with obs_mod.phase("drive.dispatch"):
+                        entry = (
+                            self.active,
+                            self.n_emitted if streaming else None,
+                            jnp.copy(self.out_buf) if streaming else None,
+                            tuple((s, self._slot_gen[s]) for s in live),
                         )
-                    else:
-                        # Streaming consumers ride the double buffer: the
-                        # entry carries the step's emitted counts plus an
-                        # out_buf SNAPSHOT (jnp.copy — out_buf itself is
-                        # donated to the next dispatch, so a raw ref would
-                        # be deleted before the depth-bound fetch; the
-                        # copy is a device-side op that overlaps compute
-                        # and only exists while a consumer is attached).
-                        streaming = self._stream_armed(live)
-                        with obs_mod.phase("drive.dispatch"):
-                            entry = (
-                                self.active,
-                                self.n_emitted if streaming else None,
-                                jnp.copy(self.out_buf) if streaming else None,
-                                tuple((s, self._slot_gen[s]) for s in live),
-                            )
-                            for ref in entry[:3]:
-                                if ref is None:
-                                    continue
-                                try:
-                                    # Start the device→host copy now; the
-                                    # fetch one iteration later should find
-                                    # it resolved.
-                                    ref.copy_to_host_async()
-                                except Exception:
-                                    pass  # optional fast path only
-                        depth, step_sync = self._retire_plain_step(
-                            inflight, entry
-                        )
+                        for ref in entry[:3]:
+                            if ref is None:
+                                continue
+                            try:
+                                # Start the device→host copy now; the
+                                # fetch one iteration later should find
+                                # it resolved.
+                                ref.copy_to_host_async()
+                            except Exception:
+                                pass  # optional fast path only
+                    depth, step_sync = self._retire_plain_step(
+                        inflight, entry
+                    )
                     self._account_step(
                         time.monotonic() - t0,
                         live,
@@ -4029,3 +4274,4 @@ class ContinuousBatcher:
                     )
                 with obs_mod.phase("drive.collect"):
                     self._collect(self._active_np)
+        self._pipe.clear()

@@ -95,9 +95,20 @@ class SpecStats(procconfig.StatsBase):
     accepted_tokens: int = 0  # draft positions accepted
     emitted_tokens: int = 0  # tokens emitted by spec steps (incl. bonus)
     rolled_back_pages: int = 0  # draft pages released by rollback
+    # Of ``spec_steps``, those of a program that was enqueued while the
+    # verify step before it was still in flight (the batcher's two-deep
+    # drive loop): the host's work of that iteration rode under a step.
+    pipelined_steps: int = 0
 
-    def record_step(self, drafted: int, accepted: int, emitted: int) -> None:
+    def record_step(
+        self,
+        drafted: int,
+        accepted: int,
+        emitted: int,
+        pipelined: bool = False,
+    ) -> None:
         self.spec_steps += 1
+        self.pipelined_steps += int(pipelined)
         self.drafted_tokens += drafted
         self.accepted_tokens += accepted
         self.emitted_tokens += emitted

@@ -659,6 +659,65 @@ class TestBatcherTier:
         # The host tier strictly reduces re-prefill under pressure.
         assert sum(on_pre) < sum(off_pre)
 
+    def test_the_first_demotion_compiles_nothing(self, tiny_model):
+        """The one-page read an eviction demotes a block through is
+        compiled at a batcher's first handoff (tiers armed), on the pool
+        as the programs return it (with weights committed to a device, as
+        a served model's are), so the first eviction, which comes
+        whenever the pool first fills, compiles nothing in the middle of
+        serving."""
+        from adversarial_spec_tpu.engine.kvcache import _pool_jits
+        from adversarial_spec_tpu.engine.scheduler import (
+            ContinuousBatcher,
+            SchedRequest,
+        )
+
+        params, cfg = tiny_model
+        params = jax.device_put(params, jax.devices()[0])
+        compiles = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, seconds, **kw: compiles.append(event)
+            if event.endswith("backend_compile_duration")
+            else None
+        )
+        kvtier.configure(enabled=True, host_mb=16, store_dir="")
+        kvtier.reset_stats()
+        read = _pool_jits()[1]
+        read.clear_cache()
+        during = []
+        try:
+            # A page size no other test's batcher reads a page of.
+            prefix_mod.configure(enabled=True, max_pages=3)
+            b = ContinuousBatcher(
+                params, cfg, max_batch=2, max_new_cap=8, page_size=32,
+                prefix_cache=True,
+            )
+            fetch = b._fetch_page_kv
+
+            def counted(page, n_tokens):
+                before = len(compiles)
+                out = fetch(page, n_tokens)
+                during.append(len(compiles) - before)
+                return out
+
+            b.prefix_cache._kv_fetch = b._fetch_page_kv = counted
+            doc = list(self.PROMPT) * 2
+            for r in range(3):
+                b.submit(
+                    SchedRequest(
+                        req_id=r,
+                        prompt_ids=doc[: 128 + 32 * (r % 2)] + [7 + r],
+                        max_new_tokens=8,
+                    )
+                )
+                b.run_all()
+            assert kvtier.snapshot()["demoted_blocks"] > 0
+            # the first call is the handoff's; every eviction's after it
+            assert len(during) > 1 and during[0] == 1
+            assert not any(during[1:]), during
+        finally:
+            kvtier.configure(enabled=False)
+
     def test_restart_rehydrates_byte_identical(self, tiny_model, tmp_path):
         params, cfg = tiny_model
         store = str(tmp_path / "kv")

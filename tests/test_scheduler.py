@@ -401,6 +401,99 @@ class TestChunkedPrefillInterleave:
         assert ev["prefill_tokens"] == (64 if fused else 0)
         assert ev["span_id"] == ("span-7" if fused else "")
 
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_a_verify_steps_wall_clock_is_booked_once(
+        self, tiny_model, monkeypatch, depth
+    ):
+        """One deep and two deep: the retired steps' walls do not
+        overlap (they sum to under the drain's wall clock with the
+        prefill beside them), every second booked to decode is on some
+        request's result, and a step that was enqueued behind another
+        says so in its StepEvent (``pipeline_depth`` 2), as many as the
+        counter ``spec.pipelined_steps`` has rows of."""
+        import time
+
+        import adversarial_spec_tpu.engine.scheduler as sched_mod
+        from adversarial_spec_tpu import obs
+        from adversarial_spec_tpu.engine import spec as spec_mod
+
+        monkeypatch.setattr(sched_mod, "_PIPELINE_DEPTH", depth)
+        params, cfg = tiny_model
+        b = ContinuousBatcher(
+            params, cfg, max_batch=2, max_new_cap=24, chunk=8,
+            speculative=True, gamma=3,
+        )
+        prompts = [[5 + i % 7 for i in range(30)], [3 + i % 5 for i in range(600)]]
+        for i, p in enumerate(prompts):
+            b.submit(SchedRequest(req_id=i, prompt_ids=p, max_new_tokens=24))
+        obs.reset_stats()
+        spec_mod.reset_stats()
+        t0 = time.monotonic()
+        results = b.run_all()
+        wall = time.monotonic() - t0
+        assert sum(r.decode_time_s for r in results) == pytest.approx(
+            b.decode_time_s
+        )
+        assert 0 < b.decode_time_s + b.prefill_time_s <= wall
+        steps = [e for e in obs.recorder.events() if e["type"] == "step"
+                 and e["kind"] in ("spec", "fused_spec")]
+        assert {e["kind"] for e in steps} == {"spec", "fused_spec"}
+        snap = obs.metrics.snapshot()["advspec_step_wall_seconds"]
+        assert snap["count"] == len(steps)
+        assert snap["sum"] == pytest.approx(
+            b.decode_time_s + b.overlapped_prefill_s
+        )
+        deep = [e for e in steps if e["pipeline_depth"] == 2]
+        assert {e["pipeline_depth"] for e in steps} <= {1, 2}
+        assert bool(deep) == (depth == 2)
+        pipelined = spec_mod.stats.pipelined_steps
+        assert bool(pipelined) == (depth == 2)
+        assert pipelined <= sum(e["n_live"] for e in deep)
+
+    def test_a_deadline_keeps_what_the_steps_in_flight_emitted(
+        self, tiny_model
+    ):
+        """At the drain's deadline the verify steps in flight are
+        retired before the rows resolve: a result holds every token its
+        consumer was shown and is a prefix of the undisturbed reply."""
+        import time
+
+        params, cfg = tiny_model
+        prompts = [[5 + i % 7 for i in range(30)], [4 + i % 6 for i in range(41)]]
+        seen = {0: [], 1: []}
+
+        def serve(timeout_s, pause):
+            b = ContinuousBatcher(
+                params, cfg, max_batch=2, max_new_cap=48,
+                speculative=True, gamma=3,
+            )
+
+            def consumer(i):
+                def on_tokens(tokens):
+                    seen[i] = tokens.tolist()
+                    time.sleep(pause)
+                    return True
+
+                return on_tokens
+
+            for i, p in enumerate(prompts):
+                b.submit(
+                    SchedRequest(
+                        req_id=i, prompt_ids=p, max_new_tokens=48,
+                        on_tokens=consumer(i),
+                    )
+                )
+            return {r.req_id: r for r in b.run_all(timeout_s)}
+
+        whole = serve(0.0, 0.0)  # also compiles every program
+        assert all(r.n_generated == 48 for r in whole.values())
+        cut = serve(0.6, 0.05)
+        for i, r in cut.items():
+            got = r.tokens[: r.n_generated].tolist()
+            assert 0 < r.n_generated < 48 and r.error is None
+            assert got == whole[i].tokens[: r.n_generated].tolist()
+            assert got == seen[i]
+
     @pytest.mark.parametrize(
         "knob", [{"interleave": False}, {"pipeline_depth": 1}]
     )

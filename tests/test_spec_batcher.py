@@ -16,6 +16,7 @@ Correctness contracts (ISSUE 6):
   reconfigurable without a reimport, validated at the knob.
 """
 
+import functools
 import io
 import json
 import random
@@ -913,3 +914,401 @@ class TestBatcherSpecPallasVerify:
         )
         assert kern == off
         assert kern[0][-1] == eos  # EOS kept, nothing after
+
+
+# -- the drive loop two steps deep (PR 37) -----------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _preset(name):
+    """(params, cfg) of a tiny preset: the dense one of this module, the
+    routed latent one and the hybrid one as their own test modules build
+    them (float32 where the program keeps floating point)."""
+    if name == "dense":
+        cfg = get_config("llama", "tiny")
+        return T.init_params(jax.random.key(0), cfg, dtype=jnp.float32), cfg
+    if name == "routed-latent":
+        from tests.test_moe import program_params
+
+        cfg = get_config(
+            "mistral4", "tiny", experts_held=[2, 4], vocab_rows=384
+        )
+        return program_params(cfg), cfg
+    cfg = get_config("granitemoehybrid", "tiny")
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+        T.init_params(jax.random.key(0), cfg, jnp.bfloat16),
+    )
+    return params, cfg
+
+
+# Five requests over two slots: the third to fifth join mid-run, as a
+# finishing row frees its slot; a 150-token prompt prefills in chunks that
+# ride the resident's verify steps (chunk=8 keeps the step-token budget
+# small enough to split it).
+_DEPTH_PROMPTS = [
+    _repetitive_prompt(40),
+    _repetitive_prompt(23, period=5),
+    _repetitive_prompt(150, period=9),
+    _repetitive_prompt(31, period=4),
+    _repetitive_prompt(18, period=6),
+]
+_DEPTH_BUDGETS = [24, 13, 9, 24, 17]
+_SAMPLED = dict(greedy=False, temperature=0.9, top_k=12, seed=5)
+
+_DEPTH_CASES = {
+    # preset, batcher keywords, chaos rule, cancel a stream at n tokens
+    "dense-greedy-admissions": ("dense", {}, "", 0),
+    "dense-sampled-admissions": ("dense", _SAMPLED, "", 0),
+    "routed-latent-greedy": ("routed-latent", {}, "", 0),
+    "routed-latent-sampled": ("routed-latent", _SAMPLED, "", 0),
+    "hybrid-greedy": ("hybrid", {}, "", 0),
+    "hybrid-sampled": ("hybrid", _SAMPLED, "", 0),
+    "dense-greedy-stream-cancel": ("dense", {}, "", 7),
+    # Sampled, the two requests that fit the slots: what a consumer will
+    # say is not the batcher's to know, so the step after a cancelled
+    # row's last is already enqueued, and a request that waited for the
+    # slot would join a step (and a key) later than one deep.
+    "dense-sampled-stream-cancel": ("dense", dict(n=2, **_SAMPLED), "", 7),
+    "dense-kv_alloc-fault": (
+        "dense", {}, "bug@kv_alloc:after=9:times=1:slot=1", 0,
+    ),
+    "dense-scheduler_chunk-fault": (
+        "dense", _SAMPLED, "bug@scheduler_chunk:after=6:times=1:slot=0", 0,
+    ),
+    # 73 or 74 pages of 4 tokens under two rows of a 128-token bucket and
+    # up to 24 tokens each: every first span fits, not every second.
+    "dense-greedy-small-pool": (
+        "dense", dict(capacity_tokens=292, prefix_cache=False), "", 0,
+    ),
+    "dense-sampled-small-pool": (
+        "dense",
+        dict(capacity_tokens=296, prefix_cache=False, **_SAMPLED),
+        "",
+        0,
+    ),
+}
+
+
+def _serve_at_depth(monkeypatch, depth, preset, kw, chaos, cancel_at):
+    """One drain of the five requests at a pipeline depth: what every
+    request got (tokens, counts, fault, cancellation, every delivery its
+    consumer saw) and the process's speculation counters."""
+    import adversarial_spec_tpu.engine.scheduler as sched_mod
+    from adversarial_spec_tpu.resilience import injector
+
+    monkeypatch.setattr(sched_mod, "_PIPELINE_DEPTH", depth)
+    params, cfg = _preset(preset)
+    kw = dict(kw)
+    n = kw.pop("n", len(_DEPTH_PROMPTS))
+    prompts = [p[:] for p in _DEPTH_PROMPTS[:n]]
+    budgets = list(_DEPTH_BUDGETS[:n])
+    if "capacity_tokens" in kw:
+        # without the 150-token prompt, whose bucket alone is the pool
+        prompts, budgets = prompts[:2] + prompts[3:], budgets[:2] + budgets[3:]
+    spec_mod.reset_stats()
+    b = ContinuousBatcher(
+        params, cfg,
+        **{
+            "max_batch": 2, "max_new_cap": max(budgets), "eos_ids": [],
+            "speculative": True, "gamma": 3, "page_size": 4, "chunk": 8,
+            "capacity_tokens": 2048, **kw,
+        },
+    )
+    seen = {i: [] for i in range(len(prompts))}
+
+    def consumer(i):
+        def on_tokens(tokens):
+            seen[i].append(tokens.tolist())
+            return not (cancel_at and i == 1 and len(tokens) >= cancel_at)
+
+        return on_tokens
+
+    for i, (p, n) in enumerate(zip(prompts, budgets)):
+        b.submit(
+            SchedRequest(
+                req_id=i, prompt_ids=p, max_new_tokens=n,
+                on_tokens=consumer(i) if cancel_at else None,
+            )
+        )
+    if chaos:
+        injector.install(
+            injector.FaultInjector(injector.parse_chaos_spec(chaos))
+        )
+    try:
+        results = b.run_all()
+    finally:
+        injector.reset()
+    b.check_invariants()
+    assert b.allocator.free_pages + (
+        b.prefix_cache.cached_pages if b.prefix_cache is not None else 0
+    ) == b.allocator.n_pages
+    served = {
+        r.req_id: (
+            r.tokens.tolist(), r.n_generated, r.fault_kind, r.cancelled,
+            r.spec_steps, r.spec_drafted, r.spec_accepted, seen[r.req_id],
+        )
+        for r in results
+    }
+    return served, spec_mod.stats.as_dict()
+
+
+class TestTwoDeepDrive:
+    @pytest.mark.parametrize("case", list(_DEPTH_CASES))
+    def test_depth_1_and_2_serve_the_same(self, case, monkeypatch):
+        """The two-deep loop enqueues the programs the one-deep loop
+        enqueues, in its order and with its keys: every request's tokens,
+        its per-step counts, the victim of an injected fault and what it
+        keeps, a cancelled stream and every delivery before it are the
+        same at ``_PIPELINE_DEPTH`` 1 and 2, greedy and sampled."""
+        preset, kw, chaos, cancel_at = _DEPTH_CASES[case]
+        one, stats1 = _serve_at_depth(
+            monkeypatch, 1, preset, kw, chaos, cancel_at
+        )
+        two, stats2 = _serve_at_depth(
+            monkeypatch, 2, preset, kw, chaos, cancel_at
+        )
+        assert sorted(one) == sorted(two) == list(range(len(one)))
+        for rid in one:
+            assert one[rid] == two[rid], f"request {rid}"
+        if chaos:
+            assert sum(1 for r in two.values() if r[2]) == 1
+        if cancel_at:
+            assert two[1][3] and two[1][1] >= cancel_at
+        # The same steps were booked; only the second run rode any of
+        # them under its predecessor.
+        pipelined = stats2.pop("pipelined_steps")
+        assert stats1.pop("pipelined_steps") == 0
+        # (pages go back later while a successor is in flight, so fewer
+        # cross a page's edge twice)
+        assert stats1.pop("rolled_back_pages") > 0
+        assert stats2.pop("rolled_back_pages") > 0
+        assert stats1 == stats2
+        assert 0 < pipelined < stats2["spec_steps"]
+        if "capacity_tokens" in kw:
+            # Counted: with room for every second span the same requests
+            # ride more of their steps.
+            roomy = dict(kw, capacity_tokens=2048)
+            _, stats = _serve_at_depth(monkeypatch, 2, preset, roomy, "", 0)
+            assert stats["spec_steps"] == stats2["spec_steps"]
+            assert pipelined < stats["pipelined_steps"]
+
+    def test_no_page_leaves_a_row_while_a_step_can_commit_on_it(
+        self, tiny_model, monkeypatch
+    ):
+        """The rollback lags with the view. Every token a verify step
+        commits lands on the page its table named when the step was
+        ENQUEUED: that page is still the row's at the step's retirement
+        and was released by nobody in between (so it went to no other
+        row and not to the prefix cache), over a run whose every step
+        rejects drafts, with pages of four tokens, the prefix cache on
+        and requests queueing for two slots."""
+        params, cfg = tiny_model
+        released = []  # every page any sequence let go of, in order
+        tables = {}  # id(step) -> (the table as pushed, len(released))
+        checked = {"tokens": 0, "ahead": 0}
+
+        real_truncate = PageAllocator.truncate
+        real_free = PageAllocator.free_sequence
+
+        def truncate(self, seq_id, n_tokens):
+            pages = real_truncate(self, seq_id, n_tokens)
+            released.extend(pages)
+            return pages
+
+        def free_sequence(self, seq_id):
+            released.extend(self.table(seq_id))
+            real_free(self, seq_id)
+
+        monkeypatch.setattr(PageAllocator, "truncate", truncate)
+        monkeypatch.setattr(PageAllocator, "free_sequence", free_sequence)
+
+        real_dispatch = ContinuousBatcher._dispatch_step
+        real_apply = ContinuousBatcher._apply_spec_counts
+
+        def dispatch(self, live, alloc_len, adm, chunk_len):
+            real_dispatch(self, live, alloc_len, adm, chunk_len)
+            tables[id(self._pipe[-1])] = (
+                np.asarray(self.page_table) - 1, len(released)
+            )
+
+        def apply(self, counts_np, step):
+            pushed, mark = tables.pop(id(step))
+            since = set(released[mark:])
+            for slot, gen in step.slots:
+                if gen != self._slot_gen[slot] or not counts_np[2, slot]:
+                    continue
+                now = self.allocator.table(self._slot_seq[slot])
+                # K/V slots the step committed: its first token's and its
+                # accepted drafts', then the slot held for its last.
+                first = int(self._cur_len_np[slot]) - 1
+                for pos in range(first, int(counts_np[4, slot])):
+                    page = int(pushed[slot, pos // self.page_size])
+                    assert page >= 0, "committed onto the trash page"
+                    assert page == now[pos // self.page_size]
+                    assert page not in since
+                    checked["tokens"] += 1
+                checked["ahead"] += step.ahead
+            real_apply(self, counts_np, step)
+            self.allocator.check_invariants()
+
+        monkeypatch.setattr(ContinuousBatcher, "_dispatch_step", dispatch)
+        monkeypatch.setattr(ContinuousBatcher, "_apply_spec_counts", apply)
+        prompts = [
+            _repetitive_prompt(30 + 7 * i, period=3 + i % 4) for i in range(6)
+        ]
+        b, out, results = _drain(
+            params, cfg, prompts, [32, 20, 32, 9, 27, 32], max_batch=2,
+            speculative=True, gamma=7, page_size=4, capacity_tokens=512,
+        )
+        assert all(r.error is None for r in results)
+        s = spec_mod.stats
+        assert s.accepted_tokens < s.drafted_tokens  # drafts were rejected
+        assert s.accepted_tokens > 0  # and some crossed a page's edge
+        assert s.rolled_back_pages > 0
+        assert checked["ahead"] == s.pipelined_steps > 0
+        assert checked["tokens"] >= s.emitted_tokens
+        b.check_invariants()
+
+    def test_no_step_is_enqueued_for_rows_that_are_all_finished(
+        self, tiny_model, monkeypatch
+    ):
+        """Rule 4: a verify step is enqueued ahead only if some row is
+        certain to outlive the step in flight, so every program enqueued
+        finds a row to emit for, and a drained dispatch never pays a step
+        for nothing. ``spec.pipelined_steps`` counts, in the unit of
+        ``spec.spec_steps``, the rows of the programs that were."""
+        params, cfg = tiny_model
+        enqueued = []  # one entry a program: [ahead, rows that emitted]
+        real_dispatch = ContinuousBatcher._dispatch_step
+        real_apply = ContinuousBatcher._apply_spec_counts
+
+        def dispatch(self, live, alloc_len, adm, chunk_len):
+            span = self.gamma + 1
+            left = [
+                int(self._max_new_np[s])
+                - int(self._cur_len_np[s] - self._row_len_np[s])
+                - self._steps_ahead(s) * span
+                for s in live
+            ]
+            real_dispatch(self, live, alloc_len, adm, chunk_len)
+            step = self._pipe[-1]
+            assert step.ahead == (len(self._pipe) == 2)
+            assert max(left) > 0, "every row certain to be finished"
+            enqueued.append([step, 0])
+
+        def apply(self, counts_np, step):
+            (entry,) = [e for e in enqueued if e[0] is step]
+            entry[1] = int((counts_np[2] > 0).sum())
+            real_apply(self, counts_np, step)
+
+        monkeypatch.setattr(ContinuousBatcher, "_dispatch_step", dispatch)
+        monkeypatch.setattr(ContinuousBatcher, "_apply_spec_counts", apply)
+        # Two rows that start together and end four steps apart, then a
+        # third alone: with random drafts a step emits one token a row.
+        rng = random.Random(3)
+        prompts = [[rng.randrange(3, 500) for _ in range(20)] for _ in range(3)]
+        b, out, results = _drain(
+            params, cfg, prompts, [14, 18, 11], max_batch=2,
+            speculative=True, gamma=3, page_size=4, prefix_cache=False,
+        )
+        assert [len(out[i]) for i in range(3)] == [14, 18, 11]
+        assert all(rows > 0 for _, rows in enqueued), "a step for nothing"
+        s = spec_mod.stats
+        assert sum(rows for _, rows in enqueued) == s.spec_steps
+        assert s.pipelined_steps == sum(
+            rows for step, rows in enqueued if step.ahead
+        )
+        # One deep: a dispatch's first step, and from where no row has
+        # more than a span (4) of budget left at the trailing view.
+        assert 0 < s.pipelined_steps <= s.spec_steps - 2 * (1 + 2)
+        assert s.pipelined_steps >= s.spec_steps // 2
+        assert not b._pipe
+
+    def test_the_share_metric_reads_the_two_counters(self):
+        """`batcher.pipelined_step_share` is `spec.pipelined_steps` over
+        `spec.spec_steps` through the standing `counter_ratio` reader:
+        both are fields of `perf.spec`, which the benchmark's counters
+        flatten; the entry is the last of `per_layer`, for the two dense
+        critique cells, and a program without the counter (the parent)
+        reads nothing and raises nothing."""
+        from pathlib import Path
+
+        from perfbench import reducers
+
+        root = Path(__file__).resolve().parents[1]
+        spec = json.loads(
+            (root / "perfbench/metrics/batcher.pipelined_step_share.json")
+            .read_text()
+        )
+        assert spec["reducer"] == "counter_ratio"
+        fields = spec_mod.snapshot()
+        for key in spec["params"]["num"] + spec["params"]["den"]:
+            assert key.startswith("spec.") and key[len("spec."):] in fields
+        entry = json.loads((root / "BENCHMARK.json").read_text())["per_layer"][-1]
+        assert entry == {
+            "name": "batcher.pipelined_step_share", "unit": "%",
+            "better": "higher", "source": "program_counter",
+            "layer": "batcher", "moves": "out_tokens_per_s",
+            "workloads": [
+                "mistral-7b-int8.critique", "qwen2-7b-int8.critique"
+            ],
+        }
+        from types import SimpleNamespace
+
+        read = lambda start, end: reducers.counter_ratio(  # noqa: E731
+            SimpleNamespace(counters_start=start, counters_end=end),
+            spec["params"],
+        )
+        assert read(
+            {"spec.spec_steps": 100, "spec.pipelined_steps": 80},
+            {"spec.spec_steps": 300, "spec.pipelined_steps": 260},
+        ) == pytest.approx(90.0)
+        assert read({"spec.spec_steps": 1}, {"spec.spec_steps": 9}) is None
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_a_fault_at_the_fetch_clears_the_pipe(
+        self, tiny_model, monkeypatch, depth
+    ):
+        """A step's counts cannot be fetched (the device state
+        survives): the steps in flight are dropped with it, one row is
+        evicted with what the device had made for it, and the host's
+        views are read back from the device, so the co-resident goes on
+        to the tokens it would have served undisturbed."""
+        import adversarial_spec_tpu.engine.scheduler as sched_mod
+
+        class Unfetchable:
+            def __init__(self, counts):
+                self.counts = counts
+
+            def copy_to_host_async(self):
+                pass
+
+            def __array__(self, *args, **kwargs):
+                raise RuntimeError("the counts were lost")
+
+        monkeypatch.setattr(sched_mod, "_PIPELINE_DEPTH", depth)
+        params, cfg = tiny_model
+        prompts = [_repetitive_prompt(40), _repetitive_prompt(33, period=5)]
+        _, ref, _ = _drain(params, cfg, prompts, [24, 24], speculative=False)
+        real = ContinuousBatcher._dispatch_spec
+        calls = {"n": 0}
+
+        def dispatch(self, *args, **kwargs):
+            counts = real(self, *args, **kwargs)
+            calls["n"] += 1
+            return Unfetchable(counts) if calls["n"] == 4 else counts
+
+        monkeypatch.setattr(ContinuousBatcher, "_dispatch_spec", dispatch)
+        b, out, results = _drain(
+            params, cfg, prompts, [24, 24],
+            speculative=True, gamma=3, page_size=4, prefix_cache=False,
+        )
+        (gone,) = [r.req_id for r in results if r.error is not None]
+        # the victim leaves with a prefix of its undisturbed reply
+        n = results[gone].n_generated
+        assert 4 <= n < 24 and out[gone][:n] == ref[gone][:n]
+        assert out[1 - gone] == ref[1 - gone]
+        assert not b._pipe
+        b.allocator.check_invariants()
+        assert b.allocator.free_pages == b.allocator.n_pages

@@ -101,7 +101,8 @@ class GraftlintConfig:
     # interprocedural port this list holds ONLY the pipelined double
     # buffer's entry elements: the tuples round-trip through a deque
     # (an opaque container the flow analysis does not model), so the
-    # unpacked refs in _fetch_entry are seeded by hand. Everything the
+    # unpacked refs in _fetch_entry, and the counts_ref of a verify step
+    # in _retire_spec_step, are seeded by hand. Everything the
     # list used to carry because taint died at an assignment or a call
     # boundary (first, adm_logits, spec_counts, demote_kv, promo_kv) is
     # now DERIVED — see tools/graftlint/dataflow.py.
@@ -110,6 +111,7 @@ class GraftlintConfig:
             "active_ref",
             "emitted_ref",
             "out_ref",
+            "counts_ref",
         ]
     )
     # Bounded depth for the interprocedural passes: summary recursion,
