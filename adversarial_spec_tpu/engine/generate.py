@@ -373,6 +373,17 @@ def generate(
         refuse_beside_state_space(
             cfg, "generate() (dense KV, or a mesh of more than one device)"
         )
+    if cfg.gated is not None and (
+        kv_dtype or paged or (mesh is not None and mesh.size > 1)
+    ):
+        from adversarial_spec_tpu.models.config import refuse_unwired
+
+        refuse_unwired(
+            cfg,
+            "generate() over a mesh, over pages of its own or with int8 KV",
+            "the ContinuousBatcher serves it on one device with paged KV "
+            "in the model dtype, generate() on one device with dense KV",
+        )
     # An explicit use_pallas_decode=True records caller intent (it
     # selects a louder fallback when the mesh can't support the kernel).
     explicit_pallas = use_pallas_decode is True

@@ -91,6 +91,20 @@ class PageAllocator:
     def refcount(self, page: int) -> int:
         return self._refs.get(page, 0)
 
+    def held_pages(self) -> set[int]:
+        """Every page some sequence or cache holds a reference to."""
+        return set(self._refs)
+
+    def pages_within(self, n_tokens: int) -> set[int]:
+        """The pages of every live sequence that reach into its last
+        ``n_tokens`` tokens: what a layer that sees ``n_tokens``
+        positions back still needs for the sequence's next query."""
+        out: set[int] = set()
+        for seq_id, table in self._tables.items():
+            first = max(0, self._lengths[seq_id] - n_tokens) // self.page_size
+            out.update(table[first:])
+        return out
+
     def new_sequence(self, seq_id: int) -> None:
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already allocated")

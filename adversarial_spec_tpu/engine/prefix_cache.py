@@ -246,6 +246,22 @@ class PrefixCache:
     def cached_pages(self) -> int:
         return len(self._by_page)
 
+    def pages_within(self, n_tokens: int) -> set[int]:
+        """The pages of the blocks that reach into the last ``n_tokens``
+        tokens of SOME cached path through them (a path ends at its
+        leaf): what a layer that sees ``n_tokens`` positions back still
+        needs of the cache if a hit takes a path up at its end. Every
+        other block lies further back on every path it is on."""
+        ps = self.page_size
+        out: set[int] = set()
+        for leaf in self._leaves():
+            # a block k links above the leaf ends k * ps tokens before it
+            node, back = leaf, 0
+            while node is not None and back < n_tokens:
+                out.add(node.page)
+                node, back = node.parent, back + ps
+        return out
+
     def _blocks(self, tokens) -> list[tuple]:
         ps = self.page_size
         n = len(tokens) // ps

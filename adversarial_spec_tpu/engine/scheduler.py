@@ -106,7 +106,10 @@ from adversarial_spec_tpu.engine.sampling import sample_tokens
 from adversarial_spec_tpu.models import moe as moe_mod
 from adversarial_spec_tpu.models.config import ModelConfig
 from adversarial_spec_tpu.ops import quant
-from adversarial_spec_tpu.models.config import refuse_beside_state_space
+from adversarial_spec_tpu.models.config import (
+    refuse_beside_state_space,
+    refuse_unwired,
+)
 from adversarial_spec_tpu.models.transformer import (
     STATE_LEAVES,
     commit_span,
@@ -1338,6 +1341,20 @@ class ContinuousBatcher:
                 refuse_beside_state_space(cfg, "int8 / int4 weights")
             if self._replicated is not None and self._replicated.mesh.size > 1:
                 refuse_beside_state_space(cfg, "a mesh of more than one device")
+        if cfg.gated is not None:
+            serves = (
+                "the ContinuousBatcher serves it on one device with paged "
+                "KV in the model dtype"
+            )
+            if kv_dtype:
+                # int8 pages' scale pages keep the grid kernel, which no
+                # test or chip run has put under per-layer windows
+                refuse_unwired(cfg, f"{kv_dtype} KV pages", serves)
+            if self._replicated is not None and self._replicated.mesh.size > 1:
+                refuse_unwired(cfg, "a mesh of more than one device", serves)
+        # The windowed layers' windows, one entry a layer (the host's
+        # counters of what their bounds leave unread and unreadable).
+        self._windows = tuple(w for w in cfg.layer_windows if w)
         n_pages = -(-capacity_tokens // page_size)
         # Physical page 0 is the trash page; allocator ids shift +1.
         self.allocator = PageAllocator(n_pages, page_size)
@@ -2458,13 +2475,7 @@ class ContinuousBatcher:
             # chunks) through the handoff that produced its first
             # sampled token. No queueing in it: not a TTFT.
             obs_mod.hot.prefill_wall.observe(self._slot_prefill_s[slot])
-            obs_mod.hot.pool_util.set(
-                round(
-                    1.0
-                    - self.allocator.free_pages / self.allocator.n_pages,
-                    6,
-                )
-            )
+            self._gauge_pool()
             obs_mod.emit(
                 obs_mod.RequestEvent(
                     req_id=req.req_id,
@@ -2991,13 +3002,7 @@ class ContinuousBatcher:
             obs_mod.hot.cancel_tokens_saved.observe(float(saved))
             if self.speculative and st[1]:
                 obs_mod.hot.spec_acceptance.observe(st[2] / st[1])
-            obs_mod.hot.pool_util.set(
-                round(
-                    1.0
-                    - self.allocator.free_pages / self.allocator.n_pages,
-                    6,
-                )
-            )
+            self._gauge_pool()
             obs_mod.emit(
                 obs_mod.RequestEvent(
                     req_id=req.req_id,
@@ -3110,13 +3115,7 @@ class ContinuousBatcher:
         self._deadline_t.pop(req.req_id, None)
         if obs_mod.config().enabled:
             obs_mod.hot.req_finished.inc()
-            obs_mod.hot.pool_util.set(
-                round(
-                    1.0
-                    - self.allocator.free_pages / self.allocator.n_pages,
-                    6,
-                )
-            )
+            self._gauge_pool()
             obs_mod.emit(
                 obs_mod.RequestEvent(
                     req_id=req.req_id,
@@ -3822,14 +3821,55 @@ class ContinuousBatcher:
             if self.cfg.latent is not None and obs_mod.config().enabled:
                 # the row's cached tokens, read once this step
                 obs_mod.hot.latent_tokens_read.inc(new_cl)
+            if self._windows and obs_mod.config().enabled:
+                obs_mod.hot.record_attn_read(
+                    new_cl,
+                    self._windows,
+                    self.cfg.n_kv_layers - len(self._windows),
+                )
         n_rows = N_STEP_COUNTS + span
         if counts_np.shape[0] > n_rows and obs_mod.config().enabled:
             obs_mod.hot.record_routing(
                 "decode",
                 counts_np[n_rows:, 0],
-                self.cfg.n_layers,
+                self.cfg.n_layers - self.cfg.n_leading,  # the routed layers
                 self.cfg.experts.n_held,
             )
+
+    def _gauge_pool(self) -> None:
+        """The pool's gauges, where its holdings change (an admission's
+        handoff, a release): the share of pages held, and beside windowed
+        layers the bytes they hold behind every window."""
+        held = self.allocator.n_pages - self.allocator.free_pages
+        obs_mod.hot.pool_util.set(round(held / self.allocator.n_pages, 6))
+        if not self._windows:
+            return
+        kv_heads, k_dim, v_dim = self.cfg.kv_layout
+        layer_page = (
+            kv_heads * self.page_size * (k_dim + v_dim)
+            * np.dtype(self._dtype).itemsize
+        )
+        dead = sum(
+            self._window_dead_pages(w) * self._windows.count(w)
+            for w in set(self._windows)
+        )
+        obs_mod.hot.kv_window_dead_bytes.set(dead * layer_page)
+        obs_mod.hot.kv_held_bytes.set(held * layer_page * self.cfg.n_kv_layers)
+
+    def _window_dead_pages(self, window: int) -> int:
+        """Pages held whose content, in a layer of ``window``, no query
+        can reach any more: those that end more than ``window`` tokens
+        before the end of EVERY live sequence and cached path that holds
+        them (a sequence's next query sees ``window`` positions back from
+        its own; a cached path is taken up again at its leaf). What a
+        cache per kind of layer would free. A hit that ends inside a
+        cached path would find such a page's window layers gone and
+        recompute up to ``window`` tokens of them: the price of freeing
+        them, not counted here."""
+        live = self.allocator.pages_within(window)
+        if self.prefix_cache is not None:
+            live |= self.prefix_cache.pages_within(window)
+        return len(self.allocator.held_pages() - live)
 
     @staticmethod
     def _entry_ready(entry: tuple) -> bool:

@@ -86,6 +86,14 @@ class RoutedExperts:
     norm_topk: bool = True
     routed_scaling: float = 1.0
     held: tuple[int, int] = (0, 0)  # (0, 0) = all of them
+    # "sigmoid": scores are sigmoids, the top k are chosen by score + a
+    # bias an expert (``router_bias``, learned for load balance), and the
+    # weights come from the scores alone (models/moe.py ``route``).
+    scoring: str = "softmax"  # softmax | sigmoid
+    # deviation of the synthetic checkpoint's ``router_bias`` (a trained
+    # one loads its own); it has to move the choice or a program that
+    # drops the bias serves the same tokens
+    bias_std: float = 0.0
 
     @property
     def first_held(self) -> int:
@@ -125,6 +133,35 @@ class StateSpace:
     def in_dim(self) -> int:
         """Columns of the input projection: gate z, conv channels, dt."""
         return self.inner_dim + self.conv_dim + self.n_heads
+
+
+@dataclass(frozen=True)
+class GatedAttention:
+    """What afmoe's attention adds to GQA: an RMSNorm over each head of
+    the queries and of the keys (a learned weight ``head_dim`` wide,
+    before any rotation), and a sigmoid gate ``sigmoid(h W_g)`` on the
+    heads' output before ``W_o``. Its layers are of two kinds, which a
+    period's positions name as their mixer: "swa" rotates queries and
+    keys and sees the last ``window`` positions (its own included);
+    "nope" rotates nothing and sees every earlier position."""
+
+    window: int
+
+
+@dataclass(frozen=True)
+class DensePrefix:
+    """Layers ahead of the first period: each with the attention kind
+    ``mixers`` names, each with a dense FFN ``ffn_dim`` wide, whatever
+    the periods' FFN is. They have a weight stack of their own
+    (``params["leading"]``) and the first rows of the KV cache."""
+
+    mixers: tuple[str, ...]
+    ffn_dim: int
+
+
+# Mixer kinds that are grouped-query attention over cached keys and
+# values: one weight stack, one pool, whatever each layer rotates or sees.
+GQA_MIXERS = ("gqa", "swa", "nope")
 
 
 @dataclass(frozen=True)
@@ -172,29 +209,37 @@ class ModelConfig:
     logits_scaling: float = 1.0  # logits / this
     rope: bool = True
     # One period of the layer pattern, as (mixer kind, FFN kind): mixer
-    # "gqa" | "latent" | "ssm", FFN "dense" | "routed". The forwards scan
-    # the stack period by period; a kind's sizes live in its own group
-    # below, not as more flags on this set. A period holds one kind of
-    # attention at most, and one kind of FFN.
+    # "gqa" | "swa" | "nope" | "latent" | "ssm", FFN "dense" | "routed".
+    # A kind's sizes live in its own group below, not as more flags on
+    # this set. A period holds attention of one shape at most (the
+    # ``GQA_MIXERS`` share one), and one kind of FFN. ``prefix`` layers
+    # stand ahead of the first period; the periods then repeat to
+    # ``n_layers``: whole ones where a period holds more than one weight
+    # stack (state-space layers: the forwards scan such a stack period by
+    # period), else layer by layer, so that the last may be cut short
+    # (Trinity's 54 routed layers are thirteen periods and a half).
     layer_kinds: tuple[tuple[str, str], ...] = (("gqa", "dense"),)
     latent: LatentAttention | None = None
     experts: RoutedExperts | None = None
     ssm: StateSpace | None = None
+    gated: GatedAttention | None = None
+    prefix: DensePrefix | None = None
 
     def __post_init__(self):
         for kinds in self.layer_kinds:
-            if kinds[0] not in ("gqa", "latent", "ssm") or kinds[1] not in (
-                "dense", "routed",
-            ):
+            if kinds[0] not in (*GQA_MIXERS, "latent", "ssm") or kinds[
+                1
+            ] not in ("dense", "routed"):
                 raise ValueError(f"unknown layer kinds {kinds}")
         mixers = {m for m, _ in self.layer_kinds}
         ffns = {f for _, f in self.layer_kinds}
-        if len(mixers - {"ssm"}) > 1 or len(ffns) != 1:
+        shapes = {"gqa" if m in GQA_MIXERS else m for m in mixers}
+        if len(shapes - {"ssm"}) > 1 or len(ffns) != 1:
             raise NotImplementedError(
                 f"one kind of attention and one of FFN a period, not "
                 f"{self.layer_kinds}"
             )
-        if self.n_layers % len(self.layer_kinds):
+        if self.ssm is not None and (self.n_layers - self.n_leading) % self.period:
             raise ValueError(
                 f"{self.n_layers} layers are no whole number of periods "
                 f"of {len(self.layer_kinds)}"
@@ -203,10 +248,11 @@ class ModelConfig:
             ("latent" in mixers) != (self.latent is not None)
             or ("ssm" in mixers) != (self.ssm is not None)
             or ("routed" in ffns) != (self.experts is not None)
+            or bool(mixers & {"swa", "nope"}) != (self.gated is not None)
         ):
             raise ValueError(
-                f"layer kinds {self.layer_kinds} and the latent/ssm/experts "
-                "groups disagree"
+                f"layer kinds {self.layer_kinds} and the latent/ssm/experts/"
+                "gated groups disagree"
             )
         if self.ssm is not None and (
             self.latent is not None or self.experts is not None
@@ -214,15 +260,56 @@ class ModelConfig:
             raise NotImplementedError(
                 "state-space layers stand beside GQA attention and a dense FFN"
             )
+        if self.prefix is not None and (
+            not set(self.prefix.mixers) <= mixers & set(GQA_MIXERS)
+            or not 0 < self.n_leading <= self.n_layers
+        ):
+            raise NotImplementedError(
+                f"leading layers {self.prefix.mixers} are of the periods' "
+                f"kinds of attention ({sorted(mixers)}), and fewer than "
+                f"{self.n_layers}"
+            )
 
     @property
     def period(self) -> int:
         return len(self.layer_kinds)
 
     @property
+    def n_leading(self) -> int:
+        return len(self.prefix.mixers) if self.prefix else 0
+
+    @property
+    def layer_mixers(self) -> tuple[str, ...]:
+        """Every layer's mixer kind, in order: the prefix, then the
+        periods repeated."""
+        lead = self.prefix.mixers if self.prefix else ()
+        return lead + tuple(
+            self.layer_kinds[i % self.period][0]
+            for i in range(self.n_layers - self.n_leading)
+        )
+
+    @property
+    def layer_windows(self) -> tuple[int, ...]:
+        """Every layer's window: how many positions back a query sees,
+        its own included; 0 = all. A "gqa" layer takes the model-wide
+        ``sliding_window`` (on the layers its pattern names)."""
+
+        def window(i: int, kind: str) -> int:
+            if kind == "swa":
+                return self.gated.window
+            if kind == "gqa" and self.sliding_window > 0:
+                every = self.sliding_window_pattern
+                return self.sliding_window if i % every == 0 else 0
+            return 0
+
+        return tuple(window(i, m) for i, m in enumerate(self.layer_mixers))
+
+    @property
     def attn_kind(self) -> str:
-        """The period's kind of attention ("ssm" where it has none)."""
-        return next((m for m, _ in self.layer_kinds if m != "ssm"), "ssm")
+        """The shape of the period's attention: "gqa", "latent", or
+        "ssm" where it has none."""
+        kind = next((m for m, _ in self.layer_kinds if m != "ssm"), "ssm")
+        return "gqa" if kind in GQA_MIXERS else kind
 
     @property
     def ffn_kind(self) -> str:
@@ -232,9 +319,8 @@ class ModelConfig:
     def mixer_counts(self) -> tuple[int, int]:
         """(layers that cache keys and values, state-space layers): the
         depths of the page pool and of the recurrent state."""
-        n_ssm = sum(m == "ssm" for m, _ in self.layer_kinds)
-        periods = self.n_layers // self.period
-        return (self.period - n_ssm) * periods, n_ssm * periods
+        n_ssm = sum(m == "ssm" for m in self.layer_mixers)
+        return self.n_layers - n_ssm, n_ssm
 
     @property
     def n_kv_layers(self) -> int:
@@ -364,6 +450,42 @@ def _granite_hybrid(
         + (("gqa", "dense"),)
         + (("ssm", "dense"),) * 4,
         ssm=ssm,
+    )
+
+
+def _afmoe(
+    *, vocab, dim, n_layers, n_heads, n_kv_heads, head_dim, window, leading,
+    dense_ffn, period, n_routed, top_k, expert_dim, route_scale, max_seq_len,
+):
+    """Arcee's ``afmoe`` (Trinity): gated GQA attention with a norm a
+    head, windowed rotated layers beside global unrotated ones, sandwich
+    norms, the embedding times sqrt(dim); ``leading`` dense layers, then
+    a sigmoid router with a selection bias over ``n_routed`` experts
+    beside one shared expert. ``period`` is the attention kinds from the
+    first routed layer on."""
+    return ModelConfig(
+        vocab_size=vocab,
+        dim=dim,
+        n_layers=n_layers,
+        n_heads=n_heads,
+        n_kv_heads=n_kv_heads,
+        head_dim=head_dim,
+        ffn_dim=expert_dim,  # the shared expert's width
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        scale_embeddings=True,
+        post_norms=True,
+        max_seq_len=max_seq_len,
+        layer_kinds=tuple((m, "routed") for m in period),
+        gated=GatedAttention(window=window),
+        prefix=DensePrefix(mixers=tuple(leading), ffn_dim=dense_ffn),
+        experts=RoutedExperts(
+            n_routed=n_routed, top_k=top_k, expert_dim=expert_dim, n_shared=1,
+            routed_scaling=route_scale, scoring="sigmoid",
+            # moves the chosen four of half of the tokens at the published
+            # width, the chosen two of 7% at tiny (tests/test_afmoe.py)
+            bias_std=0.01,
+        ),
     )
 
 
@@ -542,6 +664,33 @@ CONFIGS: dict[tuple[str, str], ModelConfig] = {
         ssm=StateSpace(n_heads=64, head_dim=64, state_dim=128, chunk=256),
         max_seq_len=32768,  # published 131,072; the ctx buffer is sized by it
     ),
+    # Trinity-Large-Preview (HF model_type "afmoe", 400B-A13B): 60 layers
+    # whose attention is windowed and rotated ("swa", window 4096) but for
+    # every fourth, which is global and unrotated ("nope"): layer_types
+    # s,s,s,f from layer 0. The first 6 layers have a dense FFN of 12288;
+    # the 54 after them 256 experts of 3072 (sigmoid scores, top 4 by
+    # score + bias, renormalised, times 2.448) beside one shared expert.
+    # The routed layers begin at layer 6, so their period reads s,f,s,s.
+    # A cut in depth (``get_config``'s ``n_layers``) keeps whole periods
+    # of the published numbering: n_layers % 4 leading dense layers (the
+    # published first ones), then n_layers // 4 periods s,s,s,f (the
+    # published layers from 8 on). "tiny" has two leading layers and a
+    # period of two, one of each kind in both (off the chip a layer's
+    # attention gathers the whole table's width, so a test's time goes by
+    # layers x heads x max_seq_len: few, few and short).
+    ("afmoe", "tiny"): _afmoe(
+        vocab=512, dim=128, n_layers=4, n_heads=2, n_kv_heads=1, head_dim=64,
+        window=128, leading=("swa", "nope"), dense_ffn=512,
+        period=("swa", "nope"), n_routed=8, top_k=2, expert_dim=128,
+        route_scale=2.448, max_seq_len=1600,
+    ),
+    ("afmoe", "trinity-large"): _afmoe(
+        vocab=200192, dim=3072, n_layers=60, n_heads=48, n_kv_heads=8,
+        head_dim=128, window=4096,
+        leading=("swa", "swa", "swa", "nope", "swa", "swa"), dense_ffn=12288,
+        period=("swa", "nope", "swa", "swa"), n_routed=256, top_k=4,
+        expert_dim=3072, route_scale=2.448, max_seq_len=262144,
+    ),
     ("gemma2", "27b"): ModelConfig(
         vocab_size=256000,
         dim=4608,
@@ -569,11 +718,19 @@ def family_of(cfg: ModelConfig) -> str:
     """The family whose presets have ``cfg``'s layer pattern ("" if none):
     what an error names, where only the config is in hand."""
     for (family, _), preset in CONFIGS.items():
-        if preset.layer_kinds == cfg.layer_kinds and (
+        if set(preset.layer_kinds) == set(cfg.layer_kinds) and (
             preset.latent is None
         ) == (cfg.latent is None):
             return family
     return ""
+
+
+def refuse_unwired(cfg: ModelConfig, what: str, serves: str):
+    """Refuse aloud, by the family's name, what is not wired for it,
+    rather than serve a wrong answer. ``serves``: what does serve it."""
+    raise NotImplementedError(
+        f"{family_of(cfg) or 'this family'}: {what} is not wired; {serves}"
+    )
 
 
 def refuse_beside_state_space(cfg: ModelConfig, what: str):
@@ -584,6 +741,31 @@ def refuse_beside_state_space(cfg: ModelConfig, what: str):
         "state-space layers (a recurrent state a sequence); the "
         "ContinuousBatcher serves it on one device, in the model dtype, "
         "with paged KV in the model dtype"
+    )
+
+
+def _cut_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """``cfg`` at a depth of ``n_layers``. Behind a dense prefix the cut
+    keeps whole periods of the published numbering: the first
+    ``n_layers % period`` leading layers, then whole periods that begin
+    where the published pattern does (its first period boundary behind
+    the prefix), not in the middle of one where the prefix happens to
+    end."""
+    if cfg.prefix is None:
+        return replace(cfg, n_layers=n_layers)
+    keep = n_layers % cfg.period
+    if not 0 < keep <= cfg.n_leading:
+        raise ValueError(
+            f"{n_layers} layers keep {keep} of {cfg.n_leading} leading "
+            f"layers and whole periods of {cfg.period}: not between 1 "
+            f"and {cfg.n_leading}"
+        )
+    shift = -cfg.n_leading % cfg.period
+    return replace(
+        cfg,
+        n_layers=n_layers,
+        layer_kinds=cfg.layer_kinds[shift:] + cfg.layer_kinds[:shift],
+        prefix=replace(cfg.prefix, mixers=cfg.prefix.mixers[:keep]),
     )
 
 
@@ -607,7 +789,7 @@ def get_config(
     if max_seq_len:
         cfg = replace(cfg, max_seq_len=max_seq_len)
     if n_layers:
-        cfg = replace(cfg, n_layers=n_layers)
+        cfg = _cut_depth(cfg, n_layers)
     if experts_held:
         if cfg.experts is None:
             raise ValueError(f"{family}/{size} has no routed experts to share")

@@ -38,19 +38,33 @@ from adversarial_spec_tpu.ops.quant import is_quantized, is_quantized_int4
 # every step) and read by layer index instead.
 EXPERT_WEIGHTS = ("we_gate", "we_up", "we_down")
 
-def route(h2: jnp.ndarray, w_router, ex: RoutedExperts):
-    """Top-k of a float32 softmax over all experts. h2 [T, D] normed
-    activations -> (weights [T, k] f32, expert ids [T, k] int32)."""
+def route(h2: jnp.ndarray, w_router, ex: RoutedExperts, bias=None):
+    """Top-k of float32 scores over all experts. h2 [T, D] normed
+    activations -> (weights [T, k] f32, expert ids [T, k] int32).
+
+    "softmax": the top k of a softmax, renormalised. "sigmoid": the
+    scores are sigmoids; the k are chosen by score + ``bias`` [n_routed]
+    (an expert's learned correction of its load), but weighted by the
+    scores alone, renormalised over sum + 1e-20."""
     with jax.named_scope("moe.route"):
         logits = jnp.matmul(
             h2.astype(jnp.float32),
             w_router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        gates = jax.nn.softmax(logits, axis=-1)
-        w, idx = jax.lax.top_k(gates, ex.top_k)
-        if ex.norm_topk:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        if ex.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                scores + bias.astype(jnp.float32), ex.top_k
+            )
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            if ex.norm_topk:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        else:
+            gates = jax.nn.softmax(logits, axis=-1)
+            w, idx = jax.lax.top_k(gates, ex.top_k)
+            if ex.norm_topk:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
         return w * ex.routed_scaling, idx.astype(jnp.int32)
 
 
@@ -163,12 +177,13 @@ def routed_ffn(
     *,
     use_pallas: bool = False,
     interpret: bool = False,
+    router_bias=None,  # [n_routed]: a sigmoid router's selection bias
 ):
     """The held experts' part of the routed result, [B, S, D], and the
     routing ([B*S, k] expert ids) for the caller's counters."""
     B, S, D = h.shape
     h2 = h.reshape(B * S, D)
-    w, idx = route(h2, w_router, ex)
+    w, idx = route(h2, w_router, ex, router_bias)
     with jax.named_scope("moe.experts"):
         T, k = idx.shape
         bm = _tile_rows(T * k, ex.n_held)

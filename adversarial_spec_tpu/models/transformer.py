@@ -50,7 +50,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -105,12 +105,43 @@ def init_params(
         w = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
         return div_const(w, math.sqrt(fan_in)).astype(dtype)
 
-    L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
+    # ``L``: the layers of the periods' stack (all of them but a dense
+    # prefix's, which has its own)
+    L, D, F = cfg.n_layers - cfg.n_leading, cfg.dim, cfg.ffn_dim
     QD = cfg.n_heads * cfg.head_dim
     KD = cfg.n_kv_heads * cfg.head_dim
+
+    def gqa_stack(keys, n: int) -> dict:
+        # wq, wk, wv, wo; gated attention's wg after them
+        stack = {
+            "attn_norm": jnp.ones((n, D), dtype),
+            "wq": dense(next(keys), (n, D, QD), D),
+            "wk": dense(next(keys), (n, D, KD), D),
+            "wv": dense(next(keys), (n, D, KD), D),
+            "wo": dense(next(keys), (n, QD, D), QD),
+        }
+        if cfg.gated is not None:
+            stack["wg"] = dense(next(keys), (n, D, QD), D)
+            stack["q_head_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+            stack["k_head_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+        return stack
+
+    def ffn_stack(keys, n: int, width: int) -> dict:
+        stack = {
+            "ffn_norm": jnp.ones((n, D), dtype),
+            "w_gate": dense(next(keys), (n, D, width), D),
+            "w_up": dense(next(keys), (n, D, width), D),
+            "w_down": dense(next(keys), (n, width, D), width),
+        }
+        if cfg.post_norms:
+            stack["post_attn_norm"] = jnp.ones((n, D), dtype)
+            stack["post_ffn_norm"] = jnp.ones((n, D), dtype)
+        return stack
+
     # THE RECIPE (perfbench/architectures/*.py follow it): sixteen splits
     # of the seed's key, taken in the order the weights are named below;
-    # norms one, biases zero.
+    # norms one, biases zero. A dense prefix's stack takes its own
+    # sixteen from ``fold_in(rng, 1)``, in the same order.
     mixers: dict[str, dict] = {}
     if cfg.ssm is not None:
         # A period longer than one layer: what every layer has (the
@@ -170,27 +201,18 @@ def init_params(
             "wo": dense(next(keys), (L, OD, D), OD),
         }
     else:
-        layers = {
-            "attn_norm": jnp.ones((L, D), dtype),
-            "wq": dense(next(keys), (L, D, QD), D),
-            "wk": dense(next(keys), (L, D, KD), D),
-            "wv": dense(next(keys), (L, D, KD), D),
-            "wo": dense(next(keys), (L, QD, D), QD),
-        }
+        layers = gqa_stack(keys, L)
     # The dense FFN; beside routed experts it is the shared expert.
-    layers.update(
-        {
-            "ffn_norm": jnp.ones((L, D), dtype),
-            "w_gate": dense(next(keys), (L, D, F), D),
-            "w_up": dense(next(keys), (L, D, F), D),
-            "w_down": dense(next(keys), (L, F, D), F),
-        }
-    )
+    layers.update(ffn_stack(keys, L, F))
     if cfg.experts is not None:
         ex = cfg.experts
         if ex.n_shared != 1:
             raise NotImplementedError("one shared expert beside the routed")
         layers["w_router"] = dense(next(keys), (L, D, ex.n_routed), D)
+        if ex.scoring == "sigmoid":
+            layers["router_bias"] = ex.bias_std * jax.random.normal(
+                next(keys), (L, ex.n_routed), jnp.float32
+            )
         for name, shape, fan_in in (
             ("we_gate", (D, ex.expert_dim), D),
             ("we_up", (D, ex.expert_dim), D),
@@ -203,9 +225,6 @@ def init_params(
         layers["bq"] = jnp.zeros((L, QD), dtype)
         layers["bk"] = jnp.zeros((L, KD), dtype)
         layers["bv"] = jnp.zeros((L, KD), dtype)
-    if cfg.post_norms:
-        layers["post_attn_norm"] = jnp.ones((L, D), dtype)
-        layers["post_ffn_norm"] = jnp.ones((L, D), dtype)
     if cfg.norm_scale_plus_one:
         # Gemma stores RMSNorm scale as (1 + w); init w at zero.
         for name in ("attn_norm", "ffn_norm", "post_attn_norm", "post_ffn_norm"):
@@ -234,6 +253,12 @@ def init_params(
     }
     if mixers:
         params["mixers"] = mixers
+    if cfg.prefix is not None:
+        lead_keys = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
+        params["leading"] = {
+            **gqa_stack(lead_keys, cfg.n_leading),
+            **ffn_stack(lead_keys, cfg.n_leading, cfg.prefix.ffn_dim),
+        }
     if not cfg.tied_embeddings:
         params["lm_head"] = dense(next(keys), (D, cfg.vocab_size), D)
     elif transposed_head:
@@ -254,7 +279,8 @@ def _inverse_softplus(y):
 
 
 def _expert_stack(key, cfg: ModelConfig, shape, fan_in, dtype, quant: str):
-    """The held experts' weights of every layer, [L, E_held, in, out], made
+    """The held experts' weights of every routed layer, [L, E_held, in,
+    out] (``l`` counts from the first layer behind a dense prefix), made
     ONE LAYER AT A TIME: piece (layer l, expert e) draws from
     ``fold_in(key, l * n_routed + e)`` with e the expert's index among ALL
     routed experts, so every share of a deployment holds the values the
@@ -264,7 +290,7 @@ def _expert_stack(key, cfg: ModelConfig, shape, fan_in, dtype, quant: str):
     the stack weighs."""
     ex = cfg.experts
     ids = (
-        jnp.arange(cfg.n_layers)[:, None] * ex.n_routed
+        jnp.arange(cfg.n_layers - cfg.n_leading)[:, None] * ex.n_routed
         + ex.first_held
         + jnp.arange(ex.n_held)[None, :]
     )
@@ -422,8 +448,13 @@ def attention(
     return out.reshape(B, S, Hq, v.shape[-1])
 
 
-def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
+def _project_qkv(
+    lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul, kind="gqa"
+):
     """Shared QKV projection + bias + head reshape + RoPE (dense & paged).
+    Gated attention norms each head of q and k first, and a layer of
+    ``kind`` "swa" rotates them where one of "nope" does not; "gqa"
+    follows the model's ``rope``.
 
     ``mm`` is the matmul implementation — the plain dispatch by default,
     or a partial carrying ``use_pallas``/``interpret`` when the caller
@@ -438,7 +469,10 @@ def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.rope:
+    if cfg.gated is not None:
+        q = rms_norm(q, lp["q_head_norm"], cfg.rms_eps, False)
+        k = rms_norm(k, lp["k_head_norm"], cfg.rms_eps, False)
+    if cfg.rope if kind == "gqa" else kind == "swa":
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     pad = cfg.kv_layout[1] - cfg.head_dim
@@ -449,6 +483,26 @@ def _project_qkv(lp, cfg: ModelConfig, h, B: int, S: int, cos, sin, mm=matmul):
             jnp.pad(t, ((0, 0), (0, 0), (0, 0), (0, pad))) for t in (q, k, v)
         )
     return q, k, v
+
+
+def _attn_gate(lp, cfg: ModelConfig, h, out, mm):
+    """Gated attention: the heads' output [B, S, Hq, D] times
+    sigmoid(h W_g), elementwise, before ``W_o`` (``h``: the block's
+    normed input). Nothing for any other attention."""
+    if cfg.gated is None:
+        return out
+    with jax.named_scope("attn.gate"):
+        gate = jax.nn.sigmoid(mm(h, lp["wg"]).astype(jnp.float32))
+        return (out.astype(jnp.float32) * gate.reshape(out.shape)).astype(
+            out.dtype
+        )
+
+
+def _attn_scope(kind: str):
+    """The device-side name of a gated layer's attention, by its kind."""
+    if kind == "gqa":
+        return contextlib.nullcontext()
+    return jax.named_scope({"swa": "attn.window", "nope": "attn.full"}[kind])
 
 
 def _rope_tables(cfg: ModelConfig, positions):
@@ -585,8 +639,9 @@ def _ssm_chunked(lp, cfg: ModelConfig, h, window, state, valid, mm):
 
 
 def _layer_of(stack: dict, index) -> dict:
-    """Row ``index`` (traced) of every leaf of a stack of layers."""
-    return {k: v[index] for k, v in stack.items()}
+    """Row ``index`` (traced) of every leaf of a stack of layers (a
+    quantized weight's values and scales alike)."""
+    return jax.tree.map(lambda a: a[index], stack)
 
 
 def _commit_layer(pool, cfg, li, rows, saved, n_keep, use_pallas, interpret):
@@ -713,9 +768,16 @@ def _embed(params: Params, cfg: ModelConfig, tokens):
     return x
 
 
-def _layer_window_start(cfg: ModelConfig, layer_id, base_start, q_pos):
+def _layer_window_start(
+    cfg: ModelConfig, layer_id, base_start, q_pos, kind: str = "gqa"
+):
     """Per-layer valid-window start: sliding window tightens it (on the
-    windowed layers only, for alternating-pattern families)."""
+    windowed layers only: a gated layer's ``kind`` says which it is, an
+    alternating-pattern family's layer index)."""
+    if kind != "gqa":
+        if kind == "nope":
+            return base_start
+        return jnp.maximum(base_start, q_pos - cfg.gated.window + 1)
     if cfg.sliding_window <= 0:
         return base_start
     win_start = jnp.maximum(base_start, q_pos - cfg.sliding_window + 1)
@@ -801,8 +863,9 @@ def forward(
     )  # [1|B, S, 1]
     causal = slot_ids <= q_slot
     base_mask = kv_valid[:, None, :] & causal  # [B, S, T]
-    if cfg.sliding_window > 0:
-        window_mask = base_mask & (slot_ids > q_slot - cfg.sliding_window)
+    window = cfg.gated.window if cfg.gated else cfg.sliding_window
+    if window > 0:
+        window_mask = base_mask & (slot_ids > q_slot - window)
     else:
         window_mask = base_mask
 
@@ -921,11 +984,16 @@ def forward(
             )
         return out, cache_l
 
-    def attn_block(x, scanned):
+    def attn_block(x, scanned, kind="gqa"):
         lp, layer_id, cache_l = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
-        q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
-        cache_l, k_read, v_read = _write_and_read_kv(cache_l, k, v, x.dtype)
+        with _attn_scope(kind):
+            out, cache_l = attend(h, lp, layer_id, cache_l, kind)
+        return _attn_gate(lp, cfg, h, out, mm), cache_l
+
+    def attend(h, lp, layer_id, cache_l, kind):
+        q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm, kind=kind)
+        cache_l, k_read, v_read = _write_and_read_kv(cache_l, k, v, h.dtype)
 
         if pallas_decode:
             from adversarial_spec_tpu.ops.pallas_decode import (
@@ -934,7 +1002,7 @@ def forward(
             )
 
             start = _layer_window_start(
-                cfg, layer_id, pallas_start, cache_index
+                cfg, layer_id, pallas_start, cache_index, kind
             )
             bounds = jnp.stack([start, pallas_end], axis=1)
             if quant_kv:
@@ -976,7 +1044,7 @@ def forward(
             )
 
             starts_l = _layer_window_start(
-                cfg, layer_id, pallas_start[:, None], mq_q_pos
+                cfg, layer_id, pallas_start[:, None], mq_q_pos, kind
             )
             if quant_kv:
                 # Raw int8 tiles + scale tiles; the dequantized
@@ -1000,7 +1068,9 @@ def forward(
                 **mq_kw,
             )
         else:
-            if cfg.sliding_window > 0 and cfg.sliding_window_pattern > 1:
+            if kind != "gqa":
+                mask = window_mask if kind == "swa" else base_mask
+            elif cfg.sliding_window > 0 and cfg.sliding_window_pattern > 1:
                 # Gemma-2: alternate windowed / global layers.
                 use_window = (layer_id % cfg.sliding_window_pattern) == 0
                 mask = jnp.where(use_window, window_mask, base_mask)
@@ -1024,7 +1094,12 @@ def forward(
     # rolled for every span (see the module docstring). "layers" names
     # the scan itself: the slicing of each layer's weights out of the
     # stacked arrays has no other owner.
-    if cfg.period > 1:
+    if cfg.gated is not None:
+        return _forward_segments(
+            params, cfg, x, cache, attn_block, mm, lm_head_last_only,
+            fused_mm, pallas_interpret,
+        )
+    if cfg.ssm is not None:
         return _forward_periods(
             params, cfg, x, cache, attn_block, mm, lm_head_last_only,
             # a chunk position counts iff its slot holds a real token
@@ -1041,6 +1116,104 @@ def forward(
             (scanned_layers, layer_ids, cache),
         )
 
+    logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
+    return logits, new_cache
+
+
+class _Run(NamedTuple):
+    """Layers that one scan covers: ``reps`` repetitions of ``kinds``."""
+
+    stack: str  # the weight stack's name in the params
+    row0: int  # the run's first row of that stack
+    layer0: int  # the run's first layer of the cache or pool
+    kinds: tuple[str, ...]  # the mixer kinds of one repetition, in order
+    reps: int
+
+
+def _segments(cfg: ModelConfig) -> list[_Run]:
+    """A gated-attention stack as the runs of layers that one scan each
+    covers. The body of a run's scan is its kinds' layers in order, each
+    kind static (what it rotates, what it sees, its name in the trace);
+    the weights are read out of their whole stacks by a traced row. The
+    prefix is one run of its own layers; the periods are one run of whole
+    periods and, where the depth cuts the last one short, one more of
+    what is left of it."""
+    lead = cfg.n_leading
+    kinds = tuple(m for m, _ in cfg.layer_kinds)
+    whole, rest = divmod(cfg.n_layers - lead, cfg.period)
+    runs = []
+    if lead:
+        runs.append(_Run("leading", 0, 0, cfg.prefix.mixers, 1))
+    if whole:
+        runs.append(_Run("layers", 0, lead, kinds, whole))
+    if rest:
+        at = whole * cfg.period
+        runs.append(_Run("layers", at, lead + at, kinds[:rest], 1))
+    return runs
+
+
+def _run_layers(params, cfg, run, fused_mm, interpret, rows: int, dtype, p):
+    """The layers of repetition ``p`` (traced) of ``run``, in order, each
+    as (kind, the pool's layer, its weights as its body reads them, its
+    routed FFN or None, the list that leaves its routing in or None)."""
+    scanned, indexed, experts = _split_layers(
+        params[run.stack], fused_mm, rows, dtype
+    )
+    out = []
+    for j, kind in enumerate(run.kinds):
+        row = run.row0 + p * len(run.kinds) + j
+        lp = _layer_weights(_layer_of(scanned, row), indexed, row)
+        routed, routing = (
+            _routed_ffn_of(cfg, lp, experts, row, fused_mm, interpret)
+            if run.stack == "layers"
+            else (None, None)
+        )
+        out.append((kind, run.layer0 - run.row0 + row, lp, routed, routing))
+    return out
+
+
+def _forward_segments(
+    params, cfg, x, cache, attn_block, mm, lm_head_last_only, fused_mm,
+    interpret,
+):
+    """``forward``'s layer scans for gated attention behind a dense
+    prefix (``_segments``). A run's rows of the cache scan with it."""
+    B, S = x.shape[:2]
+    parts = []
+    for run in _segments(cfg):
+        layer0, n, reps = run.layer0, len(run.kinds), run.reps
+
+        def body(x, scanned, run=run):
+            p, cache_p = scanned
+            new = []
+            for j, (kind, layer, lp, routed, _) in enumerate(
+                _run_layers(params, cfg, run, fused_mm, interpret, B * S, x.dtype, p)
+            ):
+                with jax.named_scope("attn"):
+                    out, cache_l = attn_block(
+                        x, (lp, layer, {k: v[j] for k, v in cache_p.items()}),
+                        kind,
+                    )
+                x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm, routed=routed)
+                new.append(cache_l)
+            return x, {k: jnp.stack([c[k] for c in new]) for k in cache_p}
+
+        with jax.named_scope("layers"):
+            x, new = jax.lax.scan(
+                body,
+                x,
+                (
+                    jnp.arange(reps),
+                    {
+                        k: v[layer0 : layer0 + n * reps].reshape(
+                            (reps, n) + v.shape[1:]
+                        )
+                        for k, v in cache.items()
+                    },
+                ),
+            )
+        parts.append({k: v.reshape((-1,) + v.shape[2:]) for k, v in new.items()})
+    new_cache = {k: jnp.concatenate([p[k] for p in parts]) for k in cache}
     logits = _lm_head_logits(params, cfg, x, lm_head_last_only, mm=mm)
     return logits, new_cache
 
@@ -1122,7 +1295,7 @@ def _forward_periods(
 # and values. Not ``wkv_b``, which ``_latent_up`` dequantizes whole, nor
 # the router, the norms and the biases.
 _MM_WEIGHTS = (
-    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down",
     "wq_a", "wq_b", "wkv_a",
 )
 
@@ -1194,6 +1367,7 @@ def _routed_ffn_of(cfg, lp, experts, layer_id, use_pallas, interpret):
             h, lp["w_router"], experts, layer_id, cfg.experts,
             functools.partial(_activation, kind=cfg.activation),
             use_pallas=use_pallas, interpret=interpret,
+            router_bias=lp.get("router_bias"),
         )
         routing.append(idx)
         return out
@@ -1465,16 +1639,21 @@ def forward_paged_decode(
                 )
         return out, pool
 
-    def attn_block(x, pool, scanned):
+    def attn_block(x, pool, scanned, kind="gqa"):
+        lp, layer_id = scanned
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
+        with _attn_scope(kind):
+            out, pool = attend(h, pool, lp, layer_id, kind)
+        return _attn_gate(lp, cfg, h, out, mm), pool
+
+    def attend(h, pool, lp, layer_id, kind):
         # The WHOLE pool rides the scan carry and every layer updates and
         # reads it in place, addressed by layer index. Scanning it as
         # per-layer xs/ys instead makes XLA slice one layer's pages out
         # (a copy), restack them into a second pool-sized buffer, and
         # copy that back over the carried pool every step: temporaries
         # of twice the pool, which a pool sized to the chip cannot pay.
-        lp, layer_id = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps, cfg.norm_scale_plus_one)
-        q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm)
+        q, k, v = _project_qkv(lp, cfg, h, B, S, cos, sin, mm=mm, kind=kind)
 
         kf = k.reshape(B * S, cfg.n_kv_heads, k.shape[-1])
         vf = v.reshape(B * S, cfg.n_kv_heads, v.shape[-1])
@@ -1493,7 +1672,7 @@ def forward_paged_decode(
         )
 
         start = _layer_window_start(
-            cfg, layer_id, bounds[..., 0], q_pos
+            cfg, layer_id, bounds[..., 0], q_pos, kind
         )  # [B, S]
         end = bounds[..., 1]  # [B, S]
 
@@ -1579,11 +1758,11 @@ def forward_paged_decode(
                 k_dense = (
                     to_dense(pool["k"]).astype(jnp.float32)
                     * to_dense(pool["ks"])
-                ).astype(x.dtype)
+                ).astype(h.dtype)
                 v_dense = (
                     to_dense(pool["v"]).astype(jnp.float32)
                     * to_dense(pool["vs"])
-                ).astype(x.dtype)
+                ).astype(h.dtype)
             else:
                 k_dense = to_dense(pool["k"])
                 v_dense = to_dense(pool["v"])
@@ -1686,8 +1865,38 @@ def forward_paged_decode(
         )
         return (x, pool), span
 
+    def run_body(run):
+        # One repetition of a gated stack's run (``_segments``): its
+        # layers in order on their own layer of the pool; a routed run
+        # hands its layers' routing out, stacked.
+        def body(carry, p):
+            x, pool = carry
+            routings = []
+            for kind, layer, lp, routed, routing in _run_layers(
+                params, cfg, run, fused_mm, pallas_interpret, B * S, x.dtype, p
+            ):
+                with jax.named_scope("attn"):
+                    out, pool = attn_block(x, pool, (lp, layer), kind)
+                x = _attn_out_and_ffn(x, out, lp, cfg, B, S, mm=mm, routed=routed)
+                if routing:
+                    routings.append(routing[0])
+            return (x, pool), (jnp.stack(routings) if routings else None)
+
+        return body
+
     with jax.named_scope("layers"):
-        if cfg.period > 1:
+        if cfg.gated is not None:
+            new_pool, routed_runs = pool, []
+            for run in _segments(cfg):
+                (x, new_pool), routing = jax.lax.scan(
+                    run_body(run), (x, new_pool), jnp.arange(run.reps)
+                )
+                if routing is not None:
+                    routed_runs.append(
+                        routing.reshape((-1,) + routing.shape[2:])
+                    )
+            routing = jnp.concatenate(routed_runs) if routed_runs else None
+        elif cfg.ssm is not None:
             (x, new_pool), span = jax.lax.scan(
                 period_body,
                 (x, pool),

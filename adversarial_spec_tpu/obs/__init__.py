@@ -101,7 +101,8 @@ PHASES = (
 # shared expert in ``moe.route``, ``moe.experts``, ``moe.shared`` under
 # ``mlp``.
 DEVICE_SCOPES = (
-    "layers", "attn", "attn.latent", "qmm", "mlp", "moe.route",
+    "layers", "attn", "attn.latent", "attn.window", "attn.full", "attn.gate",
+    "qmm", "mlp", "moe.route",
     "moe.experts", "moe.shared", "head", "sample",
     "ssm", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.commit",
 )
@@ -250,6 +251,9 @@ class HotMetrics:
         "ssm_state_restores",
         "ssm_snapshots",
         "ssm_snapshot_bytes",
+        "kv_window_dead_bytes",
+        "kv_held_bytes",
+        "_attn_kv",
         "_moe",
         "_m",
         "_phase",
@@ -471,6 +475,21 @@ class HotMetrics:
             "advspec_ssm_snapshot_bytes",
             help="device bytes the prefix blocks' state snapshots hold",
         )
+        # Windowed attention layers beside global ones in one page pool
+        # (engine/scheduler.py ``_gauge_pool``): of the bytes the pool
+        # holds for live sequences and cached prefixes, those of windowed
+        # layers that lie behind every window a query can still have.
+        self.kv_window_dead_bytes = m.gauge(
+            "advspec_kv_window_dead_bytes",
+            help="pool bytes of windowed layers that lie behind every "
+            "live sequence's and cached path's window (held, never read)",
+        )
+        self.kv_held_bytes = m.gauge(
+            "advspec_kv_held_bytes",
+            help="pool bytes of the pages live sequences and cached "
+            "prefixes hold, all layers",
+        )
+        self._attn_kv: dict = {}
         self._moe: dict = {}
         self._phase: dict = {}
         self._sync: dict = {}
@@ -504,6 +523,34 @@ class HotMetrics:
                 phase=name,
             )
         return h
+
+    def attn_kv(self, layers: str, bounds: str):
+        """``advspec_attn_kv_tokens_total``: cached tokens a verify step's
+        attention covers, a row's length once a layer a step. ``layers``:
+        "window" or "full" (the layer's kind); ``bounds``: "in" (the
+        tokens inside the layer's bounds: min(length, window)) or "all"
+        (the row's whole length: what no window would read)."""
+        key = (layers, bounds)
+        c = self._attn_kv.get(key)
+        if c is None:
+            c = self._attn_kv[key] = self._m.counter(
+                "advspec_attn_kv_tokens_total",
+                help="cached tokens under a verify step's attention, by "
+                "layer kind, inside its bounds and without them",
+                layers=layers,
+                bounds=bounds,
+            )
+        return c
+
+    def record_attn_read(
+        self, length: int, windows: tuple, n_full: int
+    ) -> None:
+        """One row of one verify step, ``length`` tokens long after it:
+        the windowed layers (one entry of ``windows`` each) cover
+        min(length, window) of them, the ``n_full`` others all."""
+        self.attn_kv("window", "in").inc(sum(min(length, w) for w in windows))
+        self.attn_kv("window", "all").inc(len(windows) * length)
+        self.attn_kv("full", "all").inc(n_full * length)
 
     def moe(self, what: str, positions: str, program: str):
         """Routing counters ``advspec_moe_<what>_total``: ``pairs``

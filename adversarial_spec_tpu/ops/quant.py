@@ -43,6 +43,8 @@ QUANTIZABLE = frozenset(
         # ([L, E, in, out]: scales per layer, expert and output channel);
         # the router stays full precision (its top-k is rounding-sensitive)
         "wq_a", "wq_b", "wkv_a", "wkv_b", "we_gate", "we_up", "we_down",
+        # gated attention's output gate (models/config.py GatedAttention)
+        "wg",
     }
 )
 

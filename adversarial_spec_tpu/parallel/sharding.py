@@ -42,6 +42,11 @@ _PARAM_RULES: dict[str, P] = {
     "bk": P(None, TP),
     "bv": P(None, TP),
     "wo": P(None, TP, None),
+    # Gated attention: the gate splits by head like wq; the head norms
+    # are one head wide.
+    "wg": P(None, None, TP),
+    "q_head_norm": P(None, None),
+    "k_head_norm": P(None, None),
     "w_gate": P(None, None, TP),
     "w_up": P(None, None, TP),
     "w_down": P(None, TP, None),
@@ -56,6 +61,7 @@ _PARAM_RULES: dict[str, P] = {
     # Routed experts [L, E, in, out]: the expert axis over ``ep``, each
     # expert whole on its device; the router is everyone's.
     "w_router": P(None, None, None),
+    "router_bias": P(None, None),
     "we_gate": P(None, EP, None, None),
     "we_up": P(None, EP, None, None),
     "we_down": P(None, EP, None, None),

@@ -1229,7 +1229,7 @@ class TestTwoDeepDrive:
         """`batcher.pipelined_step_share` is `spec.pipelined_steps` over
         `spec.spec_steps` through the standing `counter_ratio` reader:
         both are fields of `perf.spec`, which the benchmark's counters
-        flatten; the entry is the last of `per_layer`, for the two dense
+        flatten; the entry (found by its name) is for the two dense
         critique cells, and a program without the counter (the parent)
         reads nothing and raises nothing."""
         from pathlib import Path
@@ -1245,7 +1245,11 @@ class TestTwoDeepDrive:
         fields = spec_mod.snapshot()
         for key in spec["params"]["num"] + spec["params"]["den"]:
             assert key.startswith("spec.") and key[len("spec."):] in fields
-        entry = json.loads((root / "BENCHMARK.json").read_text())["per_layer"][-1]
+        [entry] = [
+            m
+            for m in json.loads((root / "BENCHMARK.json").read_text())["per_layer"]
+            if m["name"] == "batcher.pipelined_step_share"
+        ]
         assert entry == {
             "name": "batcher.pipelined_step_share", "unit": "%",
             "better": "higher", "source": "program_counter",

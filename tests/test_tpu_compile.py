@@ -544,3 +544,49 @@ def test_state_space_span_kernels_compile(chip, span, rows):
     )
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# The cell's share of Trinity-Large (`afmoe`): one leading dense layer and
+# two periods of windowed and global gated attention, 32 of 256 experts, an
+# eighth of the vocabulary, tables of 32,768 positions.
+TRINITY = get_config(
+    "afmoe", "trinity-large", max_seq_len=32768, n_layers=9,
+    experts_held=(0, 32), vocab_rows=25024,
+)
+
+
+@pytest.mark.parametrize("program", ["verify", "admission_128", "cold_chunk"])
+def test_gated_window_stack_compiles_in_place(chip, program):
+    """The batcher's programs at the cell's share of Trinity-Large, over a
+    pool of 65,536 tokens. The verify step and a cached prompt's admission
+    hold every layer's kernels (the leading layer's 5 attention matmuls, 3
+    of its FFN and its walk; a period body's 4 x (5 + 3 shared + 3 grouped
+    + the walk at 6 query heads a KV head)), read the int8 stacks and the
+    expert stacks by a traced row and update the donated pool in place:
+    temporaries of megabytes. A cold 512-token chunk into the 16,384-slot
+    dense cache of a 13.5k-token prompt keeps XLA's attention: 2.6 GB of
+    temporaries by this analysis (float32 scores of 48 heads x 512 x
+    16,384; PERF.md section 7, "From PR 38" (ii))."""
+    params = _int8_params(chip, TRINITY, expert_quant="int8")
+    if program == "verify":
+        compiled = _compiled_verify_step(chip, TRINITY, params, 1025)
+        kernels, temp = 9 + 4 * 12, 64 << 20
+    elif program == "admission_128":
+        compiled = _compiled_paged_admission(chip, TRINITY, params, 1025, 128)
+        kernels, temp = 9 + 4 * 12, 64 << 20
+    else:
+        from adversarial_spec_tpu.engine.generate import prefill_chunk
+        from adversarial_spec_tpu.models.transformer import init_cache
+
+        cache = _on_chip(
+            chip,
+            jax.eval_shape(lambda: init_cache(TRINITY, 1, 16384, dtype=jnp.bfloat16)),
+        )
+        compiled = prefill_chunk.lower(
+            params, TRINITY, _shape(chip, (1, 512), jnp.int32),
+            _shape(chip, (1,), jnp.int32), cache, _shape(chip, (), jnp.int32),
+            use_pallas_matmul=True,
+        ).compile()
+        kernels, temp = 8 + 4 * 11, 3 << 30
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
