@@ -1964,26 +1964,29 @@ class ContinuousBatcher:
         # record=False: a pool-full deferral retries this whole method
         # every scheduler iteration — stats count once, on success, with
         # the clamped (actually adopted) match.
-        if self.tiers is not None:
-            matched, pages, tier_hits = self.prefix_cache.lookup_tiered(
-                ids, record=False
-            )
-        else:
-            matched, pages = self.prefix_cache.lookup(ids, record=False)
-            tier_hits = []
-        # Keep at least the last token to prefill (logits source).
-        limit = ((S_real - 1) // ps) * ps
-        matched = min(matched, limit)
-        radix_matched, state0 = None, None
-        if self.cfg.ssm is not None:
-            # Pages alone do not restore a prefix: the admission resumes
-            # at the deepest boundary under the match whose block still
-            # carries a snapshot of the recurrent state, and recomputes
-            # the rest, K/V and state alike. A host-tier block carries
-            # none, so nothing is promoted.
-            radix_matched = matched
-            matched, state0 = self.prefix_cache.lookup_state(ids, matched)
-            tier_hits = []
+        with obs_mod.phase("admit.lookup"):
+            if self.tiers is not None:
+                matched, pages, tier_hits = self.prefix_cache.lookup_tiered(
+                    ids, record=False
+                )
+            else:
+                matched, pages = self.prefix_cache.lookup(ids, record=False)
+                tier_hits = []
+            # Keep at least the last token to prefill (logits source).
+            limit = ((S_real - 1) // ps) * ps
+            matched = min(matched, limit)
+            radix_matched, state0 = None, None
+            if self.cfg.ssm is not None:
+                # Pages alone do not restore a prefix: the admission
+                # resumes at the deepest boundary under the match whose
+                # block still carries a snapshot of the recurrent state,
+                # and recomputes the rest, K/V and state alike. A
+                # host-tier block carries none, so nothing is promoted.
+                radix_matched = matched
+                matched, state0 = self.prefix_cache.lookup_state(
+                    ids, matched
+                )
+                tier_hits = []
         pages = pages[: matched // ps]
         tier_hits = tier_hits[: (limit - matched) // ps]
         S = bucket_length(S_real)
@@ -1994,22 +1997,22 @@ class ContinuousBatcher:
                 self.allocator.adopt(seq_id, pages, matched)
             # Same lazy-reservation rule as the padded path: prompt + 1
             # under speculation, full budget otherwise.
-            self._extend_evicting(
-                seq_id,
-                (S_real - matched)
-                + (1 if self.speculative else req.max_new_tokens),
-            )
+            with obs_mod.phase("admit.evict"):
+                self._extend_evicting(
+                    seq_id,
+                    (S_real - matched)
+                    + (1 if self.speculative else req.max_new_tokens),
+                )
             # Lower-tier blocks continuing the device match promote into
             # the pages the extend just reserved — async host→device
             # writes that overlap the delta prefill below; a hit that
             # lost the race degrades to prefill (chaos seam: kv_swap).
-            promoted = (
-                self._promote_tier_blocks(
-                    slot, seq_id, ids, matched, tier_hits
-                )
-                if tier_hits
-                else 0
-            )
+            promoted = 0
+            if tier_hits:
+                with obs_mod.phase("admit.promote"):
+                    promoted = self._promote_tier_blocks(
+                        slot, seq_id, ids, matched, tier_hits
+                    )
             total = matched + promoted
             over_pages = 0 < total and S_real - total <= ADMISSION_CHUNK
             adm = _Admission(
@@ -2148,53 +2151,54 @@ class ContinuousBatcher:
             # one program.
             self._finish_admission()
             return
-        t0 = time.monotonic()
-        chunk_len = _next_chunk_len(adm.remaining)
-        adm.cache, adm.last_logits = prefill_chunk(
-            self.params,
-            self.cfg,
-            adm.tokens[:, adm.pos : adm.pos + chunk_len],
-            adm.pads,
-            adm.cache,
-            jnp.int32(adm.pos),
-            use_pallas_matmul=self._prefill_pallas_matmul,
-            pallas_interpret=self._pallas_interpret,
-        )
-        adm.pos += chunk_len
-        self._keep_snapshot(adm, chunk_len)
-        # Block before stamping: async dispatch would otherwise push this
-        # chunk's device time into the NEXT decode chunk's blocked wait,
-        # billing resident rows for the newcomer's prefill. A standalone
-        # chunk is a genuine stall, so this sync is sanctioned (GL-SYNC
-        # allowlists this method in [tool.graftlint]).
-        jax.block_until_ready(adm.last_logits)
-        elapsed = time.monotonic() - t0
-        self._record_prefill_time(elapsed, overlapped=False)
-        adm.prefill_s += elapsed
-        interleave_mod.stats.record_step(fused=False, prefill_only=True)
-        prefix_mod.stats.record_prefill(chunk_len, 0)
-        if obs_mod.config().enabled:
-            obs_mod.retrace.observe(
-                "prefill_chunk", ("prefill", chunk_len, adm.S),
-                fn=prefill_chunk,
+        with obs_mod.phase("prefill.chunk"):
+            t0 = time.monotonic()
+            chunk_len = _next_chunk_len(adm.remaining)
+            adm.cache, adm.last_logits = prefill_chunk(
+                self.params,
+                self.cfg,
+                adm.tokens[:, adm.pos : adm.pos + chunk_len],
+                adm.pads,
+                adm.cache,
+                jnp.int32(adm.pos),
+                use_pallas_matmul=self._prefill_pallas_matmul,
+                pallas_interpret=self._pallas_interpret,
             )
-            obs_mod.hot.prefill_chunk.observe(elapsed)
-            obs_mod.emit(
-                obs_mod.StepEvent(
-                    kind="prefill",
-                    n_live=int(sum(self._active_np)),
-                    admission_slot=adm.slot,
-                    prefill_tokens=chunk_len,
+            adm.pos += chunk_len
+            self._keep_snapshot(adm, chunk_len)
+            # Block before stamping: async dispatch would otherwise push this
+            # chunk's device time into the NEXT decode chunk's blocked wait,
+            # billing resident rows for the newcomer's prefill. A standalone
+            # chunk is a genuine stall, so this sync is sanctioned (GL-SYNC
+            # allowlists this method in [tool.graftlint]).
+            jax.block_until_ready(adm.last_logits)
+            elapsed = time.monotonic() - t0
+            self._record_prefill_time(elapsed, overlapped=False)
+            adm.prefill_s += elapsed
+            interleave_mod.stats.record_step(fused=False, prefill_only=True)
+            prefix_mod.stats.record_prefill(chunk_len, 0)
+            if obs_mod.config().enabled:
+                obs_mod.retrace.observe(
+                    "prefill_chunk", ("prefill", chunk_len, adm.S),
+                    fn=prefill_chunk,
                 )
-            )
-            obs_mod.emit(
-                obs_mod.RequestEvent(
-                    req_id=adm.req.req_id,
-                    state="prefill",
-                    slot=adm.slot,
-                    tokens=chunk_len,
+                obs_mod.hot.prefill_chunk.observe(elapsed)
+                obs_mod.emit(
+                    obs_mod.StepEvent(
+                        kind="prefill",
+                        n_live=int(sum(self._active_np)),
+                        admission_slot=adm.slot,
+                        prefill_tokens=chunk_len,
+                    )
                 )
-            )
+                obs_mod.emit(
+                    obs_mod.RequestEvent(
+                        req_id=adm.req.req_id,
+                        state="prefill",
+                        slot=adm.slot,
+                        tokens=chunk_len,
+                    )
+                )
         if adm.pos >= adm.prefill_end:
             self._finish_admission()
 
@@ -2292,123 +2296,133 @@ class ContinuousBatcher:
         t0 = time.monotonic()
         adm = self._admission
         slot, req, seq_id = adm.slot, adm.req, adm.seq_id
-        # Canonical rows live at pad 0 with their true length; padded
-        # rows keep the bucketed length + left pad. Per-row pad_lens and
-        # cur_len let both layouts coexist in one decode batch.
-        row_len = adm.S_real if adm.canonical else adm.S
-        row_table = np.zeros((self.max_pages_per_seq,), np.int32)
-        table = self.allocator.table(seq_id)
-        row_table[: len(table)] = np.asarray(table, np.int32) + 1
-        ids_np = np.asarray(req.prompt_ids, np.int32)
-        handoff = dict(
-            rows={name: getattr(self, name) for name in _ROW_STATE},
-            out_buf=self.out_buf,
-            ctx_buf=None,
-            slot=jnp.int32(slot),
-            row_table=jnp.asarray(row_table),
-            max_new=jnp.int32(req.max_new_tokens),
-            eos_ids=self._eos,
-            ctx_row=None,
-            n_ctx=None,
-            prev=None,
-        )
-        if self.speculative:
-            # The draft source: the row's REAL (unpadded) prompt ids,
-            # then its first sampled token. ctx coordinates are
-            # independent of the KV layout — padded rows draft from the
-            # same clean token stream canonical rows do.
-            ctx_row = np.zeros((self._ctx_cap,), np.int32)
-            ctx_row[: len(ids_np)] = ids_np
-            handoff.update(
-                ctx_buf=self.ctx_buf,
-                ctx_row=jnp.asarray(ctx_row),
-                n_ctx=jnp.int32(len(ids_np)),
-                prev=jnp.int32(ids_np[-1] if len(ids_np) else 0),
+        with obs_mod.phase("handoff.launch"):
+            # Canonical rows live at pad 0 with their true length; padded
+            # rows keep the bucketed length + left pad. Per-row pad_lens and
+            # cur_len let both layouts coexist in one decode batch.
+            row_len = adm.S_real if adm.canonical else adm.S
+            row_table = np.zeros((self.max_pages_per_seq,), np.int32)
+            table = self.allocator.table(seq_id)
+            row_table[: len(table)] = np.asarray(table, np.int32) + 1
+            ids_np = np.asarray(req.prompt_ids, np.int32)
+            handoff = dict(
+                rows={name: getattr(self, name) for name in _ROW_STATE},
+                out_buf=self.out_buf,
+                ctx_buf=None,
+                slot=jnp.int32(slot),
+                row_table=jnp.asarray(row_table),
+                max_new=jnp.int32(req.max_new_tokens),
+                eos_ids=self._eos,
+                ctx_row=None,
+                n_ctx=None,
+                prev=None,
             )
-        self._key, sub = jax.random.split(self._key)
-        sampling = dict(
-            greedy=self.greedy,
-            top_k=self.top_k,
-            temperature=self._temp,
-            top_p=self._top_p,
-            use_top_p=self._use_top_p,
-        )
-        if adm.cache is None:
-            n_real = adm.S_real - adm.pos
-            width = _span_width(n_real)
-            delta = np.zeros((1, width), np.int32)
-            delta[0, :n_real] = ids_np[adm.pos :]
-            # The walk kernel skips what a row does not hold; the gather
-            # path (off the TPU) reads the table's whole width, so it
-            # gets the prompt's bucket, as a dense cache would be sized.
-            table_pages = (
-                self.max_pages_per_seq
-                if self._use_pallas
-                else min(self.max_pages_per_seq, -(-adm.S // self.page_size))
-            )
-            self.pool, rows, self.out_buf, ctx_buf, first = paged_admission(
-                self.params,
-                self.cfg,
-                self.pool,
-                tokens=jnp.asarray(delta),
-                start=jnp.int32(adm.pos),
-                n_real=jnp.int32(n_real),
-                key=sub,
-                state0=adm.state0,
-                table_pages=table_pages,
-                use_pallas=self._use_pallas,
-                use_pallas_matmul=self._use_pallas_matmul,
-                pallas_interpret=self._pallas_interpret,
-                **handoff,
-                **sampling,
-            )
-            interleave_mod.stats.record_step(fused=False, prefill_only=True)
-            prefix_mod.stats.record_prefill(n_real, 0)
-            if obs_mod.config().enabled:
-                obs_mod.retrace.observe(
-                    "paged_admission",
-                    (
-                        "paged_admission", width, table_pages, self.B,
-                        self.cap, self.greedy,
-                    ),
-                    fn=paged_admission,
+            if self.speculative:
+                # The draft source: the row's REAL (unpadded) prompt ids,
+                # then its first sampled token. ctx coordinates are
+                # independent of the KV layout — padded rows draft from the
+                # same clean token stream canonical rows do.
+                ctx_row = np.zeros((self._ctx_cap,), np.int32)
+                ctx_row[: len(ids_np)] = ids_np
+                handoff.update(
+                    ctx_buf=self.ctx_buf,
+                    ctx_row=jnp.asarray(ctx_row),
+                    n_ctx=jnp.int32(len(ids_np)),
+                    prev=jnp.int32(ids_np[-1] if len(ids_np) else 0),
                 )
-                obs_mod.emit(
-                    obs_mod.StepEvent(
-                        kind="prefill",
-                        n_live=int(sum(self._active_np)),
-                        admission_slot=slot,
-                        prefill_tokens=n_real,
+            self._key, sub = jax.random.split(self._key)
+            sampling = dict(
+                greedy=self.greedy,
+                top_k=self.top_k,
+                temperature=self._temp,
+                top_p=self._top_p,
+                use_top_p=self._use_top_p,
+            )
+            if adm.cache is None:
+                n_real = adm.S_real - adm.pos
+                width = _span_width(n_real)
+                delta = np.zeros((1, width), np.int32)
+                delta[0, :n_real] = ids_np[adm.pos :]
+                # The walk kernel skips what a row does not hold; the gather
+                # path (off the TPU) reads the table's whole width, so it
+                # gets the prompt's bucket, as a dense cache would be sized.
+                table_pages = (
+                    self.max_pages_per_seq
+                    if self._use_pallas
+                    else min(
+                        self.max_pages_per_seq, -(-adm.S // self.page_size)
                     )
                 )
-                obs_mod.emit(
-                    obs_mod.RequestEvent(
-                        req_id=req.req_id,
-                        state="prefill",
-                        slot=slot,
-                        tokens=n_real,
-                    )
+                (
+                    self.pool, rows, self.out_buf, ctx_buf, first
+                ) = paged_admission(
+                    self.params,
+                    self.cfg,
+                    self.pool,
+                    tokens=jnp.asarray(delta),
+                    start=jnp.int32(adm.pos),
+                    n_real=jnp.int32(n_real),
+                    key=sub,
+                    state0=adm.state0,
+                    table_pages=table_pages,
+                    use_pallas=self._use_pallas,
+                    use_pallas_matmul=self._use_pallas_matmul,
+                    pallas_interpret=self._pallas_interpret,
+                    **handoff,
+                    **sampling,
                 )
-        else:
-            first = self._dense_cache_to_pages(adm, row_table, sub, sampling)
-            # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: a padded row's left pad is a host number from here on
-            pad = 0 if adm.canonical else int(np.asarray(adm.pads)[0])
-            rows, self.out_buf, ctx_buf = activate_slot(
-                first=first,
-                row_len=jnp.int32(row_len),
-                pad=jnp.int32(pad),
-                **handoff,
-            )
-        for name in _ROW_STATE:
-            setattr(self, name, rows[name])
-        if self.speculative:
-            self.ctx_buf = ctx_buf
-        # Admission handoff is a sanctioned sync point: ``first`` was
-        # fetched above, blocking on every step in flight.
-        interleave_mod.stats.record_sync()
-        obs_mod.record_sync("admission_handoff")
-        # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: the first sampled token decides slot activation (and seeds the slot's stream delivery)
-        first_np = np.asarray(first)
+                interleave_mod.stats.record_step(
+                    fused=False, prefill_only=True
+                )
+                prefix_mod.stats.record_prefill(n_real, 0)
+                if obs_mod.config().enabled:
+                    obs_mod.retrace.observe(
+                        "paged_admission",
+                        (
+                            "paged_admission", width, table_pages, self.B,
+                            self.cap, self.greedy,
+                        ),
+                        fn=paged_admission,
+                    )
+                    obs_mod.emit(
+                        obs_mod.StepEvent(
+                            kind="prefill",
+                            n_live=int(sum(self._active_np)),
+                            admission_slot=slot,
+                            prefill_tokens=n_real,
+                        )
+                    )
+                    obs_mod.emit(
+                        obs_mod.RequestEvent(
+                            req_id=req.req_id,
+                            state="prefill",
+                            slot=slot,
+                            tokens=n_real,
+                        )
+                    )
+            else:
+                first = self._dense_cache_to_pages(
+                    adm, row_table, sub, sampling
+                )
+                # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: a padded row's left pad is a host number from here on
+                pad = 0 if adm.canonical else int(np.asarray(adm.pads)[0])
+                rows, self.out_buf, ctx_buf = activate_slot(
+                    first=first,
+                    row_len=jnp.int32(row_len),
+                    pad=jnp.int32(pad),
+                    **handoff,
+                )
+            for name in _ROW_STATE:
+                setattr(self, name, rows[name])
+            if self.speculative:
+                self.ctx_buf = ctx_buf
+            # Admission handoff is a sanctioned sync point: ``first`` was
+            # fetched above, blocking on every step in flight.
+            interleave_mod.stats.record_sync()
+            obs_mod.record_sync("admission_handoff")
+        with obs_mod.phase("handoff.sync"):
+            # graftlint: disable=GL-SYNC -- admission handoff is a sanctioned sync point: the first sampled token decides slot activation (and seeds the slot's stream delivery)
+            first_np = np.asarray(first)
         # What ``_activate_slot_impl`` decided on the device, from the
         # same token.
         row_active = (req.max_new_tokens > 1) and not bool(
@@ -2427,19 +2441,20 @@ class ContinuousBatcher:
         # --no-speculative' is the field contract).
         self._slot_spec[slot] = [0, 0, 0]
         if adm.canonical and self.prefix_cache is not None:
-            # Cache this prompt's full blocks (the already-adopted prefix
-            # re-inserts as a no-op; only new tail blocks take refs).
-            n_full = adm.S_real // self.page_size
-            if n_full:
-                self.prefix_cache.insert(
-                    list(req.prompt_ids[: n_full * self.page_size]),
-                    self.allocator.table(seq_id)[:n_full],
-                )
-            for boundary, snap in adm.snapshots.items():
-                self.prefix_cache.attach_state(
-                    req.prompt_ids, boundary, snap, self._snapshot_bytes
-                )
-            prefix_mod.stats.record_prefill(0, adm.matched)
+            with obs_mod.phase("handoff.insert"):
+                # Cache this prompt's full blocks (the already-adopted prefix
+                # re-inserts as a no-op; only new tail blocks take refs).
+                n_full = adm.S_real // self.page_size
+                if n_full:
+                    self.prefix_cache.insert(
+                        list(req.prompt_ids[: n_full * self.page_size]),
+                        self.allocator.table(seq_id)[:n_full],
+                    )
+                for boundary, snap in adm.snapshots.items():
+                    self.prefix_cache.attach_state(
+                        req.prompt_ids, boundary, snap, self._snapshot_bytes
+                    )
+                prefix_mod.stats.record_prefill(0, adm.matched)
         # Ownership handoff: from here the slot (not the admission)
         # accounts for the sequence.
         self._admission = None
@@ -2456,7 +2471,8 @@ class ContinuousBatcher:
             # output from now on, and a fresh array would be another
             # signature.
             self._demotion_warm = True
-            self._fetch_page_kv(0, self.page_size)
+            with obs_mod.phase("handoff.insert"):
+                self._fetch_page_kv(0, self.page_size)
         self._slot_cached[slot] = adm.matched
         self._slot_trace[slot] = req.trace_id
         self._slot_span[slot] = req.span_id
@@ -2471,61 +2487,63 @@ class ContinuousBatcher:
         self._record_prefill_time(elapsed, overlapped=False)
         self._slot_prefill_s[slot] = adm.prefill_s + elapsed
         if obs_mod.config().enabled:
-            # This request's own prefill wall (stalled + overlapped
-            # chunks) through the handoff that produced its first
-            # sampled token. No queueing in it: not a TTFT.
-            obs_mod.hot.prefill_wall.observe(self._slot_prefill_s[slot])
-            self._gauge_pool()
-            obs_mod.emit(
-                obs_mod.RequestEvent(
-                    req_id=req.req_id,
-                    state="decode",
-                    slot=slot,
-                    tokens=1,
-                    cached_tokens=adm.matched,
+            with obs_mod.phase("handoff.books"):
+                # This request's own prefill wall (stalled + overlapped
+                # chunks) through the handoff that produced its first
+                # sampled token. No queueing in it: not a TTFT.
+                obs_mod.hot.prefill_wall.observe(self._slot_prefill_s[slot])
+                self._gauge_pool()
+                obs_mod.emit(
+                    obs_mod.RequestEvent(
+                        req_id=req.req_id,
+                        state="decode",
+                        slot=slot,
+                        tokens=1,
+                        cached_tokens=adm.matched,
+                    )
                 )
-            )
-            # Trace spans: prefill closes with this request's OWN
-            # prefill wall (stalled + overlapped chunks + handoff —
-            # exactly SchedResult.prefill_time_s), decode opens. The
-            # TTFT SLO gate sees the same wall the ttft histogram does;
-            # a breach arms the once-per-request trace-scoped capture.
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="prefill",
-                    phase="end",
-                    req_id=req.req_id,
-                    slot=slot,
-                    wall_s=self._slot_prefill_s[slot],
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                # Trace spans: prefill closes with this request's OWN
+                # prefill wall (stalled + overlapped chunks + handoff —
+                # exactly SchedResult.prefill_time_s), decode opens. The
+                # TTFT SLO gate sees the same wall the ttft histogram does;
+                # a breach arms the once-per-request trace-scoped capture.
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="prefill",
+                        phase="end",
+                        req_id=req.req_id,
+                        slot=slot,
+                        wall_s=self._slot_prefill_s[slot],
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="decode",
-                    phase="begin",
-                    req_id=req.req_id,
-                    slot=slot,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="decode",
+                        phase="begin",
+                        req_id=req.req_id,
+                        slot=slot,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            obs_mod.slo_check(
-                "ttft", req.span_id, self._slot_prefill_s[slot]
-            )
-        # First-token stream delivery: ``first`` was already fetched
-        # for the EOS check above, so this rides the handoff sync. A
-        # consumer that cancels on the very first token (its marker is
-        # a single token, or the prompt itself decided the verdict)
-        # stops the row before it ever joins a decode step.
-        if req.on_tokens is not None:
-            keep = self._deliver_stream(slot, 1, first_np.reshape(1))
-            if not keep and row_active:
-                self._cancel_slot(slot, 1, first_np.reshape(1))
-                return
-        if not row_active:
-            self._finish_slot(slot)
+                obs_mod.slo_check(
+                    "ttft", req.span_id, self._slot_prefill_s[slot]
+                )
+        with obs_mod.phase("handoff.deliver"):
+            # First-token stream delivery: ``first`` was already fetched
+            # for the EOS check above, so this rides the handoff sync. A
+            # consumer that cancels on the very first token (its marker is
+            # a single token, or the prompt itself decided the verdict)
+            # stops the row before it ever joins a decode step.
+            if req.on_tokens is not None:
+                keep = self._deliver_stream(slot, 1, first_np.reshape(1))
+                if not keep and row_active:
+                    self._cancel_slot(slot, 1, first_np.reshape(1))
+                    return
+            if not row_active:
+                self._finish_slot(slot)
 
     def _admit(self) -> None:
         """Fill free slots from the queue. Single-chunk (short) prompts
@@ -2808,8 +2826,10 @@ class ContinuousBatcher:
         # budget bought (the watchdog's contract — partial text
         # delivered, then the timeout fault). The cancel return is
         # moot; the slot is going away regardless.
-        self._deliver_stream(slot, n, partial)
-        pages_freed = self._release_slot(slot)
+        with obs_mod.phase("collect.deliver"):
+            self._deliver_stream(slot, n, partial)
+        with obs_mod.phase("collect.release"):
+            pages_freed = self._release_slot(slot)
         interleave_mod.stats.record_sync()  # fault decision point
         obs_mod.record_sync("fault")
         if obs_mod.config().enabled:
@@ -2961,22 +2981,24 @@ class ContinuousBatcher:
         # reports the exact remainder instead; engine/streaming.py).
         saved = max(int(req.max_new_tokens) - n, 0)
         if self.prefix_cache is not None:
-            covered = len(req.prompt_ids) + max(n - 1, 0)
-            n_full = covered // self.page_size
-            if n_full:
-                ids = list(req.prompt_ids) + [
-                    int(t) for t in tokens[: max(n - 1, 0)]
-                ]
-                self.prefix_cache.insert(
-                    ids[: n_full * self.page_size],
-                    self.allocator.table(seq)[:n_full],
-                )
+            with obs_mod.phase("collect.books"):
+                covered = len(req.prompt_ids) + max(n - 1, 0)
+                n_full = covered // self.page_size
+                if n_full:
+                    ids = list(req.prompt_ids) + [
+                        int(t) for t in tokens[: max(n - 1, 0)]
+                    ]
+                    self.prefix_cache.insert(
+                        ids[: n_full * self.page_size],
+                        self.allocator.table(seq)[:n_full],
+                    )
         st = self._slot_spec[slot]
         cached = self._slot_cached[slot]
         prefill_s = self._slot_prefill_s[slot]
         decode_s = self._slot_decode_s[slot]
         queue_s = self._slot_queue_s[slot]
-        self._release_slot(slot)
+        with obs_mod.phase("collect.release"):
+            self._release_slot(slot)
         self._deadline_t.pop(req.req_id, None)
         stream_mod.stats.record_cancel(n, saved)
         self.results.append(
@@ -2998,66 +3020,67 @@ class ContinuousBatcher:
             )
         )
         if obs_mod.config().enabled:
-            obs_mod.hot.cancel(reason).inc()
-            obs_mod.hot.cancel_tokens_saved.observe(float(saved))
-            if self.speculative and st[1]:
-                obs_mod.hot.spec_acceptance.observe(st[2] / st[1])
-            self._gauge_pool()
-            obs_mod.emit(
-                obs_mod.RequestEvent(
-                    req_id=req.req_id,
-                    state="cancelled",
-                    slot=slot,
-                    tokens=n,
-                    cached_tokens=cached,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+            with obs_mod.phase("collect.books"):
+                obs_mod.hot.cancel(reason).inc()
+                obs_mod.hot.cancel_tokens_saved.observe(float(saved))
+                if self.speculative and st[1]:
+                    obs_mod.hot.spec_acceptance.observe(st[2] / st[1])
+                self._gauge_pool()
+                obs_mod.emit(
+                    obs_mod.RequestEvent(
+                        req_id=req.req_id,
+                        state="cancelled",
+                        slot=slot,
+                        tokens=n,
+                        cached_tokens=cached,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            obs_mod.emit(
-                obs_mod.CancelEvent(
-                    req_id=req.req_id,
-                    slot=slot,
-                    reason=reason,
-                    tokens_emitted=n,
-                    tokens_saved=saved,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                obs_mod.emit(
+                    obs_mod.CancelEvent(
+                        req_id=req.req_id,
+                        slot=slot,
+                        reason=reason,
+                        tokens_emitted=n,
+                        tokens_saved=saved,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            # Truncated span set: decode closes with the slot's
-            # accumulated share, the request envelope closes with
-            # phase ``cancelled`` and the service wall SO FAR — still
-            # exactly prefill + decode, so tools/trace_view.py's
-            # decomposition check holds for cancelled requests too.
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="decode",
-                    phase="end",
-                    req_id=req.req_id,
-                    slot=slot,
-                    wall_s=decode_s,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                # Truncated span set: decode closes with the slot's
+                # accumulated share, the request envelope closes with
+                # phase ``cancelled`` and the service wall SO FAR — still
+                # exactly prefill + decode, so tools/trace_view.py's
+                # decomposition check holds for cancelled requests too.
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="decode",
+                        phase="end",
+                        req_id=req.req_id,
+                        slot=slot,
+                        wall_s=decode_s,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="request",
-                    phase="cancelled",
-                    req_id=req.req_id,
-                    slot=slot,
-                    wall_s=prefill_s + decode_s,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="request",
+                        phase="cancelled",
+                        req_id=req.req_id,
+                        slot=slot,
+                        wall_s=prefill_s + decode_s,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            # A cancelled request still consumed service: the round SLO
-            # judges the wall it actually paid, exactly as
-            # ``_finish_slot`` does (and as the mock does for cancelled
-            # lifecycles) — a breach that happens to end in a cancel
-            # must still count and self-capture.
-            obs_mod.slo_check("round", req.span_id, prefill_s + decode_s)
+                # A cancelled request still consumed service: the round SLO
+                # judges the wall it actually paid, exactly as
+                # ``_finish_slot`` does (and as the mock does for cancelled
+                # lifecycles) — a breach that happens to end in a cancel
+                # must still count and self-capture.
+                obs_mod.slo_check("round", req.span_id, prefill_s + decode_s)
 
     # -- completion --------------------------------------------------------
 
@@ -3081,83 +3104,87 @@ class ContinuousBatcher:
     def _finish_slot(self, slot: int) -> None:
         self._active_np[slot] = False  # invariant: no owner ⇒ not live
         req = self._slot_req[slot]
-        n, row = self._slot_tokens(slot)
+        with obs_mod.phase("collect.fetch"):
+            n, row = self._slot_tokens(slot)
         st = self._slot_spec[slot]
-        # Final-tail stream delivery: an EOS/budget-terminated row hands
-        # its consumer the last tokens here (a late cancel is moot —
-        # the row is already done, nothing left to save).
-        self._deliver_stream(slot, n, row)
-        self.results.append(
-            SchedResult(
-                req_id=req.req_id,
-                tokens=row,
-                n_generated=n,
-                cached_tokens=self._slot_cached[slot],
-                prefill_time_s=self._slot_prefill_s[slot],
-                spec_steps=st[0],
-                spec_drafted=st[1],
-                spec_accepted=st[2],
-                decode_time_s=self._slot_decode_s[slot],
-                queue_wait_s=self._slot_queue_s[slot],
-                trace_id=req.trace_id,
-                span_id=req.span_id,
+        with obs_mod.phase("collect.deliver"):
+            # Final-tail stream delivery: an EOS/budget-terminated row hands
+            # its consumer the last tokens here (a late cancel is moot —
+            # the row is already done, nothing left to save).
+            self._deliver_stream(slot, n, row)
+            self.results.append(
+                SchedResult(
+                    req_id=req.req_id,
+                    tokens=row,
+                    n_generated=n,
+                    cached_tokens=self._slot_cached[slot],
+                    prefill_time_s=self._slot_prefill_s[slot],
+                    spec_steps=st[0],
+                    spec_drafted=st[1],
+                    spec_accepted=st[2],
+                    decode_time_s=self._slot_decode_s[slot],
+                    queue_wait_s=self._slot_queue_s[slot],
+                    trace_id=req.trace_id,
+                    span_id=req.span_id,
+                )
             )
-        )
-        if self.speculative and st[1] and obs_mod.config().enabled:
-            # Per-request acceptance rate at completion — the obs
-            # histogram the ISSUE's serving headline reads from.
-            obs_mod.hot.spec_acceptance.observe(st[2] / st[1])
+            if self.speculative and st[1] and obs_mod.config().enabled:
+                # Per-request acceptance rate at completion — the obs
+                # histogram the ISSUE's serving headline reads from.
+                obs_mod.hot.spec_acceptance.observe(st[2] / st[1])
         # The shared release surgery (also fault eviction's and
         # cancellation's): beyond the ref drop it clears _slot_seq —
         # the hand-rolled version left it stale — and keeps every
         # release invariant in one place.
-        self._release_slot(slot)
+        with obs_mod.phase("collect.release"):
+            self._release_slot(slot)
         self._deadline_t.pop(req.req_id, None)
         if obs_mod.config().enabled:
-            obs_mod.hot.req_finished.inc()
-            self._gauge_pool()
-            obs_mod.emit(
-                obs_mod.RequestEvent(
-                    req_id=req.req_id,
-                    state="finished",
-                    slot=slot,
-                    tokens=n,
-                    cached_tokens=self._slot_cached[slot],
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+            with obs_mod.phase("collect.books"):
+                obs_mod.hot.req_finished.inc()
+                self._gauge_pool()
+                obs_mod.emit(
+                    obs_mod.RequestEvent(
+                        req_id=req.req_id,
+                        state="finished",
+                        slot=slot,
+                        tokens=n,
+                        cached_tokens=self._slot_cached[slot],
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            # Close the trace spans: decode with the slot's accumulated
-            # decode share, the request envelope with prefill + decode
-            # (its SERVICE wall — the sum tools/trace_view.py checks
-            # against the stage walls, and the value the per-request
-            # round SLO gate judges).
-            service_s = (
-                self._slot_prefill_s[slot] + self._slot_decode_s[slot]
-            )
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="decode",
-                    phase="end",
-                    req_id=req.req_id,
-                    slot=slot,
-                    wall_s=self._slot_decode_s[slot],
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                # Close the trace spans: decode with the slot's accumulated
+                # decode share, the request envelope with prefill + decode
+                # (its SERVICE wall — the sum tools/trace_view.py checks
+                # against the stage walls, and the value the per-request
+                # round SLO gate judges).
+                service_s = (
+                    self._slot_prefill_s[slot] + self._slot_decode_s[slot]
                 )
-            )
-            obs_mod.emit(
-                obs_mod.SpanEvent(
-                    name="request",
-                    phase="end",
-                    req_id=req.req_id,
-                    slot=slot,
-                    wall_s=service_s,
-                    trace_id=req.trace_id,
-                    span_id=req.span_id,
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="decode",
+                        phase="end",
+                        req_id=req.req_id,
+                        slot=slot,
+                        wall_s=self._slot_decode_s[slot],
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
                 )
-            )
-            obs_mod.slo_check("round", req.span_id, service_s)
+                obs_mod.emit(
+                    obs_mod.SpanEvent(
+                        name="request",
+                        phase="end",
+                        req_id=req.req_id,
+                        slot=slot,
+                        wall_s=service_s,
+                        trace_id=req.trace_id,
+                        span_id=req.span_id,
+                    )
+                )
+                obs_mod.slo_check("round", req.span_id, service_s)
 
     def _collect(self, active_np: np.ndarray | None = None) -> None:
         """Resolve finished slots. Timeout expiry passes nothing (full

@@ -69,11 +69,33 @@ from adversarial_spec_tpu.obs.retrace import RetraceWatch
 
 DEFAULT_RECORDER_SIZE = 512
 
+# The phases that nest inside another, and the one the drive loop opens
+# them in: the parts of an admission and a completion, each run once an
+# admission, an eviction or a completion, never on the verify step's own
+# path. (A completion elsewhere, a row that ends at its first token or a
+# cancel at a step's delivery, runs the ``collect.*`` spans inside
+# ``handoff.deliver`` / ``drive.stream``.)
+PHASE_PARENT = {
+    "admit.lookup": "drive.admit",
+    "admit.evict": "drive.admit",
+    "admit.promote": "drive.admit",
+    "prefill.chunk": "drive.prefill",
+    "handoff.launch": "drive.prefill",
+    "handoff.sync": "drive.prefill",
+    "handoff.insert": "drive.prefill",
+    "handoff.books": "drive.prefill",
+    "handoff.deliver": "drive.prefill",
+    "collect.fetch": "drive.collect",
+    "collect.deliver": "drive.collect",
+    "collect.release": "drive.collect",
+    "collect.books": "drive.collect",
+}
+
 # THE closed vocabulary of ``phase()``: every layer boundary of the
 # serving path that is timed, from the daemon's coalesced dispatch down
-# to the drive loop's iteration. One name = one label of
-# ``advspec_phase_seconds`` = one ``advspec.<name>`` span in a profile
-# (docs/observability.md lists what each covers).
+# to the drive loop's iteration, and what nests in it. One name = one
+# label of ``advspec_phase_seconds`` = one ``advspec.<name>`` span in a
+# profile (docs/observability.md lists what each covers).
 PHASES = (
     "serve.dispatch",
     "engine.tokenize",
@@ -89,7 +111,7 @@ PHASES = (
     "drive.apply",
     "drive.stream",
     "drive.collect",
-)
+) + tuple(PHASE_PARENT)
 
 # The device side's names: the ``jax.named_scope``s of the step programs
 # (models/transformer.py, ops/quant.py, engine/scheduler.py), which reach

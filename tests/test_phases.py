@@ -33,6 +33,7 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 DRIVE = [p for p in obs.PHASES if p.startswith("drive.")]
 INNER = [p for p in DRIVE if p != "drive.iteration"]
+NESTED = set(obs.PHASE_PARENT)  # an admission's and a completion's parts
 # The metric files this PR brought: the ones that read a phase, a
 # request stage or a batcher counter of the registry.
 NEW_SERIES = re.compile(
@@ -61,23 +62,39 @@ def spec_on():
     spec_mod.configure(enabled=prev_enabled, gamma=prev_gamma)
 
 
-def _drain(tiny, n=3, prompt=40):
+def _drain(tiny, n=3, prompt=40, max_batch=2, new=12):
     """One ``run_all`` of ``n`` streamed requests, speculation on."""
     params, cfg = tiny
     b = ContinuousBatcher(
-        params, cfg, max_batch=2, capacity_tokens=2048, max_new_cap=16,
-        eos_ids=[], greedy=True,
+        params, cfg, max_batch=max_batch, capacity_tokens=2048,
+        max_new_cap=max(16, new), eos_ids=[], greedy=True,
     )
     for i in range(n):
         b.submit(
             SchedRequest(
                 req_id=i,
                 prompt_ids=list(range(5, 5 + prompt + i)),
-                max_new_tokens=12,
+                max_new_tokens=new,
                 on_tokens=lambda ids: True,
             )
         )
     return b.run_all()
+
+
+def _profile(tiny, path, python_tracer_level=0, **drain):
+    """A drain under the profiler (compiled outside it): its host events."""
+    from perfbench.reduce import find_xplane, load_xplane
+
+    _drain(tiny, **drain)
+    obs.reset_stats()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = python_tracer_level
+    jax.profiler.start_trace(str(path), profiler_options=opts)
+    try:
+        _drain(tiny, **drain)
+    finally:
+        jax.profiler.stop_trace()
+    return load_xplane(find_xplane(str(path))).host
 
 
 def _phase_sums() -> dict:
@@ -100,11 +117,14 @@ class TestDriveLoopPhases:
             if k.startswith("advspec_phase_seconds{") and v["count"]
         }
         # a closed vocabulary: nothing observed outside it, and every
-        # phase of the speculative streamed path observed
+        # phase of the speculative streamed path observed; what nests in
+        # those is an admission's and a completion's parts
         assert seen <= set(obs.PHASES)
-        assert seen == set(DRIVE)
+        assert set(DRIVE) <= seen <= set(DRIVE) | NESTED
         sums = _phase_sums()
         iteration, n_iter = sums["drive.iteration"]
+        # drive.iteration's own children: the drive.* phases, not what
+        # nests in them
         inner = sum(sums[p][0] for p in INNER)
         assert n_iter >= 3
         assert inner <= iteration
@@ -119,6 +139,50 @@ class TestDriveLoopPhases:
         assert all(r.queue_wait_s >= 0.0 for r in results)
         # the third request waited for a slot: its wait holds a prefill
         assert results[2].queue_wait_s > results[0].queue_wait_s
+
+    def test_sub_phases_take_no_more_than_their_parent(self, tiny, spec_on):
+        obs.configure(enabled=True)
+        obs.reset_stats()
+        _drain(tiny, prompt=150)
+        sums = _phase_sums()
+        assert set(obs.PHASE_PARENT.values()) <= set(DRIVE)
+        for parent in set(obs.PHASE_PARENT.values()):
+            kids = [k for k, p in obs.PHASE_PARENT.items() if p == parent]
+            assert sum(sums[k][0] for k in kids) <= sums[parent][0], parent
+        # an admission's handoff and a completion, each once a request;
+        # the third prompt is a hit admitted over its pages: no chunk
+        for name in ("handoff.launch", "handoff.sync", "handoff.insert",
+                     "handoff.books", "handoff.deliver", "collect.fetch",
+                     "collect.deliver", "collect.release", "collect.books",
+                     "admit.lookup", "admit.evict"):
+            assert sums[name][1] == 3, name
+        assert sums["prefill.chunk"][1] == 2
+
+    def test_verify_only_iterations_open_no_sub_phase(
+        self, tiny, spec_on, monkeypatch
+    ):
+        """Between one request's admission and its completion the drive
+        loop runs verify steps only, and opens no phase of an admission
+        or a completion: the new spans cost the step's path nothing."""
+        opened: list[str] = []
+        real = obs.phase
+
+        def tapped(name):
+            opened.append(name)
+            return real(name)
+
+        obs.configure(enabled=True)
+        monkeypatch.setattr(obs, "phase", tapped)
+        _drain(tiny, n=1, max_batch=1, new=40)
+        iters: list[set] = []
+        for name in opened:
+            if name == "drive.iteration":
+                iters.append(set())
+            elif iters:
+                iters[-1].add(name)
+        touched = [i for i, names in enumerate(iters) if names & NESTED]
+        assert len(iters) >= 10
+        assert touched == [0, len(iters) - 1], touched
 
     def test_unknown_phase_is_refused(self):
         obs.configure(enabled=True)
@@ -145,27 +209,28 @@ class TestDriveLoopPhases:
     def test_phases_sit_on_the_profilers_clock(self, tiny, spec_on, tmp_path):
         """The same run under the profiler: the annotation and the
         histogram take the same two clock readings, so the profile and
-        the phase table agree, and every phase lies in an iteration."""
-        from perfbench.reduce import find_xplane, load_xplane
-
+        the phase table agree, every phase lies in an iteration, and
+        every sub-phase in its parent on the same thread."""
         obs.configure(enabled=True)
-        _drain(tiny)  # compile outside the traced run
-        obs.reset_stats()
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
-        try:
-            _drain(tiny)
-        finally:
-            jax.profiler.stop_trace()
+        host = _profile(tiny, tmp_path)
         sums = _phase_sums()
-        trace = load_xplane(find_xplane(str(tmp_path)))
-        events = [e for e in trace.host if e[1].startswith("advspec.drive.")]
         by_name: dict = {}
-        for thread, name, start, dur in events:
-            by_name.setdefault(name[len("advspec."):], []).append(
-                (thread, start, start + dur)
-            )
+        for thread, name, start, dur in host:
+            if name.startswith("advspec."):
+                by_name.setdefault(name[len("advspec."):], []).append(
+                    (thread, start, start + dur)
+                )
+        nested = set(by_name) & NESTED
+        assert nested
+        for name in nested:
+            assert len(by_name[name]) == sums[name][1], name
+            parents = by_name[obs.PHASE_PARENT[name]]
+            for thread, a, b in by_name[name]:
+                assert any(
+                    t == thread and a0 <= a and b <= b0
+                    for t, a0, b0 in parents
+                ), f"{name} outside every {obs.PHASE_PARENT[name]}"
+        by_name = {k: v for k, v in by_name.items() if k in DRIVE}
         assert set(by_name) == set(DRIVE)
         iterations = by_name["drive.iteration"]
         assert len(iterations) == sums["drive.iteration"][1]
@@ -187,6 +252,58 @@ class TestDriveLoopPhases:
         assert traced_inner == pytest.approx(
             sum(sums[n][0] for n in INNER), rel=0.05
         )
+
+
+# The host work the new spans are named for, by the Python tracer's frame
+# (file, function), and the spans it may run in: one named for it, or, on
+# a verify step's own path, the drive.* phase it ran in before.
+NAMED_FOR = {
+    ("prefix_cache.py", "insert"): {
+        "handoff.insert", "collect.books", "admit.promote",
+    },
+    ("prefix_cache.py", "lookup_tiered"): {"admit.lookup"},
+    ("scheduler.py", "_gauge_pool"): {"handoff.books", "collect.books"},
+    ("scheduler.py", "_release_slot"): {"collect.release"},
+    ("scheduler.py", "_fetch_page_kv"): {"admit.evict", "handoff.insert"},
+    ("scheduler.py", "_deliver_stream"): {
+        "handoff.deliver", "collect.deliver", "drive.stream",
+    },
+}
+
+
+def test_host_work_lies_in_the_span_named_for_it(tiny, spec_on, tmp_path):
+    """Under the Python tracer (the benchmark's traced run) every call of
+    the functions whose idle time the profile used to name by its frame
+    lies inside the span named for it: the spans can take the frames'
+    place."""
+    from adversarial_spec_tpu.engine import kvtier
+
+    obs.configure(enabled=True)
+    # the host tier, as the benchmark's cells run: the tiered lookup, and
+    # the demotion's read warmed at the first handoff
+    kvtier.configure(enabled=True, host_mb=16, store_dir="")
+    host = _profile(tiny, tmp_path, python_tracer_level=1, prompt=150)
+    spans = [
+        (t, n[len("advspec."):], a, a + d)
+        for t, n, a, d in host
+        if n.startswith("advspec.")
+    ]
+    frame = re.compile(r"(\w+\.py):\d+ (\w+)$")
+    ran = set()
+    for thread, name, a, d in host:
+        m = frame.search(name)
+        if m is None or m.groups() not in NAMED_FOR:
+            continue
+        around = [
+            (s1 - s0, span)
+            for t, span, s0, s1 in spans
+            if t == thread and s0 <= a and a + d <= s1
+        ]
+        assert around, f"{name}: in no span"
+        innermost = min(around)[1]
+        assert innermost in NAMED_FOR[m.groups()], (name, innermost)
+        ran.add(m.groups())
+    assert ran == set(NAMED_FOR)
 
 
 # -- the names the benchmark's patterns lean on ------------------------------

@@ -44,6 +44,14 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
+from perfbench.spans import (  # noqa: E402
+    IDLE_PARTS,
+    NO_SPAN as NO_PHASE,
+    PREFIX as PHASE_PREFIX,
+    SHARES,
+    idle_by_phase,
+    share,
+)
 from tools.obs_dump import load_events  # noqa: E402
 
 # |request_wall - (prefill + decode)| must stay within
@@ -294,83 +302,7 @@ def membership_changes(events: list[dict]) -> str:
 
 
 # -- device idle time by host phase (--xplane) ------------------------------
-
-PHASE_PREFIX = "advspec."
-NO_PHASE = "(no phase)"
-
-
-def innermost_segments(phases: list[tuple]) -> list[tuple]:
-    """Flatten nested ``(name, start, end)`` phase events into disjoint,
-    sorted ``(start, end, name)`` segments, each named by the innermost
-    phase open over it (phases of one thread nest; across threads the
-    later-opened one wins, which is still the most specific answer)."""
-    cuts = sorted({t for _, a, b in phases for t in (a, b)})
-    events = sorted(phases, key=lambda p: (p[1], -p[2]))
-    out: list[tuple] = []
-    stack: list[tuple] = []  # open phases, outermost first
-    i = 0
-    for lo, hi in zip(cuts, cuts[1:]):
-        while i < len(events) and events[i][1] <= lo:
-            stack.append(events[i])
-            i += 1
-        stack = [p for p in stack if p[2] > lo]
-        if stack:
-            name = stack[-1][0]
-            if out and out[-1][2] == name and out[-1][1] == lo:
-                out[-1] = (out[-1][0], hi, name)
-            else:
-                out.append((lo, hi, name))
-    return out
-
-
-IDLE_PARTS = ("head", "between", "tail", "whole")
-
-
-def idle_by_phase(busy: list[tuple], phases: list[tuple]) -> dict:
-    """Seconds of device idle time (the gaps between the sorted,
-    disjoint ``busy`` intervals, in ns) under each innermost host phase
-    and under no phase at all, split by where in the phase's stretch the
-    gap lies: at its ``head`` (the device had nothing yet: the host was
-    still enqueueing, or launch latency), ``between`` two operations
-    inside it (eager dispatches), at its ``tail`` (the device was done
-    and the host had not moved on: completion latency), or over the
-    ``whole`` stretch (no operation ran in it)."""
-    gaps = [(b0, a1) for (_, b0), (a1, _) in zip(busy, busy[1:]) if a1 > b0]
-    segs = innermost_segments(phases)
-    idle: dict[str, list[float]] = {}
-
-    def add(name: str, part: str, ns: float) -> None:
-        row = idle.setdefault(name, [0.0] * len(IDLE_PARTS))
-        row[IDLE_PARTS.index(part)] += ns / 1e9
-
-    j = 0
-    for a, b in gaps:
-        covered = 0.0
-        while j < len(segs) and segs[j][1] <= a:
-            j += 1
-        k = j
-        while k < len(segs) and segs[k][0] < b:
-            lo, hi, name = segs[k]
-            ns = min(hi, b) - max(lo, a)
-            if ns > 0:
-                head, tail = a <= lo, b >= hi
-                add(
-                    name,
-                    "whole" if head and tail
-                    else "head" if head
-                    else "tail" if tail
-                    else "between",
-                    ns,
-                )
-                covered += ns
-            k += 1
-        rest = (b - a) - covered
-        if rest > 0:
-            add(NO_PHASE, "whole", rest)
-    return {
-        name: {**dict(zip(IDLE_PARTS, row)), "idle_s": sum(row)}
-        for name, row in idle.items()
-    }
+# The table and its four shares are perfbench/spans.py's.
 
 
 def _union(intervals: list[tuple]) -> list[tuple]:
@@ -425,11 +357,15 @@ def xplane_report(path: str) -> dict | None:
     for name, a, b in phases:
         wall[name] = wall.get(name, 0.0) + (b - a) / 1e9
         count[name] = count.get(name, 0) + 1
+    by_phase = idle_by_phase(busy, phases)
     return {
         "window_s": window_s,
         "busy_s": busy_s,
         "idle_s": window_s - busy_s,
-        "idle_by_phase_s": idle_by_phase(busy, phases),
+        "idle_by_phase_s": by_phase,
+        "idle_shares": {
+            name: share(by_phase, rx, window_s) for name, rx in SHARES.items()
+        },
         "phase_wall_s": wall,
         "phase_count": count,
     }
@@ -457,6 +393,8 @@ def render_xplane(rep: dict) -> str:
             + (f"{wall:>9.3f}" if wall is not None else f"{'':>9}")
             + f"{rep['phase_count'].get(name, 0):>6}"
         )
+    lines.append("")
+    lines += [f"{name:<24}{v:>8.2f}%" for name, v in rep["idle_shares"].items()]
     return "\n".join(lines)
 
 
