@@ -4,8 +4,9 @@ Host-side bookkeeping (free list, per-sequence page tables) stays in numpy
 — it is O(pages) integer work with data-dependent control flow that has no
 business inside an XLA program — while the page pool itself lives on
 device as two dense arrays [n_pages, Hkv, page_size, D] per layer group,
-written with vectorized scatters and read by the paged Pallas kernel
-(ops/pallas_paged.py).
+written with vectorized scatters, pages copied whole and, for a span
+inside a step program, by DMA (ops/kv_write.py), and read by the paged
+Pallas kernel (ops/pallas_paged.py).
 
 Sizing: a debate round's opponents share the pool; ``n_pages`` bounds
 total resident tokens across all rows, not per-row length — the property
@@ -376,8 +377,8 @@ def _row_index(layers, pool_array, page_ids, offsets):
     Leaving the layer or head axis as a slice widens the window per
     token, and XLA:TPU then re-lays the WHOLE pool out around the
     operation: a pool-sized temporary a pool sized to the chip cannot
-    pay (models/transformer.py:forward_paged_decode scatters the same
-    way for the same reason).
+    pay (ops/kv_write.py ``write_span`` scatters the same way for the
+    same reason).
     """
     import jax.numpy as jnp
 
@@ -443,8 +444,11 @@ def write_tokens(
     vs_new: jnp.ndarray | None = None,
     layer=None,  # a (traced) layer: the arrays above are that layer's
     # alone, without the L axis
+    use_pallas: bool = False,  # with ``layer``: the Pallas kernels on,
+    # one device (ops/kv_write.py ``span_write_kernel``)
+    interpret: bool = False,
 ) -> dict[str, jnp.ndarray]:
-    """Scatter freshly computed K/V into their pages (vectorized).
+    """Write freshly computed K/V into their pages.
 
     The way a prompt's K/V reach the pool, whichever way it is admitted:
     a dense admission cache at its handoff and a promoted tier block go
@@ -457,7 +461,8 @@ def write_tokens(
     and what it leaves of a page at either end token by token: the same
     bytes as writing every token by itself. The handoff of a cold
     5.3k-token prompt is 65 pages and a 60-token tail. A traced
-    ``layer`` writes token by token.
+    ``layer`` goes through ``ops/kv_write.py`` ``write_span``, the verify
+    step's write: by DMA where the pool allows it, else token by token.
 
     The pool is DONATED and updated in place — callers rebind it
     (``pool = write_tokens(pool, ...)``). Dispatched eagerly, every
@@ -473,9 +478,26 @@ def write_tokens(
                 "quantized pool requires ks_new/vs_new scale slices"
             )
         new.update(ks=ks_new, vs=vs_new)
-    write, _ = _pool_jits()
     if layer is not None:
-        return write(pool, new, page_ids, offsets, layer)
+        import jax.numpy as jnp
+
+        from adversarial_spec_tpu.ops import kv_write
+
+        return kv_write.write_span(
+            pool,
+            layer,
+            {  # token-major [B·S, Hkv, *]
+                name: jnp.swapaxes(x, 1, 2).reshape(
+                    (-1,) + x.shape[1:2] + x.shape[3:]
+                )
+                for name, x in new.items()
+            },
+            page_ids,
+            offsets,
+            use_pallas=use_pallas,
+            interpret=interpret,
+        )
+    write, _ = _pool_jits()
     page_ids, offsets = np.asarray(page_ids), np.asarray(offsets)
     start, pages = _whole_pages(page_ids, offsets, pool["k"].shape[3])
     if obs_mod.config().enabled:
@@ -484,14 +506,13 @@ def write_tokens(
             int(pages.size) * pool["k"].shape[3]
         )
     if not pages.size:
-        return write(pool, new, page_ids, offsets, None)
+        return write(pool, new, page_ids, offsets)
     end = start + pages.shape[1] * pool["k"].shape[3]
     return write(
         pool,
         new,
         np.concatenate([page_ids[:, :start], page_ids[:, end:]], axis=1),
         np.concatenate([offsets[:, :start], offsets[:, end:]], axis=1),
-        None,
         pages,
         start,
     )
@@ -561,7 +582,7 @@ def _pool_jits():
             for name, x in new.items()
         }
 
-    def write(pool, new, page_ids, offsets, layer=None, pages=None, start=0):
+    def write(pool, new, page_ids, offsets, pages=None, start=0):
         # One scatter per layer: a single scatter over every (layer,
         # token, head) row compiles in time proportional to the token
         # count on XLA:TPU (25 s at 5k tokens against 0.2 s this way).
@@ -577,13 +598,11 @@ def _pool_jits():
                 **{
                     name: pool[name]
                     .at[_row_index(l, pool[name], page_ids, offsets)]
-                    .set(new[name][l] if layer is None else new[name])
+                    .set(new[name][l])
                     for name in new
                 },
             }
 
-        if layer is not None:
-            return one_layer(layer, pool)
         n_layers = pool["k"].shape[0]
         return jax.lax.fori_loop(0, n_layers, one_layer, pool)
 

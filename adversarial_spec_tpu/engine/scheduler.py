@@ -105,7 +105,7 @@ from adversarial_spec_tpu.engine.kvcache import (
 from adversarial_spec_tpu.engine.sampling import sample_tokens
 from adversarial_spec_tpu.models import moe as moe_mod
 from adversarial_spec_tpu.models.config import ModelConfig
-from adversarial_spec_tpu.ops import quant
+from adversarial_spec_tpu.ops import kv_write, quant
 from adversarial_spec_tpu.models.config import (
     refuse_beside_state_space,
     refuse_unwired,
@@ -1017,13 +1017,14 @@ def snapshot_state(cache):
     return {k: cache[k][:, 0] for k in STATE_LEAVES}
 
 
-def _write_span_kv(pool, layer, new_kv, page_ids, offsets):
+def _write_span_kv(pool, layer, new_kv, page_ids, offsets, **how):
     """A layer's K/V of an admission's span into their pages, through the
     function every admission's K/V reach the pool by (``write_tokens``:
-    where the benchmark plants its ``state_unchanged`` fault)."""
+    where the benchmark plants its ``state_unchanged`` fault); ``how``:
+    ``forward_paged_decode``'s ``use_pallas`` and ``interpret``."""
     return write_tokens(
         pool, new_kv["k"], new_kv["v"], page_ids, offsets,
-        ks_new=new_kv.get("ks"), vs_new=new_kv.get("vs"), layer=layer,
+        ks_new=new_kv.get("ks"), vs_new=new_kv.get("vs"), layer=layer, **how,
     )
 
 
@@ -2375,6 +2376,7 @@ class ContinuousBatcher:
                     fused=False, prefill_only=True
                 )
                 prefix_mod.stats.record_prefill(n_real, 0)
+                self._count_span_write(n_real)
                 if obs_mod.config().enabled:
                     obs_mod.retrace.observe(
                         "paged_admission",
@@ -3938,6 +3940,18 @@ class ContinuousBatcher:
             out_np = np.asarray(out_ref)
             self._stream_entry(emitted_np, out_np, live_slots)
 
+    def _count_span_write(self, n_tokens: int) -> None:
+        """Book the ``n_tokens`` span positions of live rows whose K/V a
+        verify step or paged admission just dispatched writes into the
+        pool, and whether the program writes them by DMA: the predicate
+        its trace took (``forward_paged_decode``; a batcher is one
+        device)."""
+        if obs_mod.config().enabled:
+            obs_mod.hot.kv_span_written_tokens.inc(n_tokens)
+            # graftlint: disable=GL-SYNC -- the predicate reads the pool's shapes and dtypes alone, never a device value
+            if kv_write.span_write_kernel(self.pool, self._use_pallas):
+                obs_mod.hot.kv_span_kernel_tokens.inc(n_tokens)
+
     def _dispatch_step(self, live: list, alloc_len, adm, chunk_len: int):
         """Enqueue one step for the rows in ``live``; ``adm`` is the
         admission whose ``chunk_len`` prompt tokens ride it, or None. A
@@ -3952,6 +3966,7 @@ class ContinuousBatcher:
             return
         slots = tuple((s, self._slot_gen[s]) for s in live)
         counts = self._dispatch_spec(alloc_len, adm, chunk_len)
+        self._count_span_write(len(live) * (self.gamma + 1))
         try:
             # Start the copy at the enqueue: the fetch, an iteration
             # later when a successor runs ahead, should find it landed.

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from adversarial_spec_tpu.models import moe
 from adversarial_spec_tpu.models.config import ModelConfig
+from adversarial_spec_tpu.ops import kv_write
 from adversarial_spec_tpu.ops import ssm as ssm_ops
 from adversarial_spec_tpu.ops.quant import (
     StackedLayer,
@@ -1431,8 +1432,8 @@ def forward_paged_decode(
     logits_at: jnp.ndarray | None = None,  # [B] int32: the one span
     # position a row's logits are wanted for (None: every position)
     write_kv=None,  # (pool, layer_id, {name: [B, Hkv, S, *]}, write_page,
-    # write_off) -> pool: the caller's own way of putting a layer's new
-    # K/V into their pages (None: the scatter below)
+    # write_off, use_pallas=, interpret=) -> pool: the caller's own way of
+    # putting a layer's new K/V into their pages (None: ``write_span``)
     state_rows: jnp.ndarray | None = None,  # [B] int32: the row of the
     # pool's recurrent state each span row owns (None: row b is b's)
     state_keep: jnp.ndarray | None = None,  # [B] int32: the state after
@@ -1527,37 +1528,28 @@ def forward_paged_decode(
 
     flat_page = write_page.reshape(-1)
     flat_off = write_off.reshape(-1)
-    heads = jnp.arange(cfg.kv_layout[0])
     scanned_layers, indexed, experts = _split_layers(
         params["layers"], fused_mm, B * S, x.dtype
     )
+    # A span's K/V go into their pages by DMA where the pool allows it
+    # (ops/kv_write.py ``span_write_kernel``), else by XLA's scatter
+    # (rejected-draft targets are the trash page, never read).
+    write_kw = dict(
+        use_pallas=use_pallas and single_device, interpret=pallas_interpret
+    )
 
     def scatter(pool, layer_id, new_kv):
-        # Pages are heads-major [L, n_pages, Hkv, page_size, D]. The
-        # scatter addresses (layer, page, head, offset) and moves whole
-        # D-rows — contiguous in that layout, so XLA keeps the pool in
-        # the layout the attention kernels require. (A [Hkv, D] update
-        # window per token makes it re-lay the WHOLE pool out
-        # token-major and convert it back for every kernel call.) One
-        # scatter per pool array per layer regardless of span width
-        # (rejected-draft targets are the trash page, never read).
         if write_kv is not None:
             heads_major = {
                 name: jnp.swapaxes(val.reshape(B, S, *val.shape[1:]), 1, 2)
                 for name, val in new_kv.items()
             }
-            return write_kv(pool, layer_id, heads_major, write_page, write_off)
-        return {
-            **pool,
-            **{
-                name: pool[name]
-                .at[
-                    layer_id, flat_page[:, None], heads[None, :], flat_off[:, None]
-                ]
-                .set(val)
-                for name, val in new_kv.items()
-            },
-        }
+            return write_kv(
+                pool, layer_id, heads_major, write_page, write_off, **write_kw
+            )
+        return kv_write.write_span(
+            pool, layer_id, new_kv, flat_page, flat_off, **write_kw
+        )
 
     def layer_body(carry, scanned):
         x, pool = carry

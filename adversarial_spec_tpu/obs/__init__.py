@@ -277,6 +277,8 @@ class HotMetrics:
         "kv_held_bytes",
         "kv_written_tokens",
         "kv_page_written_tokens",
+        "kv_span_written_tokens",
+        "kv_span_kernel_tokens",
         "_attn_kv",
         "_moe",
         "_m",
@@ -523,6 +525,19 @@ class HotMetrics:
             "advspec_kv_page_written_tokens_total",
             help="of those, the tokens of pages written whole, one "
             "copy a page of every layer and head",
+        )
+        # What the verify steps and paged admissions wrote into the pool
+        # inside their programs, counted where each is dispatched
+        # (engine/scheduler.py ``_count_span_write``).
+        self.kv_span_written_tokens = m.counter(
+            "advspec_kv_span_written_tokens_total",
+            help="span positions whose K/V a verify step or paged "
+            "admission program writes into the page pool",
+        )
+        self.kv_span_kernel_tokens = m.counter(
+            "advspec_kv_span_kernel_tokens_total",
+            help="of those, the positions written by DMA "
+            "(ops/kv_write.py paged_kv_write), not by XLA's scatter",
         )
         self._attn_kv: dict = {}
         self._moe: dict = {}

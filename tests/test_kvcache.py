@@ -254,3 +254,178 @@ class TestPageRows:
         w0, p0 = written.value, paged.value
         one_layer(pool, jnp.asarray(new["k"][0]), jnp.asarray(new["v"][0]), 1)
         assert (written.value, paged.value) == (w0, p0)
+
+
+# -- a span's K/V by DMA (ops/kv_write.py) ------------------------------------
+
+SPAN_PS = 32  # two bfloat16 tiles of 16 slots a page
+
+
+def _span_rows(rows, width, first, trash_tail=0, seed=0):
+    """(page_ids, offsets) [rows, width]: row b's positions first + b ..
+    first + b + width - 1 of a sequence of its own, through pages of its
+    own; the last ``trash_tail`` positions of a row aimed at page 0."""
+    perm = np.random.default_rng(seed).permutation(np.arange(1, 13))
+    pages = np.zeros((rows, width), np.int32)
+    offs = np.zeros((rows, width), np.int32)
+    for b in range(rows):
+        table = perm[3 * b : 3 * b + 3] if width < 2 * SPAN_PS else perm[b:]
+        q = first + b + np.arange(width)
+        pages[b] = table[q // SPAN_PS]
+        offs[b] = q % SPAN_PS
+        if trash_tail:
+            pages[b, -trash_tail:] = 0
+    return pages, offs
+
+
+# name: (page_ids, offsets, heads, k width, v width, what else the pool holds)
+SPAN_WRITES = {
+    "verify 4 x 9 across a page, drafts on the trash page": (
+        *_span_rows(4, 9, SPAN_PS - 4, trash_tail=3), 2, 128, 128, None
+    ),
+    "admission of 64 from mid-page": (*_span_rows(1, 64, 13), 2, 128, 128, None),
+    "admission of 128 from mid-page": (
+        *_span_rows(1, 128, 21, trash_tail=5), 2, 128, 128, None
+    ),
+    "latent: one head, k 128, v 256": (
+        *_span_rows(4, 9, 7, trash_tail=2), 1, 128, 256, None
+    ),
+    "64-wide heads on 128 lanes": (*_span_rows(4, 9, 3), 2, 64, 64, None),
+    "beside state rows": (*_span_rows(2, 9, 26), 2, 128, 128, "state"),
+}
+
+
+def _span_pool(heads, dk, dv, rest, rng):
+    L, n_pages = 3, 13
+    lanes = lambda d: -(-d // 128) * 128  # noqa: E731 (stored on whole lanes)
+    pool = {
+        "k": rng.normal(size=(L, n_pages, heads, SPAN_PS, lanes(dk))),
+        "v": rng.normal(size=(L, n_pages, heads, SPAN_PS, lanes(dv))),
+    }
+    pool = {name: jnp.asarray(x, jnp.bfloat16) for name, x in pool.items()}
+    if rest == "state":
+        pool["ssm"] = jnp.asarray(rng.normal(size=(L, 4, 5, 128)), jnp.float32)
+        pool["conv"] = jnp.asarray(rng.normal(size=(L, 4, 3, 128)), jnp.bfloat16)
+    return pool
+
+
+class TestSpanWrite:
+    @pytest.mark.parametrize("case", SPAN_WRITES)
+    def test_the_kernel_leaves_the_scatter_s_bytes(self, case):
+        """A span's K/V through ``paged_kv_write`` (interpret mode) leave
+        the pool byte for byte what XLA's scatter leaves, on every page
+        but the trash page: the verify step's form (token-major, in a
+        layer loop) and the admission's (``write_tokens`` with a
+        ``layer``, heads-major). The other leaves are untouched."""
+        import jax
+
+        from adversarial_spec_tpu.ops import kv_write
+
+        page_ids, offsets, heads, dk, dv, rest = SPAN_WRITES[case]
+        B, S = page_ids.shape
+        rng = np.random.default_rng(3)
+        pool = _span_pool(heads, dk, dv, rest, rng)
+        assert kv_write.span_write_kernel(pool, True)
+
+        def rows(d, width):  # heads-major [B, H, S, width], zero past d
+            x = rng.normal(size=(B, heads, S, width)).astype(np.float32)
+            x[..., d:] = 0
+            return jnp.asarray(x, jnp.bfloat16)
+
+        new = {"k": rows(dk, pool["k"].shape[-1]), "v": rows(dv, pool["v"].shape[-1])}
+        flat = {  # the verify step's token-major rows
+            name: jnp.swapaxes(x, 1, 2).reshape(B * S, heads, -1)
+            for name, x in new.items()
+        }
+
+        def admission(use_pallas):
+            return jax.jit(
+                lambda pool, k, v: write_tokens(
+                    pool, k, v, page_ids, offsets, layer=1,
+                    use_pallas=use_pallas, interpret=True,
+                )
+            )(pool, new["k"], new["v"])
+
+        def verify(use_pallas):
+            def step(pool, new):
+                return jax.lax.fori_loop(
+                    1, 3,
+                    lambda l, pool: kv_write.write_span(
+                        pool, l, new, page_ids, offsets,
+                        use_pallas=use_pallas, interpret=True,
+                    ),
+                    pool,
+                )
+
+            return jax.jit(step)(pool, flat)
+
+        for form in (admission, verify):
+            want, got = form(False), form(True)
+            assert set(got) == set(pool)
+            for name in pool:
+                w, g = np.asarray(want[name]), np.asarray(got[name])
+                if name in ("k", "v"):
+                    w, g = w[:, 1:], g[:, 1:]  # page 0: the trash page
+                    assert not (g == np.asarray(pool[name])[:, 1:]).all()
+                assert g.tobytes() == w.tobytes(), (form.__name__, name)
+
+    def test_int8_pools_and_meshes_take_the_scatter(self, monkeypatch):
+        """One predicate decides: a bfloat16 pool of whole-lane heads goes
+        through the kernel where the Pallas kernels are on; an int8 pool
+        (its [page, 1] scale pages) and a span over a mesh of two
+        devices go through XLA's scatter, in ``forward_paged_decode``
+        as in ``span_write_kernel``."""
+        import dataclasses
+
+        import jax
+
+        from adversarial_spec_tpu.models import transformer as T
+        from adversarial_spec_tpu.models.config import get_config
+        from adversarial_spec_tpu.ops import kv_write
+
+        cfg = dataclasses.replace(get_config("llama", "tiny"), head_dim=128)
+        params = T.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+        layout = PagedCacheLayout(
+            n_pages=5, page_size=SPAN_PS, n_layers=cfg.n_layers,
+            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        )
+        calls = []
+        real = kv_write.paged_kv_write
+
+        def spy(*a, **kw):
+            calls.append(1)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(kv_write, "paged_kv_write", spy)
+        B, S = 2, 3
+        q_pos = jnp.asarray([[5, 6, 7], [9, 10, 11]], jnp.int32)
+        args = (
+            jnp.ones((B, S), jnp.int32), q_pos,
+        )
+        rest = (
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32),  # page tables
+            jnp.asarray([[1] * S, [3] * S], jnp.int32),  # write pages
+            q_pos % SPAN_PS,
+            jnp.stack([jnp.zeros_like(q_pos), q_pos + 1], -1),
+            q_pos,
+        )
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("dp",))
+
+        def kernel_ran(kv_dtype, mesh):
+            pool = init_page_pool(layout, jnp.float32, kv_dtype=kv_dtype)
+            assert kv_write.span_write_kernel(
+                pool, mesh is None
+            ) == (kv_dtype == "" and mesh is None)
+            del calls[:]
+            T.forward_paged_decode(
+                params, cfg, *args, pool, *rest, use_pallas=True,
+                pallas_interpret=True, mesh=mesh,
+            )
+            return bool(calls)
+
+        assert kernel_ran("", None)
+        assert not kernel_ran("int8", None)
+        assert not kernel_ran("", mesh)
+        assert not kv_write.span_write_kernel(
+            init_page_pool(layout, jnp.float32), False
+        )

@@ -409,3 +409,85 @@ def test_a_write_that_leaves_the_pool_as_it_was_shows_in_a_cold_prompts_tokens(
     assert calls == [None]
     assert prefix_mod.stats.paged_admissions == 0
     assert got != want
+
+
+# -- a span's K/V by DMA (ops/kv_write.py) ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def wide_heads():
+    """A tiny dense model whose heads fill whole lanes (head dim 128): a
+    pool the span-write kernel addresses."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("llama", "tiny"), head_dim=128)
+    return T.init_params(jax.random.key(0), cfg, dtype=jnp.float32), cfg
+
+
+def _on_kernels(b):
+    """The Pallas kernels in interpret mode (the batcher turns them on for
+    a TPU alone): attention by the walk, a span's K/V by DMA."""
+    b._use_pallas = True
+    b._pallas_interpret = True
+    return b
+
+
+def test_the_span_write_kernel_serves_the_scatter_s_tokens(wide_heads):
+    """Greedy verify steps and two paged admissions serve the same tokens
+    with the kernels on (K/V written by ``paged_kv_write``) as through the
+    gather path (XLA's scatter), and the two counters say which path
+    wrote the span positions they count."""
+    from adversarial_spec_tpu import obs
+    from adversarial_spec_tpu.ops import kv_write
+
+    params, cfg = wide_heads
+    prompts, _ = _rounds("one_page")
+    written = obs.hot.kv_span_written_tokens
+    by_kernel = obs.hot.kv_span_kernel_tokens
+    was = obs.config().enabled
+    obs.configure(enabled=True)
+    served, counted = {}, {}
+    try:
+        for on in (False, True):
+            b = _batcher(params, cfg, speculative=True, prefix_cache=True)
+            if on:
+                _on_kernels(b)
+            assert kv_write.span_write_kernel(b.pool, b._use_pallas) == on
+            w0, k0 = written.value, by_kernel.value
+            served[on] = [_serve(b, p).tokens.tolist() for p in prompts]
+            counted[on] = (written.value - w0, by_kernel.value - k0)
+    finally:
+        obs.configure(enabled=was)
+    assert served[True] == served[False]
+    assert prefix_mod.stats.paged_admissions == 4
+    assert counted[False][0] > 0 and counted[False][1] == 0
+    assert counted[True][1] == counted[True][0] == counted[False][0]
+
+
+def test_a_write_that_leaves_the_pool_as_it_was_is_reached_with_the_kernel_on(
+    wide_heads, monkeypatch
+):
+    """With the kernels on, a hit's span still reaches its pages through
+    the scheduler's ``write_tokens`` (a ``layer`` and ``use_pallas``),
+    where the benchmark plants ``state_unchanged``: with that write a
+    no-op the hit decodes over a delta that was never written."""
+    params, cfg = wide_heads
+    prompt = _tokens(4 * PAGE)
+    b = _on_kernels(_batcher(params, cfg, speculative=False, prefix_cache=True))
+    want = _serve(b, prompt).tokens.tolist()
+    calls = []
+
+    def unchanged(pool, *a, **kw):
+        calls.append((kw.get("layer") is not None, kw.get("use_pallas")))
+        return pool
+
+    monkeypatch.setattr(sched_mod, "write_tokens", unchanged)
+    sched_mod.paged_admission.clear_cache()
+    try:
+        got = _serve(b, prompt).tokens.tolist()
+    finally:
+        monkeypatch.undo()
+        sched_mod.paged_admission.clear_cache()
+    assert prefix_mod.stats.paged_admissions == 1
+    assert calls and set(calls) == {(True, True)}
+    assert got != want

@@ -353,6 +353,7 @@ def test_every_pallas_call_is_named_after_its_entry_point():
         "paged_decode_attention_mq", "decode_attention",
         "decode_attention_mq", "matmul_int8_grouped",
         "paged_latent_attention_mq", "ssm_span_read", "ssm_span_update",
+        "paged_kv_write",
     }
 
 

@@ -327,6 +327,49 @@ def test_whole_decode_chunk_compiles_in_place(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
+@pytest.mark.parametrize(
+    "model,tokens",
+    [("mistral-7b", B * (GAMMA + 1)), ("qwen2-7b", 128), ("mistral-7b", 512),
+     ("latent", B * (GAMMA + 1))],
+)
+def test_span_write_compiles_in_place(chip, model, tokens):
+    """A span's K/V by DMA (``ops/kv_write.py``) at the cells' pools: a
+    verify step's 36 positions, admissions of 128 and 512, the latent
+    pool's one head of 128 and 256 lanes. Each tile the span touches is
+    cut out of the HBM pool (16 slots x every head; Mosaic refuses one
+    slot) and the donated pool is updated in place: nothing pool-sized
+    is copied, the temporaries are the tiles."""
+    from adversarial_spec_tpu.ops import kv_write
+
+    if model == "latent":
+        heads, dims, layers = 1, (128, 256), 9
+    else:
+        cfg = MODELS[model]
+        heads, dims, layers = cfg.n_kv_heads, (cfg.head_dim,) * 2, cfg.n_layers
+    pool = {
+        name: _shape(chip, (layers, N_PAGES, heads, PAGE, d), jnp.bfloat16)
+        for name, d in zip("kv", dims)
+    }
+    new = {
+        name: _shape(chip, (tokens, heads, d), jnp.bfloat16)
+        for name, d in zip("kv", dims)
+    }
+    where = _shape(chip, (tokens,), jnp.int32)
+    assert kv_write.span_write_kernel(pool, True)
+    compiled = (
+        jax.jit(
+            lambda pool, new, layer, pages, offs: kv_write.write_span(
+                pool, layer, new, pages, offs, use_pallas=True
+            ),
+            donate_argnums=0,
+        )
+        .lower(pool, new, _shape(chip, (), jnp.int32), where, where)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
 # -- Mistral-Small-4: the latent cache and the routed experts ---------------
 
 M4 = get_config(
