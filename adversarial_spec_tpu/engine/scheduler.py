@@ -116,6 +116,7 @@ from adversarial_spec_tpu.models.transformer import (
     forward_paged_decode,
     init_cache,
     init_recurrent_state,
+    min_weight_block_bytes,
     n_indexed_stacks,
 )
 from adversarial_spec_tpu.resilience import faults, injector
@@ -1458,12 +1459,16 @@ class ContinuousBatcher:
         # How the decode step reads its layer weights is fixed when it
         # is traced, so it is told once, here: the quantized stacks the
         # fused kernel reads by layer index instead of a slice's copy.
+        # So is the block plan of each of them, and with it the smallest
+        # weight block the step streams.
         if obs_mod.config().enabled:
+            step_rows = self.B * (self.gamma + 1 if self.speculative else 1)
             obs_mod.hot.qmm_indexed_stacks.set(
-                n_indexed_stacks(
-                    params,
-                    self._use_pallas_matmul,
-                    self.B * (self.gamma + 1 if self.speculative else 1),
+                n_indexed_stacks(params, self._use_pallas_matmul, step_rows)
+            )
+            obs_mod.hot.qmm_min_block_bytes.set(
+                min_weight_block_bytes(
+                    params, self._use_pallas_matmul, step_rows
                 )
             )
 

@@ -1352,6 +1352,18 @@ def n_indexed_stacks(params: Params, fused_mm: bool, rows: int) -> int:
     return len(indexed)
 
 
+def min_weight_block_bytes(params: Params, fused_mm: bool, rows: int) -> int:
+    """The smallest weight block, in bytes, that the fused dequant-matmul
+    streams a grid step among the stacks ``n_indexed_stacks`` counts, at
+    ``rows`` activation rows (0 where it counts none)."""
+    from adversarial_spec_tpu.ops.pallas_quant import weight_block_bytes
+
+    dtype = params["embed"].dtype
+    _, indexed, _ = _split_layers(params["layers"], fused_mm, rows, dtype)
+    x = jax.ShapeDtypeStruct((rows, 1), dtype)
+    return min((weight_block_bytes(x, w) for w in indexed.values()), default=0)
+
+
 def _routed_ffn_of(cfg, lp, experts, layer_id, use_pallas, interpret):
     """(callable h -> the routed part, the list it leaves the layer's
     routing in), or (None, None) for a dense FFN. ``use_pallas``: the

@@ -266,6 +266,7 @@ class HotMetrics:
         "batcher_distinct_prompts",
         "batcher_builds",
         "qmm_indexed_stacks",
+        "qmm_min_block_bytes",
         "moe_imbalance",
         "latent_tokens_read",
         "prefix_matched_tokens",
@@ -458,6 +459,11 @@ class HotMetrics:
             "advspec_qmm_indexed_stacks",
             help="quantized layer stacks the newest batcher's decode step "
             "reads by a prefetched layer index (none is sliced or copied)",
+        )
+        self.qmm_min_block_bytes = m.gauge(
+            "advspec_qmm_min_block_bytes",
+            help="smallest int8 weight block (bytes) the fused dequant-matmul "
+            "streams a grid step among those stacks, at the verify step's rows",
         )
         # Routed experts (models/moe.py; counted by the step programs and
         # fetched with their counts): how uneven a program's routing was,

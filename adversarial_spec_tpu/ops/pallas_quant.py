@@ -50,10 +50,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _SUBLANE = 8
+_LANE = 128
 # Per-step VMEM working-set budget for the whole-K fast path (one x
 # block + one weight block; Pallas double-buffers, scratch/out ride on
 # top). Conservative against the ~16 MiB TensorCore VMEM.
 _QMM_VMEM_BUDGET = 3 << 20
+# A call of at most this many rows reads each weight byte for a few
+# flops, far below the chip's ~240 flops a byte: the weight's stream
+# bounds it, and the stream runs near the HBM rate only in large blocks
+# (each grid step costs a fixed time besides its bytes). Such a call
+# widens its output block up to ``_QMM_BLOCK_BYTES`` of weight, the size
+# at which the whole-K plans already stream at ~90%, within
+# ``_QMM_VMEM_LIMIT`` of VMEM in all (Mosaic's default scope is 16 MiB).
+_QMM_WIDE_ROWS = 128
+_QMM_BLOCK_BYTES = 2 << 20
+_QMM_VMEM_LIMIT = 12 << 20
+# A widened plan keeps at least this many weight blocks a matrix: over
+# fewer, the pipeline's first fetch and last dot weigh more than the
+# steps saved (on a TPU v5e a 4-8 MB matrix ran up to 6% slower in 2-4
+# blocks of 2 MiB than in 8 of 0.5-1 MiB).
+_QMM_MIN_BLOCKS = 8
+# The int4 body shifts its packed block as int32 (``_qmm_int4_kernel``).
+_INT4_UNPACK_ITEMSIZE = 4
 
 
 def _pick_tile(dim: int, candidates: tuple[int, ...]) -> int | None:
@@ -66,13 +84,36 @@ def _pick_tile(dim: int, candidates: tuple[int, ...]) -> int | None:
     return None
 
 
+def _vmem_bytes(
+    bm: int, bk: int, bn: int, x_itemsize: int, w_itemsize: int,
+    unpack_itemsize: int,
+) -> int:
+    """VMEM a plan takes: two buffers each of the activation, weight and
+    scale blocks (a scale row pads to 8 sublanes), the weight block as
+    the kernel body unpacks it, the f32 accumulator, and two output
+    buffers (f32, the widest output)."""
+    return (
+        2 * bm * bk * x_itemsize
+        + 2 * bk * bn * w_itemsize
+        + 2 * _SUBLANE * bn * 4
+        + bk * bn * unpack_itemsize
+        + bm * bn * 4
+        + 2 * bm * bn * 4
+    )
+
+
 def _plan_blocks(
-    M: int, K: int, N: int, x_itemsize: int, w_itemsize: int
+    M: int, K: int, N: int, x_itemsize: int, w_itemsize: int,
+    unpack_itemsize: int = 0,
 ) -> tuple[int, int, int] | None:
     """(bm, bk, bn) for an [M, K] @ [K, N] blocked matmul, or None when
     no block assignment divides the weight dims (caller falls back to
     the XLA path). ``K`` is the *stored* contraction width (packed rows
-    for int4)."""
+    for int4); ``unpack_itemsize`` the bytes a stored weight element
+    takes while the kernel body unpacks it (int4's int32 shifts; 0 for
+    int8, whose upcast Mosaic does not hold as a block of its own). A
+    pure function of the shapes: the same call always gets the same
+    plan."""
     bn = _pick_tile(N, (512, 256, 128))
     if bn is None:
         if N > 2048:
@@ -89,6 +130,22 @@ def _plan_blocks(
             if K > 8192:
                 return None
             bk = K
+    if bm <= _QMM_WIDE_ROWS:
+        # Widen the output block of a weight-bound call to the widest
+        # lane multiple dividing N within the block, block-count and
+        # VMEM limits. bm, bk and the K order stay: every output column
+        # is summed exactly as before, so the result is bit for bit the
+        # same.
+        top = min(N, _QMM_BLOCK_BYTES // (bk * w_itemsize)) // _LANE * _LANE
+        for wide in range(top, bn, -_LANE):
+            if (
+                N % wide == 0
+                and (K // bk) * (N // wide) >= _QMM_MIN_BLOCKS
+                and _vmem_bytes(
+                    bm, bk, wide, x_itemsize, w_itemsize, unpack_itemsize
+                ) <= _QMM_VMEM_LIMIT
+            ):
+                return bm, bk, wide
     return bm, bk, bn
 
 
@@ -290,7 +347,9 @@ def matmul_int4(
     xe = x2[:, 0::2]  # rows 2k of the unpacked weight
     xo = x2[:, 1::2]  # rows 2k+1
     M = x2.shape[0]
-    plan = _plan_blocks(M, K2, N, 2 * x2.dtype.itemsize, 1)
+    plan = _plan_blocks(
+        M, K2, N, 2 * x2.dtype.itemsize, 1, _INT4_UNPACK_ITEMSIZE
+    )
     xe = _pad_rows(xe, plan[0])
     xo = _pad_rows(xo, plan[0])
     kernel, kw, operands = _blocked(
@@ -420,6 +479,22 @@ def matmul_int8_grouped(
     )
 
 
+def _leaf_plan(x, w) -> tuple[int, int, int] | None:
+    """The plan ``quant_matmul`` takes for activations ``x`` (a shape
+    and dtype suffice) against the quantized leaf ``w``, flat or a
+    layer stack, or None where none divides the weight."""
+    from adversarial_spec_tpu.ops.quant import is_quantized
+
+    if is_quantized(w):
+        q, halves, unpack = w["q"], 1, 0
+    else:  # two activation halves against the packed rows
+        q, halves, unpack = w["q4"], 2, _INT4_UNPACK_ITEMSIZE
+    K, N = q.shape[-2:]
+    return _plan_blocks(
+        math.prod(x.shape[:-1]), K, N, halves * x.dtype.itemsize, 1, unpack
+    )
+
+
 def fused_supported(x, w, layer=None) -> bool:
     """True iff the fused kernel covers this (activation, weight) pair:
     a quantized weight, flat or a ``[L, K, N]`` layer stack given WITH
@@ -442,11 +517,15 @@ def fused_supported(x, w, layer=None) -> bool:
     _check_stack(q, layer)
     if len(x.shape) < 1 or math.prod(x.shape) == 0:
         return False
-    K, N = q.shape[-2:]
-    return (
-        _plan_blocks(math.prod(x.shape[:-1]), K, N, x.dtype.itemsize, 1)
-        is not None
-    )
+    return _leaf_plan(x, w) is not None
+
+
+def weight_block_bytes(x, w) -> int:
+    """Bytes of the packed weight block the fused kernel streams a grid
+    step for ``x`` against the quantized leaf ``w`` that
+    ``fused_supported`` covers."""
+    _, bk, bn = _leaf_plan(x, w)
+    return bk * bn
 
 
 def quant_matmul(

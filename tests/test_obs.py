@@ -430,6 +430,31 @@ class TestSchedulerInstrumentation:
         )
         assert obs.metrics.snapshot()["advspec_qmm_indexed_stacks"] == want
 
+    @pytest.mark.parametrize(
+        "quantized,fused,want",
+        [(True, True, 256 * 128), (True, False, 0), (False, True, 0)],
+    )
+    def test_min_block_gauge_is_set_where_the_batcher_is_built(
+        self, tiny_model, quantized, fused, want
+    ):
+        """`advspec_qmm_min_block_bytes`: the smallest int8 weight block
+        the fused dequant-matmul streams among those stacks, at the
+        verify step's rows. The tiny model's matrices are whole blocks;
+        the smallest is `wk` / `wv`, [256, 128]. None with the kernel off
+        or nothing quantized."""
+        from adversarial_spec_tpu.engine.scheduler import ContinuousBatcher
+        from adversarial_spec_tpu.ops import quant
+
+        params, cfg = tiny_model
+        if quantized:
+            params = quant.quantize_params(params)
+        obs.reset_stats()
+        ContinuousBatcher(
+            params, cfg, max_batch=2, max_new_cap=8, chunk=4,
+            use_pallas_matmul=fused,
+        )
+        assert obs.metrics.snapshot()["advspec_qmm_min_block_bytes"] == want
+
     def test_retrace_watch_sees_scheduler_programs(self, tiny_model):
         params, cfg = tiny_model
         obs.reset_stats()

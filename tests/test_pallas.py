@@ -719,6 +719,212 @@ class TestFusedQuantMatmul:
         assert default.dtype == jnp.bfloat16
 
 
+def _narrow_plan(M, K, N, x_itemsize, w_itemsize):
+    """The block plan before a weight-bound call widened its output
+    block: ``bn`` the widest of (512, 256, 128) dividing N, ``bk`` the
+    whole contraction within the 3 MiB working set, else the widest of
+    (2048 .. 128) dividing K."""
+    from adversarial_spec_tpu.ops.pallas_quant import _pick_tile
+
+    bn = _pick_tile(N, (512, 256, 128))
+    if bn is None:
+        if N > 2048:
+            return None
+        bn = N
+    bm = min(256, -(-M // 8) * 8)
+    if bm * K * x_itemsize + K * bn * w_itemsize <= 3 << 20:
+        return bm, K, bn
+    bk = _pick_tile(K, (2048, 1024, 512, 256, 128))
+    if bk is None:
+        return None if K > 8192 else (bm, K, bn)
+    return bm, bk, bn
+
+
+# The configurations of the benchmark's cells, at their widths (depth,
+# experts and vocabulary as the cells cut them; the widths are what a
+# block plan reads).
+BENCH_CONFIGS = {
+    "mistral-7b-int8": (("mistral", "7b"), {}),
+    "qwen2-7b-int8": (("qwen2", "7b"), {}),
+    "mistral-small-4-int8-ep4": (
+        ("mistral4", "small-119b"),
+        dict(n_layers=9, experts_held=(0, 32), vocab_rows=32768),
+    ),
+    "granite-4.0-h-micro-bf16": (("granitemoehybrid", "h-micro"), {}),
+    "trinity-large-int8-ep8": (
+        ("afmoe", "trinity-large"),
+        dict(max_seq_len=32768, n_layers=9, experts_held=(0, 32),
+             vocab_rows=25024),
+    ),
+}
+
+
+def _int8_shapes(config):
+    """Shapes of a configuration's int8 checkpoint (no array is made)."""
+    from adversarial_spec_tpu.ops import quant
+
+    args, kw = BENCH_CONFIGS[config]
+    cfg = get_config(*args, **kw)
+    routed = cfg.ffn_kind == "routed"
+    return jax.eval_shape(
+        lambda: quant.quantize_params(
+            T.init_params(
+                jax.random.key(0), cfg, jnp.bfloat16,
+                expert_quant="int8" if routed else "",
+            )
+        )
+    )
+
+
+def _layer_matrices(config):
+    """{(K, N): leaf paths} of every quantized matrix of a configuration:
+    layer stacks, expert stacks and the head alike."""
+    mats = {}
+    leaves = jax.tree_util.tree_flatten_with_path(_int8_shapes(config))[0]
+    for path, leaf in leaves:
+        keys = [getattr(k, "key", getattr(k, "idx", None)) for k in path]
+        if keys[-1] == "q":
+            name = "/".join(str(k) for k in keys[:-1])
+            mats.setdefault(tuple(leaf.shape[-2:]), []).append(name)
+    return mats
+
+
+class TestBlockPlan:
+    """`_plan_blocks` widens the output block of a weight-bound call (at
+    most 128 rows) to stream weight blocks of up to 2 MiB, and keeps the
+    row and contraction blocks, so the sums run in the same order."""
+
+    # What the widening changes at the cells' widths (bk, bn), at 36 rows
+    # (a verify step) and 128 (an admission): the block grows to 1.75 MiB
+    # (Qwen2's w_down, from 256 KB) or 2 MiB (from 1 MiB). Mistral-Small-4's
+    # matrices (4-8 MB, 8 blocks of 0.5-1 MiB) keep theirs: 2 MiB blocks
+    # would leave them 2-4.
+    WIDENED = {
+        ("qwen2-7b-int8", (18944, 3584)): (512, 3584),
+        ("mistral-7b-int8", (14336, 4096)): (2048, 1024),
+        ("trinity-large-int8-ep8", (6144, 3072)): (2048, 1024),
+        ("trinity-large-int8-ep8", (12288, 3072)): (2048, 1024),
+    }
+
+    @pytest.mark.parametrize("M", [36, 128, 512])
+    @pytest.mark.parametrize("config", list(BENCH_CONFIGS))
+    def test_plan_of_every_layer_matrix(self, config, M):
+        """Every quantized matrix of the five benchmark configurations:
+        ``bm`` and ``bk`` as the narrow plan has them; ``bn`` divides N,
+        is never narrower, and where it grew it is a lane multiple whose
+        block is at most 2 MiB, that leaves the matrix at least 8 blocks
+        and whose footprint fits; at 512 rows (``bm`` 256, the MXU's
+        bound) the plan is the narrow one."""
+        from adversarial_spec_tpu.ops import pallas_quant as pq
+
+        for (K, N), names in _layer_matrices(config).items():
+            got, old = pq._plan_blocks(M, K, N, 2, 1), _narrow_plan(M, K, N, 2, 1)
+            assert (got is None) == (old is None), names
+            if got is None:
+                continue
+            bm, bk, bn = got
+            assert (bm, bk) == old[:2], names
+            assert N % bn == 0 and bn >= old[2], names
+            if M == 512:
+                assert got == old, names
+            if bn != old[2]:
+                assert bn % 128 == 0 and bk * bn <= 2 << 20, names
+                assert (K // bk) * (N // bn) >= 8, names
+                assert pq._vmem_bytes(bm, bk, bn, 2, 1, 0) <= 12 << 20, names
+                # of what the kernel reads in an int8 cell, only these
+                # (granite runs bfloat16; `wkv_b` is dequantized whole)
+                if not config.startswith("granite") and names != [
+                    "layers/wkv_b"
+                ]:
+                    assert (config, (K, N)) in self.WIDENED, names
+            if M < 512 and (config, (K, N)) in self.WIDENED:
+                assert (bk, bn) == self.WIDENED[config, (K, N)], names
+
+    @pytest.mark.parametrize(
+        "config,want",
+        [("qwen2-7b-int8", 1_835_008), ("mistral-7b-int8", 2_097_152)],
+    )
+    def test_smallest_block_a_verify_step_streams(self, config, want):
+        """`min_weight_block_bytes` (the gauge `advspec_qmm_min_block_bytes`)
+        at the dense cells' verify step of 4 x 9 rows: every layer stack
+        streams 1.75 MiB blocks in Qwen2 (w_down's were 256 KB) and 2 MiB
+        in Mistral (w_down's were 1 MiB)."""
+        params = _int8_shapes(config)
+        assert T.min_weight_block_bytes(params, True, 36) == want
+        assert T.min_weight_block_bytes(params, False, 36) == 0
+
+    def test_int4_counts_its_unpack(self):
+        """The int4 body shifts its packed block as int32, so a plan of
+        int4 counts four bytes a packed byte more: at an admission's 128
+        rows Mistral's packed w_down [7168, 4096] keeps 1 MiB blocks
+        where a footprint without the unpack would take 2 MiB."""
+        from adversarial_spec_tpu.ops import pallas_quant as pq
+
+        assert pq._plan_blocks(128, 7168, 4096, 4, 1, 4) == (128, 1024, 1024)
+        assert pq._plan_blocks(128, 7168, 4096, 4, 1) == (128, 1024, 2048)
+
+    # Split K (bk 2048 of 8192 stored rows at a verify step's 40 rows)
+    # and N 1,536: the narrow plan's bn 512 widens to 768, a block of
+    # 1.5 MiB (int4 packs 16,384 rows into the same 8,192).
+    K, N, ROWS = 8192, 1536, 40
+
+    def _weights(self, lead=()):
+        kq, ks = jax.random.split(jax.random.key(11), 2)
+        q = jax.random.randint(kq, lead + (self.K, self.N), -127, 128, jnp.int8)
+        scale = jax.random.uniform(
+            ks, lead + (1, self.N), jnp.float32, 1e-3, 1e-2
+        )
+        return q, scale
+
+    @pytest.mark.parametrize(
+        "call", ["flat", "stacked", "stacked_int4", "grouped"]
+    )
+    def test_widened_plan_is_the_narrow_plan_bit_for_bit(
+        self, monkeypatch, call
+    ):
+        """The same call under the widened plan and under the narrow one
+        (the planner swapped for the narrow rule, in a fresh trace) gives
+        the same bits: the flat and the stacked call, int8 and int4, and
+        the grouped kernel over two experts."""
+        from adversarial_spec_tpu.ops import pallas_quant as pq
+
+        int4 = call.endswith("int4")
+        x = jax.random.normal(
+            jax.random.key(12), (self.ROWS, self.K * (1 + int4)), jnp.bfloat16
+        )
+        xi = 2 * (1 + int4)
+        wide = pq._plan_blocks(self.ROWS, self.K, self.N, xi, 1, 4 * int4)
+        assert wide[2] > _narrow_plan(self.ROWS, self.K, self.N, xi, 1)[2]
+        if call == "grouped":
+            q, scale = self._weights((1, 2))
+            xg = jnp.concatenate([x, -x])  # two row tiles, one an expert
+            args = (
+                xg, q, scale, jnp.int32(0), jnp.array([1, 0], jnp.int32),
+                jnp.int32(2),
+            )
+            fn, kw = pq.matmul_int8_grouped, dict(bm=self.ROWS, interpret=True)
+        else:
+            lead = (2,) if call.startswith("stacked") else ()
+            q, scale = self._weights(lead)
+            args = (x, q, scale) + ((jnp.int32(1),) if lead else ())
+            fn = pq.matmul_int4 if int4 else pq.matmul_int8
+            kw = dict(interpret=True)
+        got = fn(*args, **kw)
+        plans = []
+
+        def narrow(M, K, N, xi, wi, *_):
+            plans.append(_narrow_plan(M, K, N, xi, wi))
+            return plans[-1]
+
+        monkeypatch.setattr(pq, "_plan_blocks", narrow)
+        # a new function, so that jax traces it anew and asks the planner
+        fresh = jax.jit(lambda *a: fn.__wrapped__(*a, **kw))
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(fresh(*args), np.float32)
+        )
+        assert plans and plans[-1][2] < wide[2]
+
+
 class TestPagedMqKernel:
     """paged_decode_attention_mq: the γ+1-position verify span over the
     PAGED pool — per-position causal bounds, one pass over the row's

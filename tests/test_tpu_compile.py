@@ -25,8 +25,11 @@ PAGE = 64  # ContinuousBatcher's page size
 B, GAMMA = 4, 8  # four opponents; default draft length
 N_PAGES = 257  # a 16k-token pool + the trash page
 TABLE_WIDTH = 32768 // PAGE  # the benchmark's entries ask max_seq_len 32768
-# decode rows, verify rows (B·(γ+1)), one admission prefill chunk
-ROW_COUNTS = {"decode": B, "verify": B * (GAMMA + 1), "prefill": 512}
+# decode rows, verify rows (B·(γ+1)), one admission prefill chunk, a
+# cached prompt's admission span of 128
+ROW_COUNTS = {
+    "decode": B, "verify": B * (GAMMA + 1), "prefill": 512, "admission": 128,
+}
 MODELS = {
     "mistral-7b": get_config("mistral", "7b"),
     "qwen2-7b": get_config("qwen2", "7b"),
@@ -219,7 +222,7 @@ def _layer_weight_shapes(cfg):
 
 
 @pytest.mark.parametrize("model", MODELS)
-@pytest.mark.parametrize("rows", ["decode", "verify"])
+@pytest.mark.parametrize("rows", ["decode", "verify", "admission"])
 @pytest.mark.parametrize("weight", ["wq", "wk", "w_up", "w_down"])
 def test_dequant_matmul_reads_a_layer_of_the_stack_in_place(
     chip, model, rows, weight
@@ -228,8 +231,12 @@ def test_dequant_matmul_reads_a_layer_of_the_stack_in_place(
     [L, K, N] stack and the layer a prefetched scalar. Every distinct
     matrix of a layer (whole-K and split-K plans, K 3584 .. 18944); no
     int8 value of the program but the stack itself: nothing is sliced
-    out of it."""
+    out of it. At these weight-bound row counts every matrix streams
+    blocks of at least 1.75 MiB (`w_down` by a widened output block)
+    and the plan fits the chip's VMEM."""
     import re
+
+    from adversarial_spec_tpu.ops.pallas_quant import weight_block_bytes
 
     cfg = MODELS[model]
     k, n = _layer_weight_shapes(cfg)[weight]
@@ -237,12 +244,13 @@ def test_dequant_matmul_reads_a_layer_of_the_stack_in_place(
         "q": _shape(chip, (cfg.n_layers, k, n), jnp.int8),
         "scale": _shape(chip, (cfg.n_layers, 1, n), jnp.float32),
     }
+    x = _shape(chip, (ROW_COUNTS[rows], k), jnp.bfloat16)
+    assert weight_block_bytes(x, w) >= 1_835_008
     text = _compiled_text(
         lambda x, w, layer: quant.matmul(
             x, quant.StackedLayer(w, layer), use_pallas=True
         ),
-        _shape(chip, (ROW_COUNTS[rows], k), jnp.bfloat16), w,
-        _shape(chip, (), jnp.int32),
+        x, w, _shape(chip, (), jnp.int32),
     )
     assert "tpu_custom_call" in text
     assert set(re.findall(r"s8\[([\d,]+)\]", text)) == {f"{cfg.n_layers},{k},{n}"}
@@ -408,12 +416,13 @@ def test_paged_latent_attention_compiles(chip, rows, span):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows", ["verify", "prefill"])
+@pytest.mark.parametrize("rows", ["verify", "prefill", "admission"])
 @pytest.mark.parametrize("weight", ["we_up", "we_down"])
 def test_grouped_dequant_matmul_compiles(chip, rows, weight):
     """The grouped int8 matmul over the held experts' stack of every
     layer ([9, 32, in, out]), at the row tiles the routed layer picks for
-    a verify span and for a prefill chunk."""
+    a verify span, for a prefill chunk and for an admission's span (8, 64
+    and 16 rows a tile)."""
     from adversarial_spec_tpu.models import moe
     from adversarial_spec_tpu.ops import pallas_quant
 

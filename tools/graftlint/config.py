@@ -178,8 +178,8 @@ class GraftlintConfig:
     # --- GL-RETRACE --------------------------------------------------
     # Functions that bound a Python scalar to a small fixed set of
     # values (pow2 buckets): their results may feed static args.
-    # _plan_blocks buckets fused quant-matmul block shapes to a fixed
-    # candidate table (ops/pallas_quant.py).
+    # _plan_blocks derives fused quant-matmul block shapes from the
+    # call's shapes alone (ops/pallas_quant.py): a pure function.
     retrace_bucketers: list[str] = field(
         default_factory=lambda: [
             "bucket_length",
